@@ -262,6 +262,14 @@ def initialize(
             tpu_evaluator.schema_mgr = schema_mgr
         else:
             tpu_evaluator = _make_evaluator(manager.rule_table, engine_conf, schema_mgr)
+        if getattr(tpu_evaluator, "use_jax", False):
+            # this process dispatches to the device: open it NOW (after any
+            # fork, never in prebuild) so a backend that cannot initialize
+            # fails the boot with its own error (jitcache.DeviceInitError).
+            # Only faults after a successful boot are the breaker's.
+            from .tpu import jitcache
+
+            jitcache.open_device()
 
         def _sub_evaluator(ep, _ev=tpu_evaluator) -> None:
             # re-lower the SHARED lowered table first; every later subscriber

@@ -9,6 +9,7 @@ from __future__ import annotations
 import argparse
 import json
 import os
+import signal
 import sys
 
 
@@ -71,6 +72,28 @@ def _build_server(core, config, http_addr=None, grpc_addr=None, reuse_port=False
     )
 
 
+def _device_fields() -> str:
+    """``key=value`` description of the device THIS process opened at boot
+    (bootstrap.initialize -> jitcache.open_device) and its compile cache;
+    empty in a process that owns no device (front end, tpu disabled)."""
+    from .tpu import jitcache
+
+    st = jitcache.status()
+    dev = st["device"]
+    if dev is None:
+        return ""
+    return (
+        f"platform={dev['platform']} device_kind={json.dumps(dev['device_kind'])} "
+        f"devices={dev['count']} xla_cache={st['dir'] if st['enabled'] else 'off'}"
+    )
+
+
+def _native_field() -> str:
+    from . import native
+
+    return f"native={'true' if native.get() is not None else 'false'}"
+
+
 def cmd_server(args: argparse.Namespace) -> int:
     from .bootstrap import initialize
     from .config import Config
@@ -91,12 +114,20 @@ def cmd_server(args: argparse.Namespace) -> int:
         if mx is not None:
             mx.add_source(core.service.metrics.snapshot)
 
+    def post_init_pool(core) -> None:
+        # pool children: the parent announced the ports before forking and
+        # holds no device; each device-owning child says what it opened
+        wire_metrics(core)
+        fields = _device_fields()
+        if fields:
+            print(f"cerbos-tpu device: pid={os.getpid()} {fields}", flush=True)
+
     n_frontends = int(getattr(args, "frontends", 0) or server_conf.get("frontends", 0) or 0)
     if n_frontends > 0:
         # multi-process front door: N GIL-light request processes feeding ONE
-        # shared batcher/evaluator process over the unix ticket queue. This is
-        # the topology that closes the served-RPS gap (docs/PERF.md round 7);
-        # --workers multiplies full PDPs instead and fragments device batches.
+        # shared batcher/evaluator process over the unix ticket queue — the
+        # device topology (one process per chip). --workers multiplies full
+        # PDPs instead: a CPU topology, its 2nd worker cannot open a held chip.
         from .server.workers import run_frontdoor_pool
 
         def announce_fd(http_addr: str, grpc_addr: str) -> None:
@@ -104,7 +135,7 @@ def cmd_server(args: argparse.Namespace) -> int:
             grpc_port = grpc_addr.rpartition(":")[2]
             print(
                 f"cerbos-tpu serving: http={http_port} grpc={grpc_port} "
-                f"frontends={n_frontends} batcher=1",
+                f"frontends={n_frontends} batcher=1 {_native_field()}",
                 flush=True,
             )
 
@@ -122,7 +153,7 @@ def cmd_server(args: argparse.Namespace) -> int:
             _build_server,
             announce=announce_fd,
             post_fork=post_fork_fd,
-            post_init=wire_metrics,
+            post_init=post_init_pool,
             pre_exit=pre_exit_fd,
         )
 
@@ -138,7 +169,8 @@ def cmd_server(args: argparse.Namespace) -> int:
             http_port = http_addr.rpartition(":")[2]
             grpc_port = grpc_addr.rpartition(":")[2]
             print(
-                f"cerbos-tpu serving: http={http_port} grpc={grpc_port} workers={n_workers}",
+                f"cerbos-tpu serving: http={http_port} grpc={grpc_port} workers={n_workers} "
+                f"{_native_field()}",
                 flush=True,
             )
 
@@ -156,7 +188,7 @@ def cmd_server(args: argparse.Namespace) -> int:
             _build_server,
             announce=announce,
             post_fork=post_fork,
-            post_init=wire_metrics,
+            post_init=post_init_pool,
             pre_exit=pre_exit,
         )
 
@@ -166,15 +198,19 @@ def cmd_server(args: argparse.Namespace) -> int:
     wire_metrics(core)
     server = _build_server(core, config)
     server.start()
-    from .tpu import jitcache
-
-    cache_status = jitcache.status()
-    xla_cache = cache_status["dir"] if cache_status["enabled"] else "off"
     print(
         f"cerbos-tpu serving: http={server.http_port} grpc={server.grpc_port} "
-        f"xla_cache={xla_cache}",
+        f"{_device_fields() or 'platform=none'} {_native_field()}",
         flush=True,
     )
+
+    def on_term(signum, frame):
+        # SIGTERM is how supervisors stop a replica: leave through the same
+        # drain as Ctrl-C (listeners, batcher, audit log, the device) and
+        # exit 0, instead of dying mid-flight with the chip held
+        raise KeyboardInterrupt
+
+    signal.signal(signal.SIGTERM, on_term)
     try:
         server.wait()
     except KeyboardInterrupt:

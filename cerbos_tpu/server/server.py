@@ -1142,8 +1142,6 @@ class Server:
             return web.json_response({"error": str(e)}, status=409)
         except profiler.ProfilerDisabled as e:
             return web.json_response({"error": str(e)}, status=403)
-        except profiler.ProfilerUnavailable as e:
-            return web.json_response({"error": str(e)}, status=501)
         return web.json_response(artifact)
 
     async def _h_swagger(self, request: web.Request) -> web.Response:

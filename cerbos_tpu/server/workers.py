@@ -30,6 +30,9 @@ from ..util import gctune
 
 _RESTART_LIMIT = 10  # per worker slot; a crash-looping config must not spin forever
 _RESTART_WINDOW_S = 60.0
+# child exit code for "could not open the device" (sysexits EX_CONFIG): at
+# first boot the pool shuts down instead of restarting into the same wall
+_EXIT_DEVICE_INIT = 78
 
 
 def resolve_listen_addr(addr: str) -> str:
@@ -96,8 +99,10 @@ class WorkerPool:
                 self.worker_main(idx, respawn)
                 os._exit(0)
             except BaseException as e:  # noqa: BLE001
+                from ..tpu.jitcache import DeviceInitError
+
                 print(f"worker {idx} crashed: {type(e).__name__}: {e}", file=sys.stderr, flush=True)
-                os._exit(1)
+                os._exit(_EXIT_DEVICE_INIT if isinstance(e, DeviceInitError) else 1)
         self._children[pid] = idx
 
     def run(self) -> int:
@@ -135,6 +140,19 @@ class WorkerPool:
             if self._shutdown:
                 continue
             code = os.waitstatus_to_exitcode(status)
+            if code == _EXIT_DEVICE_INIT and not self._restarts.get(idx):
+                # the device could not be opened at FIRST boot: restarting
+                # cannot help, and serving this slot from the oracle would
+                # hide the missing device. (A respawn that hits it goes
+                # through the normal restart budget: the chip may free up.)
+                self.log(
+                    f"worker {idx} could not open the device at boot; a chip belongs to "
+                    "ONE process — use --frontends N (one device-owning batcher) instead "
+                    "of --workers N (a CPU topology). Shutting pool down"
+                )
+                exit_code = 1
+                handle_term(signal.SIGTERM, None)
+                continue
             stamps = self._restarts.setdefault(idx, [])
             now = time.monotonic()
             stamps[:] = [t for t in stamps if now - t < _RESTART_WINDOW_S] + [now]

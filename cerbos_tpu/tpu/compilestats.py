@@ -1,7 +1,7 @@
 """Compile-economy telemetry: the other half of the device serving cost.
 
 The request path is lit end to end (spans, stage histograms, the flight
-recorder), but XLA compilation — ~35 s cold per distinct trace, the single
+recorder), but XLA compilation — seconds per distinct trace, the single
 largest latency event a replica can produce — was dark. This module wraps
 every ``jit_cache`` population site in :mod:`evaluator` plus the persistent
 cache in :mod:`jitcache` and answers, per process:
@@ -27,7 +27,6 @@ serving batcher, the pipelined path, and bench all account into one place.
 from __future__ import annotations
 
 import logging
-import sys
 import threading
 import time
 from collections import deque
@@ -179,6 +178,11 @@ class CompileStats:
             self._per_layout[layout_key] = self._per_layout.get(layout_key, 0) + 1
             card = len(self._layouts)
         self.m_cardinality.set(card)
+        # every compile is a flight event: /_cerbos/debug/flight then answers
+        # "which layout, how long, fresh or from the persistent cache"
+        flight_recorder().record_event(
+            "xla_compile", layout_key=layout_key, seconds=round(seconds, 4), source=source
+        )
         distinct = self.detector.observe(tk)
         if distinct is not None:
             self.m_storms.inc()
@@ -214,31 +218,18 @@ class CompileStats:
         self.m_variant_fallbacks.inc()
 
     def refresh_device_memory(self) -> None:
-        """Update the device memory gauges when a backend reports them.
+        """Update the device memory gauges (summed over this process's local
+        devices). Runs only in the process that owns the device
+        (``jitcache.open_device``): a front end or pre-fork parent that has
+        merely imported jax must never be the one to initialize a backend."""
+        from . import jitcache
 
-        Reads ``sys.modules`` instead of importing: telemetry must never be
-        the thing that initializes a jax backend."""
-        jax = sys.modules.get("jax")
-        if jax is None:
+        per_device = jitcache.device_memory()
+        if not per_device:
             return
-        try:
-            devs = jax.devices()
-        except Exception:
-            return
-        if not devs:
-            return
-        try:
-            stats = devs[0].memory_stats()
-        except Exception:
-            stats = None
-        if not stats:
-            return
-        if "bytes_in_use" in stats:
-            self.m_mem_in_use.set(float(stats["bytes_in_use"]))
-        if "bytes_limit" in stats:
-            self.m_mem_limit.set(float(stats["bytes_limit"]))
-        if "peak_bytes_in_use" in stats:
-            self.m_mem_peak.set(float(stats["peak_bytes_in_use"]))
+        self.m_mem_in_use.set(float(sum(d["bytes_in_use"] for d in per_device)))
+        self.m_mem_limit.set(float(sum(d["bytes_limit"] for d in per_device)))
+        self.m_mem_peak.set(float(sum(d["peak_bytes_in_use"] for d in per_device)))
 
     # -- reading -----------------------------------------------------------
 

@@ -33,10 +33,6 @@ class ProfilerBusy(RuntimeError):
     traces corrupt each other)."""
 
 
-class ProfilerUnavailable(RuntimeError):
-    """The jax profiler cannot run in this process (no jax, old jax)."""
-
-
 _lock = threading.Lock()
 _enabled = False
 _dir = ""
@@ -81,13 +77,14 @@ def _prune(base: str, keep: int) -> None:
 
 def _run_trace(path: str, seconds: float) -> None:
     """Separated for testability: the actual jax capture."""
-    try:
-        import jax  # noqa: F401  (availability probe: surface ImportError here)
-        from jax import profiler as jprof
-    except Exception as e:  # pragma: no cover - jax is a hard dep in practice
-        raise ProfilerUnavailable(f"jax profiler unavailable: {e}") from e
-    if not hasattr(jprof, "trace"):
-        raise ProfilerUnavailable("this jax has no profiler.trace")
+    from . import jitcache
+
+    if jitcache.device() is None:
+        # jax.profiler initializes a backend: a front end asking would try
+        # to open the chip its batcher holds
+        raise ProfilerDisabled("this process owns no device; only the device owner can trace it")
+    from jax import profiler as jprof
+
     with jprof.trace(path):
         time.sleep(seconds)
 
@@ -95,8 +92,8 @@ def _run_trace(path: str, seconds: float) -> None:
 def capture(seconds: float) -> dict:
     """Blocking capture; returns ``{path, seconds}`` for the response body.
 
-    Raises ProfilerDisabled / ProfilerBusy / ProfilerUnavailable /
-    ValueError (bad duration) — the HTTP handler maps each to a status.
+    Raises ProfilerDisabled / ProfilerBusy / ValueError (bad duration) —
+    the HTTP handler maps each to a status.
     """
     global _active, _seq
     if not _enabled:

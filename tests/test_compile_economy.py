@@ -383,6 +383,113 @@ class TestJitcacheStatus:
         assert st["warm_at_enable"] is False
 
 
+# jax's cache config is process-global and latched from the environment at
+# import, so placement is shown in fresh interpreters
+_PLACEMENT_PROBE = """
+import json, jax
+from cerbos_tpu.tpu import jitcache
+d = jitcache.enable()
+print(json.dumps({
+    "dir": d,
+    "jax_dir": jax.config.jax_compilation_cache_dir,
+    "external": jitcache.status()["external"],
+    "min_secs": jax.config.jax_persistent_cache_min_compile_time_secs,
+    "min_bytes": jax.config.jax_persistent_cache_min_entry_size_bytes,
+    "backend_initialized": bool(jax._src.xla_bridge._backends),
+}))
+"""
+
+
+def _placement(env_overrides: dict) -> dict:
+    import subprocess
+    import sys
+
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    env = {
+        k: v
+        for k, v in os.environ.items()
+        if k not in ("JAX_COMPILATION_CACHE_DIR", "CERBOS_TPU_XLA_CACHE_DIR")
+    }
+    env.update(env_overrides, PYTHONPATH=repo)
+    p = subprocess.run(
+        [sys.executable, "-c", _PLACEMENT_PROBE], capture_output=True, text=True, timeout=120, env=env
+    )
+    assert p.returncode == 0, p.stderr[-2000:]
+    return json.loads(p.stdout.strip().splitlines()[-1])
+
+
+class TestJitcachePlacement:
+    def test_env_set_uses_that_directory_and_sets_no_other(self, tmp_path):
+        got = _placement({"JAX_COMPILATION_CACHE_DIR": str(tmp_path / "from-env")})
+        assert got["dir"] == got["jax_dir"] == str(tmp_path / "from-env")
+        assert got["external"] is True
+        # the persist-everything thresholds apply on this route too:
+        # otherwise a sub-second compile is never written, and the next
+        # compile's unchanged entry count reads as a persistent load
+        assert got["min_secs"] == 0.0 and got["min_bytes"] == 0
+        assert got["backend_initialized"] is False
+
+    def test_unset_uses_the_checkout(self):
+        repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        got = _placement({})
+        assert got["dir"] == got["jax_dir"] == os.path.join(repo, ".xla_cache")
+        assert got["external"] is False
+        assert got["min_secs"] == 0.0 and got["min_bytes"] == 0
+        assert got["backend_initialized"] is False
+
+    def test_old_private_variable_is_gone(self, tmp_path):
+        repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        got = _placement({"CERBOS_TPU_XLA_CACHE_DIR": str(tmp_path / "ignored")})
+        assert got["dir"] == os.path.join(repo, ".xla_cache")
+        assert not (tmp_path / "ignored").exists()
+
+
+class TestDeviceOwnership:
+    def test_open_device_reports_what_jax_reports(self):
+        import jax
+
+        dev = jitcache.open_device()
+        assert dev["platform"] == jax.devices()[0].platform == "cpu"
+        assert dev["device_kind"] == jax.devices()[0].device_kind
+        assert dev["count"] == len(jax.devices())
+        assert dev["pid"] == os.getpid()
+        assert jitcache.status()["device"] == dev
+
+    def test_a_forked_child_does_not_inherit_ownership(self, monkeypatch):
+        jitcache.open_device()
+        # what a front end forked from a device-opening process would see
+        monkeypatch.setattr(jitcache.os, "getpid", lambda: -1)
+        assert jitcache.device() is None
+        assert jitcache.status()["device"] is None
+        assert jitcache.device_memory() == []
+
+    def test_memory_gauges_only_move_in_the_owner(self, monkeypatch):
+        cs = compilestats.stats()
+        monkeypatch.setattr(
+            jitcache, "device_memory",
+            lambda: [
+                {"id": 0, "bytes_in_use": 5, "peak_bytes_in_use": 7, "bytes_limit": 100},
+                {"id": 1, "bytes_in_use": 1, "peak_bytes_in_use": 2, "bytes_limit": 100},
+            ],
+        )
+        cs.refresh_device_memory()
+        assert (cs.m_mem_in_use.value, cs.m_mem_peak.value, cs.m_mem_limit.value) == (6.0, 9.0, 200.0)
+        monkeypatch.setattr(jitcache, "device_memory", lambda: [])  # not the owner
+        cs.refresh_device_memory()
+        assert cs.m_mem_in_use.value == 6.0  # untouched, and no backend was asked
+
+    def test_backend_failure_is_a_boot_failure(self, monkeypatch):
+        import jax
+
+        def boom():
+            raise RuntimeError("TPU is already in use by process 4242")
+
+        monkeypatch.setattr(jitcache, "_device", None)
+        monkeypatch.setattr(jax, "devices", boom)
+        with pytest.raises(jitcache.DeviceInitError, match="already in use by process 4242"):
+            jitcache.open_device()
+
+
 # -- profiler -----------------------------------------------------------------
 
 

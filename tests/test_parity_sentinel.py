@@ -398,6 +398,11 @@ class TestSingleBatcherTopology:
         try:
             outs = batcher.check([inp(i) for i in range(4)])
             assert len(outs) == 4  # requests answered (wrongly) — not lost
+            # the drain thread offers the batch to the sentinel AFTER it
+            # settles the waiter: wait for the offer before draining
+            deadline = time.monotonic() + 10.0
+            while sentinel.stats["sampled"] < 1 and time.monotonic() < deadline:
+                time.sleep(0.005)
             assert sentinel.drain(timeout=10.0)
             assert sentinel.stats["checks"] >= 1
             assert sentinel.stats["divergences"] >= 1

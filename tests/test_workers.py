@@ -313,3 +313,35 @@ def test_frontdoor_batcher_sigkill_midload_loses_zero_requests(frontdoor):
     assert new_pid is not None and new_pid != victim, "batcher was not respawned"
     status, body = _get(port, "/_cerbos/ready")
     assert status == 200
+
+
+# -- one process per chip ------------------------------------------------------
+
+_DEVICE_HELD_POOL = """
+import sys, time
+from cerbos_tpu.server.workers import WorkerPool
+from cerbos_tpu.tpu.jitcache import DeviceInitError
+
+def worker_main(idx, respawn):
+    if idx == 1:  # the 2nd full PDP finds the chip held by the 1st
+        raise DeviceInitError("device backend failed to initialize: TPU is already in use by pid 7")
+    time.sleep(120)
+
+sys.exit(WorkerPool(2, worker_main).run())
+"""
+
+
+def test_pool_fails_at_boot_when_a_worker_cannot_open_the_device():
+    """--workers N on the device path is N PDPs on one chip: the one that
+    cannot open it must take the pool down with a message naming
+    --frontends — not be restarted, and not serve from the oracle."""
+    env = dict(os.environ, PYTHONPATH=REPO)
+    t0 = time.monotonic()
+    p = subprocess.run(
+        [sys.executable, "-c", _DEVICE_HELD_POOL], capture_output=True, text=True, timeout=60, env=env
+    )
+    assert p.returncode == 1
+    assert time.monotonic() - t0 < 30  # the healthy worker was stopped, not waited out
+    assert "already in use by pid 7" in p.stderr
+    assert "--frontends" in p.stderr and "could not open the device at boot" in p.stderr
+    assert "restarting" not in p.stderr
