@@ -15,7 +15,7 @@ Two payload versions:
   at 8k docs ~12.6s → ~0.35s (round 4: msgpack container, the native
   linear node-pool decoder ``cerbos_native.decode_node_pool``, and
   ``util/gctune.build_phase`` GC pacing took the 8k decode+build from
-  ~0.9s to ~0.35s; docs/PERF.md "Cold start" has the breakdown).
+  ~0.9s to ~0.35s on the CPU host of that round).
 
 The compiled IR is a structured, versioned encoding
 (``cerbos_tpu.bundle_codec``: tagged JSON over a closed node vocabulary) —

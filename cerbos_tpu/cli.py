@@ -11,6 +11,7 @@ import json
 import os
 import signal
 import sys
+import threading
 
 
 def _parse_duration_s(v) -> int:
@@ -192,27 +193,35 @@ def cmd_server(args: argparse.Namespace) -> int:
             pre_exit=pre_exit,
         )
 
+    # SIGTERM is how supervisors stop a replica: leave through the same drain
+    # as Ctrl-C (listeners, batcher, audit log, the device) and exit 0,
+    # instead of dying mid-flight with the chip held. As in the pool roles
+    # the handler only sets a flag and is installed BEFORE the slow init, so
+    # a signal during boot still drains. It ignores every later SIGTERM: a
+    # repeated one must not interrupt the drain, nor kill the interpreter
+    # while it finalizes (which puts a Python handler back to the default).
+    stop = threading.Event()
+
+    def on_term(signum, frame):
+        signal.signal(signal.SIGTERM, signal.SIG_IGN)
+        stop.set()
+
+    signal.signal(signal.SIGTERM, on_term)
     init_otlp_from_env()  # OTEL_EXPORTER_OTLP_ENDPOINT et al (ref: otel.go)
     init_otlp_metrics_from_env()
     core = initialize(config)
     wire_metrics(core)
     server = _build_server(core, config)
-    server.start()
-    print(
-        f"cerbos-tpu serving: http={server.http_port} grpc={server.grpc_port} "
-        f"{_device_fields() or 'platform=none'} {_native_field()}",
-        flush=True,
-    )
-
-    def on_term(signum, frame):
-        # SIGTERM is how supervisors stop a replica: leave through the same
-        # drain as Ctrl-C (listeners, batcher, audit log, the device) and
-        # exit 0, instead of dying mid-flight with the chip held
-        raise KeyboardInterrupt
-
-    signal.signal(signal.SIGTERM, on_term)
     try:
-        server.wait()
+        if not stop.is_set():
+            server.start()
+            print(
+                f"cerbos-tpu serving: http={server.http_port} grpc={server.grpc_port} "
+                f"{_device_fields() or 'platform=none'} {_native_field()}",
+                flush=True,
+            )
+        while not stop.wait(0.2):
+            pass
     except KeyboardInterrupt:
         pass
     finally:

@@ -1,8 +1,7 @@
 """Process-crossing ticket queue: N front-end processes → one shared batcher.
 
-The GIL caps a single PDP process far below what the device batcher can
-evaluate (docs/PERF.md "Served-path latency": 586 RPS served vs 64k+ dec/s in
-batch form). An SO_REUSEPORT pool of full PDPs doesn't close the gap either:
+The GIL caps a single PDP process at a request rate far below the decision
+rate of batched evaluation (PERF.md, A4). An SO_REUSEPORT pool of full PDPs doesn't close the gap either:
 each forked worker drives its OWN evaluator, fragmenting batches and
 multiplying XLA compiles per process. The fix is topological — many HTTP/gRPC
 front-end processes parse and validate traffic, ONE batcher process owns the

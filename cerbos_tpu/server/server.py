@@ -1544,9 +1544,3 @@ class Server:
             asyncio.run_coroutine_threadsafe(shutdown(), loop)
             if self._thread is not None:
                 self._thread.join(timeout=5)
-
-    def wait(self) -> None:
-        if self._grpc_server is not None:
-            self._grpc_server.wait_for_termination()
-        elif self._thread is not None:
-            self._thread.join()

@@ -81,20 +81,35 @@ class WorkerPool:
     changed since boot; a stale table would diverge from sibling workers).
     """
 
-    def __init__(self, n_workers: int, worker_main: Callable[[int, bool], None], log=None):
+    def __init__(
+        self,
+        n_workers: int,
+        worker_main: Callable[[int, bool], None],
+        log=None,
+        device_init_hint: str = "",
+    ):
         self.n = n_workers
         self.worker_main = worker_main
         self.log = log or (lambda msg: print(msg, file=sys.stderr, flush=True))
+        # what to tell the operator when a worker cannot open the device at
+        # first boot, where the topology itself is the likely cause
+        self.device_init_hint = device_init_hint
         self._children: dict[int, int] = {}  # pid -> worker idx
         self._restarts: dict[int, list[float]] = {}  # idx -> restart stamps
         self._shutdown = False
 
     def _spawn(self, idx: int, respawn: bool = False) -> None:
+        # SIGTERM stays blocked across the fork until the child has dropped the
+        # supervisor's handler: one delivered in between (a pool that shuts
+        # down right at boot) would run handle_term IN the child and be lost
+        # to it, leaving that worker serving under a parent that waits for it
+        signal.pthread_sigmask(signal.SIG_BLOCK, {signal.SIGTERM})
         pid = os.fork()
         if pid == 0:
             # child: default signal dispositions; worker_main installs its own
             signal.signal(signal.SIGTERM, signal.SIG_DFL)
             signal.signal(signal.SIGINT, signal.SIG_IGN)  # parent fans out SIGTERM
+            signal.pthread_sigmask(signal.SIG_UNBLOCK, {signal.SIGTERM})
             try:
                 self.worker_main(idx, respawn)
                 os._exit(0)
@@ -104,6 +119,7 @@ class WorkerPool:
                 print(f"worker {idx} crashed: {type(e).__name__}: {e}", file=sys.stderr, flush=True)
                 os._exit(_EXIT_DEVICE_INIT if isinstance(e, DeviceInitError) else 1)
         self._children[pid] = idx
+        signal.pthread_sigmask(signal.SIG_UNBLOCK, {signal.SIGTERM})
 
     def run(self) -> int:
         """Blocking supervisor loop; returns the pool's exit code."""
@@ -146,9 +162,9 @@ class WorkerPool:
                 # hide the missing device. (A respawn that hits it goes
                 # through the normal restart budget: the chip may free up.)
                 self.log(
-                    f"worker {idx} could not open the device at boot; a chip belongs to "
-                    "ONE process — use --frontends N (one device-owning batcher) instead "
-                    "of --workers N (a CPU topology). Shutting pool down"
+                    f"worker {idx} could not open the device at boot (its backend's error is "
+                    f"on the crash line above): device unavailable. {self.device_init_hint}"
+                    "Shutting pool down"
                 )
                 exit_code = 1
                 handle_term(signal.SIGTERM, None)
@@ -238,7 +254,14 @@ def run_server_pool(
 
     if announce is not None:
         announce(http_addr, grpc_addr)
-    pool = WorkerPool(n_workers, worker_main)
+    pool = WorkerPool(
+        n_workers,
+        worker_main,
+        device_init_hint=(
+            "A chip belongs to ONE process — use --frontends N (one device-owning "
+            "batcher) instead of --workers N (a CPU topology). "
+        ),
+    )
     return pool.run()
 
 

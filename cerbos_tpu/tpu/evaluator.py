@@ -71,9 +71,9 @@ def _sat_groups(xp, compiler, B: int, refs, variant=None):
     ``(group_index, member_positions | None)``, None = every member) each
     group is restricted to the members the batch references, and the result
     is a COMPACT [B, A] matrix in variant (concat) order — device work is
-    O(active conditions) even when the table holds thousands (VERDICT r3
-    item 2); the caller translates cond ids through its col_map. Without
-    ``variant``, the full [B, C] matrix in cond-id order."""
+    O(active conditions) even when the table holds thousands; the caller
+    translates cond ids through its col_map. Without ``variant``, the full
+    [B, C] matrix in cond-id order."""
     compiler.build_groups()
     C = len(compiler.kernels)
     if not C:
@@ -492,7 +492,7 @@ def _device_eval(
     conditions); ``col_map`` [C] maps cond_id -> compact column (-1 for
     columns not computed — assembly never reads those by construction).
     Keeping sat compact makes device and host work O(active conditions)
-    even when the table holds thousands (VERDICT r3 item 2).
+    even when the table holds thousands.
 
     With jax, runs through a shape-bucketed ``jax.jit`` cache whose key
     includes the group-member subset (static trace structure); with a
@@ -1236,7 +1236,7 @@ class TpuEvaluator:
         return chunks
 
     def _check_pipelined(self, inputs: list[T.CheckInput], params: T.EvalParams) -> list[T.CheckOutput]:
-        """Chunked double-buffered device pipeline (VERDICT r4 item 1).
+        """Chunked double-buffered device pipeline.
 
         The serial path pays pack -> put -> compute -> fetch -> assemble
         per batch with the device idle during host work and vice versa.

@@ -1,4 +1,4 @@
-"""Vectorized host predicates (VERDICT r4 item 3, memo-cold pack cost).
+"""Vectorized host predicates (the memo-cold pack cost).
 
 Predicate columns are boolean subexpressions the device kernels can't
 evaluate (string *content* ops like ``startsWith``, IP range membership).

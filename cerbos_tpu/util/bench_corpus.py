@@ -267,7 +267,7 @@ _MOD_TEMPLATES = [
     _PRINCIPAL_POLICY,
 ]
 
-# -- condition-diversity extension (VERDICT r3 item 4) ----------------------
+# -- condition-diversity extension ------------------------------------------
 #
 # The classic corpus lowers to a handful of condition kernels; a throughput
 # claim about "vectorized CEL" needs structural breadth. DIVERSE_KINDS extra

@@ -28,12 +28,18 @@ Traffic: (a) upstream-shaped requests, 1 resource x its actions, from 64
 concurrent connections — these coalesce by timing, so how many reach the
 device varies run to run and is printed, not judged; (b) batch-API-shaped
 requests of up to 50 resources sent one at a time, so the device layouts they
-hit are the same on every run.
+hit are the same on every run. After the checked pass 16 of the shape-(b)
+requests go out once more, all at once on a connection each: that burst is
+reported, not judged beyond its effects — it coalesces into flights of
+B >= 64, the layouts whose cold compile runs up to and past the default
+``requestTimeoutMs``.
 
 Prints observations (platform, device_kind, cache directory, compile seconds
 per layout by source, device/oracle split per traffic shape, per-stage p50s)
 and, as the last line of stdout, one JSON object. Exits non-zero and prints
-no result when no accelerator is found.
+no result when no accelerator is found. Platform, corpus scale and request
+counts are constants: no option makes a chipless or toy-size run end in
+``"ok": true``.
 """
 
 from __future__ import annotations
@@ -45,7 +51,6 @@ import hmac
 import json
 import os
 import re
-import select
 import shutil
 import signal
 import subprocess
@@ -58,6 +63,8 @@ import urllib.request
 REPO = os.path.dirname(os.path.abspath(__file__))
 OUT_DIR = os.path.join(REPO, "chiprun_out", "chip_smoke")
 JWT_SECRET = b"cerbos-tpu-chip-smoke-secret"
+PLATFORM = "tpu"  # what the server's device owner must report
+MODS = 100  # classic-template name mods: the 800-policy configuration
 CONNECTIONS = 64  # shape (a): upstream's loadtest drives this many connections
 N_SINGLE = 2048  # shape (a) requests per protocol per pass
 N_BATCH = 30  # shape (b) requests per protocol per pass
@@ -66,6 +73,8 @@ MAX_RESOURCES = 50  # server.requestLimits.maxResourcesPerRequest
 BATCH_SIZES = (50, 50, 50, 32, 16)
 DEVICE_SHARE_MIN = 0.9
 CHECKED_ATTEMPTS = 4
+BURST_CONNECTIONS = 16  # the reported burst: this many shape-(b) requests at once
+DEFAULT_REQUEST_TIMEOUT_S = 30.0  # both requestTimeoutMs keys when the config does not set them
 
 # existing config keys the smoke sets beyond addresses, storage and the JWT
 # key set, each with its reason; printed at start
@@ -74,6 +83,11 @@ CONFIG_SET = {
         600000,
         "cold XLA compiles run inside the first requests; at the 30 s default a timed-out "
         "waiter is oracle-served and counted against the breaker",
+    ),
+    "engine.tpu.sharedBatcher.requestTimeoutMs": (
+        600000,
+        "a front end's wait on the shared batcher is its own key, defaulted to 30 s in "
+        "config.py, so it does not follow the key above; past it the front end serves its oracle",
     ),
 }
 
@@ -166,17 +180,17 @@ def stage_p50s(d: dict[tuple, float], name: str) -> dict[str, float]:
 # -- the checks ---------------------------------------------------------------
 
 
-def check_platform(status: dict, want: str) -> None:
+def check_platform(status: dict) -> None:
     dev = status.get("device")
     if not dev:
         raise SmokeFailure(
             "the server's device owner reports no device (X-Cerbos-Jitcache carries no "
             "'device' block): nothing opened a JAX backend"
         )
-    if dev["platform"] != want:
+    if dev["platform"] != PLATFORM:
         raise SmokeFailure(
             f"the server reports platform={dev['platform']!r} (device_kind="
-            f"{dev['device_kind']!r}), not {want!r}: no accelerator behind the served path"
+            f"{dev['device_kind']!r}), not {PLATFORM!r}: no accelerator behind the served path"
         )
 
 
@@ -210,7 +224,7 @@ def check_pass(
     return failures
 
 
-def check_totals(final: dict[tuple, float], want_platform: str) -> list[str]:
+def check_totals(final: dict[tuple, float]) -> list[str]:
     """What must hold over the server's whole life, from the last scrape."""
     failures = []
     if msum(final, "cerbos_tpu_xla_compiles_total") < 1:
@@ -221,7 +235,7 @@ def check_totals(final: dict[tuple, float], want_platform: str) -> list[str]:
     if div > 0:
         failures.append(f"parity_divergence_total is {div:.0f}: device and oracle disagree")
     # a CPU backend reports no memory stats, so this is also a platform check
-    if want_platform != "cpu" and msum(final, "cerbos_tpu_device_memory_bytes_in_use") <= 0:
+    if msum(final, "cerbos_tpu_device_memory_bytes_in_use") <= 0:
         failures.append("device_memory_bytes_in_use is 0: the backend holds no device memory")
     return failures
 
@@ -343,11 +357,13 @@ class ServerProc:
 
         self.name = name
         self.stderr_path = os.path.join(OUT_DIR, f"{name}.server.stderr")
-        self.stdout_lines: list[str] = []
         tpu = {"enabled": True, **tpu_conf}
         for key, (value, _) in CONFIG_SET.items():
-            assert key.startswith("engine.tpu.")
-            tpu[key[len("engine.tpu.") :]] = value
+            *path, leaf = key.removeprefix("engine.tpu.").split(".")
+            node = tpu
+            for part in path:
+                node = node.setdefault(part, {})
+            node[leaf] = value
         cfg = {
             "server": {"httpListenAddr": "127.0.0.1:0", "grpcListenAddr": "127.0.0.1:0"},
             "storage": {"driver": "disk", "disk": {"directory": policy_dir}},
@@ -381,35 +397,32 @@ class ServerProc:
         self.http_port = self.grpc_port = 0
         self.native = None
         self.last_scrape = ""
+        # the server's stdout is read by its own thread for as long as the
+        # pipe is open: every line is echoed, the serving line is kept
+        self._serving_line = ""
+        self._serving = threading.Event()
+        self._pump = threading.Thread(target=self._pump_stdout, daemon=True)
+        self._pump.start()
 
-    def _readline(self, timeout: float) -> str | None:
-        ready, _, _ = select.select([self.proc.stdout], [], [], timeout)
-        if not ready:
-            return None
-        line = self.proc.stdout.readline()
-        if line:
-            self.stdout_lines.append(line.rstrip("\n"))
-            log(f"  [{self.name}] {line.rstrip()}")
-        return line
+    def _pump_stdout(self) -> None:
+        for line in self.proc.stdout:
+            line = line.rstrip("\n")
+            log(f"  [{self.name}] {line}")
+            if line.startswith("cerbos-tpu serving:"):
+                self._serving_line = line
+                self._serving.set()
 
     def wait_serving(self, timeout: float) -> None:
         deadline = time.monotonic() + timeout
-        while time.monotonic() < deadline:
-            line = self._readline(1.0)
-            if line is None:
-                if self.proc.poll() is not None:
-                    raise SmokeFailure(f"server exited {self.proc.returncode} before announcing ports")
-                continue
-            if line == "":
-                raise SmokeFailure(f"server exited {self.proc.wait()} before announcing ports")
-            if line.startswith("cerbos-tpu serving:"):
-                fields = dict(t.split("=", 1) for t in line.split() if "=" in t)
-                self.http_port = int(fields["http"])
-                self.grpc_port = int(fields["grpc"])
-                self.native = fields.get("native")
-                break
-        else:
-            raise SmokeFailure(f"no 'cerbos-tpu serving:' line within {timeout:.0f} s")
+        while not self._serving.wait(0.25):
+            if self.proc.poll() is not None:
+                raise SmokeFailure(f"server exited {self.proc.returncode} before announcing ports")
+            if time.monotonic() >= deadline:
+                raise SmokeFailure(f"no 'cerbos-tpu serving:' line within {timeout:.0f} s")
+        fields = dict(t.split("=", 1) for t in self._serving_line.split() if "=" in t)
+        self.http_port = int(fields["http"])
+        self.grpc_port = int(fields["grpc"])
+        self.native = fields.get("native")
         while time.monotonic() < deadline:
             try:
                 with urllib.request.urlopen(self.url("/_cerbos/ready"), timeout=2) as r:
@@ -421,10 +434,6 @@ class ServerProc:
                 raise SmokeFailure(f"server exited {self.proc.returncode} before becoming ready")
             time.sleep(0.25)
         raise SmokeFailure(f"server not ready within {timeout:.0f} s")
-
-    def drain_stdout(self) -> None:
-        while self._readline(0.0):
-            pass
 
     def url(self, path: str) -> str:
         return f"http://127.0.0.1:{self.http_port}{path}"
@@ -445,13 +454,18 @@ class ServerProc:
             r.read()
         merged: dict[tuple, float] = {}
         seen: set[str] = set()
+        texts = []
         for _ in range(64):
             with urllib.request.urlopen(self.url("/_cerbos/metrics"), timeout=30) as r:
-                self.last_scrape = r.read().decode()
-            m = parse_metrics(self.last_scrape)
+                text = r.read().decode()
+            m = parse_metrics(text)
             merged.update(m)
-            seen |= {dict(labels).get("worker", "") for _, labels in m}
+            answered = {dict(labels).get("worker", "") for _, labels in m}
+            if not answered <= seen:
+                texts.append(text)
+            seen |= answered
             if all(w in seen for w in workers):
+                self.last_scrape = "\n".join(texts)
                 return merged
         raise SmokeFailure(f"scrapes reached workers {sorted(seen)}, wanted {workers}")
 
@@ -482,7 +496,7 @@ class ServerProc:
             except subprocess.TimeoutExpired:
                 self.kill()
                 return None
-        self.drain_stdout()
+        self._pump.join(timeout=5)
         self._stderr.close()
         return self.proc.returncode
 
@@ -493,6 +507,7 @@ class ServerProc:
             except ProcessLookupError:
                 pass
         self.proc.wait()
+        self._pump.join(timeout=5)
         self._stderr.close()
 
     def stderr_tail(self, n: int = 60) -> str:
@@ -602,6 +617,69 @@ def send(make_caller, srv: ServerProc, reqs: list[Request], connections: int, ti
     return errors
 
 
+def scrape_settled(srv, workers, prev: dict[tuple, float], want: int):
+    """Scrape until the source counters cover the ``want`` decisions just
+    answered: the hot-rule recorder counts a flight's sources after its
+    replies are sent. Returns the scrape and the source split since ``prev``."""
+    for _ in range(50):
+        cur = srv.scrape(workers)
+        src = by_label(delta(prev, cur), "cerbos_tpu_decision_source_total", "source")
+        if sum(src.values()) >= want:
+            break
+        time.sleep(0.1)
+    return cur, src
+
+
+def run_burst(srv, batches, workers, prev: dict[tuple, float]) -> dict:
+    """Reported, not judged beyond its effects: one shape-(b) request on each
+    of ``BURST_CONNECTIONS`` connections at once, over HTTP. Sent one at a time
+    they only ever meet B16/B32 layouts; concurrent ones coalesce into flights
+    of B >= 64, whose cold XLA:TPU compiles are the ones that run up to and
+    past the default ``requestTimeoutMs`` — where each timed-out waiter would
+    be oracle-served and counted against the breaker. Which layouts the burst
+    meets depends on timing, which is why nothing here is a check."""
+    reqs = batches[:BURST_CONNECTIONS]
+    t0, wall0 = time.monotonic(), time.time()
+    errors = send(_http_caller, srv, reqs, BURST_CONNECTIONS, timeout=900)
+    wall = time.monotonic() - t0
+    if errors:
+        raise SmokeFailure("burst: " + "; ".join(errors))
+    want = sum(r.decisions for r in reqs)
+    cur, src = scrape_settled(srv, workers, prev, want)
+    d = delta(prev, cur)
+    _, flight = srv.status()
+    compiles = [
+        e for e in flight.get("events", []) if e.get("kind") == "xla_compile" and e.get("ts", 0) >= wall0
+    ]
+    slow = [e for e in compiles if e["seconds"] >= DEFAULT_REQUEST_TIMEOUT_S]
+    out = {
+        "split": src,
+        "flights": int(msum(d, "cerbos_tpu_batcher_batches_total")),
+        "compiles": by_label(d, "cerbos_tpu_xla_compiles_total", "source"),
+        "compile_seconds": round(msum(d, "cerbos_tpu_xla_compile_seconds_sum"), 3),
+        "max_compile_seconds": max((e["seconds"] for e in compiles), default=0.0),
+        "compiles_over_default_timeout": len(slow),
+        "fallbacks": by_label(d, "cerbos_tpu_batcher_oracle_fallbacks_total", "reason"),
+        "breaker_trips": int(msum(d, "cerbos_tpu_breaker_trips_total")),
+    }
+    log(
+        f"  burst (reported, not judged): {len(reqs)} batch-shaped requests at once over HTTP, "
+        f"one per connection, {want} decisions, effects == oracle, {wall:.2f} s; "
+        f"source {src}; flights +{out['flights']}; compiles {out['compiles'] or 'none'}, "
+        f"{out['compile_seconds']:.2f} s in XLA; fallbacks by reason {out['fallbacks'] or 'none'}; "
+        f"breaker trips {out['breaker_trips']}"
+    )
+    for e in compiles:
+        log(f"    compile {e['layout_key']}: {e['seconds']:.3f} s ({e['source']})")
+    if slow:
+        log(
+            f"  burst: {len(slow)} compile(s) ran for >= {DEFAULT_REQUEST_TIMEOUT_S:.0f} s, the default "
+            "requestTimeoutMs: a server at the default config would have timed their waiters out "
+            "(oracle-served, counted against the breaker)"
+        )
+    return out
+
+
 def run_pass(srv, singles, batches, workers, timeout: float, label: str) -> dict:
     """Send (a) then (b) over HTTP, then over gRPC, scraping around each
     phase. Returns the scrapes and the per-phase source split."""
@@ -620,15 +698,8 @@ def run_pass(srv, singles, batches, workers, timeout: float, label: str) -> dict
         wall = time.monotonic() - t0
         if errors:
             raise SmokeFailure(f"{label} pass, {name}: " + "; ".join(errors))
-        # the hot-rule recorder counts a flight's sources after its replies
-        # are sent: let the last flight land before reading the counters
         want = sum(r.decisions for r in reqs)
-        for _ in range(50):
-            cur = srv.scrape(workers)
-            src = by_label(delta(prev, cur), "cerbos_tpu_decision_source_total", "source")
-            if sum(src.values()) >= want:
-                break
-            time.sleep(0.1)
+        cur, src = scrape_settled(srv, workers, prev, want)
         d = delta(prev, cur)
         out["split"][name] = src
         if name.endswith("batch"):
@@ -653,7 +724,7 @@ def run_topology(name, extra_args, tpu_conf, workers, policy_dir, singles, batch
     try:
         srv.wait_serving(timeout=300)
         status, _ = srv.status()
-        check_platform(status, args.platform)
+        check_platform(status)
         dev = status["device"]
         log(
             f"  device owner pid {dev['pid']}: platform={dev['platform']} "
@@ -710,6 +781,12 @@ def run_topology(name, extra_args, tpu_conf, workers, policy_dir, singles, batch
         log(f"  batch stage p50 s: { {k: round(v, 6) for k, v in stage_p50s(dchk, 'cerbos_tpu_batch_stage_seconds').items()} }")
         log(f"  request stage p50 s: { {k: round(v, 6) for k, v in stage_p50s(dchk, 'cerbos_tpu_request_stage_seconds').items()} }")
 
+        # not under --lanes: every lane compiles its own copy of each layout,
+        # so the burst would cost four times the compiles and show the same
+        burst = None
+        if not args.lanes:
+            burst = run_burst(srv, batches, workers, checked["after"])
+
         # the sentinel replays off the request path: give its first check a moment
         for _ in range(100):
             final = srv.scrape(workers)
@@ -719,7 +796,7 @@ def run_topology(name, extra_args, tpu_conf, workers, policy_dir, singles, batch
         # kept beside the server's stderr: what the verdict was read from
         with open(os.path.join(OUT_DIR, f"{name}.metrics.txt"), "w") as f:
             f.write(srv.last_scrape)
-        failures = check_totals(final, args.platform)
+        failures = check_totals(final)
         status, flight = srv.status()
         with open(os.path.join(OUT_DIR, f"{name}.flight.json"), "w") as f:
             json.dump({"jitcache": status, "flight": flight}, f)
@@ -741,10 +818,12 @@ def run_topology(name, extra_args, tpu_conf, workers, policy_dir, singles, batch
         return {
             "device": {"platform": dev["platform"], "kind": dev["device_kind"], "count": dev["count"]},
             "xla_cache": status["dir"],
-            "compiles": by_label(final, "cerbos_tpu_xla_compiles_total", "source"),
-            "compile_seconds": round(msum(final, "cerbos_tpu_xla_compile_seconds_sum"), 3),
+            # up to the end of the checked pass; the burst's are its own
+            "compiles": by_label(checked["after"], "cerbos_tpu_xla_compiles_total", "source"),
+            "compile_seconds": round(msum(checked["after"], "cerbos_tpu_xla_compile_seconds_sum"), 3),
             "device_share_batch": round(share, 4),
             "split": checked["split"],
+            "burst": burst,
         }
     except BaseException:
         if srv.proc.poll() is None:
@@ -795,11 +874,12 @@ def check_workers_refused(policy_dir: str) -> None:
     log("== --workers 2 on the device path must fail at boot")
     srv = ServerProc("workers2", policy_dir, ["--workers", "2"], {})
     try:
-        deadline = time.monotonic() + 300
-        while srv.proc.poll() is None and time.monotonic() < deadline:
-            srv._readline(1.0)
-        if srv.proc.poll() is None:
-            raise SmokeFailure("--workers 2 is still up after 300 s: its 2nd worker neither opened the chip nor failed")
+        try:
+            srv.proc.wait(timeout=300)
+        except subprocess.TimeoutExpired:
+            raise SmokeFailure(
+                "--workers 2 is still up after 300 s: its 2nd worker neither opened the chip nor failed"
+            ) from None
         srv.stop()
         tail = srv.stderr_tail(200)
         if srv.proc.returncode == 0 or "--frontends" not in tail:
@@ -815,11 +895,6 @@ def check_workers_refused(policy_dir: str) -> None:
 def main() -> int:
     ap = argparse.ArgumentParser(description=__doc__, formatter_class=argparse.RawDescriptionHelpFormatter)
     ap.add_argument("--seed", type=int, default=7, help="request generator seed")
-    ap.add_argument("--mods", type=int, default=100, help="classic-template name mods (100 = the 800-policy config)")
-    ap.add_argument(
-        "--platform", default="tpu",
-        help="platform the server must report; 'cpu' is for debugging the script itself",
-    )
     ap.add_argument(
         "--lanes", type=int, default=0,
         help="builder-run, four-chip host: run ONLY the single-process topology with "
@@ -829,7 +904,6 @@ def main() -> int:
         "--workers-check", action="store_true",
         help="builder-run: also require that --workers 2 on the device path fails at boot",
     )
-    ap.add_argument("--requests", type=int, default=N_SINGLE, help="shape (a) requests per protocol per pass")
     args = ap.parse_args()
 
     sys.path.insert(0, REPO)
@@ -847,10 +921,10 @@ def main() -> int:
     try:
         policy_dir = os.path.join(work, "policies")
         os.makedirs(policy_dir)
-        n_docs = write_policies(policy_dir, args.mods)
-        singles, batches = build_requests(args.mods, args.seed, args.requests, N_BATCH)
+        n_docs = write_policies(policy_dir, MODS)
+        singles, batches = build_requests(MODS, args.seed, N_SINGLE, N_BATCH)
         log(
-            f"corpus: {n_docs} policy documents ({args.mods} mods); requests (seed {args.seed}): "
+            f"corpus: {n_docs} policy documents ({MODS} mods); requests (seed {args.seed}): "
             f"{len(singles)} single-resource ({sum(r.decisions for r in singles)} decisions), "
             f"{len(batches)} batch-shaped ({sum(len(r.expected) for r in batches)} resources, "
             f"{sum(r.decisions for r in batches)} decisions), each sent over HTTP and gRPC; "

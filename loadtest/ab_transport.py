@@ -6,8 +6,7 @@ Identical topology on both legs — one ``BatcherIpcServer`` over a
 population and request mix — with the transport knob as the ONLY variable.
 The serving side is a precomputed-output memo (near-free) so the
 measurement isolates what this drill is for: frame encode, the queue/ring
-hop, and reply decode. This is the docs/PERF.md "Round 10" artifact
-generator.
+hop, and reply decode. Its output is a host figure (PERF.md, A4).
 
 Usage:
     python loadtest/ab_transport.py [--duration 10] [--threads 8]

@@ -1169,7 +1169,7 @@ PyObject* PyDecodeNodePool(PyObject*, PyObject* args) {
 // principal / resource objects and their attr / jwt dicts are resolved
 // ONCE and shared by all specs, so the per-input Python attribute-access
 // overhead is paid once instead of P times (the packer's dominant
-// memo-cold cost; VERDICT r4 item 3).
+// memo-cold cost).
 PyObject* PyEncodeAttrColumnsMulti(PyObject*, PyObject* args) {
   PyObject* inputs;
   PyObject* specs;

@@ -20,7 +20,7 @@ sys.path.insert(0, REPO)
 
 import chip_smoke  # noqa: E402
 
-# recorded from `python chip_smoke.py --platform cpu --mods 10` (single
+# recorded from a CPU-backend debug run of the script at 10 mods (single
 # topology, final scrape), trimmed to the families the checks read; the
 # memory gauges carry a v5e-sized value where the CPU backend reported 0
 RECORDED = """\
@@ -171,15 +171,15 @@ class TestCheckedPass:
 
 class TestTotals:
     def test_recorded_totals_hold(self):
-        assert chip_smoke.check_totals(chip_smoke.parse_metrics(RECORDED), "tpu") == []
-        assert chip_smoke.check_totals(chip_smoke.parse_metrics(RECORDED_POOL), "tpu") == []
+        assert chip_smoke.check_totals(chip_smoke.parse_metrics(RECORDED)) == []
+        assert chip_smoke.check_totals(chip_smoke.parse_metrics(RECORDED_POOL)) == []
 
     def mutated(self, **series):
         m = chip_smoke.parse_metrics(RECORDED)
         for key in list(m):
             if key[0] in series:
                 m[key] = series[key[0]]
-        return chip_smoke.check_totals(m, "tpu")
+        return chip_smoke.check_totals(m)
 
     def test_a_divergence_fails(self):
         failures = self.mutated(cerbos_tpu_parity_divergence_total=2.0)
@@ -201,19 +201,31 @@ class TestPlatform:
     def test_cpu_platform_is_refused(self):
         status = {"device": {"platform": "cpu", "device_kind": "cpu", "count": 1, "pid": 1}}
         with pytest.raises(chip_smoke.SmokeFailure, match="platform='cpu'.*no accelerator"):
-            chip_smoke.check_platform(status, "tpu")
-        chip_smoke.check_platform(status, "cpu")
+            chip_smoke.check_platform(status)
+        chip_smoke.check_platform({"device": {"platform": "tpu", "device_kind": "TPU v5 lite", "count": 1, "pid": 1}})
 
     def test_a_server_that_opened_no_device_is_refused(self):
         with pytest.raises(chip_smoke.SmokeFailure, match="no device"):
-            chip_smoke.check_platform({"enabled": True, "device": None}, "tpu")
+            chip_smoke.check_platform({"enabled": True, "device": None})
+
+    def test_no_option_sets_the_platform_or_the_size(self, tmp_path):
+        """A chipless or toy-size run must not be able to end in "ok": true:
+        platform, corpus scale and request counts are constants."""
+        for flag in (["--platform", "cpu"], ["--mods", "2"], ["--requests", "8"]):
+            p = subprocess.run(
+                [sys.executable, os.path.join(REPO, "chip_smoke.py"), *flag],
+                capture_output=True, text=True, timeout=60, cwd=tmp_path,
+            )
+            assert p.returncode == 2 and "unrecognized arguments" in p.stderr
+            assert p.stdout == ""
 
     def test_script_exits_nonzero_without_a_result_on_the_cpu(self, tmp_path):
-        """The whole script against a real server process on the CPU backend:
-        it must stop at the platform line, before any traffic."""
+        """The command as the driver runs it, at its full size, against a
+        real server process on the CPU backend: it must stop at the platform
+        line, before any traffic."""
         env = dict(os.environ, JAX_PLATFORMS="cpu")
         p = subprocess.run(
-            [sys.executable, os.path.join(REPO, "chip_smoke.py"), "--mods", "2", "--requests", "8"],
+            [sys.executable, os.path.join(REPO, "chip_smoke.py")],
             capture_output=True, text=True, timeout=300, env=env, cwd=tmp_path,
         )
         assert p.returncode == 1, p.stderr[-2000:]

@@ -5,7 +5,7 @@ exercised through a recording fake DB-API connection: every statement the
 store core executes is captured and checked for (a) placeholder/arg-count
 agreement, (b) no un-rewritten '?' markers in %s dialects, (c) the exact
 statement text (golden), so a typo in dialect SQL fails here instead of at
-a customer's database (VERDICT r2 weak #7).
+a customer's database.
 """
 
 import re

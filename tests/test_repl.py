@@ -1,4 +1,4 @@
-"""Scripted REPL session (VERDICT r3 item 9).
+"""Scripted REPL session.
 
 Drives cerbos_tpu.repl.Repl the way cmd/cerbos/repl's own tests drive its
 directive handler: a sequence of lines in, assertions over the printed

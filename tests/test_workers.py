@@ -317,31 +317,136 @@ def test_frontdoor_batcher_sigkill_midload_loses_zero_requests(frontdoor):
 
 # -- one process per chip ------------------------------------------------------
 
-_DEVICE_HELD_POOL = """
-import sys, time
-from cerbos_tpu.server.workers import WorkerPool
+# the real CLI with libtpu's lockfile stood in for: the first process to
+# "open the chip" (create argv[1]) holds it, every later one cannot. Front
+# ends open no device.
+_CLI_WITH_ONE_CHIP = """
+import os, sys
+import cerbos_tpu.bootstrap as bootstrap
+from cerbos_tpu import cli
 from cerbos_tpu.tpu.jitcache import DeviceInitError
 
-def worker_main(idx, respawn):
-    if idx == 1:  # the 2nd full PDP finds the chip held by the 1st
-        raise DeviceInitError("device backend failed to initialize: TPU is already in use by pid 7")
-    time.sleep(120)
+real_initialize = bootstrap.initialize
 
-sys.exit(WorkerPool(2, worker_main).run())
+def initialize(config, **kw):
+    if kw.get("role") != "frontend":
+        try:
+            os.close(os.open(sys.argv[1], os.O_CREAT | os.O_EXCL))
+        except FileExistsError:
+            raise DeviceInitError("device backend failed to initialize: TPU is already in use")
+    return real_initialize(config, **kw)
+
+bootstrap.initialize = initialize
+sys.exit(cli.main(sys.argv[2:]))
 """
 
 
-def test_pool_fails_at_boot_when_a_worker_cannot_open_the_device():
-    """--workers N on the device path is N PDPs on one chip: the one that
-    cannot open it must take the pool down with a message naming
-    --frontends — not be restarted, and not serve from the oracle."""
+def _run_cli_with_one_chip(tmp_path, chip_free: bool, topology: list[str]):
+    policy_dir = tmp_path / "policies"
+    policy_dir.mkdir()
+    (policy_dir / "album.yaml").write_text(POLICY)
+    lock = tmp_path / "chip.lock"
+    if not chip_free:
+        lock.touch()
     env = dict(os.environ, PYTHONPATH=REPO)
     t0 = time.monotonic()
     p = subprocess.run(
-        [sys.executable, "-c", _DEVICE_HELD_POOL], capture_output=True, text=True, timeout=60, env=env
+        [
+            sys.executable, "-c", _CLI_WITH_ONE_CHIP, str(lock), "server", *topology,
+            "--set", f"storage.disk.directory={policy_dir}",
+            "--set", "server.httpListenAddr=127.0.0.1:0",
+            "--set", "server.grpcListenAddr=127.0.0.1:0",
+            "--set", "engine.tpu.backend=numpy",
+        ],
+        capture_output=True, text=True, timeout=60, env=env,
     )
+    assert time.monotonic() - t0 < 30  # the healthy processes were stopped, not waited out
+    return p
+
+
+def test_pool_fails_at_boot_when_a_worker_cannot_open_the_device(tmp_path):
+    """--workers N on the device path is N PDPs on one chip: the one that
+    cannot open it must take the pool down with a message naming
+    --frontends — not be restarted, and not serve from the oracle."""
+    p = _run_cli_with_one_chip(tmp_path, chip_free=True, topology=["--workers", "2"])
     assert p.returncode == 1
-    assert time.monotonic() - t0 < 30  # the healthy worker was stopped, not waited out
-    assert "already in use by pid 7" in p.stderr
-    assert "--frontends" in p.stderr and "could not open the device at boot" in p.stderr
+    assert "DeviceInitError: device backend failed to initialize: TPU is already in use" in p.stderr
+    assert "could not open the device at boot" in p.stderr and "--frontends" in p.stderr
     assert "restarting" not in p.stderr
+
+
+def test_frontdoor_without_a_device_fails_at_boot_and_gives_no_topology_advice(tmp_path):
+    """Under --frontends the batcher is the one device owner: when it cannot
+    open the device the cause is the device, and telling the operator to use
+    --frontends would hide it."""
+    p = _run_cli_with_one_chip(tmp_path, chip_free=False, topology=["--frontends", "2"])
+    assert p.returncode == 1
+    assert "DeviceInitError: device backend failed to initialize: TPU is already in use" in p.stderr
+    assert "worker 0 could not open the device at boot" in p.stderr and "device unavailable" in p.stderr
+    assert "--frontends" not in p.stderr
+    assert "restarting" not in p.stderr
+
+
+# -- single process: SIGTERM leaves through the drain --------------------------
+
+
+def _boot_single(tmp_path, script=None):
+    policy_dir = tmp_path / "policies"
+    policy_dir.mkdir()
+    (policy_dir / "album.yaml").write_text(POLICY)
+    args = [
+        "server",
+        "--set", f"storage.disk.directory={policy_dir}",
+        "--set", "server.httpListenAddr=127.0.0.1:0",
+        "--set", "server.grpcListenAddr=127.0.0.1:0",
+        "--set", "engine.tpu.backend=numpy",
+    ]
+    launcher = ["-c", script] if script else ["-m", "cerbos_tpu.cli"]
+    return subprocess.Popen(
+        [sys.executable, *launcher, *args],
+        stdout=subprocess.PIPE, stderr=subprocess.PIPE, text=True, env=dict(os.environ, PYTHONPATH=REPO),
+    )
+
+
+def test_single_process_exits_0_on_repeated_sigterm(tmp_path):
+    """A supervisor that repeats its SIGTERM must not abort the drain: the
+    second signal lands while listeners, batcher and device are closing."""
+    proc = _boot_single(tmp_path)
+    try:
+        line = proc.stdout.readline()
+        assert line.startswith("cerbos-tpu serving:"), line + proc.stderr.read()
+        proc.send_signal(signal.SIGTERM)
+        for _ in range(20):  # across the whole drain (grpc grace alone is up to 1 s)
+            time.sleep(0.05)
+            if proc.poll() is not None:
+                break
+            proc.send_signal(signal.SIGTERM)
+        assert proc.wait(timeout=30) == 0
+        assert "Traceback" not in proc.stderr.read()
+    finally:
+        if proc.poll() is None:
+            proc.kill()
+
+
+_SIGTERM_DURING_INIT = """
+import os, signal, sys
+import cerbos_tpu.bootstrap as bootstrap
+from cerbos_tpu import cli
+
+real_initialize = bootstrap.initialize
+
+def initialize(config, **kw):
+    os.kill(os.getpid(), signal.SIGTERM)  # the supervisor gives up while the table is still building
+    return real_initialize(config, **kw)
+
+bootstrap.initialize = initialize
+sys.exit(cli.main(sys.argv[1:]))
+"""
+
+
+def test_single_process_sigterm_during_boot_drains_and_exits_0(tmp_path):
+    proc = _boot_single(tmp_path, script=_SIGTERM_DURING_INIT)
+    out, err = proc.communicate(timeout=60)
+    assert proc.returncode == 0, err
+    assert "serving:" not in out  # it never opened its listeners
+    assert "Traceback" not in err
