@@ -36,8 +36,9 @@ B >= 64, the layouts whose cold compile runs up to and past the default
 
 Prints observations (platform, device_kind, cache directory, compile seconds
 per layout by source, device/oracle split per traffic shape, per-stage p50s)
-and, as the last line of stdout, one JSON object. Exits non-zero and prints
-no result when no accelerator is found. Platform, corpus scale and request
+then a JSON summary of them ending ``"claim": null`` and, as the last line of
+stdout, ``{"ok": true, "device": {"platform", "kind", "count"}}`` with exactly
+those keys. Exits non-zero and prints no result when no accelerator is found. Platform, corpus scale and request
 counts are constants: no option makes a chipless or toy-size run end in
 ``"ok": true``.
 """
@@ -892,6 +893,18 @@ def check_workers_refused(policy_dir: str) -> None:
         raise
 
 
+def result_line(device: dict) -> str:
+    """The last line of stdout, and nothing else on it: what the chip check
+    parses. Exactly these keys; the device is what the server's device owner
+    read from ``jax.devices()`` at boot."""
+    return json.dumps(
+        {
+            "ok": True,
+            "device": {"platform": str(device["platform"]), "kind": str(device["kind"]), "count": int(device["count"])},
+        }
+    )
+
+
 def main() -> int:
     ap = argparse.ArgumentParser(description=__doc__, formatter_class=argparse.RawDescriptionHelpFormatter)
     ap.add_argument("--seed", type=int, default=7, help="request generator seed")
@@ -949,9 +962,13 @@ def main() -> int:
         shutil.rmtree(work, ignore_errors=True)
 
     assert "jax" not in sys.modules, "the smoke's parent imported jax: it would hold the chip"
-    device = next(iter(results.values()))["device"]
     log(f"chip_smoke passed in {time.monotonic() - t_start:.0f} s")
-    print(json.dumps({"ok": True, "device": device, "topologies": results, "claim": None}), flush=True)
+    # the observations, for a reader; kept beside the server logs too
+    summary = json.dumps({"topologies": results, "claim": None})
+    with open(os.path.join(OUT_DIR, "summary.json"), "w") as f:
+        f.write(summary + "\n")
+    log(summary)
+    print(result_line(next(iter(results.values()))["device"]), flush=True)
     return 0
 
 

@@ -9,6 +9,7 @@ breaker trip, a compile in the checked pass, a parity divergence, no parity
 check, no compile at all, no device memory).
 """
 
+import json
 import os
 import subprocess
 import sys
@@ -207,6 +208,13 @@ class TestPlatform:
     def test_a_server_that_opened_no_device_is_refused(self):
         with pytest.raises(chip_smoke.SmokeFailure, match="no device"):
             chip_smoke.check_platform({"enabled": True, "device": None})
+
+    def test_result_line_has_exactly_the_contract_keys(self):
+        """The chip check parses the last stdout line and refuses any other
+        key: the observations go on the line before it."""
+        line = chip_smoke.result_line({"platform": "tpu", "kind": "TPU v5 lite", "count": 1})
+        assert "\n" not in line
+        assert json.loads(line) == {"ok": True, "device": {"platform": "tpu", "kind": "TPU v5 lite", "count": 1}}
 
     def test_no_option_sets_the_platform_or_the_size(self, tmp_path):
         """A chipless or toy-size run must not be able to end in "ok": true:
