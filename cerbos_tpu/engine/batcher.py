@@ -56,7 +56,7 @@ from .budget import (
     STAGE_SETTLE,
     Waterfall,
 )
-from . import hotrules
+from . import drainclock, hotrules
 from .budget import tracker as budget_tracker
 from .flight import recorder as flight_recorder
 from .health import DeviceHealth  # noqa: F401  (re-exported for wiring/tests)
@@ -256,6 +256,9 @@ class _Inflight:
     timings: dict = field(default_factory=dict)  # stage -> seconds
     submitted_at: float = 0.0  # perf_counter at submit return
     submitted_wall_ns: int = 0
+    # the same instant on the clock a profiler capture reports
+    # (tpu/profiler.py), so a flight can be placed on a device trace
+    submitted_monotonic_ns: int = 0
     occupancy: float = 1.0
     layout_key: Optional[str] = None
     kind: str = "check"
@@ -266,14 +269,18 @@ class _ShardStageView:
     HistogramVec so hot-path call sites keep the one-argument
     ``observe(stage, v)`` shape."""
 
-    __slots__ = ("vec", "shard")
+    __slots__ = ("vec", "shard", "_children")
 
     def __init__(self, vec: Any, shard: str):
         self.vec = vec
         self.shard = shard
+        self._children: dict[str, Any] = {}  # stage -> child histogram, bound once
 
     def observe(self, stage: str, v: float) -> None:
-        self.vec.observe((stage, self.shard), v)
+        child = self._children.get(stage)
+        if child is None:
+            child = self._children[stage] = self.vec.labels((stage, self.shard))
+        child.observe(v)
 
 
 def _settle(fut: Future, result: Any = None, error: Optional[BaseException] = None) -> None:
@@ -456,11 +463,21 @@ class BatchingEvaluator:
         )
         self._m_stage_vec = reg.histogram_vec(
             "cerbos_tpu_batch_stage_seconds",
-            "device-batch pipeline stage latency (pack/submit/device/collect/settle), by shard",
+            "device-batch pipeline stage seconds on the drain thread's clock, once per flight, by shard: "
+            "pack, submit (= stack + dispatch + compiles), device (host-clock GAP between submit "
+            "returning and collect starting, not device time), collect (= fetch + assemble), settle; "
+            "oracle (synchronous check of a flight under minDeviceBatch), post (after settle)",
             label=("stage", "shard"),
             buckets=[0.0001, 0.0005, 0.001, 0.002, 0.005, 0.01, 0.025, 0.05, 0.1, 0.5, 1.0],
         )
         self.m_stage_seconds = _ShardStageView(self._m_stage_vec, self._shard_label)
+        self.m_window_wait = reg.histogram_vec(
+            "cerbos_tpu_batcher_window_wait_seconds",
+            "per flight: how long the drain loop deliberately waited (batchWindowMs) for a second "
+            "request before draining it; part of every rider's queue wait, by shard",
+            label="shard",
+            buckets=[0.0001, 0.0005, 0.001, 0.002, 0.005, 0.01, 0.025, 0.05, 0.1],
+        ).labels(self._shard_label)
 
     # -- oracle fallback ----------------------------------------------------
 
@@ -785,6 +802,9 @@ class BatchingEvaluator:
     # -- drain loop ---------------------------------------------------------
 
     def _loop(self) -> None:
+        # the thread's own clock (engine/drainclock.py): every line below runs
+        # in exactly one of its states
+        self._clock = drainclock.install(self._shard_label)
         inflight: deque[_Inflight] = deque()
         try:
             self._loop_inner(inflight)
@@ -806,21 +826,28 @@ class BatchingEvaluator:
                     _settle(p.future, error=_BatchFailed(e, "batcher_dead"))
             self.m_inflight.set(len(inflight))
         self._settle_residual_queue()
+        self._clock.to(drainclock.OTHER)  # book the thread's last stretch
+        self._clock.book_cpu()
 
     def _loop_inner(self, inflight: deque) -> None:
+        clock = self._clock
         while True:
+            window_s = 0.0
             with self._wakeup:
                 if self._stop:
                     break
                 barrier = self._swap_barrier
                 if barrier is None and not self._queue:
                     if not inflight:
+                        clock.to(drainclock.IDLE)
                         self._wakeup.wait()
+                        clock.to(drainclock.OTHER)
                         continue
                 elif barrier is None and not inflight and self.max_wait > 0:
                     # small wait to let concurrent requests coalesce (only
                     # while the pipeline is empty: with batches in flight the
                     # collect below provides the coalescing window for free)
+                    clock.to(drainclock.WINDOW)
                     deadline = time.monotonic() + self.max_wait
                     while (
                         len(self._queue) < self.min_batch_to_wait
@@ -831,6 +858,7 @@ class BatchingEvaluator:
                         if remaining <= 0:
                             break
                         self._wakeup.wait(remaining)
+                    window_s = clock.to(drainclock.OTHER)
                     barrier = self._swap_barrier
                 pending: list[_Pending] = []
                 total = 0
@@ -863,7 +891,7 @@ class BatchingEvaluator:
                         _settle(p.future, error=_BatchFailed(None, "breaker_open"))
                 else:
                     self._draining = pending
-                    self._submit(pending, inflight)
+                    self._submit(pending, inflight, window_s)
                     self._draining = []
             # Collect when the window is full, or when there's nothing left
             # to submit (the pipeline drains while new requests may still
@@ -882,12 +910,14 @@ class BatchingEvaluator:
                 # flight boundary reached: nothing in flight, nothing mid-
                 # submit. Park here while the controller swaps the shared
                 # tables and stamps the new epoch, then resume draining.
+                clock.to(drainclock.IDLE)  # a wait with nothing to submit or collect
                 barrier.park(self)
+                clock.to(drainclock.OTHER)
                 with self._wakeup:
                     if self._swap_barrier is barrier:
                         self._swap_barrier = None
 
-    def _submit(self, pending: list[_Pending], inflight: deque) -> None:
+    def _submit(self, pending: list[_Pending], inflight: deque, window_s: float = 0.0) -> None:
         # group by (kind, params identity): globals etc. must match within a
         # batch, and plan pendings must never mix into a device check batch
         groups: dict[tuple[str, int], list[_Pending]] = {}
@@ -898,6 +928,7 @@ class BatchingEvaluator:
             groups.setdefault((p.kind, id(p.params)), []).append(p)
         now = time.perf_counter()
         shard = self.shard_id if self.shard_id is not None else 0
+        clock = self._clock
         for group in groups.values():
             if group[0].kind == "plan":
                 self._submit_plan(group, inflight, now)
@@ -937,11 +968,18 @@ class BatchingEvaluator:
                     else:
                         # plain evaluator without a streaming API: evaluate
                         # synchronously and carry the result as a ready ticket
+                        clock.to(drainclock.ORACLE)
                         ticket = _ReadyTicket(self.evaluator.check(all_inputs, group[0].params))
+                    clock.to(drainclock.OTHER)
                     submit_s = time.perf_counter() - t0
             except Exception as e:  # noqa: BLE001
+                clock.to(drainclock.OTHER)
+                clock.take_lap()
                 self._batch_failed(group, all_inputs, e, batch_id=batch_id)
                 continue
+            # stack, dispatch and oracle seconds are booked inside submit
+            # alone, and every submit ends in a take_lap: these are this flight's
+            lap = clock.take_lap()
             self.stats["batches"] += 1
             self.stats["batched_requests"] += len(group)
             self.m_batches.inc()
@@ -961,14 +999,26 @@ class BatchingEvaluator:
                 batch_id=batch_id,
                 n_inputs=len(all_inputs),
                 batch_ctx=batch_ctx,
-                timings={"pack": pack_s, "submit": max(0.0, submit_s - pack_s)},
+                timings={
+                    "window": window_s,
+                    "pack": pack_s,
+                    "submit": max(0.0, submit_s - pack_s),
+                    # parts of submit, from the thread's clock; what is left
+                    # of it is a first-call compile or chunking
+                    "stack": lap.get(drainclock.STACK, 0.0),
+                    "dispatch": lap.get(drainclock.DISPATCH, 0.0),
+                    "oracle": lap.get(drainclock.ORACLE, 0.0),
+                },
                 submitted_at=time.perf_counter(),
                 submitted_wall_ns=time.time_ns(),
+                submitted_monotonic_ns=time.monotonic_ns(),
                 occupancy=float(occupancy),
                 layout_key=getattr(ticket, "layout_key", None),
             )
-            self.m_stage_seconds.observe("pack", flight.timings["pack"])
-            self.m_stage_seconds.observe("submit", flight.timings["submit"])
+            window_s = 0.0  # a drain that splits into several flights waited once
+            self.m_window_wait.observe(flight.timings["window"])
+            for stage in ("pack", "submit", "stack", "dispatch", "oracle"):
+                self.m_stage_seconds.observe(stage, flight.timings[stage])
             self.m_occupancy.set(float(occupancy))
             if padded_rows:
                 waste = int(round(padded_rows * (1.0 - float(occupancy))))
@@ -1050,10 +1100,14 @@ class BatchingEvaluator:
             self._collect_plan(flight)
             return
         group = flight.group
+        clock = self._clock
         collect_start = time.perf_counter()
-        # the window between submit returning and collect starting is device
-        # transfer + compute time no host thread executes; synthesize it as
-        # a span so the trace shows where the latency actually went
+        # the GAP on the host's clock between submit returning and collect
+        # starting. It is not device time: in three flights of five it is
+        # under 0.1 ms, in the others the drain thread submits the next
+        # flight first (milliseconds), and the device's work, some 0.07 ms on
+        # a v5e (PERF.md), lies somewhere inside. The synthetic span is kept so
+        # a request's trace shows no hole between batch.submit and batch.collect
         if flight.submitted_at:
             device_s = max(0.0, collect_start - flight.submitted_at)
             flight.timings["device"] = device_s
@@ -1073,7 +1127,10 @@ class BatchingEvaluator:
                     outputs = flight.ticket.outputs
                 else:
                     outputs = self.evaluator.collect(flight.ticket)
+            clock.to(drainclock.SETTLE)
         except Exception as e:  # noqa: BLE001
+            clock.to(drainclock.OTHER)
+            clock.take_lap()
             flight.timings["collect"] = time.perf_counter() - collect_start
             all_inputs: list[T.CheckInput] = []
             for p in group:
@@ -1083,6 +1140,12 @@ class BatchingEvaluator:
         collect_s = time.perf_counter() - collect_start
         flight.timings["collect"] = collect_s
         self.m_stage_seconds.observe("collect", collect_s)
+        # parts of collect, from the thread's clock: the wait for the device
+        # and the one fetch, then slicing and assembly
+        lap = clock.take_lap()
+        for stage in (drainclock.FETCH, drainclock.ASSEMBLE):
+            flight.timings[stage] = lap.get(stage, 0.0)
+            self.m_stage_seconds.observe(stage, flight.timings[stage])
         if self.health is not None:
             self.health.record_success()
         settle_start = time.perf_counter()
@@ -1109,15 +1172,18 @@ class BatchingEvaluator:
         settle_s = time.perf_counter() - settle_start
         flight.timings["settle"] = settle_s
         self.m_stage_seconds.observe("settle", settle_s)
+        # after settle, so none of this adds to THIS flight's latency; it is
+        # the drain thread's time all the same, and the next flight's queue
+        # wait: stage "post" says how much
+        clock.to(drainclock.POST)
         self._record_flight(flight, outcome="ok")
-        # hot-rule heatmap (ISSUE 20): after settle like the sentinel, so
-        # attribution accounting never adds to request latency
+        # hot-rule heatmap (ISSUE 20)
         hotrules.recorder().observe(outputs)
         sentinel = self.sentinel
         if sentinel is not None:
-            # after settle so the sentinel never adds to request latency;
             # observe_batch is guaranteed non-raising and non-blocking
             sentinel.observe_batch(self, flight, outputs)
+        self.m_stage_seconds.observe("post", clock.to(drainclock.OTHER))
 
     def _record_flight(self, flight: _Inflight, outcome: str) -> None:
         health = self.health
@@ -1132,6 +1198,7 @@ class BatchingEvaluator:
             layout_key=flight.layout_key,
             breaker_state=health.state if health is not None else None,
             shard=self.shard_id,
+            submitted_monotonic_ns=flight.submitted_monotonic_ns or None,
         )
 
     def _batch_failed(

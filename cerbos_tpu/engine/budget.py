@@ -59,7 +59,7 @@ STAGE_IPC_ENCODE = "ipc_encode"          # ticket encoded for the shared batcher
 STAGE_TRANSIT = "transit"                # front-end send → batcher receipt (cross-process)
 STAGE_QUEUE_WAIT = "queue_wait"          # batcher enqueue → drain-loop pickup
 STAGE_PACK = "pack"                      # host staging + device dispatch of the batch
-STAGE_DEVICE = "device"                  # device in-flight window (submit return → collect return)
+STAGE_DEVICE = "device"                  # host-clock gap, submit return → collect start (the device's work lies inside it)
 STAGE_COLLECT = "collect"                # device readback + row decode
 STAGE_SETTLE = "settle"                  # result slicing + future settlement (includes in-flight slot waits)
 STAGE_IPC_RETURN = "ipc_return"          # batcher settle → response frame on the front end

@@ -55,6 +55,7 @@ class FlightRecorder:
         layout_key: Optional[str] = None,
         breaker_state: Optional[str] = None,
         shard: Optional[int] = None,
+        submitted_monotonic_ns: Optional[int] = None,
     ) -> None:
         if not self.enabled:
             return
@@ -72,6 +73,10 @@ class FlightRecorder:
             # which lane of the sharded pool carried this batch; None when a
             # single evaluator serves (pre-shard records keep their shape)
             "shard": shard,
+            # time.monotonic_ns() at submit return: the clock a profiler
+            # capture reports at both its ends (tpu/profiler.py), so the
+            # flight can be placed on that capture's trace
+            "submitted_monotonic_ns": submitted_monotonic_ns,
         }
         with self._lock:
             self._records.append(rec)

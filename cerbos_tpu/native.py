@@ -32,12 +32,21 @@ def _build() -> bool:
     if os.path.exists(target) and os.path.getmtime(target) >= os.path.getmtime(src):
         return True
     include = sysconfig.get_path("include")
-    cmd = ["g++", "-O2", "-fPIC", "-shared", "-std=c++17", f"-I{include}", "-o", target, src]
+    # several processes may build at once (test workers, forked servers): each
+    # compiles to a name of its own and renames it into place, so that none
+    # ever imports a half-written library
+    tmp = f"{target}.{os.getpid()}.tmp"
+    cmd = ["g++", "-O2", "-fPIC", "-shared", "-std=c++17", f"-I{include}", "-o", tmp, src]
     try:
         result = subprocess.run(cmd, capture_output=True, text=True, timeout=120)
+        if result.returncode == 0:
+            os.replace(tmp, target)
     except (OSError, subprocess.TimeoutExpired) as e:
         log.debug("native build unavailable: %s", e)
         return False
+    finally:
+        if os.path.exists(tmp):
+            os.unlink(tmp)
     if result.returncode != 0:
         log.warning("native build failed: %s", result.stderr.strip()[:500])
         return False

@@ -142,8 +142,14 @@ class SpanExporter:
     """Export finished spans; the default sink is the debug log. An OTLP
     exporter implements the same single-method interface."""
 
+    _log = logging.getLogger("cerbos_tpu.tracing")
+
     def export(self, span: Span, duration_ms: float) -> None:
-        logging.getLogger("cerbos_tpu.tracing").debug(
+        # runs for every span of every request: build the record only when
+        # somebody reads it
+        if not self._log.isEnabledFor(logging.DEBUG):
+            return
+        self._log.debug(
             "span %s", span.name,
             extra={"fields": {"traceId": span.trace_id, "spanId": span.span_id,
                               "parentId": span.parent_id, "durationMs": round(duration_ms, 3),
@@ -264,6 +270,29 @@ def init_otlp_from_env() -> bool:
 _exporter: SpanExporter = SpanExporter()
 _current: dict[int, Span] = {}  # thread id -> active span
 
+# True only while tpu/profiler.py has a capture open; the profiler runs in the
+# process that owns the device, so jax is imported there already
+capture_open = False
+_NO_REGION = contextlib.nullcontext()
+
+
+def set_capture_open(is_open: bool) -> None:
+    global capture_open
+    capture_open = bool(is_open)
+
+
+def region(name: str, **args: Any):
+    """A named region on the profiler's trace (``jax.profiler.TraceAnnotation``,
+    on the trace's own clock), emitted only while a capture is open: one
+    boolean read otherwise, and never an import of jax in a process that owns
+    no device. ``start_span`` opens one under the span's name, so the trace
+    shows the program's spans between the device's operations."""
+    if not capture_open:
+        return _NO_REGION
+    from jax.profiler import TraceAnnotation
+
+    return TraceAnnotation(name, **args)
+
 
 def set_exporter(exporter: SpanExporter) -> None:
     global _exporter
@@ -309,7 +338,8 @@ def start_span(
     )
     _current[tid] = span
     try:
-        yield span
+        with region(name):
+            yield span
     finally:
         if prev is None:
             _current.pop(tid, None)
@@ -327,8 +357,8 @@ def export_span(
     **attributes: Any,
 ) -> Span:
     """Synthesize and export a span for an interval measured elsewhere (the
-    in-flight device window has no thread executing it; the batcher stamps
-    its start/end around submit/collect instead)."""
+    gap between a flight's submit returning and its collect starting is
+    nobody's code block; the batcher stamps its two ends instead)."""
     span = Span(
         name=name,
         trace_id=parent.trace_id if parent else new_trace_id(),

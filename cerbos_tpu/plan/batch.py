@@ -172,10 +172,8 @@ class BatchPlanner(Planner):
         the same (principal, action, kind) planned once per list request —
         collapse almost entirely.
         """
-        from ..observability import start_span
-
         params = params or T.EvalParams()
-        with self._lock, start_span("engine.PlanBatch", batch=len(inputs)):
+        with self._lock:
             t0 = time.perf_counter()
             uniques: list[PlanInput] = []
             order: list[int] = []

@@ -29,6 +29,7 @@ from __future__ import annotations
 import logging
 import threading
 import time
+import zlib
 from collections import deque
 from typing import Any, Callable, Optional
 
@@ -43,6 +44,52 @@ _COMPILE_BUCKETS = [0.01, 0.05, 0.1, 0.25, 0.5, 1.0, 2.5, 5.0, 10.0, 20.0, 40.0,
 
 STORM_THRESHOLD = 8
 STORM_WINDOW_S = 120.0
+
+# the dimensions of a jit key, in the order a compile is blamed on them
+NOVEL_DIMS = ("shape", "depth", "variant", "columns")
+NOVEL_COMBINATION = "combination"  # every component seen before, never together
+
+
+def key_components(trace_key: Any) -> Optional[dict]:
+    """The seven components of the evaluator's jit key ``(B_pad, BA_pad, K, J,
+    D, variant, layout.sig)`` (six on the mesh path, which has no column
+    layout), grouped by dimension; None for a key of another shape."""
+    if not isinstance(trace_key, tuple) or len(trace_key) not in (6, 7):
+        return None
+    if not all(isinstance(x, int) for x in trace_key[:5]):
+        return None
+    return {
+        "shape": trace_key[:2],
+        "depth": trace_key[2:5],
+        "variant": trace_key[5],
+        "columns": trace_key[6] if len(trace_key) == 7 else None,
+    }
+
+
+def _digest(component: Any) -> Optional[str]:
+    """A variant or a column layout is a tuple of hundreds of entries: the
+    flight event carries a checksum of it, one that repeats across processes
+    (``hash()`` of strings does not), so two events say whether it differed."""
+    if component is None:
+        return None
+    return f"{zlib.crc32(repr(component).encode()):08x}"
+
+
+class NoveltyClassifier:
+    """Which dimension of the jit key made a compile necessary: the first, in
+    the order of ``NOVEL_DIMS``, whose value no earlier compile had."""
+
+    def __init__(self):
+        self._seen: dict[str, set] = {d: set() for d in NOVEL_DIMS}
+
+    def observe(self, components: dict) -> str:
+        novel = NOVEL_COMBINATION
+        for dim in reversed(NOVEL_DIMS):
+            value = components[dim]
+            if value is not None and value not in self._seen[dim]:
+                self._seen[dim].add(value)
+                novel = dim
+        return novel
 
 
 class RecompileStormDetector:
@@ -108,6 +155,12 @@ class CompileStats:
             "XLA compilations by source: fresh (XLA ran) or persistent (loaded from the on-disk cache)",
             label="source",
         )
+        self.m_novel = reg.counter_vec(
+            "cerbos_tpu_xla_compile_novel_total",
+            "XLA compilations by the first dimension of the jit key that was new: shape (B_pad, BA_pad), "
+            "depth (K, J, D), variant, columns (the column layout), or combination (all seen, never together)",
+            label="dim",
+        )
         self.m_compile_seconds = reg.histogram(
             "cerbos_tpu_xla_compile_seconds",
             "Wall time of each XLA compile (first invocation of a new jit trace)",
@@ -148,6 +201,7 @@ class CompileStats:
         self.detector = RecompileStormDetector(
             threshold=storm_threshold, window_s=storm_window_s, clock=clock
         )
+        self._novelty = NoveltyClassifier()
         self._lock = threading.Lock()
         self._layouts: set[Any] = set()
         self._per_layout: dict[str, int] = {}
@@ -169,7 +223,17 @@ class CompileStats:
         tk = trace_key if trace_key is not None else layout_key
         self.m_compiles.inc(source)
         self.m_compile_seconds.observe(seconds)
+        parts = key_components(trace_key)
+        key_fields: dict[str, Any] = {}
         with self._lock:
+            if parts is not None:
+                novel = self._novelty.observe(parts)
+                (b_pad, ba_pad), (k, j, d) = parts["shape"], parts["depth"]
+                key_fields = {
+                    "B_pad": b_pad, "BA_pad": ba_pad, "K": k, "J": j, "D": d,
+                    "variant": _digest(parts["variant"]), "columns": _digest(parts["columns"]),
+                    "novel": novel,
+                }
             self._compiles += 1
             self._compile_seconds += seconds
             if source == "persistent":
@@ -178,10 +242,13 @@ class CompileStats:
             self._per_layout[layout_key] = self._per_layout.get(layout_key, 0) + 1
             card = len(self._layouts)
         self.m_cardinality.set(card)
+        if key_fields:
+            self.m_novel.inc(key_fields["novel"])
         # every compile is a flight event: /_cerbos/debug/flight then answers
-        # "which layout, how long, fresh or from the persistent cache"
+        # "which layout, how long, fresh or from the persistent cache", and,
+        # with the key's seven components, "what about it was new"
         flight_recorder().record_event(
-            "xla_compile", layout_key=layout_key, seconds=round(seconds, 4), source=source
+            "xla_compile", layout_key=layout_key, seconds=round(seconds, 4), source=source, **key_fields
         )
         distinct = self.detector.observe(tk)
         if distinct is not None:

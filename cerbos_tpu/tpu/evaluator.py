@@ -15,6 +15,7 @@ tensors are batch-aligned so the same jit works single-chip or multi-chip
 
 from __future__ import annotations
 
+import contextlib
 import threading
 import time
 from typing import Any, Optional
@@ -22,6 +23,7 @@ from typing import Any, Optional
 import numpy as np
 
 from .. import namer
+from ..engine import drainclock
 from ..engine import types as T
 from ..observability import start_span
 from ..ruletable.check import EvalContext, build_request_messages, check_input
@@ -60,6 +62,17 @@ CODE_ALLOW = 1
 CODE_DENY = 2
 
 _BIG = 127
+
+
+def _scope(xp, name: str):
+    """A stable name for a part of the device program (``jax.named_scope``:
+    metadata on the traced operations, no operation of its own); nothing on
+    the numpy path, which must not import jax."""
+    if xp is np:
+        return contextlib.nullcontext()
+    import jax
+
+    return jax.named_scope(name)
 
 
 def _sat_groups(xp, compiler, B: int, refs, variant=None):
@@ -154,8 +167,21 @@ def _compute(
     # scope_sp is always [B, 2, D]; column dicts can all be empty when the
     # policy set has only unconditional rules, so B must not come from them
     B = scope_sp.shape[0]
-    sat_cond = _sat_groups(xp, compiler, B, refs, variant=variant)
+    with _scope(xp, "sat_groups"):
+        sat_cond = _sat_groups(xp, compiler, B, refs, variant=variant)
+    with _scope(xp, "lattice"):
+        return _lattice(
+            xp, K, J, D, sat_cond, ba_input, cand_cond, cand_drcond, cand_effect,
+            cand_pt, cand_depth, cand_valid, scope_sp,
+        )
 
+
+def _lattice(
+    xp, K: int, J: int, D: int, sat_cond, ba_input, cand_cond, cand_drcond, cand_effect,
+    cand_pt, cand_depth, cand_valid, scope_sp,
+):
+    """Effect resolution over (policy type, role slot, scope depth): the
+    second half of :func:`_compute`, named ``lattice`` in the device program."""
     BA = cand_cond.shape[0]
     sat_by_input = sat_cond[ba_input]  # [BA, C]
 
@@ -908,6 +934,7 @@ def _device_dispatch(lt: LoweredTable, batch: PackedBatch, jit_cache: dict) -> _
     import jax
     import jax.numpy as jnp
 
+    drainclock.to(drainclock.STACK)
     compiler = lt.compiler
     K, J, D = batch.K, batch.J, batch.D
     BA = batch.cand_cond.shape[0]
@@ -937,21 +964,23 @@ def _device_dispatch(lt: LoweredTable, batch: PackedBatch, jit_cache: dict) -> _
         lay = layout
 
         def run(**kw):
-            parts = _unstack_padded(jnp, lay, kw)
+            with jax.named_scope("unstack"):
+                parts = _unstack_padded(jnp, lay, kw)
             final, role_results, win_j, sat_arr = _compute(
                 jnp, compiler, K, J, D, variant=vt, **parts
             )
-            out = jnp.concatenate(
-                [
-                    final.reshape(BA_pad, -1).astype(jnp.int8),
-                    role_results.reshape(BA_pad, -1).astype(jnp.int8),
-                    win_j.reshape(BA_pad, -1).astype(jnp.int8),
-                ],
-                axis=1,
-            )
-            return jnp.concatenate(
-                [out.ravel(), sat_arr.astype(jnp.int8).ravel()]
-            )
+            with jax.named_scope("pack_result"):
+                out = jnp.concatenate(
+                    [
+                        final.reshape(BA_pad, -1).astype(jnp.int8),
+                        role_results.reshape(BA_pad, -1).astype(jnp.int8),
+                        win_j.reshape(BA_pad, -1).astype(jnp.int8),
+                    ],
+                    axis=1,
+                )
+                return jnp.concatenate(
+                    [out.ravel(), sat_arr.astype(jnp.int8).ravel()]
+                )
 
         fn = jax.jit(run)
         jit_cache[key] = fn
@@ -959,11 +988,14 @@ def _device_dispatch(lt: LoweredTable, batch: PackedBatch, jit_cache: dict) -> _
         # jit defers trace+compile to the first call: time it there so the
         # compile histogram sees the real XLA cost (dispatch of the compiled
         # program stays async and costs microseconds by comparison)
+        drainclock.to(drainclock.COMPILE)
         out = compilestats.timed_first_call(
             f"B{B_pad}xBA{BA_pad}", fn, stacked, trace_key=key
         )
+        drainclock.to(drainclock.DISPATCH)
     else:
         compilestats.stats().record_hit()
+        drainclock.to(drainclock.DISPATCH)
         out = fn(**stacked)
     out.copy_to_host_async()  # start the (single) fetch immediately
     h.out = out
@@ -977,9 +1009,12 @@ def _device_dispatch(lt: LoweredTable, batch: PackedBatch, jit_cache: dict) -> _
 def _device_finalize(h: _DeviceHandle):
     """Block on one in-flight batch and slice its results apart."""
     if h.ready is not None:
+        drainclock.to(drainclock.ASSEMBLE)
         return h.ready
     K, BA = h.K, h.BA
-    flat = np.asarray(h.out)  # ONE device->host fetch
+    drainclock.to(drainclock.FETCH)
+    flat = np.asarray(h.out)  # ONE device->host fetch: the wait for the device is here
+    drainclock.to(drainclock.ASSEMBLE)
     if h.leased:
         # the output is materialized, so every transfer that read the staging
         # buffers has completed — recycle them for the next batch
@@ -1180,6 +1215,7 @@ class TpuEvaluator:
             or self.mesh is not None
             or len(inputs) < self.min_device_batch
         ):
+            drainclock.to(drainclock.ORACLE)
             t.ready = self.check(inputs, params)
             return t
         # split oversized batches along the same chunk boundaries as
@@ -1189,6 +1225,7 @@ class TpuEvaluator:
         t.parts = []
         with start_span("batch.pack", inputs=len(inputs), chunks=len(chunks)), self._device_scope():
             for ch in chunks:
+                drainclock.to(drainclock.PACK)
                 p0 = time.perf_counter()
                 batch = self.packer.pack(ch, params)
                 t.pack_s += time.perf_counter() - p0

@@ -1,0 +1,551 @@
+"""The batcher drain thread's own clock (engine/drainclock.py) and what reads it.
+
+One cursor on one thread: the states tile the thread's life; a flight's new
+stages (stack, dispatch, oracle, fetch, assemble, post) are observed once per
+flight and lie inside the stages they split (submit, collect); the window
+wait is per flight; a compile is blamed on the first new dimension of its jit
+key; the profiler runs with the Python tracer off and the program's regions
+land on its trace with the clock readings at both ends. CPU backend, no
+native extension needed.
+"""
+
+import json
+import os
+import re
+import subprocess
+import sys
+import threading
+import time
+
+import pytest
+
+from cerbos_tpu import observability as obs
+from cerbos_tpu.engine import drainclock as dc
+from cerbos_tpu.engine import flight
+from cerbos_tpu.engine import types as T
+from cerbos_tpu.engine.batcher import BatchingEvaluator
+from cerbos_tpu.tpu import compilestats, profiler
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+if REPO not in sys.path:
+    sys.path.insert(0, REPO)
+
+from benchmarks.lib import prom, spec, trace_reduce  # noqa: E402
+
+THREAD = "cerbos_tpu_batcher_thread_seconds_total"
+STAGE = "cerbos_tpu_batch_stage_seconds"
+WINDOW = "cerbos_tpu_batcher_window_wait_seconds"
+NOVEL = "cerbos_tpu_xla_compile_novel_total"
+NEW_STAGES = ("stack", "dispatch", "oracle", "fetch", "assemble", "post")
+
+
+def spin(seconds: float) -> None:
+    """Hold the CPU (a sleep would book wall time and no CPU time)."""
+    end = time.perf_counter() + seconds
+    while time.perf_counter() < end:
+        pass
+
+
+class Ticket:
+    def __init__(self, inputs):
+        self.inputs = inputs
+        self.pack_s = 0.0
+        self.occupancy = 1.0
+        self.padded_rows = None
+        self.layout_key = "B16xBA16"
+
+
+class FakeStreamingEvaluator:
+    """``submit``/``collect`` that walk the clock's states the way
+    ``TpuEvaluator`` does, reaching it through the thread-local alone."""
+
+    rule_table = None
+    schema_mgr = None
+
+    def __init__(self, gate: threading.Event | None = None):
+        self.gate = gate  # the first submit waits for it, holding the drain thread
+
+    def submit(self, inputs, params=None):
+        if self.gate is not None:
+            self.gate.wait(timeout=10)
+            self.gate = None
+        t = Ticket(inputs)
+        dc.to(dc.PACK)
+        p0 = time.perf_counter()
+        spin(0.0004)
+        t.pack_s = time.perf_counter() - p0
+        dc.to(dc.STACK)
+        spin(0.0003)
+        dc.to(dc.DISPATCH)
+        spin(0.0002)
+        return t
+
+    def collect(self, ticket):
+        dc.to(dc.FETCH)
+        time.sleep(0.001)
+        dc.to(dc.ASSEMBLE)
+        spin(0.0003)
+        return [T.CheckOutput(request_id="", resource_id=str(k)) for k in range(len(ticket.inputs))]
+
+
+class SyncEvaluator:
+    """No streaming API: the batcher calls ``check`` on the drain thread."""
+
+    rule_table = None
+    schema_mgr = None
+
+    def check(self, inputs, params=None):
+        spin(0.0005)
+        return [T.CheckOutput(request_id="", resource_id=str(k)) for k in range(len(inputs))]
+
+
+def scrape() -> dict:
+    return prom.parse(obs.metrics().render())
+
+
+def fly(batcher: BatchingEvaluator, flights: int, inputs: int = 3) -> None:
+    for _ in range(flights):
+        assert len(batcher.check([object()] * inputs)) == inputs
+
+
+@pytest.fixture()
+def shard(request):
+    """A shard label of the test's own: its series start at zero."""
+    return 900 + abs(hash(request.node.name)) % 9000
+
+
+def test_states_tile_the_threads_life_and_cpu_is_the_work_it_held_the_cpu_for(shard):
+    before = scrape()
+    t0 = time.perf_counter()
+    b = BatchingEvaluator(FakeStreamingEvaluator(), max_wait_ms=1.0, shard_id=shard)
+    try:
+        fly(b, 50)
+    finally:
+        b.close()  # joins the drain thread
+    lifetime = time.perf_counter() - t0
+    assert not b._thread.is_alive()
+    d = prom.delta(before, scrape())
+    walls = {s: prom.total(d, THREAD, state=s, clock="wall", shard=str(shard)) for s in dc.STATES}
+    assert sum(walls.values()) == pytest.approx(lifetime, rel=0.02)
+    assert prom.total(d, THREAD, clock="wall", shard=str(shard)) == pytest.approx(sum(walls.values()))
+    # every state the fake walks was booked, waits as waits and work as work
+    for s in (dc.WINDOW, dc.PACK, dc.STACK, dc.DISPATCH, dc.FETCH, dc.ASSEMBLE, dc.SETTLE, dc.POST, dc.OTHER):
+        assert walls[s] > 0, s
+    assert prom.total(d, THREAD, kind="wait", clock="wall", shard=str(shard)) == pytest.approx(
+        walls[dc.IDLE] + walls[dc.WINDOW] + walls[dc.FETCH]
+    )
+    # the CPU clock is the thread's, not split by state, and all of it is work: the fake spins for
+    # 1.2 ms a flight and sleeps through its fetch, so it lies between the spinning and the working wall time
+    cpu = prom.total(d, THREAD, clock="cpu", shard=str(shard))
+    assert cpu == prom.total(d, THREAD, state=dc.ALL, kind="work", clock="cpu", shard=str(shard))
+    work = prom.total(d, THREAD, kind="work", clock="wall", shard=str(shard))
+    assert 50 * 0.0012 * 0.8 <= cpu <= work + 0.011  # the coarsest CPU clock met ticks in 10 ms
+    assert cpu < walls[dc.FETCH] + walls[dc.IDLE] + walls[dc.WINDOW] + work
+
+
+def test_each_new_stage_is_observed_once_per_flight_inside_the_stage_it_splits(shard):
+    before = scrape()
+    b = BatchingEvaluator(FakeStreamingEvaluator(), max_wait_ms=0.5, shard_id=shard)
+    try:
+        fly(b, 20)
+    finally:
+        b.close()
+    d = prom.delta(before, scrape())
+
+    def count(stage):
+        return prom.total(d, STAGE + "_count", stage=stage, shard=str(shard))
+
+    def seconds(stage):
+        return prom.total(d, STAGE + "_sum", stage=stage, shard=str(shard))
+
+    for stage in NEW_STAGES + ("pack", "submit", "device", "collect", "settle"):
+        assert count(stage) == 20, stage
+    assert prom.total(d, WINDOW + "_count", shard=str(shard)) == 20
+    assert 0 < seconds("stack") + seconds("dispatch") <= seconds("submit")
+    assert 0 < seconds("fetch") + seconds("assemble") <= seconds("collect")
+    assert seconds("stack") >= 20 * 0.0003 and seconds("dispatch") >= 20 * 0.0002
+    assert seconds("fetch") >= 20 * 0.001 and seconds("post") > 0
+    assert seconds("oracle") == 0  # a streamed flight has no synchronous check
+
+
+def test_a_flight_without_a_streaming_evaluator_is_booked_as_oracle(shard):
+    before = scrape()
+    b = BatchingEvaluator(SyncEvaluator(), max_wait_ms=0.5, shard_id=shard)
+    try:
+        fly(b, 10, inputs=1)
+    finally:
+        b.close()
+    d = prom.delta(before, scrape())
+    assert prom.total(d, STAGE + "_count", stage="oracle", shard=str(shard)) == 10
+    oracle = prom.total(d, STAGE + "_sum", stage="oracle", shard=str(shard))
+    assert 10 * 0.0005 <= oracle <= prom.total(d, STAGE + "_sum", stage="submit", shard=str(shard))
+    assert prom.total(d, THREAD, state="oracle", clock="wall", shard=str(shard)) == pytest.approx(oracle)
+    for stage in ("stack", "dispatch", "fetch", "assemble"):
+        assert prom.total(d, STAGE + "_sum", stage=stage, shard=str(shard)) == 0
+
+
+def flights_of(shard: int) -> list[dict]:
+    return [r for r in flight.recorder().dump()["batches"] if r["shard"] == shard]
+
+
+def test_window_wait_is_the_whole_window_for_a_lone_request(shard):
+    b = BatchingEvaluator(FakeStreamingEvaluator(), max_wait_ms=30.0, shard_id=shard)
+    try:
+        fly(b, 1)
+    finally:
+        b.close()
+    (rec,) = flights_of(shard)
+    assert 0.029 <= rec["timings"]["window"] < 0.08
+    assert rec["submitted_monotonic_ns"] <= time.monotonic_ns()
+    assert time.monotonic_ns() - rec["submitted_monotonic_ns"] < 60e9
+
+
+def test_window_wait_is_nothing_when_two_requests_are_queued(shard):
+    gate = threading.Event()
+    b = BatchingEvaluator(FakeStreamingEvaluator(gate), max_wait_ms=200.0, shard_id=shard)
+    try:
+        first = b.check_async([object()])
+        time.sleep(0.3)  # the first flight's window is over and its submit holds the drain thread
+        second, third = b.check_async([object()]), b.check_async([object()])
+        gate.set()
+        for fut in (first, second, third):
+            assert len(fut.result(timeout=10)) == 1
+    finally:
+        b.close()
+    lone, pair = flights_of(shard)
+    assert lone["requests"] == 1 and lone["timings"]["window"] >= 0.199
+    assert pair["requests"] == 2 and pair["timings"]["window"] < 0.02
+
+
+# -- which dimension of the jit key made a compile necessary ---------------------
+
+V1, V2 = ((0, None),), ((0, None), (1, (2, 3)))
+S1, S2 = (("a",), (), (), (), (), 1, False), (("a", "b"), (), (), (), (), 1, False)
+
+
+def test_novelty_classifier_on_a_hand_written_sequence():
+    nc = compilestats.NoveltyClassifier()
+    keys = [
+        ((32, 64, 2, 4, 1, V1, S1), "shape"),        # everything is new: blamed on the first
+        ((32, 128, 2, 4, 1, V1, S1), "shape"),       # BA_pad alone
+        ((32, 64, 2, 8, 1, V1, S1), "depth"),        # J alone
+        ((32, 64, 2, 4, 1, V2, S1), "variant"),
+        ((32, 64, 2, 4, 1, V1, S2), "columns"),
+        ((32, 128, 2, 8, 1, V2, S2), "combination"),  # every component seen, never together
+        ((64, 128, 2, 8, 2, V2, S2), "shape"),       # shape before depth
+        ((32, 64, 2, 8, 2, V1, S1), "combination"),  # that (K, J, D) came with the key before
+        ((16, 16, 1, 1, 1, V1), "shape"),            # the mesh path's key has no column layout
+    ]
+    for key, want in keys:
+        assert nc.observe(compilestats.key_components(key)) == want, key
+    assert compilestats.key_components(("a",)) is None
+    assert compilestats.key_components(None) is None
+
+
+def test_the_xla_compile_event_carries_the_seven_components_and_the_novel_one():
+    st = compilestats.CompileStats()
+    before = scrape()
+    st.record_compile("B4096xBA8192", 0.25, source="fresh", trace_key=(4096, 8192, 3, 5, 2, V2, S2))
+    st.record_compile("B4096xBA8192", 0.25, source="persistent", trace_key=(4096, 8192, 3, 5, 2, V1, S2))
+    st.record_compile("B16xBA16", 0.1, trace_key=("opaque",))  # a key of another shape: no component is guessed
+    events = [e for e in flight.recorder().dump()["events"] if e["kind"] == "xla_compile"][-3:]
+    assert {k: events[0][k] for k in ("B_pad", "BA_pad", "K", "J", "D", "novel", "layout_key", "source")} == {
+        "B_pad": 4096, "BA_pad": 8192, "K": 3, "J": 5, "D": 2, "novel": "shape",
+        "layout_key": "B4096xBA8192", "source": "fresh",
+    }
+    assert events[1]["novel"] == "variant" and events[1]["source"] == "persistent"
+    assert events[0]["columns"] == events[1]["columns"] and events[0]["variant"] != events[1]["variant"]
+    assert re.fullmatch(r"[0-9a-f]{8}", events[0]["variant"]) and re.fullmatch(r"[0-9a-f]{8}", events[0]["columns"])
+    assert "novel" not in events[2] and "B_pad" not in events[2]
+    d = prom.delta(before, scrape())
+    assert prom.total(d, NOVEL, dim="shape") == 1 and prom.total(d, NOVEL, dim="variant") == 1
+    assert prom.total(d, NOVEL) == 2
+
+
+# -- the profiler: options, regions, the shared clock ----------------------------
+
+
+def test_run_trace_turns_the_python_tracer_off(monkeypatch, tmp_path):
+    from jax import profiler as jprof
+
+    from cerbos_tpu.tpu import jitcache
+
+    import contextlib
+
+    seen = {}
+
+    @contextlib.contextmanager
+    def trace(path, profiler_options=None, **kw):
+        seen.update(path=path, options=profiler_options, open_at_start=obs.capture_open)
+        yield
+        seen["open_at_stop"] = obs.capture_open
+
+    monkeypatch.setattr(jprof, "trace", trace)
+    monkeypatch.setattr(jitcache, "device", lambda: {"platform": "cpu"})
+    monkeypatch.setattr(time, "sleep", lambda s: seen.update(open_inside=obs.capture_open, slept=s))
+    clocks = profiler._run_trace(str(tmp_path), 0.5)
+    assert seen["options"].python_tracer_level == 0
+    assert seen["options"].host_tracer_level == 1
+    assert seen["slept"] == 0.5 and seen["open_inside"] is True
+    assert seen["open_at_start"] is False and seen["open_at_stop"] is False and obs.capture_open is False
+    assert set(clocks) == {
+        "trace_start_monotonic_ns", "trace_start_unix_ns", "trace_stop_monotonic_ns", "trace_stop_unix_ns",
+    }
+    assert clocks["trace_start_monotonic_ns"] <= clocks["trace_stop_monotonic_ns"] <= time.monotonic_ns()
+
+
+def test_a_front_end_cannot_open_a_capture(monkeypatch, tmp_path):
+    from cerbos_tpu.tpu import jitcache
+
+    monkeypatch.setattr(jitcache, "device", lambda: None)
+    with pytest.raises(profiler.ProfilerDisabled):
+        profiler._run_trace(str(tmp_path), 0.1)
+    assert obs.capture_open is False
+
+
+@pytest.fixture(scope="module")
+def capture(tmp_path_factory):
+    """One real capture of a second over a few flights of the real evaluator,
+    on the CPU backend: the reply and the planes of its trace."""
+    from test_streaming_serving import inp, table
+
+    from cerbos_tpu.tpu import TpuEvaluator, jitcache
+
+    jitcache.open_device()
+    ev = TpuEvaluator(table(), use_jax=True, min_device_batch=4)
+    b = BatchingEvaluator(ev, max_wait_ms=1.0, shard_id=77)
+    base = tmp_path_factory.mktemp("profiles")
+    profiler.configure(enabled=True, dir=str(base))
+    try:
+        b.check([inp(i) for i in range(8)])  # compiles before the capture
+        box = {}
+        thread = threading.Thread(target=lambda: box.update(profiler.capture(1.0)))
+        thread.start()
+        time.sleep(0.15)
+        for k in range(6):
+            b.check([inp(i) for i in range(8)])
+            b.check([inp(k)])  # under minDeviceBatch: the oracle state
+            time.sleep(0.01)
+        thread.join(timeout=60)
+        assert not thread.is_alive()
+    finally:
+        profiler.configure()
+        b.close()
+    path = trace_reduce.find_xplane(box["path"])
+    assert path is not None
+    with open(path, "rb") as f:
+        planes = trace_reduce.read_planes(f.read())
+    records = [r for r in flights_of(77) if r["inputs"] == 8]
+    return {"reply": box, "planes": planes, "bytes": os.path.getsize(path), "flights": records}
+
+
+def host_event_names(planes) -> dict[str, int]:
+    names: dict[str, int] = {}
+    for p in planes:
+        if p["name"].startswith("/host:"):
+            for line in p["lines"]:
+                for _, _, name in line["events"]:
+                    names[name] = names.get(name, 0) + 1
+    return names
+
+
+def test_a_real_capture_holds_the_programs_regions_and_no_python_frame(capture):
+    names = host_event_names(capture["planes"])
+    for want in ("batch.pack", "batch.stack", "batch.dispatch", "batch.fetch", "batch.assemble", "batch.post",
+                 "batch.oracle", "batcher.idle", "batcher.window", "batch.submit", "batch.collect", "request.settle"):
+        assert names.get(want, 0) >= 6, (want, names)
+    assert names["cerbos.clock"] == 2
+    frames = [n for n in names if ".py" in n or n.startswith("$")]
+    assert not frames, frames
+    assert capture["bytes"] < 2 << 20  # six flights: hundreds of kilobytes, not the Python tracer's megabytes
+
+
+def test_the_captures_reply_places_flights_on_the_trace(capture):
+    reply = capture["reply"]
+    assert reply["seconds"] == 1.0 and os.path.isdir(reply["path"])
+    lo, hi = reply["trace_start_monotonic_ns"], reply["trace_stop_monotonic_ns"]
+    assert 0.99e9 <= hi - lo < 3e9
+    assert abs((reply["trace_stop_unix_ns"] - reply["trace_start_unix_ns"]) - (hi - lo)) < 50e6
+    inside = [r for r in capture["flights"] if lo <= r["submitted_monotonic_ns"] <= hi]
+    assert len(inside) == 6  # the flight before the capture is outside it
+    assert not obs.capture_open
+
+
+def test_region_with_no_capture_open_imports_no_jax_and_emits_nothing():
+    code = (
+        "import sys\n"
+        "from cerbos_tpu import observability as obs\n"
+        "from cerbos_tpu.engine import drainclock\n"
+        "clock = drainclock.install('0')\n"
+        "for state in drainclock.STATES:\n"
+        "    clock.to(state)\n"
+        "assert clock._region is None\n"
+        "assert obs.region('batch.pack') is obs.region('batcher.idle')\n"
+        "with obs.region('x', k=1):\n"
+        "    pass\n"
+        "with obs.start_span('y'):\n"
+        "    pass\n"
+        "assert 'jax' not in sys.modules, 'jax was imported'\n"
+        "print('ok')\n"
+    )
+    out = subprocess.run([sys.executable, "-c", code], cwd=REPO, capture_output=True, text=True, timeout=120)
+    assert out.returncode == 0 and out.stdout.strip() == "ok", out.stderr
+
+
+def test_to_is_nothing_on_a_thread_without_a_clock():
+    box = {}
+
+    def other_thread():
+        box["clock"] = getattr(dc._tls, "clock", None)
+        dc.to(dc.PACK)
+        box["done"] = True
+
+    t = threading.Thread(target=other_thread)
+    t.start()
+    t.join(timeout=10)
+    assert box == {"clock": None, "done": True}
+
+
+def test_span_export_builds_nothing_when_debug_logging_is_off():
+    class Exploding:  # a mapping whose unpacking into the record would show
+        def keys(self):
+            raise AssertionError("the record was built")
+
+    import logging
+
+    span = obs.Span(name="x", trace_id=obs.new_trace_id())
+    span.attributes = Exploding()
+    log = logging.getLogger("cerbos_tpu.tracing")
+    old = log.level
+    try:
+        log.setLevel(logging.INFO)
+        obs.SpanExporter().export(span, 1.0)
+        log.setLevel(logging.DEBUG)
+        with pytest.raises(AssertionError):
+            obs.SpanExporter().export(span, 1.0)
+    finally:
+        log.setLevel(old)
+
+
+def test_one_flights_worth_of_the_clock_costs_microseconds(shard):
+    """12 ``to()``, 2 ``take_lap`` and 7 histogram observes: what a flight
+    pays with no capture open (the CPU clock, read ten times a second, apart). Printed for PERF.md (``pytest -s``); the limit
+    is loose, a tenth of the cheapest stage."""
+    from cerbos_tpu.engine.batcher import _ShardStageView
+
+    clock = dc.install(str(shard))
+    try:
+        b = BatchingEvaluator.__new__(BatchingEvaluator)
+        b._shard_label = str(shard)
+        b._init_metrics()
+        stages: _ShardStageView = b.m_stage_seconds
+        walk = (dc.IDLE, dc.OTHER, dc.WINDOW, dc.OTHER, dc.PACK, dc.STACK, dc.DISPATCH, dc.OTHER,
+                dc.FETCH, dc.ASSEMBLE, dc.SETTLE, dc.POST)
+
+        def one_flight():
+            for state in walk[:8]:
+                clock.to(state)
+            lap = clock.take_lap()
+            b.m_window_wait.observe(0.002)
+            for stage in ("stack", "dispatch", "oracle"):
+                stages.observe(stage, lap.get(stage, 0.0))
+            for state in walk[8:]:
+                dc.to(state)
+            lap = clock.take_lap()
+            for stage in ("fetch", "assemble"):
+                stages.observe(stage, lap.get(stage, 0.0))
+            stages.observe("post", clock.to(dc.OTHER))
+
+        for _ in range(500):
+            one_flight()
+        best = min(_timed(one_flight, 2000) for _ in range(5))
+    finally:
+        del dc._tls.clock
+    print(f"\ndrain clock, per flight, no capture open: {best * 1e6:.1f} us")
+    assert best < 500e-6
+
+
+def _timed(fn, n: int) -> float:
+    t0 = time.perf_counter()
+    for _ in range(n):
+        fn()
+    return (time.perf_counter() - t0) / n
+
+
+# -- the new per-layer metric files, on hand-written scrapes ----------------------
+
+BENCH = os.path.join(REPO, "benchmarks")
+
+
+def thread_series(walls: dict, cpu: float) -> str:
+    kind = {s: "wait" if s in dc.WAIT else "work" for s in dc.STATES}
+    return f'{THREAD}{{state="all",kind="work",clock="cpu",shard="0"}} {cpu}\n' + "".join(
+        f'{THREAD}{{state="{s}",kind="{kind[s]}",clock="wall",shard="0"}} {v}\n' for s, v in walls.items()
+    )
+
+
+def stage_series(rows: dict) -> str:
+    return "".join(
+        f'{STAGE}_sum{{stage="{s}",shard="0"}} {total}\n{STAGE}_count{{stage="{s}",shard="0"}} {n}\n'
+        for s, (total, n) in rows.items()
+    )
+
+
+BEFORE = (
+    stage_series({"pack": (1.0, 100), "stack": (0.5, 100), "dispatch": (0.25, 100), "fetch": (0.125, 100),
+                  "assemble": (2.0, 100), "settle": (0.0625, 100), "post": (0.75, 100), "oracle": (0.3, 100),
+                  "device": (9.0, 100)})
+    + f'{WINDOW}_sum{{shard="0"}} 0.19\n{WINDOW}_count{{shard="0"}} 100\n'
+    + thread_series({"idle": 50.0, "window": 2.0, "pack": 10.0, "fetch": 1.0}, cpu=9.0)
+    + f'{NOVEL}{{dim="shape"}} 12\n{NOVEL}{{dim="columns"}} 30\n{NOVEL}{{dim="combination"}} 6\n'
+)
+AFTER = (
+    stage_series({"pack": (1.0 + 0.2, 200), "stack": (0.5 + 0.11, 200), "dispatch": (0.25 + 0.07, 200),
+                  "fetch": (0.125 + 0.03, 200), "assemble": (2.0 + 0.31, 200), "settle": (0.0625 + 0.009, 200),
+                  "post": (0.75 + 0.05, 200), "oracle": (0.3 + 0.045, 200), "device": (9.0 + 0.18, 200)})
+    + f'{WINDOW}_sum{{shard="0"}} {0.19 + 0.19}\n{WINDOW}_count{{shard="0"}} 200\n'
+    + thread_series({"idle": 50.0 + 26.0, "window": 2.0 + 3.0, "pack": 10.0 + 10.0, "fetch": 1.0 + 1.0}, cpu=9.0 + 8.0)
+    + f'{NOVEL}{{dim="shape"}} 13\n{NOVEL}{{dim="columns"}} 30\n{NOVEL}{{dim="combination"}} 6\n'
+)
+EXPECTED = {
+    "pack_mean_ms.pages": 2.0, "stack_mean_ms.pages": 1.1, "dispatch_mean_ms.pages": 0.7,
+    "fetch_wait_mean_ms.pages": 0.3, "assemble_mean_ms.pages": 3.1, "settle_mean_ms.pages": 0.09,
+    "post_settle_mean_ms.pages": 0.5, "oracle_eval_mean_ms.sidecar": 0.45,
+    "window_wait_mean_ms.pages": 1.9, "window_wait_mean_ms.sidecar": 1.9,
+    # wall: idle 26 + window 3 + fetch 1 waiting, pack 10 working, of 40 s
+    "batcher_busy_share.pages": 25.0, "batcher_busy_share.sidecar": 25.0,
+    # the working 10 s of wall held the CPU for 8 s
+    "batcher_cpu_share.pages": 80.0, "batcher_cpu_share.sidecar": 80.0,
+    "warm_layouts.pages": 48.0, "warm_shapes.pages": 12.0,  # at the window's open
+}
+
+
+def read_metric(name: str, before: str, after: str):
+    with open(os.path.join(BENCH, "metrics", name + ".json")) as f:
+        body = json.load(f)
+    ctx = {"before": prom.parse(before), "after": prom.parse(after)}
+    return spec.load_reader(BENCH, body["reader"])(ctx, **body["args"])
+
+
+@pytest.mark.parametrize("name", sorted(EXPECTED))
+def test_new_metric_file_reads_two_hand_written_scrapes(name):
+    assert read_metric(name, BEFORE, AFTER) == pytest.approx(EXPECTED[name])
+
+
+PARENT = 'cerbos_tpu_batch_stage_seconds_sum{stage="pack",shard="0"} 1\ncerbos_tpu_xla_compiles_total{source="fresh"} 3\n'
+
+
+@pytest.mark.parametrize("name", sorted(set(EXPECTED) - {"pack_mean_ms.pages"}))
+def test_new_metric_file_reads_nothing_from_a_program_without_the_clock(name):
+    """The parent commit has none of these series: the line leaves the metric out."""
+    assert read_metric(name, PARENT, PARENT) is None
+
+
+def test_every_new_metric_is_in_the_manifest_under_its_layer():
+    with open(os.path.join(REPO, "BENCHMARK.json")) as f:
+        per_layer = {m["name"]: m for m in json.load(f)["per_layer"]}
+    for name in EXPECTED:
+        entry = per_layer[name]
+        assert "workloads" not in entry
+        assert entry["moves"] == ("check_p50_ms" if name.endswith(".sidecar") else "page_p50_ms")
+        assert entry["source"] == ("program_counter" if "share" in name or name.startswith("warm_") else "program_span")
