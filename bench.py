@@ -550,7 +550,7 @@ def served_main(
             dispatch = FaultInjector(ev, fault_spec)
         health = DeviceHealth()
         batcher = BatchingEvaluator(
-            dispatch, max_batch=1024, max_wait_ms=2.0, min_batch_to_wait=8, max_inflight=3, health=health
+            dispatch, max_batch=1024, max_wait_ms=2.0, max_inflight=3, health=health
         )
     # parity sentinel over the bench's own lanes: the served artifact's
     # correctness block. Rate/corpus overridable for the chaos drill.

@@ -15,6 +15,12 @@ its results land. Wall-clock under concurrent load approaches
 max(host pack/assembly, device work) instead of their sum — the same
 double-buffering bench.py measures, now on the serving path.
 
+Requests coalesce by queueing behind the flight in progress. A queue that
+holds a check is drained at once: a page is a device batch already, and no
+traffic measured on the chip brings a lone check its fifteen companions within
+``batchWindowMs`` (PERF.md section 6, PR 25). The loop itself waits for more
+only with nothing in flight and plan queries alone queued (``_plans_alone``).
+
 The device path is a supervised fault domain (docs/ROBUSTNESS.md):
 
 - a ``DeviceHealth`` breaker routes ``check()`` straight to the CPU oracle
@@ -351,6 +357,8 @@ class BatchingEvaluator:
         self._shard_label = str(shard_id) if shard_id is not None else "0"
         self.max_batch = max_batch
         self.request_timeout = request_timeout_s
+        # the coalescing window, entered for plan queries alone (_plans_alone):
+        # how long it may last, and how many REQUESTS end it
         self.max_wait = max_wait_ms / 1000.0
         self.min_batch_to_wait = min_batch_to_wait
         self.max_inflight = max(1, int(max_inflight))
@@ -473,8 +481,9 @@ class BatchingEvaluator:
         self.m_stage_seconds = _ShardStageView(self._m_stage_vec, self._shard_label)
         self.m_window_wait = reg.histogram_vec(
             "cerbos_tpu_batcher_window_wait_seconds",
-            "per flight: how long the drain loop deliberately waited (batchWindowMs) for a second "
-            "request before draining it; part of every rider's queue wait, by shard",
+            "per flight: how long the drain loop deliberately waited (at most batchWindowMs) for a second "
+            "plan query before draining the queue, 0 where it did not wait (every queue that held a "
+            "check); part of every rider's queue wait, by shard",
             label="shard",
             buckets=[0.0001, 0.0005, 0.001, 0.002, 0.005, 0.01, 0.025, 0.05, 0.1],
         ).labels(self._shard_label)
@@ -843,17 +852,13 @@ class BatchingEvaluator:
                         self._wakeup.wait()
                         clock.to(drainclock.OTHER)
                         continue
-                elif barrier is None and not inflight and self.max_wait > 0:
-                    # small wait to let concurrent requests coalesce (only
+                elif barrier is None and not inflight and self.max_wait > 0 and self._plans_alone():
+                    # small wait to let concurrent plan queries coalesce (only
                     # while the pipeline is empty: with batches in flight the
                     # collect below provides the coalescing window for free)
                     clock.to(drainclock.WINDOW)
                     deadline = time.monotonic() + self.max_wait
-                    while (
-                        len(self._queue) < self.min_batch_to_wait
-                        and not self._stop
-                        and self._swap_barrier is None
-                    ):
+                    while self._plans_alone() and not self._stop and self._swap_barrier is None:
                         remaining = deadline - time.monotonic()
                         if remaining <= 0:
                             break
@@ -916,6 +921,14 @@ class BatchingEvaluator:
                 with self._wakeup:
                     if self._swap_barrier is barrier:
                         self._swap_barrier = None
+
+    def _plans_alone(self) -> bool:
+        """Under the lock: is the queue one the coalescing window may hold back?
+        Only fewer than ``min_batch_to_wait`` plan queries and nothing else: the
+        batched planner gains by deduplication inside a flight. A check never
+        waits (so the scan below meets at most ``min_batch_to_wait`` - 1 items)."""
+        q = self._queue
+        return len(q) < self.min_batch_to_wait and all(p.kind == "plan" for p in q)
 
     def _submit(self, pending: list[_Pending], inflight: deque, window_s: float = 0.0) -> None:
         # group by (kind, params identity): globals etc. must match within a

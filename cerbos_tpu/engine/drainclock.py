@@ -33,7 +33,7 @@ from typing import Optional
 from .. import observability
 
 IDLE = "idle"          # wait: queue empty and nothing in flight, or parked at a cutover barrier
-WINDOW = "window"      # wait: the coalescing window (batchWindowMs) for a second request
+WINDOW = "window"      # wait: the coalescing window, up to batchWindowMs for a second plan query (a check never waits)
 PACK = "pack"          # rows of the flight's inputs -> PackedBatch
 STACK = "stack"        # variant choice, candidate remap, pad + stack into transfer matrices
 DISPATCH = "dispatch"  # the jitted call (host->device puts) and the start of the result copy

@@ -24,6 +24,7 @@ from cerbos_tpu.engine.health import DeviceHealth
 from cerbos_tpu.observability import metrics
 from cerbos_tpu.policy.parser import parse_policies
 from cerbos_tpu.ruletable import build_rule_table, check_input
+from flightgate import FlightGate
 
 pytestmark = pytest.mark.chaos
 
@@ -299,24 +300,23 @@ class TestPoisonQuarantine:
         requests all get correct answers (never an error), and the poison is
         bisected out and quarantined."""
         rt = table()
-        inj = FaultInjector(OracleEvaluator(rt), "poison_attr:poison")
+        gate = FlightGate(FaultInjector(OracleEvaluator(rt), "poison_attr:poison"))
         health = DeviceHealth(failure_threshold=100)  # keep the breaker out of this test
-        batcher = BatchingEvaluator(
-            inj,
-            max_wait_ms=200.0,
-            min_batch_to_wait=9,
-            request_timeout_s=10.0,
-            health=health,
-        )
+        batcher = BatchingEvaluator(gate, request_timeout_s=10.0, health=health)
         poison = inp(99, poison=True)
         goods = [inp(i) for i in range(8)]
         try:
-            # a concurrent burst so poison and innocents co-batch
+            # a concurrent burst behind a flight in progress, so poison and
+            # innocents co-batch
+            plug = gate.hold(batcher, [inp(1000)])
             with concurrent.futures.ThreadPoolExecutor(max_workers=9) as pool:
                 good_futs = [pool.submit(batcher.check, [g]) for g in goods]
                 poison_fut = pool.submit(batcher.check, [poison])
+                gate.release(batcher, queued=9)
                 good_results = [f.result(timeout=15)[0] for f in good_futs]
                 poison_result = poison_fut.result(timeout=15)
+            assert len(plug.result(timeout=15)) == 1
+            assert batcher.stats["batches"] == 1  # the plug's; the burst was one flight, and it failed
             # nobody errored, everybody is bit-exact vs the oracle
             assert effects(good_results) == effects(oracle(rt, goods))
             assert effects(poison_result) == effects(oracle(rt, [poison]))
@@ -345,20 +345,19 @@ class TestPoisonQuarantine:
         """When every sub-batch fails (device down, not poison), the bisect
         must not quarantine innocent inputs."""
         rt = table()
-        inj = FaultInjector(OracleEvaluator(rt), "submit_raise:1.0,check_raise:1.0")
+        gate = FlightGate(FaultInjector(OracleEvaluator(rt), "submit_raise:1.0,check_raise:1.0"))
         health = DeviceHealth(failure_threshold=100)
-        batcher = BatchingEvaluator(
-            inj,
-            max_wait_ms=200.0,
-            min_batch_to_wait=4,
-            request_timeout_s=10.0,
-            health=health,
-        )
+        batcher = BatchingEvaluator(gate, request_timeout_s=10.0, health=health)
         try:
+            # four requests in one flight, so the bisect has siblings to try
+            plug = gate.hold(batcher, [inp(1000)])
             with concurrent.futures.ThreadPoolExecutor(max_workers=4) as pool:
                 futs = [pool.submit(batcher.check, [inp(i)]) for i in range(4)]
+                gate.release(batcher, queued=4)
                 results = [f.result(timeout=15)[0] for f in futs]
+            assert plug.exception(timeout=15) is not None  # its flight failed like every other
             assert effects(results) == effects(oracle(rt, [inp(i) for i in range(4)]))
+            assert batcher.stats["batch_errors"] == 2  # the plug's flight and ONE flight of four
             # give the bisect thread a beat, then confirm it stayed silent
             deadline = time.monotonic() + 2.0
             while batcher._bisect_busy and time.monotonic() < deadline:

@@ -269,7 +269,7 @@ class TestGoodputUnderWedge:
         # the device wedges: later requests blow their deadlines and must
         # land in outcome=expired
         wedged = FaultInjector(OracleEvaluator(rt), "wedge_after:2,wedge_sleep_s:1")
-        b = BatchingEvaluator(wedged, max_wait_ms=1.0, min_batch_to_wait=1)
+        b = BatchingEvaluator(wedged, max_wait_ms=1.0)
         vec = tracker.m_decisions
         before = {k: vec.get(("check", k)) for k in (OUTCOME_MET, OUTCOME_EXPIRED)}
         try:

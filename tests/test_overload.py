@@ -35,6 +35,7 @@ from cerbos_tpu.engine.budget import Waterfall
 from cerbos_tpu.engine.ipc import RemoteBatcherClient
 from cerbos_tpu.engine.pressure import HIGH_WATER, PressureMonitor
 from cerbos_tpu.engine.readiness import ReadinessState
+from flightgate import FlightGate
 
 pytestmark = pytest.mark.overload
 
@@ -655,7 +656,7 @@ resourcePolicy:
 """
 
 
-def _plain_batcher(**kw):
+def _plain_batcher(wrap=lambda ev: ev, **kw):
     from cerbos_tpu.compile import compile_policy_set
     from cerbos_tpu.policy.parser import parse_policies
     from cerbos_tpu.ruletable import build_rule_table, check_input
@@ -669,7 +670,7 @@ def _plain_batcher(**kw):
         def check(self, inputs, params=None):
             return [check_input(rt, i, params or EvalParams()) for i in inputs]
 
-    return BatchingEvaluator(PlainEvaluator(), **kw)
+    return BatchingEvaluator(wrap(PlainEvaluator()), **kw)
 
 
 def _inp(i: int) -> CheckInput:
@@ -682,11 +683,13 @@ def _inp(i: int) -> CheckInput:
 
 class TestBatcherQueueBudget:
     def test_over_budget_lane_refuses_without_touching_the_ring(self):
-        # a huge min_batch + window parks enqueued requests in the lanes so
-        # the budget check sees a stable backlog
-        batcher = _plain_batcher(max_wait_ms=30000.0, min_batch_to_wait=10000)
+        # a first flight held on the drain thread parks what is enqueued
+        # behind it in the lanes, so the budget check sees a stable backlog
+        batcher = _plain_batcher(wrap=FlightGate)
+        gate = batcher.evaluator
         try:
             batcher.configure_lanes([("gold", 0, 4, 0), ("default", 1, 1, 1)])
+            plug = gate.hold(batcher, [_inp(1000)])
             refusals0 = batcher.stats["lane_refusals"]
             mq0 = batcher.m_queue_budget.get("default")
             fut1 = batcher.check_async([_inp(0)])
@@ -708,8 +711,9 @@ class TestBatcherQueueBudget:
             # the unbudgeted gold lane still admits
             fut3 = batcher.check_async([_inp(3)], pclass="gold")
             assert batcher.lane_depths() == {"gold": 1, "default": 1}
-            for fut in (fut1, fut3):
-                fut.cancel()
+            gate.release()
+            for fut in (plug, fut1, fut3):
+                assert len(fut.result(timeout=10)) == 1
         finally:
             batcher.close()
 

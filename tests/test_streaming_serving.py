@@ -18,6 +18,7 @@ from cerbos_tpu.policy.parser import parse_policies
 from cerbos_tpu.ruletable import build_rule_table, check_input
 from cerbos_tpu.tpu import TpuEvaluator
 from cerbos_tpu.tpu import evaluator as evmod
+from flightgate import FlightGate
 
 POLICY = """
 apiVersion: api.cerbos.dev/v1
@@ -64,28 +65,26 @@ class TestStreamingBatcher:
         flight, and every output is bit-exact vs the CPU oracle."""
         rt = table()
         ev = TpuEvaluator(rt, use_jax=True, min_device_batch=4)
-        # max_batch=16 forces 64 requests to drain as 4+ tickets;
-        # min_batch_to_wait=64 with a generous window lets the whole burst
-        # queue before the first drain, so the submit loop demonstrably
-        # stacks tickets instead of racing the clients
-        batcher = BatchingEvaluator(
-            ev,
-            max_batch=16,
-            max_wait_ms=500.0,
-            min_batch_to_wait=64,
-            max_inflight=3,
-        )
+        # max_batch=16 forces 64 requests to drain as 4+ tickets; the whole
+        # burst queues behind a first flight that the gate holds, so the
+        # submit loop demonstrably stacks tickets instead of racing the clients
+        gate = FlightGate(ev)
+        batcher = BatchingEvaluator(gate, max_batch=16, max_inflight=3)
         inputs = [inp(i) for i in range(64)]
         try:
+            plug = gate.hold(batcher, [inp(1000)])
             with concurrent.futures.ThreadPoolExecutor(max_workers=64) as pool:
-                results = list(pool.map(lambda i: batcher.check([i])[0], inputs))
+                futs = [pool.submit(batcher.check, [i]) for i in inputs]
+                gate.release(batcher, queued=64)
+                results = [f.result(timeout=60)[0] for f in futs]
+            assert len(plug.result(timeout=60)) == 1
         finally:
             batcher.close()
 
         want = [check_input(rt, i, EvalParams()) for i in inputs]
         assert effects(results) == effects(want)
-        assert batcher.stats["batches"] >= 4
-        assert batcher.stats["batched_requests"] == 64
+        assert batcher.stats["batches"] == 1 + 4  # the plug's, then 64 requests in flights of max_batch
+        assert batcher.stats["batched_requests"] == 1 + 64
         assert batcher.stats["inflight_peak"] >= 2, batcher.stats
         assert ev.stats["device_inputs"] > 0  # the device path actually ran
 
