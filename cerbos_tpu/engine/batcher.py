@@ -51,6 +51,7 @@ from ..ruletable import check_input
 from . import types as T
 from .admission import OverloadRefused
 from .budget import (
+    FRONT_ENQUEUE,
     POINT_DEVICE_SUBMIT,
     POINT_ENQUEUE,
     STAGE_ADMISSION,
@@ -757,7 +758,7 @@ class BatchingEvaluator:
         deadline budget at the enqueue point."""
         shard = self.shard_id if self.shard_id is not None else 0
         if wf is not None:
-            wf.mark(STAGE_ADMISSION)
+            wf.mark(STAGE_ADMISSION, part=FRONT_ENQUEUE)
         if deadline is not None:
             budget_tracker().observe_budget(
                 POINT_ENQUEUE, deadline - time.monotonic(), shard=shard

@@ -37,6 +37,21 @@ On top of the waterfall:
   requests that reach the device already near-expired are visible before
   ROADMAP item 5 adds early refusal.
 
+**The front and the back of a request, in parts** (PR 27). ``admission`` is
+everything between "decoded" and "enqueued" and ``reply_encode`` everything
+between the drain thread's ``settle`` and the response message, each one
+number. The handlers stamp the same record at the seams inside them
+(``Waterfall.part``: one ``time.monotonic()`` and one tuple a part), and
+``finish`` flushes them beside the stages into
+``cerbos_tpu_request_front_seconds{part}`` (``validate``, ``auxdata``,
+``convert``, ``admit``, ``span``, ``enqueue``: contiguous from the end of the
+decode to the mark that ends ``admission``, or ``ipc_encode`` in a front end)
+and ``cerbos_tpu_request_back_seconds{part}`` (``wake``, ``encode``, and
+``serialize``, which for gRPC lies after ``reply_encode`` and is observed by
+the response serializer). ``cerbos_tpu_request_handler_seconds`` is the
+handler's whole extent, raw bytes in to bytes out. On with the waterfall,
+off with it; no option of their own.
+
 One process-global tracker (the flight-recorder pattern): bootstrap
 configures it from ``engine.tpu.latencyBudget.*``, every layer marks
 through it.
@@ -83,6 +98,22 @@ STAGES = (
     STAGE_ORACLE,
 )
 
+# parts of the front (tile ``admission``) and of the back (``wake`` + ``encode``
+# tile ``reply_encode`` for gRPC; for HTTP ``serialize`` lies inside it too)
+FRONT_VALIDATE = "validate"  # wire validation of the decoded request
+FRONT_AUXDATA = "auxdata"    # the token's extraction and verification (a field test where there is none)
+FRONT_CONVERT = "convert"    # message -> CheckInputs
+FRONT_ADMIT = "admit"        # admission class and try_admit
+FRONT_SPAN = "span"          # deadline, traceparent, request limits, call id, the request span's set-up
+FRONT_ENQUEUE = "enqueue"    # engine.check entry -> the mark that ends admission (lane choice, the queue's lock)
+FRONT_PARTS = (
+    FRONT_VALIDATE, FRONT_AUXDATA, FRONT_CONVERT, FRONT_ADMIT, FRONT_SPAN, FRONT_ENQUEUE,
+)
+BACK_WAKE = "wake"            # the last mark of another thread (settle) -> the handler's thread running again
+BACK_ENCODE = "encode"        # outputs -> response message; span end, audit hand-off
+BACK_SERIALIZE = "serialize"  # response message -> bytes
+BACK_PARTS = (BACK_WAKE, BACK_ENCODE, BACK_SERIALIZE)
+
 OUTCOME_MET = "deadline_met"
 OUTCOME_EXPIRED = "expired"
 OUTCOME_ORACLE = "oracle_fallback"
@@ -98,6 +129,11 @@ POINT_DEVICE_SUBMIT = "device_submit"
 _STAGE_BUCKETS = [
     0.0001, 0.00025, 0.0005, 0.001, 0.0025, 0.005, 0.01, 0.025,
     0.05, 0.1, 0.25, 0.5, 1.0, 2.5, 5.0, 10.0,
+]
+# a part is 1 us (a field test) to a millisecond (50 resources converted)
+_PART_BUCKETS = [
+    0.000005, 0.00001, 0.000025, 0.00005, 0.0001, 0.00025, 0.0005, 0.001,
+    0.0025, 0.005, 0.01, 0.05, 0.25, 1.0,
 ]
 # budget remaining is read against deadlines of ~10ms..30s
 _BUDGET_BUCKETS = [0.001, 0.005, 0.01, 0.05, 0.1, 0.5, 1.0, 5.0, 10.0, 30.0]
@@ -116,7 +152,7 @@ class Waterfall:
 
     __slots__ = (
         "t0", "wall_ns", "stages", "_last", "trace_id", "deadline",
-        "shard", "served_by", "fallback_reason",
+        "shard", "served_by", "fallback_reason", "parts", "_part_from",
     )
 
     def __init__(
@@ -131,18 +167,34 @@ class Waterfall:
         self._last = now
         self.wall_ns = time.time_ns() if wall_ns is None else wall_ns
         self.stages: list[tuple[str, float]] = []
+        # the seams inside admission and reply_encode: every stage mark moves
+        # the parts' cursor with it, so the parts stamped after a mark tile
+        # the time since that mark
+        self.parts: list[tuple[str, float]] = []
+        self._part_from = now
         self.trace_id = trace_id
         self.deadline = deadline
         self.shard: Optional[int] = None
         self.served_by = "device"
         self.fallback_reason = ""
 
-    def mark(self, stage: str, now: Optional[float] = None) -> float:
+    def mark(self, stage: str, now: Optional[float] = None, part: Optional[str] = None) -> float:
+        """``part``: the part that ends at this very instant (``enqueue`` at
+        the admission mark), so the parts add up to the stage exactly."""
         now = time.monotonic() if now is None else now
         dur = max(0.0, now - self._last)
         self.stages.append((stage, dur))
-        self._last = now
+        if part is not None:
+            self.parts.append((part, max(0.0, now - self._part_from)))
+        self._last = self._part_from = now
         return dur
+
+    def part(self, name: str) -> None:
+        """Stamp a seam inside the stage in progress: books the time since
+        the previous seam (or stage mark) to ``name``. One clock read."""
+        now = time.monotonic()
+        self.parts.append((name, max(0.0, now - self._part_from)))
+        self._part_from = now
 
     def add(self, stage: str, dur: float) -> None:
         dur = max(0.0, float(dur))
@@ -210,7 +262,7 @@ class Waterfall:
             self.shard = int(shard)
         ret = (now - self.t0) - self.attributed()
         self.stages.append((STAGE_IPC_RETURN, max(0.0, ret)))
-        self._last = now
+        self._last = self._part_from = now
 
     def snapshot(self) -> dict:
         """Slow-ring / debug-endpoint entry (milliseconds for humans)."""
@@ -222,6 +274,8 @@ class Waterfall:
             "served_by": self.served_by,
             "wall_time_ns": self.wall_ns,
         }
+        if self.parts:
+            out["parts"] = [(p, round(d * 1000, 3)) for p, d in self.parts]
         if self.shard is not None:
             out["shard"] = self.shard
         if self.fallback_reason:
@@ -245,6 +299,30 @@ class BudgetTracker:
         self.m_total = reg.histogram(
             "cerbos_tpu_request_total_seconds",
             "Per-request wall clock from ingress to reply encode (the waterfall total)",
+            buckets=_STAGE_BUCKETS,
+        )
+        m_front = reg.histogram_vec(
+            "cerbos_tpu_request_front_seconds",
+            "Per-request parts of the front half (decode end to the admission mark): "
+            "validate, auxdata, convert, admit, span, enqueue; they add up to the admission stage",
+            label="part",
+            buckets=_PART_BUCKETS,
+        )
+        m_back = reg.histogram_vec(
+            "cerbos_tpu_request_back_seconds",
+            "Per-request parts of the back half: wake (settle to the handler's thread running), "
+            "encode (outputs to response message), serialize (message to bytes)",
+            label="part",
+            buckets=_PART_BUCKETS,
+        )
+        # part -> child histogram, resolved once: the flush and the gRPC
+        # serializer observe without a vec-level lock or a label lookup
+        self._part_children = {p: m_front.labels(p) for p in FRONT_PARTS}
+        self._part_children.update((p, m_back.labels(p)) for p in BACK_PARTS)
+        self.m_handler = reg.histogram(
+            "cerbos_tpu_request_handler_seconds",
+            "The CheckResources handler's whole extent: raw request bytes in to response bytes out "
+            "(client latency less this is outside the program: transport, thread dispatch, the caller)",
             buckets=_STAGE_BUCKETS,
         )
         self.m_budget = reg.histogram_vec(
@@ -330,15 +408,18 @@ class BudgetTracker:
         outcome: str,
         final_stage: Optional[str] = None,
         api: str = "check",
-    ) -> None:
-        """Count the decision and flush the waterfall's stages to the
-        histograms; slower-than-threshold requests land in the slow ring."""
+        final_part: Optional[str] = None,
+    ) -> Optional[float]:
+        """Count the decision and flush the waterfall's stages and parts to
+        the histograms; slower-than-threshold requests land in the slow ring.
+        ``final_part`` ends at the same instant as ``final_stage``. Returns
+        that instant (None with the waterfall off)."""
         self.m_decisions.inc((api, outcome))
         if wf is None:
-            return
+            return None
         now = time.monotonic()
         if final_stage is not None:
-            wf.mark(final_stage, now=now)
+            wf.mark(final_stage, now=now, part=final_part)
         shard = str(wf.shard if wf.shard is not None else 0)
         children = self._stage_children
         for stage, dur in wf.stages:
@@ -347,6 +428,9 @@ class BudgetTracker:
                 child = self.m_stage.labels((stage, shard))
                 children[(stage, shard)] = child
             child.observe(dur)
+        parts = self._part_children
+        for name, dur in wf.parts:
+            parts[name].observe(dur)
         total = wf.attributed()
         self.m_total.observe(total)
         if total >= self.slow_threshold_s:
@@ -355,6 +439,13 @@ class BudgetTracker:
             entry["outcome"] = outcome
             with self._lock:
                 self._ring.append(entry)
+        return now
+
+    def observe_reply(self, t_raw: float, t_serialize: float, now: float) -> None:
+        """gRPC: the response serializer's two observations, after the
+        waterfall is flushed: ``serialize`` and the handler's extent."""
+        self._part_children[BACK_SERIALIZE].observe(max(0.0, now - t_serialize))
+        self.m_handler.observe(max(0.0, now - t_raw))
 
     def count(self, outcome: str, api: str = "check") -> None:
         """Goodput accounting for the waterfall-disabled path."""

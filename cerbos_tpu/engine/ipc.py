@@ -13,7 +13,10 @@ Transport: two interchangeable data planes under one control plane.
 
 - The control plane is always a SOCK_STREAM unix socket, one connection per
   front-end process: HELLO negotiation, status/flight/metrics/slow/pressure
-  snapshots, and — critically — liveness. A dying peer closes the socket,
+  snapshots, the profiler capture a front end forwards to the device owner
+  (PROFILE), the one frame the OWNER originates (SCRAPE: "render your own
+  registry", so that a scrape answered by any front end holds every
+  process of the pool), and — critically — liveness. A dying peer closes the socket,
   and that close is what fails in-flight tickets instantly and flips the
   front end onto its oracle, whichever data plane carried the tickets.
 - ``transport: uds`` (fallback) carries check tickets on that same socket as
@@ -68,7 +71,7 @@ from ..ruletable import check_input
 from . import types as T
 from .admission import OverloadRefused
 from .batcher import DeadlineExceeded, _BatchFailed
-from .budget import STAGE_IPC_ENCODE, STAGE_ORACLE, Waterfall
+from .budget import FRONT_ENQUEUE, STAGE_IPC_ENCODE, STAGE_ORACLE, Waterfall
 from .budget import tracker as budget_tracker
 
 _log = logging.getLogger("cerbos_tpu.engine.ipc")
@@ -94,6 +97,12 @@ T_PRESSURE_R = 14
 T_HELLO_R = 15
 T_HOTRULES = 16
 T_HOTRULES_R = 17
+T_PROFILE = 18    # front end -> owner: run a profiler capture ({"seconds": s})
+T_PROFILE_R = 19
+T_SCRAPE = 20     # OWNER -> front end: send me your metrics text (the one reverse request)
+T_SCRAPE_R = 21
+
+_SCRAPE_WAIT_S = 2.0  # a sibling that has not answered by then is left out of the pooled scrape
 
 _MAX_FRAME = 64 * 1024 * 1024  # a corrupt length must not allocate the moon
 
@@ -464,6 +473,11 @@ class BatcherIpcServer:
         self.transport = transport if transport in ("shm", "uds") else "shm"
         self._listener: Optional[socket.socket] = None
         self._conns: list[socket.socket] = []
+        # attached front ends' control writers by connection (registered at
+        # HELLO), and the SCRAPE requests this side has in the air
+        self._peers: dict[socket.socket, _ConnWriter] = {}
+        self._scrapes: dict[int, Future] = {}
+        self._scrape_id = 0
         self._lock = threading.Lock()
         self._outstanding = 0
         self._out_by = {"uds": 0, "shm": 0}
@@ -607,6 +621,8 @@ class BatcherIpcServer:
                     # the client blocks on it before sending any traffic, so
                     # the writer queue is empty here by construction
                     writer.send(T_HELLO_R, req_id, lambda g=grant: marshal.dumps({"transport": g}))
+                    with self._lock:
+                        self._peers[conn] = writer
                 elif mtype == T_CHECK:
                     self._handle_check(worker, req_id, payload, writer)
                 elif mtype == T_STATUS:
@@ -616,10 +632,28 @@ class BatcherIpcServer:
                     dump = self._flight_snapshot()
                     writer.send(T_FLIGHT_R, req_id, lambda d=dump: marshal.dumps(d))
                 elif mtype == T_METRICS:
-                    from ..observability import metrics
-
-                    text = metrics().render()
-                    writer.send(T_METRICS_R, req_id, lambda t=text: t.encode())
+                    # the whole pool but the asker: gathered on a thread of
+                    # its own, this one keeps reading tickets
+                    threading.Thread(
+                        target=self._pool_metrics,
+                        args=(conn, req_id, writer),
+                        daemon=True,
+                        name="ipc-pool-scrape",
+                    ).start()
+                elif mtype == T_SCRAPE_R:
+                    with self._lock:
+                        fut = self._scrapes.pop(req_id, None)
+                    if fut is not None:
+                        fut.set_result(payload)
+                elif mtype == T_PROFILE:
+                    # seconds long: a thread of its own, so tickets and status
+                    # frames of this front end keep flowing meanwhile
+                    threading.Thread(
+                        target=self._run_profile,
+                        args=(req_id, payload, writer),
+                        daemon=True,
+                        name="ipc-profile",
+                    ).start()
                 elif mtype == T_SLOW:
                     dump = self._slow_snapshot(payload)
                     writer.send(T_SLOW_R, req_id, lambda d=dump: marshal.dumps(d))
@@ -646,6 +680,7 @@ class BatcherIpcServer:
             with self._lock:
                 if conn in self._conns:
                     self._conns.remove(conn)
+                self._peers.pop(conn, None)
             self.m_conns.set(len(self._conns))
             try:
                 conn.close()
@@ -846,6 +881,54 @@ class BatcherIpcServer:
             pass
         return out
 
+    def _pool_metrics(self, asker: socket.socket, req_id: int, writer: _ConnWriter) -> None:
+        """One scrape of the whole pool for the front end that asked: this
+        process's registry as ``worker="batcher"`` and the text every OTHER
+        attached front end renders now (each labels its own series), merged.
+        A sibling that does not answer within ``_SCRAPE_WAIT_S`` is left out."""
+        from ..observability import merge_metrics_texts, metrics, relabel_metrics_text
+
+        asked: list[tuple[int, Future]] = []
+        with self._lock:
+            for conn, peer in self._peers.items():
+                if conn is asker:
+                    continue
+                self._scrape_id += 1
+                fut: Future = Future()
+                self._scrapes[self._scrape_id] = fut
+                asked.append((self._scrape_id, fut))
+                peer.send(T_SCRAPE, self._scrape_id, lambda: b"")
+        texts = [relabel_metrics_text(metrics().render(), "worker", "batcher")]
+        until = time.monotonic() + _SCRAPE_WAIT_S
+        for scrape_id, fut in asked:
+            try:
+                texts.append(fut.result(timeout=max(0.0, until - time.monotonic())).decode())
+            except (FutureTimeoutError, TimeoutError, UnicodeDecodeError):
+                with self._lock:
+                    self._scrapes.pop(scrape_id, None)
+        merged = merge_metrics_texts(*texts)
+        writer.send(T_METRICS_R, req_id, lambda t=merged: t.encode())
+
+    def _run_profile(self, req_id: int, payload: bytes, writer: _ConnWriter) -> None:
+        """A front end's ``/_cerbos/debug/profile``: the capture runs HERE,
+        in the process that owns the device. The reply is what the local
+        handler would answer, plus this process's pid, or the error with the
+        kind the front end maps to a status."""
+        from ..tpu import profiler
+
+        try:
+            seconds = float((marshal.loads(payload) or {}).get("seconds", 2.0))
+            out = {"artifact": {**profiler.capture(seconds), "pid": os.getpid()}}
+        except profiler.ProfilerBusy as e:
+            out = {"kind": "busy", "error": str(e)}
+        except profiler.ProfilerDisabled as e:
+            out = {"kind": "disabled", "error": str(e)}
+        except (ValueError, TypeError, EOFError) as e:
+            out = {"kind": "invalid", "error": str(e)}
+        except Exception as e:  # noqa: BLE001 — the front end must get an answer, not a timeout
+            out = {"kind": "failed", "error": f"{type(e).__name__}: {e}"}
+        writer.send(T_PROFILE_R, req_id, lambda o=out: marshal.dumps(o))
+
     def _slow_snapshot(self, payload: bytes) -> dict:
         """Slow-request ring dump for `/_cerbos/debug/slow` on a front end
         (the ring lives here, where requests actually settle)."""
@@ -947,6 +1030,10 @@ class RemoteBatcherClient:
         self._ever_ready = False
         self._last_status: Optional[dict] = None
         self._stop = False
+        # what this process answers the owner's SCRAPE with; the HTTP server
+        # installs its own scrape body (the registry and the service's
+        # counters, labelled), until then the registry alone
+        self.local_metrics_text: Callable[[], str] = self._registry_text
         self.stats = {
             "oracle_fallbacks": 0,
             "reconnects": 0,
@@ -1160,7 +1247,25 @@ class RemoteBatcherClient:
         except (ValueError, OSError):
             return  # segment torn down mid-pop
 
+    def _registry_text(self) -> str:
+        from ..observability import metrics, relabel_metrics_text
+
+        return relabel_metrics_text(metrics().render(), "worker", self.worker_label)
+
+    def _answer_scrape(self, req_id: int) -> None:
+        try:
+            self._send(T_SCRAPE_R, req_id, self.local_metrics_text().encode())
+        except Exception:  # noqa: BLE001 — the owner leaves a silent sibling out
+            pass
+
     def _settle_frame(self, mtype: int, req_id: int, payload: bytes) -> None:
+        if mtype == T_SCRAPE:
+            # the owner's request, numbered by the owner: rendered off the
+            # reader thread, which also carries check replies on uds
+            threading.Thread(
+                target=self._answer_scrape, args=(req_id,), daemon=True, name="ipc-client-scrape"
+            ).start()
+            return
         with self._plock:
             fut = self._pending.pop(req_id, None)
         if fut is None:
@@ -1317,7 +1422,7 @@ class RemoteBatcherClient:
                 # "pack + ring + unpack" on this plane, and ipc_encode
                 # shrinks to the admission bookkeeping above it
                 if wf is not None:
-                    wf.mark(STAGE_IPC_ENCODE)
+                    wf.mark(STAGE_IPC_ENCODE, part=FRONT_ENQUEUE)
                 carry = self._carry_spec(wf, pclass)
                 t0 = time.perf_counter_ns()
                 frame = native.get().ticket_pack(inputs, deadline_rel, traceparent, carry)
@@ -1331,7 +1436,7 @@ class RemoteBatcherClient:
             # attributed-at-carry) covers only marshal + socket + decode and
             # never double-counts the encode
             if wf is not None:
-                wf.mark(STAGE_IPC_ENCODE)
+                wf.mark(STAGE_IPC_ENCODE, part=FRONT_ENQUEUE)
             carry = self._carry_spec(wf, pclass)
             frame = marshal.dumps((deadline_rel, traceparent, rows, carry))
             self.stats["enc_ns"] += time.perf_counter_ns() - t0
@@ -1613,10 +1718,26 @@ class RemoteBatcherClient:
         return marshal.loads(data)
 
     def fetch_metrics_text(self, timeout: float = 5.0) -> str:
+        """The rest of the pool, rendered for this request: the owner's
+        registry as ``worker="batcher"`` and every other attached front
+        end's scrape body under its own label, merged."""
         mtype, payload = self._request(T_METRICS, b"", timeout=timeout)
         if mtype != T_METRICS_R:
             raise IpcError("unexpected reply to metrics request")
         return payload.decode()
+
+    def fetch_profile(self, seconds: float, timeout: float = 600.0) -> dict:
+        """Run a profiler capture in the device owner (this process holds no
+        device). ``{"artifact": {...}}`` with the owner's pid and the trace's
+        clocks, or ``{"kind", "error"}``. The wait covers the capture and the
+        minutes it can take to stop and write a trace; a dead owner fails the
+        pending request at once."""
+        mtype, data = self._request(
+            T_PROFILE, marshal.dumps({"seconds": float(seconds)}), timeout=seconds + timeout
+        )
+        if mtype != T_PROFILE_R:
+            raise IpcError("unexpected reply to profile request")
+        return marshal.loads(data)
 
     def refresh_table(self, rule_table: Any) -> None:
         """Policy-reload hook: keep the local oracle on the latest table."""

@@ -28,6 +28,12 @@ from ..engine.admission import OverloadRefused, retry_after_header
 from ..engine.admission import controller as admission_controller
 from ..engine.batcher import DeadlineExceeded
 from ..engine.budget import (
+    BACK_ENCODE,
+    BACK_SERIALIZE,
+    FRONT_ADMIT,
+    FRONT_AUXDATA,
+    FRONT_CONVERT,
+    FRONT_VALIDATE,
     OUTCOME_EXPIRED,
     OUTCOME_MET,
     OUTCOME_ORACLE,
@@ -75,6 +81,9 @@ class _IngressStamps:
 
 
 _GRPC_STAMPS = _IngressStamps()
+# the other end: (t_raw, end of reply_encode) keyed by the RESPONSE message's
+# identity, put by the handler and popped by the wrapped response serializer
+_GRPC_REPLY_STAMPS = _IngressStamps()
 
 
 def _stamping_deserializer(deserialize):
@@ -87,6 +96,22 @@ def _stamping_deserializer(deserialize):
         msg = deserialize(data)
         _GRPC_STAMPS.put(id(msg), t_raw, time.monotonic())
         return msg
+
+    return wrapped
+
+
+def _stamping_serializer(serialize):
+    """Wrap a protobuf ``SerializeToString`` so the handler's extent ends
+    where the response BYTES exist: observes ``serialize`` (end of
+    ``reply_encode`` to here) and the handler histogram, once each, for the
+    responses the handler stamped (none with the waterfall off)."""
+
+    def wrapped(msg) -> bytes:
+        stamp = _GRPC_REPLY_STAMPS.pop(id(msg))
+        data = serialize(msg)
+        if stamp is not None:
+            budget_tracker().observe_reply(stamp[0], stamp[1], time.monotonic())
+        return data
 
     return wrapped
 
@@ -292,18 +317,28 @@ def _grpc_rpcs(svc: CerbosService):
         # decode cost is a visible stage instead of unattributed time
         stamp = _GRPC_STAMPS.pop(id(req))
         t_raw = stamp[0] if stamp is not None else time.monotonic()
+        # the record exists from here so the seams of the front half can be
+        # stamped into it; the deadline joins it below
+        wf = budget_tracker().start(t0=t_raw)
+        if wf is not None and stamp is not None:
+            wf.mark(STAGE_INGRESS_PARSE, now=stamp[1])
         verr = wire_validate.check_resources_proto(req)
         if verr:
             budget_tracker().count(OUTCOME_REFUSED)
             ctx.abort(grpc.StatusCode.INVALID_ARGUMENT, verr)
-        wf = None
+        if wf is not None:
+            wf.part(FRONT_VALIDATE)
         ticket = None
         pclass = None
         try:
             aux = None
             if req.HasField("aux_data") and req.aux_data.jwt.token:
                 aux = svc._extract_aux_data(req.aux_data.jwt.token, req.aux_data.jwt.key_set_id)
+            if wf is not None:
+                wf.part(FRONT_AUXDATA)
             inputs = convert.check_resources_request_to_inputs(req, aux)
+            if wf is not None:
+                wf.part(FRONT_CONVERT)
             # front-door admission (see the HTTP handler): refuse with
             # RESOURCE_EXHAUSTED before the batcher sees the request
             adm = admission_controller()
@@ -317,17 +352,16 @@ def _grpc_rpcs(svc: CerbosService):
                 )
                 pclass = cls.name
                 ticket = adm.try_admit(cls)
+            if wf is not None:
+                wf.part(FRONT_ADMIT)
             # propagate the client's gRPC deadline down the device path so
             # already-expired requests are dropped instead of evaluated
             deadline = None
             remaining = ctx.time_remaining()
             if remaining is not None:
                 deadline = time.monotonic() + remaining
-            wf = budget_tracker().start(
-                deadline=deadline, t0=stamp[0] if stamp is not None else None
-            )
-            if wf is not None and stamp is not None:
-                wf.mark(STAGE_INGRESS_PARSE, now=stamp[1])
+            if wf is not None:
+                wf.deadline = deadline
             # W3C trace-context rides gRPC metadata; the parsed context
             # parents the request span so the device batch joins the
             # caller's trace (shim contexts may lack the metadata accessor)
@@ -343,7 +377,11 @@ def _grpc_rpcs(svc: CerbosService):
                     ctx.set_trailing_metadata((("traceparent", trace_ctx.to_traceparent()),))
             resp = convert.outputs_to_check_resources_response(req, outputs, call_id)
             outcome = OUTCOME_ORACLE if wf is not None and wf.served_by == "oracle" else OUTCOME_MET
-            budget_tracker().finish(wf, outcome, final_stage=STAGE_REPLY_ENCODE)
+            t_encoded = budget_tracker().finish(
+                wf, outcome, final_stage=STAGE_REPLY_ENCODE, final_part=BACK_ENCODE
+            )
+            if t_encoded is not None:
+                _GRPC_REPLY_STAMPS.put(id(resp), t_raw, t_encoded)
             return resp
         except OverloadRefused as e:
             admission_controller().observe_refusal(time.monotonic() - t_raw)
@@ -524,7 +562,10 @@ def _grpc_rpcs(svc: CerbosService):
             request_deserializer=_stamping_deserializer(
                 request_pb2.CheckResourcesRequest.FromString
             ),
-            response_serializer=lambda m: m.SerializeToString(),
+            # and the handler's extent ends where the response bytes exist
+            response_serializer=_stamping_serializer(
+                response_pb2.CheckResourcesResponse.SerializeToString
+            ),
         ),
         "PlanResources": grpc.unary_unary_rpc_method_handler(
             plan_resources,
@@ -1122,7 +1163,10 @@ class Server:
         return web.json_response({"transport": "local"})
 
     async def _h_profile(self, request: web.Request) -> web.Response:
-        """Operator-gated jax.profiler capture; see tpu/profiler.py."""
+        """Operator-gated jax.profiler capture; see tpu/profiler.py. A front
+        end holds no device: it forwards the capture to the device owner over
+        the control connection and answers with the owner's reply (its pid,
+        the artifact's path on this machine, the trace's clocks)."""
         from ..tpu import profiler
 
         if not profiler.enabled():
@@ -1134,6 +1178,18 @@ class Server:
         except ValueError:
             return web.json_response({"error": "seconds must be a number"}, status=400)
         loop = asyncio.get_running_loop()
+        ev = getattr(self.svc.engine, "tpu_evaluator", None)
+        if ev is not None and hasattr(ev, "fetch_profile"):
+            try:
+                remote = await loop.run_in_executor(None, ev.fetch_profile, seconds)
+            except Exception as e:  # noqa: BLE001  (owner down or gone mid-capture)
+                return web.json_response(
+                    {"error": f"device owner unreachable: {type(e).__name__}: {e}"}, status=503
+                )
+            if "artifact" in remote:
+                return web.json_response(remote["artifact"])
+            status = {"busy": 409, "disabled": 403, "invalid": 400}.get(remote.get("kind"), 500)
+            return web.json_response({"error": remote.get("error", "")}, status=status)
         try:
             artifact = await loop.run_in_executor(None, profiler.capture, seconds)
         except ValueError as e:
@@ -1157,7 +1213,9 @@ class Server:
     async def _h_server_info(self, request: web.Request) -> web.Response:
         return web.json_response(self.svc.server_info())
 
-    async def _h_metrics(self, request: web.Request) -> web.Response:
+    def _local_metrics_text(self) -> str:
+        """This process's scrape body: the service's counters and the
+        registry, under ``worker="<label>"`` in a pool."""
         m = self.svc.metrics
         lat = sorted(m.check_latency_ms)
 
@@ -1178,37 +1236,40 @@ class Server:
             "# TYPE cerbos_dev_engine_check_batch_size_total counter",
             f"cerbos_dev_engine_check_batch_size_total {sum(m.batch_sizes)}",
         ]
-        from ..observability import merge_metrics_texts, relabel_metrics_text
         from ..observability import metrics as _obs_metrics
+        from ..observability import relabel_metrics_text
 
         # refresh the pressure gauges so every scrape sees current saturation,
         # not the last background tick
         mon = pressure_monitor()
         if mon.enabled:
             try:
-                await asyncio.get_running_loop().run_in_executor(None, mon.sample)
+                mon.sample()
             except Exception:  # noqa: BLE001  (a dead signal source must not break scrapes)
                 pass
         body = "\n".join(lines) + "\n" + _obs_metrics().render()
         label = self.config.worker_label
-        if label:
-            # pool mode: a scrape lands on whichever sibling the kernel picked;
-            # the worker label keeps per-process series distinguishable
-            body = relabel_metrics_text(body, "worker", label)
-            ev = getattr(self.svc.engine, "tpu_evaluator", None)
-            if ev is not None and hasattr(ev, "fetch_metrics_text"):
-                # front-end mode: append the shared batcher process's registry
-                # (batch sizes, occupancy, ipc queue depth) so one scrape sees
-                # the whole device path, not just this front end
-                try:
-                    remote = await asyncio.get_running_loop().run_in_executor(
-                        None, ev.fetch_metrics_text
-                    )
-                    body = merge_metrics_texts(
-                        body, relabel_metrics_text(remote, "worker", "batcher")
-                    )
-                except Exception:  # noqa: BLE001  (batcher down: local series only)
-                    pass
+        # pool mode: a scrape lands on whichever sibling the kernel picked;
+        # the worker label keeps per-process series distinguishable
+        return relabel_metrics_text(body, "worker", label) if label else body
+
+    async def _h_metrics(self, request: web.Request) -> web.Response:
+        loop = asyncio.get_running_loop()
+        body = await loop.run_in_executor(None, self._local_metrics_text)
+        ev = getattr(self.svc.engine, "tpu_evaluator", None)
+        if self.config.worker_label and ev is not None and hasattr(ev, "fetch_metrics_text"):
+            # front-end mode: one pool is one server to a scrape. The device
+            # owner answers with its own registry (worker="batcher": batch
+            # sizes, occupancy, ipc queue depth) and with what every OTHER
+            # attached front end renders for this very request, so two
+            # scrapes that land on different siblings still subtract
+            from ..observability import merge_metrics_texts
+
+            try:
+                rest = await loop.run_in_executor(None, ev.fetch_metrics_text)
+                body = merge_metrics_texts(body, rest)
+            except Exception:  # noqa: BLE001  (batcher down: local series only)
+                pass
         return web.Response(text=body, content_type="text/plain")
 
     async def _h_check_resources(self, request: web.Request) -> web.Response:
@@ -1223,13 +1284,17 @@ class Server:
             return web.json_response({"code": 3, "message": "invalid JSON payload"}, status=400)
         if not isinstance(body, dict):
             return web.json_response({"code": 3, "message": "invalid JSON payload"}, status=400)
+        # the parse stage ends where the body is decoded, as in the gRPC
+        # handler: wire validation is the first part of admission
+        wf = budget_tracker().start(t0=t_raw)
+        if wf is not None:
+            wf.mark(STAGE_INGRESS_PARSE)
         verr = wire_validate.check_resources_body(body)
         if verr:
             budget_tracker().count(OUTCOME_REFUSED)
             return web.json_response({"code": 3, "message": verr}, status=400)
-        wf = budget_tracker().start(t0=t_raw)
         if wf is not None:
-            wf.mark(STAGE_INGRESS_PARSE)
+            wf.part(FRONT_VALIDATE)
         ticket = None
         pclass = None
         try:
@@ -1237,7 +1302,11 @@ class Server:
             aux_j = (body.get("auxData") or {}).get("jwt") or {}
             if aux_j.get("token"):
                 aux = self.svc._extract_aux_data(aux_j["token"], aux_j.get("keySetId", ""))
+            if wf is not None:
+                wf.part(FRONT_AUXDATA)
             inputs, request_id, include_meta = convert.json_to_check_inputs(body, aux)
+            if wf is not None:
+                wf.part(FRONT_CONVERT)
             # front-door admission: classify and gate BEFORE any dispatch —
             # a refusal costs parse + one bucket update and never reaches
             # the batcher, the ticket ring, or a device batch
@@ -1252,6 +1321,8 @@ class Server:
                 )
                 pclass = cls.name
                 ticket = adm.try_admit(cls)
+            if wf is not None:
+                wf.part(FRONT_ADMIT)
             trace_ctx = parse_traceparent(request.headers.get("traceparent"))
             if getattr(self.svc.engine, "supports_async", False):
                 # front-end mode: the evaluator settles on this event loop
@@ -1272,24 +1343,29 @@ class Server:
                         inputs, trace_ctx=trace_ctx, wf=wf, pclass=pclass
                     ),
                 )
-            resp = web.Response(
-                body=fastjson.dumps(
-                    convert.outputs_to_json(
-                        body,
-                        outputs,
-                        request_id,
-                        include_meta,
-                        call_id,
-                        provenance="X-Cerbos-TPU-Provenance" in request.headers,
-                    )
-                ),
-                content_type="application/json",
+            payload = convert.outputs_to_json(
+                body,
+                outputs,
+                request_id,
+                include_meta,
+                call_id,
+                provenance="X-Cerbos-TPU-Provenance" in request.headers,
             )
+            if wf is not None:
+                wf.part(BACK_ENCODE)
+            resp = web.Response(body=fastjson.dumps(payload), content_type="application/json")
             if trace_ctx is not None:
                 # echo the trace the work joined so callers can correlate
                 resp.headers["traceparent"] = trace_ctx.to_traceparent()
             outcome = OUTCOME_ORACLE if wf is not None and wf.served_by == "oracle" else OUTCOME_MET
-            budget_tracker().finish(wf, outcome, final_stage=STAGE_REPLY_ENCODE)
+            # the JSON dump is inside reply_encode here (for gRPC the bytes
+            # are made after it, by the wrapped serializer): the handler's
+            # extent ends at the same instant
+            t_done = budget_tracker().finish(
+                wf, outcome, final_stage=STAGE_REPLY_ENCODE, final_part=BACK_SERIALIZE
+            )
+            if t_done is not None:
+                budget_tracker().m_handler.observe(t_done - t_raw)
             return resp
         except OverloadRefused as e:
             # 429 + Retry-After, counted as a refused decision in THIS
@@ -1466,6 +1542,11 @@ class Server:
     # -- lifecycle ---------------------------------------------------------
 
     def start(self) -> None:
+        ev = getattr(self.svc.engine, "tpu_evaluator", None)
+        if hasattr(ev, "local_metrics_text"):
+            # front-end mode: what this process sends when the device owner
+            # gathers the pool for a scrape another front end is answering
+            ev.local_metrics_text = self._local_metrics_text
         if self.config.tls_cert and self.config.tls_key:
             self._cert_watcher = _CertWatcher(
                 self.config.tls_cert,

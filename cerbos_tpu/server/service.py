@@ -13,6 +13,7 @@ from typing import Any, Optional
 
 from .. import __version__
 from ..engine import types as T
+from ..engine.budget import BACK_WAKE, FRONT_SPAN
 from ..engine.engine import Engine
 from ..observability import SpanContext, start_span
 
@@ -113,11 +114,15 @@ class CerbosService:
             # re-stamps both
             T.set_current_shard(None)
             T.set_current_epoch(None)
-            if wf is not None and not wf.trace_id:
-                wf.trace_id = span.context.trace_id
+            if wf is not None:
+                if not wf.trace_id:
+                    wf.trace_id = span.context.trace_id
+                wf.part(FRONT_SPAN)
             outputs = self.engine.check(
                 inputs, params=params, deadline=deadline, wf=wf, pclass=pclass
             )
+            if wf is not None:
+                wf.part(BACK_WAKE)
             trace_id = span.context.trace_id
         self.metrics.record_check((time.perf_counter() - t0) * 1000, len(inputs))
         if self.audit_log is not None:
@@ -165,11 +170,15 @@ class CerbosService:
             span.set_attribute("call_id", call_id)
             T.set_current_shard(None)
             T.set_current_epoch(None)
-            if wf is not None and not wf.trace_id:
-                wf.trace_id = span.context.trace_id
+            if wf is not None:
+                if not wf.trace_id:
+                    wf.trace_id = span.context.trace_id
+                wf.part(FRONT_SPAN)
             outputs = await self.engine.check_await(
                 inputs, params=params, deadline=deadline, wf=wf, pclass=pclass
             )
+            if wf is not None:
+                wf.part(BACK_WAKE)
             trace_id = span.context.trace_id
         self.metrics.record_check((time.perf_counter() - t0) * 1000, len(inputs))
         if self.audit_log is not None:

@@ -22,7 +22,11 @@ server process:
   ``decision_source_total{source="device"}`` covers >= 0.9 of the batch-shaped
   decisions, no oracle fallback for any reason, no breaker trip, no XLA
   compile; overall: >= 1 compile, >= 1 parity check and 0 divergences, device
-  memory in use > 0, the native module loaded, exit 0 on SIGTERM.
+  memory in use > 0, the native module loaded, exit 0 on SIGTERM;
+- under ``--frontends 2``, that the pool is one server to its operator: every
+  scrape is ONE request and must hold ``fe1``, ``fe2`` and ``batcher``, and one
+  profiler capture asked of the served HTTP port (a front end, which holds no
+  device) comes back from the device owner and holds TPU operations.
 
 Traffic: (a) upstream-shaped requests, 1 resource x its actions, from 64
 concurrent connections — these coalesce by timing, so how many reach the
@@ -75,6 +79,7 @@ BATCH_SIZES = (50, 50, 50, 32, 16)
 DEVICE_SHARE_MIN = 0.9
 CHECKED_ATTEMPTS = 4
 BURST_CONNECTIONS = 16  # the reported burst: this many shape-(b) requests at once
+CAPTURE_S = 3.0  # the pooled leg's profiler capture, taken through the served HTTP port
 DEFAULT_REQUEST_TIMEOUT_S = 30.0  # both requestTimeoutMs keys when the config does not set them
 
 # existing config keys the smoke sets beyond addresses, storage and the JWT
@@ -446,29 +451,21 @@ class ServerProc:
             return json.loads(r.headers.get("X-Cerbos-Jitcache") or "{}"), json.loads(r.read())
 
     def scrape(self, workers: tuple[str, ...] = ()) -> dict[tuple, float]:
-        """/_cerbos/metrics. Pool front ends share one port (SO_REUSEPORT) and
-        each reports itself plus the batcher, so scrape fresh connections
-        until every expected ``worker`` label has answered."""
+        """ONE scrape of /_cerbos/metrics. Pool front ends share one port
+        (SO_REUSEPORT) and whichever answers holds the whole pool: itself,
+        every other front end and the batcher, each under its ``worker``
+        label. A scrape that lacks one of ``workers`` fails the smoke."""
         # the hot-rule recorder folds decision_source_total every 256
         # decisions or on snapshot: ask for one so the counters are current
         with urllib.request.urlopen(self.url("/_cerbos/debug/hotrules"), timeout=30) as r:
             r.read()
-        merged: dict[tuple, float] = {}
-        seen: set[str] = set()
-        texts = []
-        for _ in range(64):
-            with urllib.request.urlopen(self.url("/_cerbos/metrics"), timeout=30) as r:
-                text = r.read().decode()
-            m = parse_metrics(text)
-            merged.update(m)
-            answered = {dict(labels).get("worker", "") for _, labels in m}
-            if not answered <= seen:
-                texts.append(text)
-            seen |= answered
-            if all(w in seen for w in workers):
-                self.last_scrape = "\n".join(texts)
-                return merged
-        raise SmokeFailure(f"scrapes reached workers {sorted(seen)}, wanted {workers}")
+        with urllib.request.urlopen(self.url("/_cerbos/metrics"), timeout=30) as r:
+            self.last_scrape = r.read().decode()
+        m = parse_metrics(self.last_scrape)
+        answered = {dict(labels).get("worker", "") for _, labels in m}
+        if not all(w in answered for w in workers):
+            raise SmokeFailure(f"one scrape holds workers {sorted(answered)}, wanted all of {workers}")
+        return m
 
     def pids(self) -> list[int]:
         """The server process and its descendants."""
@@ -681,6 +678,50 @@ def run_burst(srv, batches, workers, prev: dict[tuple, float]) -> dict:
     return out
 
 
+def run_capture(srv, batches, owner_pid: int) -> dict:
+    """The pooled leg: one profiler capture asked of the served HTTP port,
+    where the kernel hands it to a front end that holds no device, over
+    batch-shaped requests sent meanwhile. It must come back from the device
+    owner and hold TPU operations; read with the benchmark's own reducer."""
+    from benchmarks.lib import trace_reduce
+
+    box: dict = {}
+
+    def capture() -> None:
+        try:
+            url = srv.url(f"/_cerbos/debug/profile?seconds={CAPTURE_S}")
+            with urllib.request.urlopen(url, timeout=CAPTURE_S + 600) as r:
+                box["reply"] = json.loads(r.read())
+        except Exception as e:  # noqa: BLE001 - reported below with its cause
+            box["error"] = f"{type(e).__name__}: {e}"
+            if hasattr(e, "read"):
+                box["error"] += f" {e.read()[:300]!r}"
+
+    thread = threading.Thread(target=capture, daemon=True)
+    thread.start()
+    time.sleep(1.0)
+    errors = send(_http_caller, srv, batches, 1, timeout=120)
+    thread.join(timeout=CAPTURE_S + 660)
+    if errors:
+        raise SmokeFailure("requests inside the capture: " + "; ".join(errors))
+    reply = box.get("reply")
+    if reply is None:
+        raise SmokeFailure(f"capture through the served port gave no trace: {box.get('error', 'no answer')}")
+    if reply.get("pid") != owner_pid:
+        raise SmokeFailure(f"the capture was taken by pid {reply.get('pid')}, the device owner is {owner_pid}")
+    path = trace_reduce.find_xplane(reply["path"])
+    traced = trace_reduce.reduce_file(path, (0.0, 3600.0)) if path else None
+    if traced is None:
+        raise SmokeFailure(f"the capture under {reply['path']} holds no TPU operation")
+    log(
+        f"  capture through the served port: taken by the device owner (pid {owner_pid}), "
+        f"{traced['events']} TPU operations on {traced['devices']}, busy {traced['busy_s']:.6f} s "
+        f"of {reply['seconds']:g} s; trace clocks "
+        f"{(reply['trace_stop_monotonic_ns'] - reply['trace_start_monotonic_ns']) / 1e9:.3f} s apart"
+    )
+    return {"tpu_operations": traced["events"], "busy_s": round(traced["busy_s"], 6), "owner_pid": owner_pid}
+
+
 def run_pass(srv, singles, batches, workers, timeout: float, label: str) -> dict:
     """Send (a) then (b) over HTTP, then over gRPC, scraping around each
     phase. Returns the scrapes and the per-phase source split."""
@@ -788,6 +829,8 @@ def run_topology(name, extra_args, tpu_conf, workers, policy_dir, singles, batch
         if not args.lanes:
             burst = run_burst(srv, batches, workers, checked["after"])
 
+        capture = run_capture(srv, batches, dev["pid"]) if workers else None
+
         # the sentinel replays off the request path: give its first check a moment
         for _ in range(100):
             final = srv.scrape(workers)
@@ -825,6 +868,7 @@ def run_topology(name, extra_args, tpu_conf, workers, policy_dir, singles, batch
             "device_share_batch": round(share, 4),
             "split": checked["split"],
             "burst": burst,
+            "capture": capture,
         }
     except BaseException:
         if srv.proc.poll() is None:
@@ -948,7 +992,12 @@ def main() -> int:
         else:
             topologies = [
                 ("single", [], {}, ()),
-                ("frontends2", ["--frontends", "2"], {}, ("fe1", "fe2", "batcher")),
+                (
+                    "frontends2",
+                    ["--frontends", "2"],
+                    {"profiler": {"enabled": True, "dir": os.path.join(work, "profiles"), "maxSeconds": CAPTURE_S}},
+                    ("fe1", "fe2", "batcher"),
+                ),
             ]
         results = {}
         for name, extra, tpu_conf, workers in topologies:

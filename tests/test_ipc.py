@@ -367,6 +367,195 @@ class TestControlFrames:
             batcher.close()
 
 
+@pytest.fixture()
+def fake_profiler(tmp_path, monkeypatch):
+    """The profiler enabled, with the jax capture stood in for: it lasts as
+    long as asked and returns the four clocks the real one publishes."""
+    from cerbos_tpu.tpu import profiler
+
+    def run_trace(path, seconds):
+        start = {"trace_start_monotonic_ns": time.monotonic_ns(), "trace_start_unix_ns": time.time_ns()}
+        time.sleep(seconds)
+        return {**start, "trace_stop_monotonic_ns": time.monotonic_ns(), "trace_stop_unix_ns": time.time_ns()}
+
+    monkeypatch.setattr(profiler, "_run_trace", run_trace)
+    profiler.configure(enabled=True, dir=str(tmp_path / "profiles"), max_seconds=5.0)
+    yield profiler
+    profiler.configure(enabled=False)
+
+
+def frontend_http(client, label="fe1"):
+    """An HTTP/gRPC server in the front-end role over ``client``."""
+    from cerbos_tpu.engine.engine import Engine
+    from cerbos_tpu.server.server import Server, ServerConfig
+    from cerbos_tpu.server.service import CerbosService
+
+    svc = CerbosService(Engine(client.rule_table, tpu_evaluator=client, tpu_batch_threshold=1))
+    srv = Server(
+        svc,
+        ServerConfig(http_listen_addr="127.0.0.1:0", grpc_listen_addr="127.0.0.1:0", worker_label=label),
+    )
+    srv.start()
+    return srv
+
+
+def http_get(port, path, timeout=10.0):
+    import json
+    import urllib.error
+    import urllib.request
+
+    try:
+        with urllib.request.urlopen(f"http://127.0.0.1:{port}{path}", timeout=timeout) as r:
+            return r.status, json.loads(r.read())
+    except urllib.error.HTTPError as e:
+        return e.code, json.loads(e.read())
+
+
+class TestProfileForward:
+    """A front end holds no device: ``/_cerbos/debug/profile`` runs in the
+    device owner, over the control connection (PR 27)."""
+
+    def test_capture_runs_in_the_owner_and_checks_keep_flowing(self, tmp_path, rt, fake_profiler):
+        import os
+
+        batcher, server, client = make_pair(tmp_path, rt)
+        try:
+            box = {}
+            t = threading.Thread(target=lambda: box.update(client.fetch_profile(0.6)))
+            t0 = time.monotonic()
+            t.start()
+            assert wait_for(lambda: fake_profiler._active)
+            # tickets and status frames keep flowing while the capture runs
+            outs = client.check([inp(i) for i in range(4)])
+            assert effects(outs) == effects(oracle(rt, [inp(i) for i in range(4)]))
+            assert client.fetch_flight()["pid"] == os.getpid()
+            assert time.monotonic() - t0 < 0.5 and fake_profiler._active
+            # a second capture meanwhile is refused as busy, as locally
+            assert client.fetch_profile(0.1)["kind"] == "busy"
+            t.join(timeout=5)
+            art = box["artifact"]
+            assert art["pid"] == os.getpid() and art["seconds"] == 0.6
+            assert art["path"].startswith(str(tmp_path / "profiles"))
+            assert art["trace_start_monotonic_ns"] < art["trace_stop_monotonic_ns"]
+            assert art["trace_start_unix_ns"] < art["trace_stop_unix_ns"]
+        finally:
+            client.close()
+            server.close()
+            batcher.close()
+
+    def test_owner_errors_carry_their_kind(self, tmp_path, rt, fake_profiler):
+        batcher, server, client = make_pair(tmp_path, rt)
+        try:
+            assert client.fetch_profile(0.0)["kind"] == "invalid"
+            fake_profiler.configure(enabled=False)
+            out = client.fetch_profile(0.1)
+            assert out["kind"] == "disabled" and "disabled" in out["error"]
+        finally:
+            client.close()
+            server.close()
+            batcher.close()
+
+    def test_http_maps_the_owner_reply_as_the_local_handler_does(self, tmp_path, rt, fake_profiler, monkeypatch):
+        import os
+
+        batcher, server, client = make_pair(tmp_path, rt)
+        srv = frontend_http(client)
+        try:
+            box = {}
+            t = threading.Thread(
+                target=lambda: box.update(first=http_get(srv.http_port, "/_cerbos/debug/profile?seconds=0.5"))
+            )
+            t.start()
+            assert wait_for(lambda: fake_profiler._active)
+            status, body = http_get(srv.http_port, "/_cerbos/debug/profile?seconds=0.1")
+            assert status == 409 and "already running" in body["error"]
+            t.join(timeout=5)
+            status, body = box["first"]
+            assert status == 200 and body["pid"] == os.getpid() and "trace_stop_unix_ns" in body
+            assert http_get(srv.http_port, "/_cerbos/debug/profile?seconds=0")[0] == 400
+            assert http_get(srv.http_port, "/_cerbos/debug/profile?seconds=x")[0] == 400
+            # disabled in the owner alone (a front end's own switch is checked first)
+            fake_profiler.configure(enabled=False)
+            assert http_get(srv.http_port, "/_cerbos/debug/profile")[0] == 403
+            monkeypatch.setattr(fake_profiler, "enabled", lambda: True)
+            status, body = http_get(srv.http_port, "/_cerbos/debug/profile")
+            assert status == 403 and "disabled" in body["error"]
+            # no owner: an answer, not a hang
+            server.close()
+            assert wait_for(lambda: not client._connected.is_set())
+            assert http_get(srv.http_port, "/_cerbos/debug/profile")[0] == 503
+        finally:
+            srv.stop()
+            client.close()
+            server.close()
+            batcher.close()
+
+
+class TestPoolScrape:
+    """``/_cerbos/metrics`` answered by any front end holds every attached
+    front end and the owner, rendered for that request (PR 27). In one test
+    process every party shares one registry, so this proves who is asked and
+    how the texts are labelled and merged; that the counts of separate
+    processes add up is tests/test_workers.py's."""
+
+    def test_owner_gathers_every_other_front_end(self, tmp_path, rt):
+        batcher, server, fe1 = make_pair(tmp_path, rt)
+        fe2 = RemoteBatcherClient(server.socket_path, rt, worker_label="fe2", status_poll_s=0.05)
+        fe1.worker_label = "fe1"
+        silent = RemoteBatcherClient(server.socket_path, rt, worker_label="fe3", status_poll_s=0.05)
+        try:
+            assert wait_for(fe2._connected.is_set) and wait_for(silent._connected.is_set)
+            assert wait_for(lambda: len(server._peers) == 3)
+            fe1.check([inp(i) for i in range(4)])
+            text = fe1.fetch_metrics_text()
+            # the owner and the two siblings, not the asker (it adds its own)
+            workers = set(re.findall(r'cerbos_tpu_decisions_total\{worker="([^"]+)"', text))
+            workers |= set(re.findall(r'cerbos_tpu_batcher_batches_total\{worker="([^"]+)"', text))
+            assert workers == {"batcher", "fe2", "fe3"}
+            assert text.count("# TYPE cerbos_tpu_batcher_batches_total counter") == 1
+            # a sibling that does not answer is left out after the wait, not waited for for ever
+            silent.local_metrics_text = lambda: time.sleep(30) or ""
+            t0 = time.monotonic()
+            text = fe1.fetch_metrics_text()
+            assert 2.0 <= time.monotonic() - t0 < 4.5
+            assert 'worker="fe2"' in text and 'worker="fe3"' not in text and 'worker="batcher"' in text
+            assert not server._scrapes
+        finally:
+            for c in (fe1, fe2, silent):
+                c.close()
+            server.close()
+            batcher.close()
+
+    def test_http_scrape_through_a_front_end_holds_the_pool(self, tmp_path, rt):
+        batcher, server, fe1 = make_pair(tmp_path, rt)
+        fe2 = RemoteBatcherClient(server.socket_path, rt, worker_label="fe2", status_poll_s=0.05)
+        srv1, srv2 = frontend_http(fe1, "fe1"), frontend_http(fe2, "fe2")
+        try:
+            assert wait_for(lambda: len(server._peers) == 2)
+            import urllib.request
+
+            with urllib.request.urlopen(f"http://127.0.0.1:{srv1.http_port}/_cerbos/metrics", timeout=10) as r:
+                text = r.read().decode()
+            # each process's own scrape body, the service's counters included, under its label
+            for w in ("fe1", "fe2"):
+                assert f'cerbos_dev_engine_check_count{{worker="{w}"}}' in text
+                assert f'cerbos_tpu_request_handler_seconds_count{{worker="{w}"}}' in text
+            assert 'cerbos_tpu_ipc_connections{worker="batcher"} 2' in text
+            assert text.count("# TYPE cerbos_tpu_request_front_seconds histogram") == 1
+            # and the new families pass the relabel/merge lint like any other
+            assert re.search(
+                r'cerbos_tpu_request_front_seconds_bucket\{worker="fe2",part="enqueue",le="[^"]+"\} \d+', text
+            )
+            assert re.search(r'cerbos_tpu_request_back_seconds_sum\{worker="fe1",part="wake"\} ', text)
+        finally:
+            srv1.stop()
+            srv2.stop()
+            fe1.close()
+            fe2.close()
+            server.close()
+            batcher.close()
+
+
 class TestCheckAsync:
     """BatchingEvaluator.check_async refuses via the settled future so the
     front-end process (not the batcher) serves the oracle."""
@@ -464,6 +653,32 @@ class TestMetricsRelabel:
             'cerbos_tpu_request_stage_seconds_bucket{worker="batcher",stage="queue_wait",shard="1",le="0.001"} 5'
             in merged
         )
+
+    def test_relabel_and_merge_cover_request_part_families(self):
+        """The PR 27 families, rendered by the live registry: the part label
+        survives relabeling, the unlabelled handler histogram picks the
+        worker label up, and two front ends' texts merge under one TYPE."""
+        from cerbos_tpu.engine import budget as budget_mod
+
+        trk = budget_mod.tracker()
+        wf = budget_mod.Waterfall()
+        wf.part("validate")
+        wf.mark("admission", part="enqueue")
+        trk.finish(wf, "deadline_met", final_stage="reply_encode", final_part="encode")
+        trk.observe_reply(wf.t0, wf.t0, wf.t0 + 0.001)
+        text = "\n".join(
+            line for line in obs.metrics().render().splitlines()
+            if re.match(r"(# TYPE )?cerbos_tpu_request_(front|back|handler)_seconds", line)
+        ) + "\n"
+        merged = merge_metrics_texts(
+            relabel_metrics_text(text, "worker", "fe1"), relabel_metrics_text(text, "worker", "fe2")
+        )
+        for family in ("front", "back", "handler"):
+            assert merged.count(f"# TYPE cerbos_tpu_request_{family}_seconds histogram") == 1
+        for w in ("fe1", "fe2"):
+            assert re.search(rf'cerbos_tpu_request_front_seconds_count\{{worker="{w}",part="enqueue"\}} [1-9]', merged)
+            assert re.search(rf'cerbos_tpu_request_back_seconds_sum\{{worker="{w}",part="serialize"\}} ', merged)
+            assert re.search(rf'cerbos_tpu_request_handler_seconds_bucket\{{worker="{w}",le="0\.001"\}} [1-9]', merged)
 
     def test_relabel_and_merge_cover_transport_families(self):
         """The PR 10 transport families flow through the textual machinery

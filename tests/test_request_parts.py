@@ -1,0 +1,260 @@
+"""The per-request front and back on a clock (PR 27): the parts the handlers
+stamp into a request's waterfall tile its ``admission`` stage (``ipc_encode``
+in a front end) and its ``reply_encode`` stage, each part once a request, over
+gRPC and HTTP, through ``check_resources`` and ``check_resources_async``; the
+handler's whole extent covers the waterfall and the serialization.
+
+Real listeners, a real ``BatchingEvaluator`` over the CPU oracle (the
+test_ipc harness), and for the front-end topology a real ticket queue.
+"""
+
+import json
+import urllib.request
+
+import grpc
+import pytest
+
+from cerbos_tpu.engine import budget as budget_mod
+from cerbos_tpu.engine.batcher import BatchingEvaluator
+from cerbos_tpu.engine.budget import (
+    BACK_PARTS,
+    FRONT_PARTS,
+    STAGE_ADMISSION,
+    STAGE_IPC_ENCODE,
+    STAGE_REPLY_ENCODE,
+)
+from cerbos_tpu.engine.engine import Engine
+from cerbos_tpu.engine.ipc import BatcherIpcServer, RemoteBatcherClient
+from cerbos_tpu.server.server import Server, ServerConfig
+from cerbos_tpu.server.service import CerbosService
+
+from test_ipc import OracleEvaluator, table, wait_for
+
+BODY = {
+    "requestId": "parts-1",
+    "principal": {"id": "u1", "roles": ["user"]},
+    "resources": [
+        {"actions": ["view"], "resource": {"kind": "album", "id": f"a{i}", "attr": {"owner": "u1", "public": i % 2 == 0}}}
+        for i in range(3)
+    ],
+}
+
+
+@pytest.fixture()
+def tracker():
+    trk = budget_mod.tracker()
+    prev = (trk.enabled, trk.slow_threshold_s, trk._ring.maxlen)
+    trk.configure(enabled=True)
+    trk.reset()
+    yield trk
+    trk.configure(enabled=prev[0], slow_threshold_ms=prev[1] * 1000, slow_capacity=prev[2])
+    trk.reset()
+
+
+@pytest.fixture()
+def finished(tracker, monkeypatch):
+    """Every waterfall the handlers finish, in order."""
+    seen = []
+    real = tracker.finish
+
+    def finish(wf, *args, **kwargs):
+        out = real(wf, *args, **kwargs)
+        if wf is not None:
+            seen.append(wf)
+        return out
+
+    monkeypatch.setattr(tracker, "finish", finish)
+    return seen
+
+
+def serve(tmp_path, topology, grpc_async=False):
+    """A started Server in the given topology, and what to close after."""
+    rt = table()
+    batcher = BatchingEvaluator(OracleEvaluator(rt), max_wait_ms=1.0)
+    closers = [batcher.close]
+    evaluator = batcher
+    if topology == "frontend":
+        ipc = BatcherIpcServer(str(tmp_path / "b.sock"), batcher)
+        ipc.start()
+        evaluator = RemoteBatcherClient(ipc.socket_path, rt, worker_label="fe1", status_poll_s=0.05)
+        assert wait_for(evaluator._connected.is_set)
+        closers = [evaluator.close, ipc.close, batcher.close]
+    svc = CerbosService(Engine(rt, tpu_evaluator=evaluator, tpu_batch_threshold=1))
+    srv = Server(
+        svc,
+        ServerConfig(http_listen_addr="127.0.0.1:0", grpc_listen_addr="127.0.0.1:0", grpc_async=grpc_async),
+    )
+    srv.start()
+    return srv, [srv.stop] + closers
+
+
+def send_grpc(srv):
+    from cerbos_tpu.api.cerbos.request.v1 import request_pb2
+    from cerbos_tpu.api.cerbos.response.v1 import response_pb2
+    from cerbos_tpu.server.convert import py_to_value
+
+    req = request_pb2.CheckResourcesRequest(request_id=BODY["requestId"])
+    req.principal.id = "u1"
+    req.principal.roles.append("user")
+    for r in BODY["resources"]:
+        entry = req.resources.add()
+        entry.actions.append("view")
+        entry.resource.kind = "album"
+        entry.resource.id = r["resource"]["id"]
+        for k, v in r["resource"]["attr"].items():
+            entry.resource.attr[k].CopyFrom(py_to_value(v))
+    with grpc.insecure_channel(f"127.0.0.1:{srv.grpc_port}") as ch:
+        stub = ch.unary_unary(
+            "/cerbos.svc.v1.CerbosService/CheckResources",
+            request_serializer=lambda m: m.SerializeToString(),
+            response_deserializer=response_pb2.CheckResourcesResponse.FromString,
+        )
+        resp = stub(req, timeout=10)
+    assert len(resp.results) == 3
+
+
+def send_http(srv):
+    req = urllib.request.Request(
+        f"http://127.0.0.1:{srv.http_port}/api/check/resources",
+        data=json.dumps(BODY).encode(),
+        headers={"Content-Type": "application/json"},
+        method="POST",
+    )
+    with urllib.request.urlopen(req, timeout=10) as resp:
+        assert len(json.loads(resp.read())["results"]) == 3
+
+
+def counts(tracker):
+    out = {p: (h.count, h.sum) for p, h in tracker._part_children.items()}
+    out["handler"] = (tracker.m_handler.count, tracker.m_handler.sum)
+    return out
+
+
+# surface, topology, the service method that carries the request, the stage the front parts tile
+CASES = [
+    pytest.param("grpc", "single", False, STAGE_ADMISSION, id="grpc-sync"),
+    pytest.param("http", "single", False, STAGE_ADMISSION, id="http-sync"),
+    pytest.param("grpc", "single", True, STAGE_ADMISSION, id="grpc-aio-sync"),
+    pytest.param("grpc", "frontend", False, STAGE_IPC_ENCODE, id="grpc-frontend-sync"),
+    pytest.param("http", "frontend", False, STAGE_IPC_ENCODE, id="http-frontend-async"),
+]
+
+
+@pytest.mark.parametrize("surface,topology,grpc_async,front_stage", CASES)
+def test_parts_tile_admission_and_reply_encode(tmp_path, tracker, finished, surface, topology, grpc_async, front_stage):
+    srv, closers = serve(tmp_path, topology, grpc_async)
+    try:
+        if surface == "http" and topology == "frontend":
+            assert srv.svc.engine.supports_async  # this case is check_resources_async
+        before = counts(tracker)
+        (send_grpc if surface == "grpc" else send_http)(srv)
+        assert wait_for(lambda: counts(tracker)["handler"][0] == before["handler"][0] + 1)
+        after = counts(tracker)
+    finally:
+        for close in closers:
+            close()
+    (wf,) = finished
+    stages = dict(wf.stages)
+    parts = dict(wf.parts)
+    names = [p for p, _ in wf.parts]
+    assert len(names) == len(set(names)), names  # each part once
+    # the front: all six, in order, and they add up to the stage they tile
+    assert names[:6] == list(FRONT_PARTS)
+    assert sum(parts[p] for p in FRONT_PARTS) == pytest.approx(stages[front_stage], abs=2e-6)
+    assert all(d >= 0.0 for _, d in wf.parts)
+    # the back: wake and encode tile reply_encode for gRPC (the bytes are made
+    # after it); for HTTP the JSON dump is inside it as serialize
+    in_record = ["wake", "encode"] if surface == "grpc" else list(BACK_PARTS)
+    assert names[6:] == in_record
+    assert sum(parts[p] for p in in_record) == pytest.approx(stages[STAGE_REPLY_ENCODE], abs=2e-6)
+    # every part observed once, the serializer's and the handler's included
+    grew = {k: (after[k][0] - before[k][0], after[k][1] - before[k][1]) for k in after}
+    assert {k: n for k, (n, _) in grew.items()} == {**{p: 1 for p in FRONT_PARTS + BACK_PARTS}, "handler": 1}
+    back = sum(grew[p][1] for p in BACK_PARTS)
+    assert back <= stages[STAGE_REPLY_ENCODE] + grew["serialize"][1] + 2e-6
+    # the handler's extent is the waterfall and, for gRPC, the serialization after it
+    extent = wf.attributed() + (grew["serialize"][1] if surface == "grpc" else 0.0)
+    assert grew["handler"][1] == pytest.approx(extent, abs=5e-6)
+
+
+def test_parts_are_off_with_the_waterfall(tmp_path, tracker):
+    """No option of their own: with ``latencyBudget`` off nothing is stamped,
+    observed or keyed by a response's identity."""
+    from cerbos_tpu.server import server as server_mod
+
+    tracker.configure(enabled=False)
+    srv, closers = serve(tmp_path, "single")
+    try:
+        before = counts(tracker)
+        send_grpc(srv)
+        send_http(srv)
+        assert counts(tracker) == before
+        assert not server_mod._GRPC_REPLY_STAMPS._stamps
+    finally:
+        for close in closers:
+            close()
+
+
+def test_slow_ring_entry_carries_the_parts(tmp_path, tracker, finished):
+    tracker.configure(slow_threshold_ms=0.0)
+    srv, closers = serve(tmp_path, "single")
+    try:
+        send_grpc(srv)
+    finally:
+        for close in closers:
+            close()
+    (entry,) = tracker.slow_dump()["requests"]
+    assert [p for p, _ in entry["parts"]] == list(FRONT_PARTS) + ["wake", "encode"]
+
+
+def clock_cost_us(n: int = 20000) -> dict:
+    """What the parts, the serializer's stamp and the two extra observations
+    add to one gRPC request, in microseconds: the same calls the handlers
+    make, timed in a loop, less the loop that makes none of them. Run on the
+    serving host (``pytest tests/test_request_parts.py -k cost -s``); PERF.md
+    section 6 has the chip host's numbers."""
+    import time
+
+    from cerbos_tpu.engine.budget import BACK_ENCODE, BACK_WAKE, BudgetTracker, Waterfall
+    from cerbos_tpu.server.server import _IngressStamps
+
+    trk = BudgetTracker()
+    stamps = _IngressStamps()
+    front = FRONT_PARTS[:-1]
+
+    def request(with_parts: bool) -> None:
+        wf = Waterfall()
+        wf.mark("ingress_parse")
+        if with_parts:
+            for p in front:
+                wf.part(p)
+            wf.mark(STAGE_ADMISSION, part="enqueue")
+        else:
+            wf.mark(STAGE_ADMISSION)
+        wf.mark("queue_wait")
+        wf.mark("settle")
+        if with_parts:
+            wf.part(BACK_WAKE)
+            t = trk.finish(wf, "deadline_met", final_stage=STAGE_REPLY_ENCODE, final_part=BACK_ENCODE)
+            stamps.put(id(wf), wf.t0, t)
+            got = stamps.pop(id(wf))
+            trk.observe_reply(got[0], got[1], time.monotonic())
+        else:
+            trk.finish(wf, "deadline_met", final_stage=STAGE_REPLY_ENCODE)
+
+    out = {}
+    for name, flag in (("without", False), ("with", True), ("without_again", False), ("with_again", True)):
+        t0 = time.perf_counter()
+        for _ in range(n):
+            request(flag)
+        out[name] = (time.perf_counter() - t0) / n * 1e6
+    out["added_us"] = min(out["with"], out["with_again"]) - min(out["without"], out["without_again"])
+    return out
+
+
+def test_clock_cost_is_a_few_microseconds_a_request():
+    """The issue's budget is 15 us a request on the serving host; here, on a
+    shared CPU under the test runner, only that it is not an order off."""
+    cost = clock_cost_us()
+    print(f"clock cost per request, us: { {k: round(v, 2) for k, v in cost.items()} }")
+    assert cost["added_us"] < 150.0

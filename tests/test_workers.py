@@ -6,6 +6,7 @@ the same entry a production pool uses — and drives it over HTTP.
 
 import json
 import os
+import re
 import signal
 import subprocess
 import sys
@@ -189,6 +190,7 @@ def frontdoor(tmp_path_factory):
             "--set", "server.httpListenAddr=127.0.0.1:0",
             "--set", "server.grpcListenAddr=127.0.0.1:0",
             "--set", "engine.tpu.backend=numpy",
+            "--set", "engine.tpu.profiler.enabled=true",
         ],
         stdout=subprocess.PIPE,
         stderr=subprocess.STDOUT,
@@ -263,6 +265,50 @@ def test_frontdoor_ready_and_worker_labeled_metrics(frontdoor):
 
     if native.get() is not None:
         assert 'transport="shm"' in text, "front door did not grant shm"
+
+
+def _handled_by_worker(port: int) -> tuple[dict, str]:
+    """One scrape: the CheckResources handlers each process has run."""
+    status, body = _get(port, "/_cerbos/metrics")
+    assert status == 200
+    text = body.decode()
+    found = re.findall(r'^cerbos_tpu_request_handler_seconds_count\{worker="([^"]+)"\} (\S+)$', text, re.M)
+    return {w: float(v) for w, v in found}, text
+
+
+def test_frontdoor_one_scrape_holds_the_whole_pool(frontdoor):
+    """PR 27: whichever front end the kernel hands a scrape to, it holds the
+    series of EVERY front end and of the batcher, rendered for that request,
+    so two scrapes subtract even when they land on different siblings."""
+    proc, port = frontdoor
+    before, _ = _handled_by_worker(port)
+    burst = 64
+    for _ in range(burst):  # a connection each: the kernel spreads them over both front ends
+        _check(port)
+    after, text = _handled_by_worker(port)
+    assert set(after) == {"fe1", "fe2", "batcher"}, sorted(after)
+    assert after.pop("batcher") == 0  # the owner runs no handler: requests finish on the front ends
+    assert 'cerbos_tpu_ipc_connections{worker="batcher"} 2' in text
+    grew = {w: after[w] - before.get(w, 0.0) for w in after}
+    assert sum(grew.values()) == burst, grew
+    assert all(n > 0 for n in grew.values()), grew  # sent through two, counted in two
+    # the parts of the front door's clock, summed over the pool as the benchmark's readers sum them
+    for part in ("validate", "auxdata", "convert", "admit", "span", "enqueue"):
+        got = re.findall(
+            rf'^cerbos_tpu_request_front_seconds_count\{{worker="fe[12]",part="{part}"\}} (\S+)$', text, re.M
+        )
+        assert len(got) == 2 and sum(map(float, got)) == sum(after.values()), (part, got)
+
+
+def test_frontdoor_profile_is_answered_by_the_device_owner(frontdoor):
+    """PR 27: a front end forwards ``/_cerbos/debug/profile``. This pool's
+    owner runs the numpy backend and holds no JAX device, so what comes back
+    through the served port is the OWNER's refusal, not the front end's."""
+    proc, port = frontdoor
+    status, body = _get(port, "/_cerbos/debug/profile?seconds=0.2", timeout=30)
+    assert status == 403, body
+    assert "owns no device" in json.loads(body)["error"]
+    assert _check(port)["results"][0]["actions"]["view"] == "EFFECT_ALLOW"
 
 
 def test_frontdoor_batcher_sigkill_midload_loses_zero_requests(frontdoor):
