@@ -34,8 +34,9 @@ DEFAULTS: dict[str, Any] = {
             "maxCandidates": 32,
             "maxDepth": 8,
             # streaming pipeline knobs: chunk size for device batches, batch
-            # size at which check() switches to the chunked pipeline, and how
-            # many device batches the pipeline/batcher keep in flight
+            # size at which check() switches to the chunked pipeline (a batch
+            # up to pipelineChunk is one chunk), and how many device batches
+            # the pipeline/batcher keep in flight
             "pipelineChunk": 4096,
             "streamingThreshold": 1024,
             "inflightDepth": 3,

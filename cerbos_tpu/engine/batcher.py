@@ -480,6 +480,14 @@ class BatchingEvaluator:
             buckets=[0.0001, 0.0005, 0.001, 0.002, 0.005, 0.01, 0.025, 0.05, 0.1, 0.5, 1.0],
         )
         self.m_stage_seconds = _ShardStageView(self._m_stage_vec, self._shard_label)
+        self.m_device_calls = reg.histogram_vec(
+            "cerbos_tpu_batch_device_calls",
+            "jitted device calls per device-served flight (one pack, dispatch, fetch and assemble each): "
+            "1 unless a direct batch is larger than pipelineChunk; an oracle-served flight is not observed, "
+            "by shard",
+            label="shard",
+            buckets=[1, 2, 3, 4, 8, 16],
+        ).labels(self._shard_label)
         self.m_window_wait = reg.histogram_vec(
             "cerbos_tpu_batcher_window_wait_seconds",
             "per flight: how long the drain loop deliberately waited (at most batchWindowMs) for a second "
@@ -1034,6 +1042,9 @@ class BatchingEvaluator:
             for stage in ("pack", "submit", "stack", "dispatch", "oracle"):
                 self.m_stage_seconds.observe(stage, flight.timings[stage])
             self.m_occupancy.set(float(occupancy))
+            parts = getattr(ticket, "parts", None)
+            if parts:
+                self.m_device_calls.observe(len(parts))
             if padded_rows:
                 waste = int(round(padded_rows * (1.0 - float(occupancy))))
                 if waste > 0:

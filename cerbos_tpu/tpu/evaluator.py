@@ -1250,22 +1250,13 @@ class TpuEvaluator:
         return out
 
     def _chunk_inputs(self, inputs: list[T.CheckInput]) -> list[list[T.CheckInput]]:
-        """Pipeline-chunk boundaries shared by check() and submit(): fixed
+        """Pipeline-chunk boundaries shared by check() and submit(): a batch
+        that fits one pipeline_chunk is ONE chunk (every stage of a chunk has
+        a fixed cost far above its per-input cost); a larger one is cut into
         pipeline_chunk-sized slices, with a tail smaller than the device
         threshold riding with its neighbor rather than paying a dispatch
-        (or an oracle walk) of its own.
-
-        Batches below 2x pipeline_chunk would land in a single chunk and get
-        no overlap at all, so the chunk shrinks to split them into roughly
-        ``inflight_depth`` pieces — rounded to the next pow2 bucket so the
-        shrunk chunks reuse already-traced jit shapes (B_pad buckets are
-        pow2 too)."""
+        (or an oracle walk) of its own."""
         chunk = self.pipeline_chunk if self.pipeline_chunk > 0 else len(inputs)
-        n = len(inputs)
-        if n < 2 * chunk:
-            depth = max(2, self.inflight_depth)
-            target = (n + depth - 1) // depth
-            chunk = min(chunk, _next_bucket(target, max(self.min_device_batch, 16)))
         chunks = [inputs[b : b + chunk] for b in range(0, len(inputs), chunk)]
         if len(chunks) > 1 and len(chunks[-1]) < self.min_device_batch:
             chunks[-2] = chunks[-2] + chunks[-1]

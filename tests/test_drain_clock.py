@@ -567,6 +567,19 @@ def test_new_metric_file_reads_nothing_from_a_program_without_the_clock(name):
     assert read_metric(name, PARENT, PARENT) is None
 
 
+CALLS = "cerbos_tpu_batch_device_calls"
+
+
+@pytest.mark.parametrize("calls, flights, want", [(100, 100, 1.0), (160, 100, 1.6), (0, 0, None)])
+def test_device_calls_mean_is_jitted_calls_per_device_served_flight(calls, flights, want):
+    """PR 28's file: the histogram's growth over the window; nothing from a
+    program without the series, or in a window with no device-served flight."""
+    before = f'{CALLS}_sum{{shard="0"}} 7\n{CALLS}_count{{shard="0"}} 5\n'
+    after = f'{CALLS}_sum{{shard="0"}} {7 + calls}\n{CALLS}_count{{shard="0"}} {5 + flights}\n'
+    assert read_metric("device_calls_mean.pages", before, after) == want
+    assert read_metric("device_calls_mean.pages", PARENT, PARENT) is None
+
+
 def test_every_new_metric_is_in_the_manifest_under_its_layer():
     with open(os.path.join(REPO, "BENCHMARK.json")) as f:
         per_layer = {m["name"]: m for m in json.load(f)["per_layer"]}

@@ -5,6 +5,7 @@ reference implementation.
 """
 
 import concurrent.futures
+import dataclasses
 import re
 import time
 
@@ -56,6 +57,14 @@ def inp(i: int) -> CheckInput:
 
 def effects(outs):
     return [{a: (e.effect, e.policy) for a, e in o.actions.items()} for o in outs]
+
+
+def sans_source(outs):
+    """Whole outputs, less the label that says who answered (device or oracle)."""
+    return [
+        dataclasses.replace(o, actions={a: dataclasses.replace(e, source="") for a, e in o.actions.items()})
+        for o in outs
+    ]
 
 
 class TestStreamingBatcher:
@@ -163,19 +172,69 @@ class TestStreamingThreshold:
         """ISSUE acceptance: default engagement at <= 1024 inputs."""
         assert TpuEvaluator(table(), use_jax=False).streaming_threshold <= 1024
 
-    def test_chunking_shrinks_below_two_chunks(self):
-        """Batches below 2x pipeline_chunk split into pipeline-able pieces
-        instead of a single monolithic chunk."""
+    @pytest.mark.parametrize(
+        "n, want",
+        [(n, [n]) for n in (16, 31, 32, 33, 47, 48, 50, 100, 1024, 4096)]
+        + [
+            (4097, [4097]),  # a tail of 1 rides with its neighbour
+            (4096 + 16, [4096, 16]),
+            (3 * 4096 + 5, [4096, 4096, 4096 + 5]),
+        ],
+    )
+    def test_a_batch_that_fits_one_pipeline_chunk_is_one_chunk(self, n, want):
+        """The flight's length alone decides: one chunk up to pipeline_chunk,
+        pipeline_chunk-sized slices beyond it, whatever inflight_depth says."""
+        ev = TpuEvaluator(table(), use_jax=False)  # the defaults a server boots with
+        assert (ev.pipeline_chunk, ev.min_device_batch, ev.inflight_depth) == (4096, 16, 3)
+        inputs = list(range(n))  # _chunk_inputs only slices
+        chunks = ev._chunk_inputs(inputs)
+        assert [len(c) for c in chunks] == want
+        assert [i for c in chunks for i in c] == inputs
+
+    @pytest.mark.parametrize("n, old_cuts", [(34, (16, 18)), (48, (16, 16, 16))])
+    def test_one_chunk_flight_returns_what_the_split_flight_returned(self, n, old_cuts):
+        """submit/collect of a page that used to be cut in two or three is,
+        element for element, the oracle's answer and what evaluating the
+        parent's chunks one by one gives."""
         rt = table()
-        ev = TpuEvaluator(
-            rt, use_jax=False, min_device_batch=4, pipeline_chunk=4096,
-            streaming_threshold=1024, inflight_depth=3,
-        )
-        chunks = ev._chunk_inputs([inp(i) for i in range(1024)])
-        assert len(chunks) >= 2
-        assert sum(len(c) for c in chunks) == 1024
-        # pow2 chunk sizes so the shrunk chunks reuse jit shape buckets
-        assert all(len(c) & (len(c) - 1) == 0 for c in chunks[:-1])
+        ev = TpuEvaluator(rt, use_jax=True)
+        inputs = [inp(i) for i in range(n)]
+        params = EvalParams()
+        ticket = ev.submit(inputs, params)
+        assert len(ticket.parts) == 1
+        got = ev.collect(ticket)
+        bounds = np.cumsum((0,) + old_cuts)
+        old_chunks = [inputs[a:b] for a, b in zip(bounds, bounds[1:])]
+        assert sum(len(ch) for ch in old_chunks) == n
+        assert got == [out for ch in old_chunks for out in ev.check(ch, params)]
+        want = [check_input(rt, i, params) for i in inputs]
+        assert sans_source(got) == sans_source(want)  # all but who answered
+
+
+def device_calls(shard: int) -> tuple[int, float]:
+    """(count, sum) of ``cerbos_tpu_batch_device_calls`` for the shard."""
+    from cerbos_tpu.observability import metrics
+
+    h = metrics().histogram_vec("cerbos_tpu_batch_device_calls", label="shard").labels(str(shard))
+    return h.count, h.sum
+
+
+class TestDeviceCallsHistogram:
+    @pytest.mark.parametrize("n, observed", [(3, 0), (15, 0), (16, 1), (34, 1), (48, 1), (100, 1), (4096, 1)])
+    def test_observed_once_per_device_served_flight(self, n, observed):
+        """One observation of value 1 for a flight the device serves, up to a
+        whole pipeline_chunk; none for one under min_device_batch (the oracle
+        answers it)."""
+        rt = table()
+        shard = 7000 + n  # a label of this case's own: its series start at zero
+        batcher = BatchingEvaluator(TpuEvaluator(rt, use_jax=True), shard_id=shard)
+        inputs = [inp(i) for i in range(n)]
+        try:
+            got = batcher.check(inputs)
+        finally:
+            batcher.close()
+        assert effects(got) == effects([check_input(rt, i, EvalParams()) for i in inputs])
+        assert device_calls(shard) == (observed, float(observed))
 
 
 class TestFusedPadStack:
