@@ -346,119 +346,6 @@ def plan_only_main(smoke: bool) -> int:
     return 0 if parity_ok else 1
 
 
-def _merged_percentile(buckets: list, counts: list, count: int, p: float) -> float:
-    """Histogram.percentile over shard-merged bucket counts."""
-    if count == 0:
-        return 0.0
-    rank = p * count
-    cum = 0
-    lo = 0.0
-    for i, b in enumerate(buckets):
-        prev = cum
-        cum += counts[i]
-        if cum >= rank:
-            frac = (rank - prev) / counts[i] if counts[i] else 0.0
-            return lo + (b - lo) * frac
-        lo = b
-    return buckets[-1] if buckets else 0.0
-
-
-def _stage_percentiles(metric: str = "cerbos_tpu_batch_stage_seconds") -> dict:
-    """Per-stage p50/p99 from a stage-keyed HistogramVec, for the
-    machine-readable perf artifact. Children are keyed (stage, shard) since
-    the sharded pool; shards merge into one per-stage summary here (the
-    per-shard split lives in the topology block)."""
-    from cerbos_tpu.observability import metrics
-
-    vec = metrics().instruments().get(metric)
-    if vec is None:
-        return {}
-    with vec._lock:
-        children = dict(vec._children)
-    merged: dict = {}
-    for key, hist in children.items():
-        stage = key[0] if isinstance(key, tuple) else str(key)
-        counts, total, count = hist.snapshot()
-        m = merged.setdefault(
-            stage, {"counts": [0] * len(counts), "sum": 0.0, "count": 0, "buckets": hist.buckets}
-        )
-        m["counts"] = [a + b for a, b in zip(m["counts"], counts)]
-        m["sum"] += total
-        m["count"] += count
-    stages = {}
-    for stage, m in sorted(merged.items()):
-        stages[stage] = {
-            "p50_s": round(_merged_percentile(m["buckets"], m["counts"], m["count"], 0.50), 6),
-            "p99_s": round(_merged_percentile(m["buckets"], m["counts"], m["count"], 0.99), 6),
-            "mean_s": round(m["sum"] / m["count"], 6) if m["count"] else 0.0,
-            "count": m["count"],
-        }
-    return stages
-
-
-def _request_waterfall() -> dict:
-    """Per-request latency-budget waterfall summary: per-stage percentiles
-    from cerbos_tpu_request_stage_seconds plus the fraction of request wall
-    clock the named stages explain (the reconciliation figure)."""
-    from cerbos_tpu.observability import metrics
-
-    inst = metrics().instruments()
-    vec = inst.get("cerbos_tpu_request_stage_seconds")
-    total = inst.get("cerbos_tpu_request_total_seconds")
-    if vec is None or total is None:
-        return {}
-    with vec._lock:
-        children = list(vec._children.values())
-    stage_sum = sum(h.snapshot()[1] for h in children)
-    _, total_sum, count = total.snapshot()
-    return {
-        "requests": count,
-        "total_p50_s": round(total.percentile(0.50), 6),
-        "total_p99_s": round(total.percentile(0.99), 6),
-        "attributed_frac": round(stage_sum / total_sum, 4) if total_sum else 0.0,
-        "stages": _stage_percentiles("cerbos_tpu_request_stage_seconds"),
-    }
-
-
-def _goodput(wall: float) -> dict:
-    """Goodput vs throughput from cerbos_tpu_decisions_total{outcome}:
-    goodput = correctly served inside the budget (device or oracle)."""
-    from cerbos_tpu.engine.budget import OUTCOME_MET, OUTCOME_ORACLE, tracker
-
-    vec = tracker().m_decisions
-    with vec._lock:
-        outcomes = dict(vec._children)
-    throughput = sum(outcomes.values())
-    # children are keyed (api, outcome) since the plan PR split goodput by
-    # api; fold the api dimension for the rollup and keep JSON-able keys
-    outcome_of = lambda k: k[-1] if isinstance(k, tuple) else k
-    good = sum(v for k, v in outcomes.items() if outcome_of(k) in (OUTCOME_MET, OUTCOME_ORACLE))
-    return {
-        "outcomes": {
-            ("/".join(k) if isinstance(k, tuple) else k): int(v) for k, v in sorted(outcomes.items())
-        },
-        "throughput_per_sec": round(throughput / wall, 1) if wall else 0.0,
-        "goodput_per_sec": round(good / wall, 1) if wall else 0.0,
-        "goodput_frac": round(good / throughput, 4) if throughput else 0.0,
-    }
-
-
-def _provenance_block(rule_table=None, k: int = 10) -> dict:
-    """Decision-provenance rollup for the artifact: attribution rate (what
-    fraction of decisions named a winning rule), the device/oracle source
-    split, the analyzer-class mix, and the hot-rule top-K from this run."""
-    from cerbos_tpu.engine.hotrules import recorder as hotrule_recorder
-
-    snap = hotrule_recorder().snapshot(k=k, rule_table=rule_table)
-    return {
-        "decisions": snap["decisions"],
-        "attribution_rate": snap["attribution_rate"],
-        "by_source": snap["by_source"],
-        "by_class": snap["by_class"],
-        "top": snap["top"],
-    }
-
-
 def _compile_economy() -> dict:
     """Compile-side economics for the perf artifact: how much XLA work the
     run paid and how well the jit cache amortized it — the figures that
@@ -484,259 +371,6 @@ def _compile_economy() -> dict:
     }
 
 
-def served_main(
-    smoke: bool,
-    json_path: str = "",
-    shards: int = 0,
-    routing: str = "least_loaded",
-    transport: str = "local",
-) -> int:
-    """--served: throughput through the real serving path (BatchingEvaluator).
-
-    The direct-evaluator numbers above measure the device backend in
-    isolation; this mode measures what a gRPC/HTTP client population would
-    actually see. N client threads issue small requests concurrently (the
-    ghz-style load pattern); the batcher coalesces them into padded device
-    batches and streams them through submit/collect with several batches in
-    flight. Reports decisions/sec plus the batcher's own pipeline stats —
-    ``inflight_peak`` ≥ 2 is the signature that streaming engaged.
-
-    ``--shards N`` fronts N sharded batcher lanes (one device-pinned
-    evaluator clone each, see engine/shards.py) instead of the single
-    batcher, and adds a ``topology`` block to the artifact: per-shard
-    decisions/s, occupancy, and routing-imbalance.
-
-    ``--transport shm|uds`` interposes the REAL front-door ticket queue
-    (engine/ipc.py: BatcherIpcServer + RemoteBatcherClient over a temp
-    socket) between the clients and the batcher, so the artifact's
-    ``ipc_transport`` block measures the data plane itself — the uds-vs-shm
-    A/B at identical topology (loadtest/ab_transport.py drives both legs).
-    """
-    import os
-    from concurrent.futures import ThreadPoolExecutor
-
-    from cerbos_tpu.engine.batcher import BatchingEvaluator, DeviceHealth
-    from cerbos_tpu.engine.sentinel import from_config as sentinel_from_config
-
-    device = _device_or_exit()
-
-    policies = list(parse_policies(bench_corpus.corpus_yaml(N_MODS)))
-    rt = build_rule_table(compile_policy_set(policies))
-    params = EvalParams()
-    ev = TpuEvaluator(rt, use_jax=True)
-    # chaos drills ride the same grammar as the server (engine/faults.py);
-    # flip_effect:P,shard:N under --shards is the parity-sentinel drill
-    fault_spec = os.environ.get("CERBOS_TPU_FAULTS", "")
-    sharded_pool = None
-    if shards and shards != 1:
-        from cerbos_tpu.engine.shards import build_shard_pool
-
-        sharded_pool = build_shard_pool(
-            ev,
-            n_shards=0 if shards < 0 else shards,
-            routing=routing,
-            max_batch=1024,
-            max_wait_ms=2.0,
-            fault_spec=fault_spec,
-        )
-        health = None
-        batcher = sharded_pool
-        print(f"sharded pool: {len(sharded_pool.shards)} lanes, routing={routing}", flush=True)
-    else:
-        dispatch = ev
-        if fault_spec:
-            from cerbos_tpu.engine.faults import FaultInjector
-
-            dispatch = FaultInjector(ev, fault_spec)
-        health = DeviceHealth()
-        batcher = BatchingEvaluator(
-            dispatch, max_batch=1024, max_wait_ms=2.0, max_inflight=3, health=health
-        )
-    # parity sentinel over the bench's own lanes: the served artifact's
-    # correctness block. Rate/corpus overridable for the chaos drill.
-    sentinel = sentinel_from_config(
-        {
-            "sampleRate": float(os.environ.get("CERBOS_TPU_PARITY_RATE", "0.01")),
-            "corpusDir": os.environ.get("CERBOS_TPU_PARITY_CORPUS", ""),
-        }
-    ).attach(batcher)
-
-    ipc_server = ipc_client = None
-    serve_target = batcher
-    if transport in ("shm", "uds"):
-        import tempfile
-
-        from cerbos_tpu.engine.ipc import BatcherIpcServer, RemoteBatcherClient
-
-        ipc_server = BatcherIpcServer(
-            os.path.join(tempfile.mkdtemp(prefix="cerbos-bench-ipc-"), "batcher.sock"),
-            batcher,
-            transport=transport,
-        )
-        ipc_server.start()
-        ipc_client = RemoteBatcherClient(
-            ipc_server.socket_path,
-            rt,
-            params=params,
-            worker_label="bench-fe",
-            status_poll_s=0.25,
-            transport=transport,
-        )
-        if not ipc_client._connected.wait(10.0):
-            print("ticket queue never attached", file=sys.stderr)
-            return 1
-        serve_target = ipc_client
-        print(
-            f"front door: ticket queue over {ipc_client.transport} (requested {transport})",
-            flush=True,
-        )
-
-    req_size = 4  # inputs per client request (the classic template's shape)
-    n_clients = 16 if smoke else 64
-    n_rounds = 2 if smoke else 6
-    round_inputs = 2048 if smoke else 8192
-    all_inputs = bench_corpus.requests(round_inputs, N_MODS)
-    reqs = [all_inputs[b : b + req_size] for b in range(0, round_inputs, req_size)]
-    decisions_per_round = sum(len(i.actions) for r in reqs for i in r)
-
-    # each bench client carries a latency-budget waterfall, exactly as a
-    # server ingress would, so the artifact gets the per-stage attribution
-    # and goodput split for free
-    from cerbos_tpu.engine import budget as _budget
-
-    def _serve(r):
-        trk = _budget.tracker()
-        wf = trk.start()
-        try:
-            out = serve_target.check(r, params, wf=wf)
-        except Exception:
-            trk.finish(wf, _budget.OUTCOME_EXPIRED)
-            raise
-        trk.finish(
-            wf,
-            _budget.OUTCOME_ORACLE
-            if wf is not None and wf.served_by == "oracle"
-            else _budget.OUTCOME_MET,
-            final_stage=_budget.STAGE_REPLY_ENCODE,
-        )
-        return out
-
-    pool = ThreadPoolExecutor(max_workers=n_clients)
-    try:
-        outs = list(pool.map(_serve, reqs))  # warmup
-        gctune.tune_for_serving()
-        t0 = time.perf_counter()
-        for _ in range(n_rounds):
-            outs = list(pool.map(_serve, reqs))
-        wall = time.perf_counter() - t0
-    finally:
-        pool.shutdown(wait=True)
-        sentinel.drain(timeout=30.0)  # let queued shadow replays finish
-        parity = sentinel.snapshot()
-        sentinel.close()
-        ipc_stats = {"transport": "local"}
-        if ipc_client is not None:
-            ipc_stats = ipc_client.transport_stats()  # before close() drops the plane
-            ipc_client.close()
-        if ipc_server is not None:
-            ipc_server.close()
-        batcher.close()
-    parity["overhead_pct"] = round(100.0 * parity["replay_seconds"] / wall, 3) if wall else 0.0
-
-    allow = sum(
-        1 for ro in outs for o in ro for e in o.actions.values() if e.effect == "EFFECT_ALLOW"
-    )
-    assert allow > 0, "served workload produced no allows — corpus is broken"
-    rate = decisions_per_round * n_rounds / wall
-    if sharded_pool is not None:
-        trips = sum(s["breaker_trips"] for s in sharded_pool.shard_stats())
-        occupancy = max(lane.m_occupancy.value for lane in sharded_pool.shards)
-        padding_waste = sum(lane.m_padding_waste.value for lane in sharded_pool.shards)
-    else:
-        trips = health.stats["trips"]
-        occupancy = batcher.m_occupancy.value
-        padding_waste = batcher.m_padding_waste.value
-    record = {
-        "metric": "served_decisions_per_sec",
-        "value": round(rate, 1),
-        "unit": f"decisions/s/{device['platform']}",
-        "backend": f"jax-{device['platform']}",
-        "device": device,
-        "clients": n_clients,
-        "request_size": req_size,
-        "vs_baseline": round(rate / REFERENCE_DECISIONS_PER_SEC, 2),
-        "batcher": dict(batcher.stats),
-        "breaker_trips": trips,
-        "oracle_fallbacks": batcher.stats["oracle_fallbacks"],
-        "deadline_drops": batcher.stats["deadline_drops"],
-        # per-stage latency attribution + device-layout economics from the
-        # observability layer (the same series /_cerbos/metrics exposes)
-        "stages": _stage_percentiles(),
-        # per-request latency-budget waterfall + goodput accounting (PR 9):
-        # where each request's wall clock went, and how much of the measured
-        # throughput was served inside its budget
-        "waterfall": _request_waterfall(),
-        "goodput": _goodput(wall),
-        "occupancy": occupancy,
-        "padding_waste_rows": padding_waste,
-        "compile": _compile_economy(),
-        # online shadow-oracle parity over this run's own batches
-        # (engine/sentinel.py): divergences must be 0 with faults off
-        "parity": parity,
-        # decision provenance (ISSUE 20): attribution rate, source split,
-        # hot-rule top-K — fed by the same hit counters /_cerbos/debug/hotrules reads
-        "provenance": _provenance_block(rt),
-        # ticket-queue data plane (engine/ipc.py): negotiated transport,
-        # frames each way, native codec ns/frame, ring-full sheds;
-        # transport=local when the clients call the batcher in-process
-        "ipc_transport": ipc_stats,
-    }
-    if sharded_pool is not None:
-        # per-shard share of the measured rate: routed requests carry equal
-        # decision counts on average, so the split follows the routing counts
-        total_routed = sum(sharded_pool.routed) or 1
-        per_shard = []
-        for s in sharded_pool.shard_stats():
-            s["dec_per_sec_est"] = round(rate * s["routed"] / total_routed, 1)
-            per_shard.append(s)
-        imb = sharded_pool.routing_imbalance()
-        record["topology"] = {
-            "shards": len(sharded_pool.shards),
-            "routing": sharded_pool.routing,
-            "routing_imbalance": round(imb, 3) if imb != float("inf") else "inf",
-            "per_shard": per_shard,
-        }
-    print(
-        "robustness: breaker_trips=%d oracle_fallbacks=%d deadline_drops=%d"
-        % (trips, batcher.stats["oracle_fallbacks"], batcher.stats["deadline_drops"]),
-        flush=True,
-    )
-    print(
-        "parity: checks=%d divergences=%d storms=%d lag_p99=%.4fs overhead=%.3f%%"
-        % (
-            parity["checks"],
-            parity["divergences"],
-            parity["storms"],
-            parity["lag_p99_s"],
-            parity["overhead_pct"],
-        ),
-        flush=True,
-    )
-    prov = record["provenance"]
-    print(
-        "provenance: decisions=%d attribution_rate=%.4f by_source=%s"
-        % (prov["decisions"], prov["attribution_rate"], json.dumps(prov["by_source"])),
-        flush=True,
-    )
-    print(json.dumps(record))
-    if json_path:
-        with open(json_path, "w", encoding="utf-8") as f:
-            json.dump(record, f, indent=2)
-            f.write("\n")
-        print(f"wrote perf artifact: {json_path}", flush=True)
-    return 0
-
-
 def main() -> None:
     parser = argparse.ArgumentParser(description=__doc__)
     parser.add_argument(
@@ -751,47 +385,11 @@ def main() -> None:
         "--plan", action="store_true",
         help="batched-vs-sequential PlanResources A/B + filter-AST parity gate only",
     )
-    parser.add_argument(
-        "--served", action="store_true",
-        help="measure through the real BatchingEvaluator serving path "
-        "(concurrent clients, cross-request batching, streaming pipeline)",
-    )
-    parser.add_argument(
-        "--json", metavar="PATH", default="",
-        help="with --served: also write the JSON record to PATH "
-        "(machine-readable perf artifact, e.g. BENCH_SERVED.json)",
-    )
-    parser.add_argument(
-        "--shards", type=int, default=0, metavar="N",
-        help="with --served: front N sharded batcher lanes (one device-pinned "
-        "evaluator clone each) instead of the single batcher; -1 = one per "
-        "visible device; 0/1 = single-batcher path",
-    )
-    parser.add_argument(
-        "--routing", default="least_loaded", choices=["least_loaded", "round_robin"],
-        help="with --served --shards: request routing policy across lanes",
-    )
-    parser.add_argument(
-        "--transport", default="local", choices=["local", "shm", "uds"],
-        help="with --served: interpose the front-door ticket queue between "
-        "clients and batcher over this data plane (local = in-process calls, "
-        "no queue); shm vs uds at identical topology is the transport A/B",
-    )
     args = parser.parse_args()
     if args.index_only:
         sys.exit(index_only_main(smoke=args.smoke))
     if args.plan:
         sys.exit(plan_only_main(smoke=args.smoke))
-    if args.served:
-        sys.exit(
-            served_main(
-                smoke=args.smoke,
-                json_path=args.json,
-                shards=args.shards,
-                routing=args.routing,
-                transport=args.transport,
-            )
-        )
 
     device = _device_or_exit()
 

@@ -86,8 +86,6 @@ def _make_evaluator(rule_table: Any, engine_conf: dict, schema_mgr: Any = None) 
         use_jax=backend != "numpy",
         min_device_batch=int(tpu_conf.get("minDeviceBatch", 16)),
         pipeline_chunk=int(tpu_conf.get("pipelineChunk", 4096)),
-        streaming_threshold=int(tpu_conf.get("streamingThreshold", 1024)),
-        inflight_depth=int(tpu_conf.get("inflightDepth", 3)),
     )
 
 

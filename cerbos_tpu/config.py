@@ -33,12 +33,10 @@ DEFAULTS: dict[str, Any] = {
             "maxRoles": 8,
             "maxCandidates": 32,
             "maxDepth": 8,
-            # streaming pipeline knobs: chunk size for device batches, batch
-            # size at which check() switches to the chunked pipeline (a batch
-            # up to pipelineChunk is one chunk), and how many device batches
-            # the pipeline/batcher keep in flight
+            # a batch up to pipelineChunk is one device call (a larger, direct
+            # one is cut into chunks of that size); inflightDepth is how many
+            # flights the batcher and each lane keep in flight
             "pipelineChunk": 4096,
-            "streamingThreshold": 1024,
             "inflightDepth": 3,
             # device-path fault domain (docs/ROBUSTNESS.md): circuit breaker
             # routing check() to the CPU oracle while the device is unhealthy,

@@ -12,8 +12,8 @@ joins an in-flight window of up to ``max_inflight`` batches. While earlier
 tickets' transfers + compute are in flight, the batcher keeps draining and
 submitting newer requests; ``collect()`` settles each ticket's futures as
 its results land. Wall-clock under concurrent load approaches
-max(host pack/assembly, device work) instead of their sum — the same
-double-buffering bench.py measures, now on the serving path.
+max(host pack/assembly, device work) instead of their sum (``bench.py``'s
+streaming leg keeps several tickets in flight the same way).
 
 Requests coalesce by queueing behind the flight in progress. A queue that
 holds a check is drained at once: a page is a device batch already, and no

@@ -6,8 +6,9 @@ ingress (before the request body is even decoded, so parse cost is visible)
 and carried with the request through admission, the IPC hop, the batcher
 queue, the device window, settlement, and reply encoding. Each stage is the
 delta between consecutive marks, so the stage durations tile the request's
-wall clock by construction — the reconciliation property bench/loadtest
-assert (≥95% of p99 wall attributed to named stages).
+wall clock by construction — the reconciliation property
+``tests/test_latency_budget.py`` asserts (≥95% of a request's wall clock
+attributed to named stages).
 
 Cross-process carriage reuses ``engine/ipc.py``'s deadline idiom: monotonic
 clocks are process-local, so only RELATIVE values cross the socket. The

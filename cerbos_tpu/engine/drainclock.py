@@ -38,7 +38,7 @@ PACK = "pack"          # rows of the flight's inputs -> PackedBatch
 STACK = "stack"        # variant choice, candidate remap, pad + stack into transfer matrices
 DISPATCH = "dispatch"  # the jitted call (host->device puts) and the start of the result copy
 COMPILE = "compile"    # first call of a new jit key: trace + XLA compile or cache load
-ORACLE = "oracle"      # synchronous check() of a flight that does not stream (under minDeviceBatch)
+ORACLE = "oracle"      # a flight evaluated inside submit(): the oracle under minDeviceBatch, else the numpy backend or a mesh
 FETCH = "fetch"        # wait: the device and the one device->host fetch
 ASSEMBLE = "assemble"  # result slicing + CheckOutput assembly
 SETTLE = "settle"      # futures resolved, waterfalls booked

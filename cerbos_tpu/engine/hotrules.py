@@ -23,7 +23,6 @@ sentinel's observe hook), so it never adds to request latency.
 
 from __future__ import annotations
 
-import os
 import threading
 from typing import Any, Optional, Sequence
 
@@ -80,11 +79,7 @@ class HotRuleRecorder:
 
     def observe(self, outputs: Sequence[T.CheckOutput]) -> None:
         """Fold one settled batch's decisions into the hit array. Never
-        raises; called after futures settle so it adds no request latency.
-        ``CERBOS_TPU_NO_PROVENANCE=1`` disables aggregation entirely — the
-        loadtest A/B baseline leg for the <=2% overhead gate."""
-        if os.environ.get("CERBOS_TPU_NO_PROVENANCE"):
-            return
+        raises; called after futures settle so it adds no request latency."""
         try:
             self._observe(outputs)
         except Exception:  # noqa: BLE001 - telemetry must never break serving

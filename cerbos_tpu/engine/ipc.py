@@ -1016,7 +1016,7 @@ class RemoteBatcherClient:
         self.connect_retry_s = connect_retry_s
         # requested transport; the ACTIVE one is renegotiated per attach
         # (native module present on both ends, server willing) and visible
-        # as .transport for bench/loadtest reporting
+        # as .transport (/_cerbos/debug/transport reports it)
         self.transport_requested = transport if transport in ("shm", "uds") else "shm"
         self.ring_bytes = max(64 * 1024, int(ring_kib) * 1024)
         self._transport_active = "uds"
@@ -1648,7 +1648,7 @@ class RemoteBatcherClient:
     # -- pool observability surfaces ----------------------------------------
 
     def transport_stats(self) -> dict:
-        """The ``transport`` block loadtest/bench report: which plane carried
+        """What ``/_cerbos/debug/transport`` reports: which plane carried
         tickets, frame counts, and mean encode/decode ns per frame."""
         s = self.stats
         return {

@@ -835,7 +835,9 @@ class ParitySentinel:
         return False
 
     def snapshot(self) -> dict:
-        """The bench/loadtest ``parity`` block's source of truth."""
+        """Counts since start (checks, divergences, storms, lag, replay
+        seconds), per lane and in all; ``tests/test_parity_sentinel.py`` is
+        its reader (no endpoint serves it yet)."""
         with self._lock:
             lanes = {
                 shard: {"seen": st.seen, "sampled": st.sampled}

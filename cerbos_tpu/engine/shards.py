@@ -1,8 +1,8 @@
 """Sharded serving pool: N per-device batcher lanes behind one front door.
 
-MULTICHIP_r01–r05 proved evaluation scales across the 8-device mesh, but
-the serving path drove a single evaluator — the mesh was a benchmark
-artifact, not capacity. Here the pool owns one ``BatchingEvaluator`` lane
+Evaluation over a data mesh (``__graft_entry__.dryrun_multichip``) spreads
+one batch over the devices, but a serving path that drives a single
+evaluator uses one of them. Here the pool owns one ``BatchingEvaluator`` lane
 per shard, each wrapping a ``TpuEvaluator`` clone pinned to its device (or
 per-shard mesh slice) via ``parallel.mesh.shard_devices``. The clones share
 the expensive read-only artifacts — rule table, lowered device tables —
@@ -67,8 +67,8 @@ class ShardedBatchingEvaluator:
         self.routing = routing if routing in (ROUTING_LEAST_LOADED, ROUTING_ROUND_ROBIN) else ROUTING_LEAST_LOADED
         self._rr = 0
         self._rr_lock = threading.Lock()
-        # per-shard routed-request counts: the imbalance signal bench.py and
-        # loadtest publish (max/min over these ≈ 1.0 means fair routing)
+        # per-shard routed-request counts: what routing_imbalance() reads
+        # (max/min over these ≈ 1.0 means fair routing)
         self.routed = [0] * len(self.shards)
 
     # -- routing ------------------------------------------------------------
@@ -200,31 +200,6 @@ class ShardedBatchingEvaluator:
         out = {k: sum(lane.stats[k] for lane in self.shards) for k in keys}
         out["inflight_peak"] = max(lane.stats["inflight_peak"] for lane in self.shards)
         out["routed"] = list(self.routed)
-        return out
-
-    def shard_stats(self) -> list[dict]:
-        """Per-lane serving stats (the bench/loadtest topology block)."""
-        out = []
-        for i, lane in enumerate(self.shards):
-            health = lane.health
-            ev = lane.evaluator
-            out.append(
-                {
-                    "shard": i,
-                    "routed": self.routed[i],
-                    "batches": lane.stats["batches"],
-                    "batched_requests": lane.stats["batched_requests"],
-                    "inflight_peak": lane.stats["inflight_peak"],
-                    "oracle_fallbacks": lane.stats["oracle_fallbacks"],
-                    "batch_errors": lane.stats["batch_errors"],
-                    "quarantined": lane.stats["quarantined"],
-                    "breaker_state": health.state if health is not None else None,
-                    "breaker_trips": health.stats["trips"] if health is not None else 0,
-                    "occupancy": lane.m_occupancy.value,
-                    "device_inputs": getattr(ev, "stats", {}).get("device_inputs", 0),
-                    "device": str(getattr(ev, "device", None) or getattr(ev, "mesh", None) or ""),
-                }
-            )
         return out
 
     def routing_imbalance(self) -> float:

@@ -1154,8 +1154,7 @@ class Server:
     async def _h_transport(self, request: web.Request) -> web.Response:
         """Ticket-queue data-plane stats for THIS front end: the active
         plane (shm ring / uds socket), requested vs granted transport, frame
-        counts, native codec cost per frame, and ring-full shed events —
-        the numbers loadtest/bench fold into their --json artifacts. The
+        counts, native codec cost per frame, and ring-full shed events. The
         single-process topology (no ticket queue) reports transport=local."""
         ev = getattr(self.svc.engine, "tpu_evaluator", None)
         if ev is not None and hasattr(ev, "transport_stats"):

@@ -21,7 +21,7 @@ cache in :mod:`jitcache` and answers, per process:
   is spending its time in XLA instead of serving.
 
 Everything is process-global (like the metrics registry it feeds) so the
-serving batcher, the pipelined path, and bench all account into one place.
+serving batcher, a direct ``check()`` and bench all account into one place.
 """
 
 from __future__ import annotations

@@ -294,82 +294,8 @@ class _StackLayout:
         self.sig = (paths, ts_paths, list_paths, list_widths, pred_ids, D, has_now)
 
 
-def _stack_padded(padded: dict) -> tuple[dict, _StackLayout]:
-    """Fuse the per-path column dicts into a handful of typed matrices so a
-    device dispatch costs O(1) host->device transfers (see _device_eval)."""
-    paths = tuple(sorted(padded["tags"]))
-    ts_paths = tuple(sorted(padded["ts_his"]))
-    list_paths = tuple(sorted(padded["list_sids"]))
-    list_widths = tuple(int(padded["list_sids"][p].shape[1]) for p in list_paths)
-    pred_ids = tuple(sorted(padded["pred_vals"]))
-    scope_sp = padded["scope_sp"]
-    B = scope_sp.shape[0]
-    D = scope_sp.shape[2]
-    has_now = padded["now_hi"] is not None
-
-    i32_rows = (
-        [padded["his"][p] for p in paths]
-        + [padded["los"][p] for p in paths]
-        + [padded["sids"][p] for p in paths]
-        + [padded["ts_his"][p] for p in ts_paths]
-        + [padded["ts_los"][p] for p in ts_paths]
-    )
-    i32_cols = np.stack(i32_rows) if i32_rows else np.zeros((0, B), dtype=np.int32)
-    i8_rows = (
-        [padded["tags"][p] for p in paths]
-        + [padded["ts_states"][p] for p in ts_paths]
-        + [padded["list_states"][p] for p in list_paths]
-    )
-    i8_cols = np.concatenate(
-        [
-            np.stack(i8_rows).astype(np.int8) if i8_rows else np.zeros((0, B), np.int8),
-            np.ascontiguousarray(scope_sp.transpose(1, 2, 0).reshape(2 * D, B)),
-        ]
-    )
-    bool_rows = (
-        [padded["nans"][p] for p in paths]
-        + [padded["pred_vals"][q] for q in pred_ids]
-        + [padded["pred_errs"][q] for q in pred_ids]
-    )
-    bool_cols = np.stack(bool_rows) if bool_rows else np.zeros((0, B), dtype=bool)
-    if list_paths:
-        wmax = max(list_widths)
-        lists = np.zeros((len(list_paths), B, wmax), dtype=np.int32)
-        for i, p in enumerate(list_paths):
-            a = padded["list_sids"][p]
-            lists[i, :, : a.shape[1]] = a
-    else:
-        lists = np.zeros((0, B, 1), dtype=np.int32)
-    cand_i32 = np.stack([padded["cand_cond"], padded["cand_drcond"]])
-    cand_i8 = np.stack(
-        [
-            padded["cand_effect"],
-            padded["cand_pt"],
-            padded["cand_depth"],
-            padded["cand_valid"].astype(np.int8),
-        ]
-    )
-    now = (
-        np.asarray([int(padded["now_hi"]), int(padded["now_lo"])], dtype=np.int32)
-        if has_now
-        else np.zeros(2, dtype=np.int32)
-    )
-    layout = _StackLayout(paths, ts_paths, list_paths, list_widths, pred_ids, D, has_now)
-    stacked = dict(
-        i32_cols=i32_cols,
-        i8_cols=i8_cols,
-        bool_cols=bool_cols,
-        lists=lists,
-        cand_i32=cand_i32,
-        cand_i8=cand_i8,
-        ba_input=padded["ba_input"],
-        now=now,
-    )
-    return stacked, layout
-
-
 def _unstack_padded(xp, lay: _StackLayout, kw: dict) -> dict:
-    """Inverse of _stack_padded, executed INSIDE the traced graph (slices of
+    """Inverse of _pad_stack, executed INSIDE the traced graph (slices of
     traced arrays are free — XLA fuses them into the consumers)."""
     i32 = kw["i32_cols"]
     i8 = kw["i8_cols"]
@@ -503,15 +429,10 @@ def _select_variant(lt: LoweredTable, batch: PackedBatch, jit_cache: dict):
     return variant_key
 
 
-def _device_eval(
-    lt: LoweredTable,
-    batch: PackedBatch,
-    use_jax: bool = True,
-    jit_cache: Optional[dict] = None,
-    mesh=None,
-):
-    """Run the condition kernels + lattice, returning
-    ``(final, role_results, win_j, sat_arr, col_map)``.
+def _host_or_mesh_eval(lt: LoweredTable, batch: PackedBatch, mesh, jit_cache: dict):
+    """Run the condition kernels + lattice SYNCHRONOUSLY, off the
+    single-device jit path (that one is _device_dispatch/_device_finalize),
+    returning ``(final, role_results, win_j, sat_arr, col_map)``.
 
     ``sat_arr`` is COMPACT: [B, A] over only the condition columns this
     batch references (candidates, synthetic denies, derived-role
@@ -520,21 +441,14 @@ def _device_eval(
     Keeping sat compact makes device and host work O(active conditions)
     even when the table holds thousands.
 
-    With jax, runs through a shape-bucketed ``jax.jit`` cache whose key
-    includes the group-member subset (static trace structure); with a
-    ``mesh``, batch-axis arrays are placed with a NamedSharding over the
-    mesh's "data" axis (padded bucket sizes are powers of two >=16, so they
-    divide evenly over 2/4/8-device meshes) and XLA partitions the
-    computation across devices.
+    With ``mesh`` None, numpy on the host (with the native lattice where the
+    extension has it). With one, a shape-bucketed ``jax.jit`` cache whose
+    key includes the group-member subset (static trace structure):
+    batch-axis arrays are placed with a NamedSharding over the mesh's "data"
+    axis (padded bucket sizes are powers of two >=16, so they divide evenly
+    over 2/4/8-device meshes) and XLA partitions the computation across
+    devices.
     """
-    if use_jax and mesh is None:
-        # single-chip device path: async dispatch + blocking finalize
-        # (an EMPTY caller dict is still the caller's cache — only None
-        # gets a throwaway)
-        return _device_finalize(
-            _device_dispatch(lt, batch, jit_cache if jit_cache is not None else {})
-        )
-
     compiler = lt.compiler
     K, J, D = batch.K, batch.J, batch.D
     BA = batch.cand_cond.shape[0]
@@ -546,13 +460,9 @@ def _device_eval(
     if BA == 0:
         return _zero_result(B, K, C)
 
-    if use_jax:
+    if mesh is not None:
         # decide the (static trace structure) variant BEFORE remapping /
         # padding / sharding so those all see the final choice
-        if jit_cache is None:
-            jit_cache = {}
-        B_pad = _next_bucket(B)
-        BA_pad = _next_bucket(BA)
         variant_key = _select_variant(lt, batch, jit_cache)
     else:
         # the numpy path pays no compile cost: always evaluate compactly
@@ -565,18 +475,8 @@ def _device_eval(
         variant_key, compiler, C, batch.cand_cond, batch.cand_drcond
     )
     cols = batch.columns
-    arrays = dict(
-        tags=cols.tags, his=cols.his, los=cols.los, sids=cols.sids, nans=cols.nans,
-        pred_vals=cols.pred_vals, pred_errs=cols.pred_errs,
-        ba_input=batch.ba_input, cand_cond=cand_cond_c, cand_drcond=cand_drcond_c,
-        cand_effect=batch.cand_effect, cand_pt=batch.cand_pt, cand_depth=batch.cand_depth,
-        cand_valid=batch.cand_valid, scope_sp=batch.scope_sp,
-        list_sids=cols.list_sids, list_states=cols.list_states,
-        ts_his=cols.ts_his, ts_los=cols.ts_los, ts_states=cols.ts_states,
-        now_hi=cols.now_hi, now_lo=cols.now_lo,
-    )
 
-    if not use_jax:
+    if mesh is None:
         from .. import native as native_mod
 
         native = native_mod.get()
@@ -611,7 +511,16 @@ def _device_eval(
             return final, role_results, win_j, sat_arr, col_map
 
         final, role_results, win_j, sat_arr = _compute(
-            np, compiler, K, J, D, variant=variant_key, **arrays
+            np, compiler, K, J, D,
+            tags=cols.tags, his=cols.his, los=cols.los, sids=cols.sids, nans=cols.nans,
+            pred_vals=cols.pred_vals, pred_errs=cols.pred_errs,
+            ba_input=batch.ba_input, cand_cond=cand_cond_c, cand_drcond=cand_drcond_c,
+            cand_effect=batch.cand_effect, cand_pt=batch.cand_pt, cand_depth=batch.cand_depth,
+            cand_valid=batch.cand_valid, scope_sp=batch.scope_sp,
+            list_sids=cols.list_sids, list_states=cols.list_states,
+            ts_his=cols.ts_his, ts_los=cols.ts_los, ts_states=cols.ts_states,
+            now_hi=cols.now_hi, now_lo=cols.now_lo,
+            variant=variant_key,
         )
         return (
             np.asarray(final), np.asarray(role_results), np.asarray(win_j),
@@ -621,14 +530,16 @@ def _device_eval(
     import jax
     import jax.numpy as jnp
 
-    padded = _pad_arrays(batch, cols, cand_cond_c, cand_drcond_c, B_pad, BA_pad)
-
-    # multi-chip path: per-path arrays shard independently over the
-    # mesh's batch axis; transfer fusion doesn't apply (and would fight
-    # the shardings), so call _compute directly
+    # per-path arrays shard independently over the mesh's batch axis;
+    # transfer fusion doesn't apply (and would fight the shardings), so
+    # call _compute directly
     from ..parallel.mesh import shard_packed_arrays
 
-    padded = shard_packed_arrays(padded, mesh)
+    B_pad = _next_bucket(B)
+    BA_pad = _next_bucket(BA)
+    padded = shard_packed_arrays(
+        _pad_arrays(batch, cols, cand_cond_c, cand_drcond_c, B_pad, BA_pad), mesh
+    )
     key = (B_pad, BA_pad, K, J, D, variant_key)
     fn = jit_cache.get(key)
     if fn is None:
@@ -780,14 +691,15 @@ def _fill_rows(dst: np.ndarray, rows: list, native) -> None:
 
 
 def _pad_stack(batch: PackedBatch, cols, cand_cond_c, cand_drcond_c, B_pad: int, BA_pad: int):
-    """Fused _pad_arrays + _stack_padded for the single-device path.
+    """The transfer format of the single-device path: every column padded to
+    its shape bucket (the fills of _pad_arrays) and stacked into a handful of
+    typed matrices, so a device dispatch costs O(1) host->device transfers
+    (see _device_dispatch); _unstack_padded reads it back inside the trace.
 
-    The two-step version materializes a padded copy of every column (~100
-    np.concatenate) and then stacks those copies into the transfer matrices
-    (another full pass). Here each column's bytes are written exactly once,
-    straight into pooled padded matrices. Returns (stacked, layout, leased);
-    hand ``leased`` back to ``_buffer_pool`` once the device is done with
-    the batch (see _device_finalize)."""
+    Each column's bytes are written exactly once, straight into pooled
+    padded matrices. Returns (stacked, layout, leased); hand ``leased`` back
+    to ``_buffer_pool`` once the device is done with the batch (see
+    _device_finalize)."""
     from .. import native as native_mod
 
     native = native_mod.get()
@@ -1068,8 +980,6 @@ class TpuEvaluator:
         min_device_batch: int = 16,
         mesh=None,
         pipeline_chunk: int = 4096,
-        streaming_threshold: int = 1024,
-        inflight_depth: int = 3,
         device=None,
         shard_id: Optional[int] = None,
         _lowered: Optional[LoweredTable] = None,
@@ -1088,12 +998,6 @@ class TpuEvaluator:
         self.device = device
         self.shard_id = shard_id
         self.pipeline_chunk = pipeline_chunk
-        # batch size at which check() switches to the chunked double-buffered
-        # pipeline; 0 disables. Small enough that cross-request batches from
-        # the serving path engage it, not just bench-sized megabatches.
-        self.streaming_threshold = streaming_threshold
-        # device batches kept in flight by the pipelined path
-        self.inflight_depth = max(1, int(inflight_depth))
         if use_jax:
             from .jitcache import enable as _enable_jit_cache
 
@@ -1155,8 +1059,6 @@ class TpuEvaluator:
             min_device_batch=self.min_device_batch,
             mesh=mesh,
             pipeline_chunk=self.pipeline_chunk,
-            streaming_threshold=self.streaming_threshold,
-            inflight_depth=self.inflight_depth,
             device=device,
             shard_id=shard_id,
             _lowered=self.lowered,
@@ -1174,53 +1076,47 @@ class TpuEvaluator:
         return jax.default_device(self.device)
 
     def check(self, inputs: list[T.CheckInput], params: Optional[T.EvalParams] = None) -> list[T.CheckOutput]:
-        params = params or T.EvalParams()
-        if len(inputs) < self.min_device_batch:
-            # device dispatch has a fixed cost; tiny batches are faster on
-            # the serial oracle (the reference's parallelismThreshold analogue)
-            self.stats["oracle_inputs"] += len(inputs)
-            return [check_input(self.rule_table, i, params, self.schema_mgr) for i in inputs]
-        if (
-            self.use_jax
-            and self.mesh is None
-            and self.pipeline_chunk > 0
-            and self.streaming_threshold > 0
-            and len(inputs) >= self.streaming_threshold
-        ):
-            return self._check_pipelined(inputs, params)
-        batch = self.packer.pack(inputs, params)
-        with self._device_scope():
-            final, role_results, win_j, sat_arr, col_map = _device_eval(
-                self.lowered, batch, use_jax=self.use_jax, jit_cache=self._jit_cache, mesh=self.mesh
-            )
-        return self._assemble_batch(batch, final, role_results, win_j, sat_arr, col_map, params)
+        """Evaluate one batch and wait for it: :meth:`submit` and
+        :meth:`collect` in one call, so a direct caller (warm-up, an engine
+        with no batcher, the bisect, explain) takes the route a served
+        flight takes, to the same jit-cache entries."""
+        return self.collect(self.submit(inputs, params))
 
     def submit(self, inputs: list[T.CheckInput], params: Optional[T.EvalParams] = None) -> "CheckTicket":
-        """Queue one batch WITHOUT waiting for its results.
+        """Queue one batch WITHOUT waiting for its results: the one route
+        from a list of inputs to the device.
 
-        The device work (transfers + compute + result copy) runs
-        asynchronously; the caller keeps packing/submitting further batches
-        — or assembling earlier ones via :meth:`collect` — while this one
-        is in flight. This is how a serving loop hides the interconnect's
+        The batch is cut by :meth:`_chunk_inputs` (one chunk up to
+        ``pipeline_chunk``), and each chunk is packed and dispatched in
+        turn. The device work (transfers + compute + result copy) runs
+        asynchronously; the caller keeps submitting further batches — or
+        assembling earlier ones via :meth:`collect` — while this one is in
+        flight. This is how a serving loop hides the interconnect's
         per-batch latency: N batches in flight amortize transfer latency
         the way the reference's ghz load (hundreds of concurrent requests)
-        amortizes per-request overhead. Non-device paths (numpy backend,
-        mesh, tiny batches) evaluate synchronously and the ticket is
-        already complete."""
+        amortizes per-request overhead. What does not go to the single
+        device is evaluated here and now, and the ticket is already
+        complete: a batch under ``min_device_batch`` by the oracle, the
+        numpy backend and a mesh by :func:`_host_or_mesh_eval`."""
         params = params or T.EvalParams()
         t = CheckTicket()
         t.params = params
-        if (
-            not self.use_jax
-            or self.mesh is not None
-            or len(inputs) < self.min_device_batch
-        ):
+        if len(inputs) < self.min_device_batch:
+            # device dispatch has a fixed cost; tiny batches are faster on
+            # the serial oracle (the reference's parallelismThreshold analogue)
             drainclock.to(drainclock.ORACLE)
-            t.ready = self.check(inputs, params)
+            self.stats["oracle_inputs"] += len(inputs)
+            t.ready = [check_input(self.rule_table, i, params, self.schema_mgr) for i in inputs]
             return t
-        # split oversized batches along the same chunk boundaries as
-        # check(), so streaming reuses the already-traced shape buckets
-        # instead of compiling a monolithic one
+        if not self.use_jax or self.mesh is not None:
+            drainclock.to(drainclock.ORACLE)
+            batch = self.packer.pack(inputs, params)
+            with self._device_scope():
+                res = _host_or_mesh_eval(
+                    self.lowered, batch, self.mesh if self.use_jax else None, self._jit_cache
+                )
+            t.ready = self._assemble_batch(batch, *res, params)
+            return t
         chunks = self._chunk_inputs(inputs)
         t.parts = []
         with start_span("batch.pack", inputs=len(inputs), chunks=len(chunks)), self._device_scope():
@@ -1230,16 +1126,18 @@ class TpuEvaluator:
                 batch = self.packer.pack(ch, params)
                 t.pack_s += time.perf_counter() - p0
                 t.parts.append((batch, _device_dispatch(self.lowered, batch, self._jit_cache)))
-        real = sum(h.B for _, h in t.parts)
-        padded = sum(h.B_pad for _, h in t.parts)
+        # a chunk with no candidate rows (every input trivial) never reached the device
+        sent = [h for _, h in t.parts if h.ready is None]
+        padded = sum(h.B_pad for h in sent)
         if padded:
-            t.occupancy = real / padded
+            t.occupancy = sum(h.B for h in sent) / padded
             t.padded_rows = padded
-            t.layout_key = "+".join(f"B{h.B_pad}xBA{h.BA_pad}" for _, h in t.parts)
+            t.layout_key = "+".join(f"B{h.B_pad}xBA{h.BA_pad}" for h in sent)
         return t
 
     def collect(self, ticket: "CheckTicket") -> list[T.CheckOutput]:
-        """Block on one submitted batch and assemble its CheckOutputs."""
+        """Block on one submitted batch and assemble its CheckOutputs, chunk
+        by chunk in the order they were dispatched."""
         if ticket.ready is not None:
             return ticket.ready
         out: list[T.CheckOutput] = []
@@ -1262,34 +1160,6 @@ class TpuEvaluator:
             chunks[-2] = chunks[-2] + chunks[-1]
             chunks.pop()
         return chunks
-
-    def _check_pipelined(self, inputs: list[T.CheckInput], params: T.EvalParams) -> list[T.CheckOutput]:
-        """Chunked double-buffered device pipeline.
-
-        The serial path pays pack -> put -> compute -> fetch -> assemble
-        per batch with the device idle during host work and vice versa.
-        Here the batch is split into fixed-size chunks; each chunk's device
-        work is QUEUED asynchronously (`_device_dispatch` returns before
-        the device runs, with the result copy already started), so chunk
-        N's transfers/compute overlap chunk N-1's assembly and chunk N+1's
-        packing. Wall-clock approaches max(host work, device work) instead
-        of their sum."""
-        outputs: list[T.CheckOutput] = []
-        chunks = self._chunk_inputs(inputs)
-        inflight: list[tuple[PackedBatch, _DeviceHandle]] = []
-        for ci, ch in enumerate(chunks):
-            batch = self.packer.pack(ch, params)
-            with self._device_scope():
-                h = _device_dispatch(self.lowered, batch, self._jit_cache)
-            inflight.append((batch, h))
-            if len(inflight) >= self.inflight_depth:
-                b, hh = inflight.pop(0)
-                outputs.extend(
-                    self._assemble_batch(b, *_device_finalize(hh), params)
-                )
-        for b, hh in inflight:
-            outputs.extend(self._assemble_batch(b, *_device_finalize(hh), params))
-        return outputs
 
     def _assemble_batch(
         self, batch: PackedBatch, final, role_results, win_j, sat_arr, col_map, params

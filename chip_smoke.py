@@ -339,7 +339,7 @@ def build_requests(mods: int, seed: int, n_single: int, n_batch: int):
 
 
 def write_policies(policy_dir: str, mods: int) -> int:
-    """One policy per file plus the schemas, as loadtest/loadtest.py does."""
+    """One policy per file plus the schemas, as ``benchmarks/lib/server.py`` does."""
     from cerbos_tpu.util import bench_corpus
 
     docs = bench_corpus.corpus_yaml(mods).split("\n---\n")

@@ -1,6 +1,6 @@
 """The memo-cold workload must preserve the replay workload's decisions.
 
-requests_unique's whole claim (bench.py memo_cold, loadtest --cold) is
+requests_unique's whole claim (bench.py memo_cold) is
 "unique values, same decision mix": every condition's truth value survives
 the uniquification. This pins it by checking per-request effects against
 the unjittered requests() the variant derives from.

@@ -54,13 +54,12 @@ class TestHelmTemplate:
         # readiness is warmup-gated and split from liveness
         assert c["livenessProbe"]["httpGet"]["path"] == "/_cerbos/health"
         assert c["readinessProbe"]["httpGet"]["path"] == "/_cerbos/ready"
-        # the rendered config carries the streaming knobs end to end
+        # the rendered config carries the serving-path knobs end to end
         conf = yaml.safe_load(
             docs[("ConfigMap", "pdp-cerbos-tpu-config")]["data"]["config.yaml"]
         )
         tpu = conf["engine"]["tpu"]
         assert tpu["enabled"] is True
-        assert tpu["streamingThreshold"] == 1024
         assert tpu["inflightDepth"] == 3
         assert tpu["pipelineChunk"] == 4096
         # device-path fault domain defaults (docs/ROBUSTNESS.md)
@@ -92,7 +91,7 @@ class TestHelmTemplate:
         docs = render(
             "policies.configMapName=my-policies",
             "cerbos.config.engine.tpu.inflightDepth=2",
-            "cerbos.config.engine.tpu.streamingThreshold=512",
+            "cerbos.config.engine.tpu.pipelineChunk=512",
         )
         dep = docs[("Deployment", "pdp-cerbos-tpu")]
         vols = {v["name"]: v for v in dep["spec"]["template"]["spec"]["volumes"]}
@@ -104,7 +103,7 @@ class TestHelmTemplate:
             docs[("ConfigMap", "pdp-cerbos-tpu-config")]["data"]["config.yaml"]
         )
         assert conf["engine"]["tpu"]["inflightDepth"] == 2
-        assert conf["engine"]["tpu"]["streamingThreshold"] == 512
+        assert conf["engine"]["tpu"]["pipelineChunk"] == 512
 
 
 class TestChartStatic:
@@ -123,7 +122,7 @@ class TestChartStatic:
         from cerbos_tpu.config import DEFAULTS
 
         want = DEFAULTS["engine"]["tpu"]
-        for knob in ("streamingThreshold", "inflightDepth", "pipelineChunk", "quarantineMax"):
+        for knob in ("inflightDepth", "pipelineChunk", "quarantineMax"):
             assert tpu[knob] == want[knob], knob
         for knob in ("enabled", "failureThreshold", "probeBackoffBaseMs", "probeBackoffCapMs"):
             assert tpu["breaker"][knob] == want["breaker"][knob], knob

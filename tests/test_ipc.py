@@ -665,7 +665,7 @@ class TestMetricsRelabel:
         wf.part("validate")
         wf.mark("admission", part="enqueue")
         trk.finish(wf, "deadline_met", final_stage="reply_encode", final_part="encode")
-        trk.observe_reply(wf.t0, wf.t0, wf.t0 + 0.001)
+        trk.observe_reply(wf.t0, wf.t0, wf.t0 + 0.0007)  # inside the le="0.001" bucket, not on its edge
         text = "\n".join(
             line for line in obs.metrics().render().splitlines()
             if re.match(r"(# TYPE )?cerbos_tpu_request_(front|back|handler)_seconds", line)

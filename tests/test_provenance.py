@@ -228,7 +228,7 @@ class TestDifferentialAttribution:
         assert ae.source == "device"
 
     def test_bench_corpus_attribution_parity(self):
-        """The golden corpus (the bench/loadtest workload) end to end."""
+        """The golden corpus (``bench.py``'s workload) end to end."""
         from cerbos_tpu.util import bench_corpus
 
         rt = build_rule_table(
@@ -428,12 +428,6 @@ class TestHotRules:
         assert snap["attributed"] == 0
         assert snap["unattributed"] == 1
         assert snap["attribution_rate"] == 0.0
-
-    def test_kill_switch_env(self, rt, monkeypatch):
-        monkeypatch.setenv("CERBOS_TPU_NO_PROVENANCE", "1")
-        rec = HotRuleRecorder()
-        rec.observe(oracle(rt, [inp(0)]))
-        assert rec.snapshot()["decisions"] == 0
 
     def test_observe_never_raises(self):
         rec = HotRuleRecorder()

@@ -186,10 +186,13 @@ def test_parts_are_off_with_the_waterfall(tmp_path, tracker):
     srv, closers = serve(tmp_path, "single")
     try:
         before = counts(tracker)
+        # the store is the process's: an earlier test's in-process handler
+        # call may have left its stamp there (evicted FIFO, by design)
+        stamps_before = dict(server_mod._GRPC_REPLY_STAMPS._stamps)
         send_grpc(srv)
         send_http(srv)
         assert counts(tracker) == before
-        assert not server_mod._GRPC_REPLY_STAMPS._stamps
+        assert server_mod._GRPC_REPLY_STAMPS._stamps.items() <= stamps_before.items()  # these two left none
     finally:
         for close in closers:
             close()

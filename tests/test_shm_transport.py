@@ -377,7 +377,7 @@ class TestShmDataPlane:
             assert ts["transport"] == "shm"
             assert ts["frames_out"] >= 1 and ts["frames_in"] >= 1
             assert ts["encode_ns_per_frame"] > 0 and ts["decode_ns_per_frame"] > 0
-            assert json.dumps(ts)  # loadtest/bench embed this verbatim
+            assert json.dumps(ts)  # /_cerbos/debug/transport serves this verbatim
         finally:
             close_pair(batcher, server, client)
 

@@ -1,7 +1,7 @@
 """Streaming serving path: the batcher drives submit/collect with several
-device batches in flight, the pipelined check engages at realistic batch
-sizes, and the fused pad+stack transfer staging is bit-exact vs the two-step
-reference implementation.
+device batches in flight, a direct check() takes the same route chunk by
+chunk, and the pad+stack transfer staging round-trips to the plainly padded
+arrays.
 """
 
 import concurrent.futures
@@ -144,33 +144,72 @@ class TestStreamingBatcher:
         assert metrics().counter("cerbos_tpu_batcher_oracle_fallbacks_total").value == before + 1
 
 
-class TestStreamingThreshold:
-    @pytest.mark.parametrize("n", [63, 64, 65, 130])
-    def test_parity_around_threshold(self, n):
-        """check() stays bit-exact at, below and above the streaming
-        threshold, and the pipelined path engages exactly at the knob."""
+def nokind(i: int) -> CheckInput:
+    """An input no policy covers: trivial to the packer, no candidate row."""
+    return dataclasses.replace(inp(i), resource=Resource(kind="nokind", id=f"n{i}", attr={}))
+
+
+class TestOneRoute:
+    @pytest.mark.parametrize("n, cuts", [(63, [32, 31]), (64, [32, 32]), (65, [32, 33]), (130, [32, 32, 32, 34])])
+    def test_direct_check_cut_into_chunks_is_bit_exact(self, n, cuts):
+        """A direct check() over pipeline_chunk is dispatched chunk by chunk
+        and collected in order: element for element the oracle's answer."""
         rt = table()
-        ev = TpuEvaluator(
-            rt,
-            use_jax=True,
-            min_device_batch=4,
-            pipeline_chunk=32,
-            streaming_threshold=64,
-            inflight_depth=2,
-        )
-        calls = []
-        orig = ev._check_pipelined
-        ev._check_pipelined = lambda i, p: (calls.append(len(i)), orig(i, p))[1]
+        ev = TpuEvaluator(rt, use_jax=True, min_device_batch=4, pipeline_chunk=32)
         inputs = [inp(i) for i in range(n)]
         params = EvalParams()
+        assert [len(c) for c in ev._chunk_inputs(inputs)] == cuts
         got = ev.check(inputs, params)
         want = [check_input(rt, i, params) for i in inputs]
-        assert effects(got) == effects(want)
-        assert bool(calls) == (n >= 64)
+        assert sans_source(got) == sans_source(want)
+        assert ev.stats["device_inputs"] == n
 
-    def test_default_threshold_realistic(self):
-        """ISSUE acceptance: default engagement at <= 1024 inputs."""
-        assert TpuEvaluator(table(), use_jax=False).streaming_threshold <= 1024
+    @pytest.mark.parametrize(
+        "n, chunk, layouts", [(40, 4096, ["B64xBA64"]), (100, 32, ["B32xBA32", "B32xBA32", "B64xBA64"])]
+    )
+    def test_check_is_submit_and_collect(self, n, chunk, layouts):
+        """check() and collect(submit()) are one route: the same outputs, the
+        oracle's but for ``source``, and the same jit-cache entries (whichever
+        comes second compiles nothing)."""
+        from cerbos_tpu.tpu import compilestats
+
+        rt = table()
+        ev = TpuEvaluator(rt, use_jax=True, pipeline_chunk=chunk)
+        inputs = [inp(i) for i in range(n)]
+        params = EvalParams()
+
+        def counts():
+            snap = compilestats.stats().snapshot()
+            return snap["cache_hits"], snap["cache_misses"]
+
+        h0, m0 = counts()
+        direct = ev.check(inputs, params)
+        h1, m1 = counts()
+        distinct = len(set(layouts))
+        assert (h1 - h0, m1 - m0) == (len(layouts) - distinct, distinct)
+        ticket = ev.submit(inputs, params)
+        assert ticket.layout_key == "+".join(layouts)
+        streamed = ev.collect(ticket)
+        h2, m2 = counts()
+        assert (h2 - h1, m2 - m1) == (len(layouts), 0)
+        assert streamed == direct
+        assert sans_source(direct) == sans_source([check_input(rt, i, params) for i in inputs])
+        assert len([k for k in ev._jit_cache if k != ("_variant_budget",)]) == distinct
+
+    @pytest.mark.parametrize("backend", ["jax", "numpy"])
+    def test_a_batch_with_no_candidate_row_is_served(self, backend):
+        """Twenty inputs no policy covers make a packed batch the device is
+        never asked about: submit() books no layout for it and both doors
+        answer what the oracle answers."""
+        rt = table()
+        ev = TpuEvaluator(rt, use_jax=backend == "jax")
+        inputs = [nokind(i) for i in range(20)]
+        params = EvalParams()
+        ticket = ev.submit(inputs, params)
+        assert (ticket.layout_key, ticket.padded_rows, ticket.occupancy) == (None, None, None)
+        want = sans_source([check_input(rt, i, params) for i in inputs])
+        assert sans_source(ev.collect(ticket)) == want
+        assert sans_source(ev.check(inputs + [inp(0)], params)) == want + sans_source([check_input(rt, inp(0), params)])
 
     @pytest.mark.parametrize(
         "n, want",
@@ -183,9 +222,9 @@ class TestStreamingThreshold:
     )
     def test_a_batch_that_fits_one_pipeline_chunk_is_one_chunk(self, n, want):
         """The flight's length alone decides: one chunk up to pipeline_chunk,
-        pipeline_chunk-sized slices beyond it, whatever inflight_depth says."""
+        pipeline_chunk-sized slices beyond it."""
         ev = TpuEvaluator(table(), use_jax=False)  # the defaults a server boots with
-        assert (ev.pipeline_chunk, ev.min_device_batch, ev.inflight_depth) == (4096, 16, 3)
+        assert (ev.pipeline_chunk, ev.min_device_batch) == (4096, 16)
         inputs = list(range(n))  # _chunk_inputs only slices
         chunks = ev._chunk_inputs(inputs)
         assert [len(c) for c in chunks] == want
@@ -243,47 +282,43 @@ class TestFusedPadStack:
         ev = TpuEvaluator(rt, use_jax=False, min_device_batch=0)
         return ev.packer.pack([inp(i) for i in range(n)], EvalParams())
 
-    def test_matches_two_step_reference(self):
-        """_pad_stack (fused, pooled, native fill) produces byte-identical
-        transfer matrices to _pad_arrays + _stack_padded."""
-        batch = self._packed()
-        B = batch.scope_sp.shape[0]
-        BA = batch.cand_cond.shape[0]
-        B_pad = evmod._next_bucket(B)
-        BA_pad = evmod._next_bucket(BA)
-        padded = evmod._pad_arrays(
-            batch, batch.columns, batch.cand_cond, batch.cand_drcond, B_pad, BA_pad
-        )
-        want, lay_want = evmod._stack_padded(padded)
-        got, lay_got, leased = evmod._pad_stack(
-            batch, batch.columns, batch.cand_cond, batch.cand_drcond, B_pad, BA_pad
-        )
-        try:
-            assert lay_got.sig == lay_want.sig
-            assert set(got) == set(want)
-            for k in want:
-                assert np.array_equal(np.asarray(got[k]), np.asarray(want[k])), k
-        finally:
-            evmod._buffer_pool.release(leased)
-
-    def test_dirty_pool_buffers_are_fully_overwritten(self):
-        """Recycled buffers carry garbage; a second fused pass over the same
-        shapes must still match the freshly-allocated reference."""
-        batch = self._packed()
+    def _round_trip(self, batch):
+        """_unstack_padded of what _pad_stack wrote, beside _pad_arrays of the
+        same batch: the format's writer and reader against the plain padding,
+        field by field, whatever the bytes in between look like."""
         B_pad = evmod._next_bucket(batch.scope_sp.shape[0])
         BA_pad = evmod._next_bucket(batch.cand_cond.shape[0])
         args = (batch, batch.columns, batch.cand_cond, batch.cand_drcond, B_pad, BA_pad)
-        _, _, leased = evmod._pad_stack(*args)
+        want = evmod._pad_arrays(*args)
+        stacked, layout, leased = evmod._pad_stack(*args)
+        got = evmod._unstack_padded(np, layout, stacked)
+        assert set(got) == set(want)
+        for name, w in want.items():
+            g = got[name]
+            if isinstance(w, dict):
+                assert set(g) == set(w), name
+                for k in w:
+                    assert np.array_equal(g[k], w[k]), (name, k)
+            elif w is None:
+                assert g is None, name
+            else:
+                assert np.array_equal(g, w), name
+        return leased
+
+    def test_unstack_gives_back_what_pad_arrays_gives(self):
+        evmod._buffer_pool.release(self._round_trip(self._packed()))
+
+    def test_dirty_pool_buffers_are_fully_overwritten(self, monkeypatch):
+        """Recycled buffers carry garbage; a second pass over the same shapes
+        must still round-trip to the freshly padded arrays."""
+        pool = evmod._BufferPool()  # this test's own, so the second pass leases what the first returned
+        monkeypatch.setattr(evmod, "_buffer_pool", pool)
+        batch = self._packed()
+        leased = self._round_trip(batch)
         for a in leased:
             a.fill(-1 if a.dtype != np.bool_ else True)  # poison
-        evmod._buffer_pool.release(leased)
-        want, _ = evmod._stack_padded(evmod._pad_arrays(*args))
-        got, _, leased2 = evmod._pad_stack(*args)
-        try:
-            for k in want:
-                assert np.array_equal(np.asarray(got[k]), np.asarray(want[k])), k
-        finally:
-            evmod._buffer_pool.release(leased2)
+        pool.release(leased)
+        assert {id(a) for a in self._round_trip(batch)} == {id(a) for a in leased}
 
     def test_buffer_pool_recycles(self):
         pool = evmod._BufferPool()
