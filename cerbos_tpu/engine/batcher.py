@@ -21,6 +21,12 @@ traffic measured on the chip brings a lone check its fifteen companions within
 ``batchWindowMs`` (PERF.md section 6, PR 25). The loop itself waits for more
 only with nothing in flight and plan queries alone queued (``_plans_alone``).
 
+A request crosses to the drain thread only when crossing can change the
+flight: one under the evaluator's ``min_device_batch`` that finds the queue
+empty would make a flight of its own that ``submit()`` hands to the CPU oracle,
+so ``check()`` answers it from that oracle on the caller's thread
+(``_serve_inline``; PERF.md section 6, PR 30).
+
 The device path is a supervised fault domain (docs/ROBUSTNESS.md):
 
 - a ``DeviceHealth`` breaker routes ``check()`` straight to the CPU oracle
@@ -46,7 +52,7 @@ from concurrent.futures import TimeoutError as FutureTimeoutError
 from dataclasses import dataclass, field
 from typing import Any, Optional, Sequence
 
-from ..observability import SpanContext, export_span, start_span
+from ..observability import SpanContext, current_span, export_span, start_span
 from ..ruletable import check_input
 from . import types as T
 from .admission import OverloadRefused
@@ -57,6 +63,7 @@ from .budget import (
     STAGE_ADMISSION,
     STAGE_COLLECT,
     STAGE_DEVICE,
+    STAGE_EVALUATE,
     STAGE_ORACLE,
     STAGE_PACK,
     STAGE_QUEUE_WAIT,
@@ -325,7 +332,16 @@ def _fingerprint(inp: T.CheckInput) -> int:
 
 class BatchingEvaluator:
     """Wraps a batch evaluator (TpuEvaluator) with cross-request batching
-    and an in-flight streaming window over its submit/collect pipeline."""
+    and an in-flight streaming window over its submit/collect pipeline.
+
+    ``check()`` routes a request before it queues it. One of fewer inputs than
+    the evaluator's ``min_device_batch`` that finds the queue empty (and no
+    cutover barrier pending) is answered on the caller's own thread by the CPU
+    oracle, which is where ``submit()`` would send a flight of it alone: no
+    ``_Pending``, no flight, no two thread crossings. Every other request
+    queues, so a single that arrives behind a queued page still rides the
+    device with it. ``cerbos_tpu_batcher_checks_total{route}`` counts both.
+    ``check_async()`` (the pool owner's door) always queues."""
 
     # Engine forwards per-request deadlines only to evaluators that opt in.
     supports_deadline = True
@@ -445,6 +461,12 @@ class BatchingEvaluator:
         self.m_requests = reg.counter(
             "cerbos_tpu_batcher_requests_total", "requests coalesced into device batches"
         )
+        self.m_checks = reg.counter_vec(
+            "cerbos_tpu_batcher_checks_total",
+            "check() calls past the refusal ladder, by route: inline (under minDeviceBatch on an empty "
+            "queue: answered by the CPU oracle on the caller's thread, no flight) or queued",
+            label="route",
+        )
         self.m_deadline_drops = reg.counter(
             "cerbos_tpu_batcher_deadline_drops_total",
             "requests dropped with DEADLINE_EXCEEDED before device work",
@@ -475,7 +497,7 @@ class BatchingEvaluator:
             "device-batch pipeline stage seconds on the drain thread's clock, once per flight, by shard: "
             "pack, submit (= stack + dispatch + compiles), device (host-clock GAP between submit "
             "returning and collect starting, not device time), collect (= fetch + assemble), settle; "
-            "oracle (synchronous check of a flight under minDeviceBatch), post (after settle)",
+            "oracle (synchronous check of a flight or of a request under minDeviceBatch), post (after settle)",
             label=("stage", "shard"),
             buckets=[0.0001, 0.0005, 0.001, 0.002, 0.005, 0.01, 0.025, 0.05, 0.1, 0.5, 1.0],
         )
@@ -499,6 +521,24 @@ class BatchingEvaluator:
 
     # -- oracle fallback ----------------------------------------------------
 
+    def _oracle_outputs(
+        self, inputs: Sequence[T.CheckInput], params: Optional[T.EvalParams]
+    ) -> list[T.CheckOutput]:
+        """The CPU oracle's answer on the calling thread, for a fallback and
+        for a request that never needed a flight alike."""
+        ev = self.evaluator
+        # read the table once: a cutover between inputs must not split this
+        # request across two tables; the epoch stamp travels with the table
+        rt = ev.rule_table
+        T.set_current_epoch(getattr(rt, "policy_epoch", None))
+        params = params or T.EvalParams()
+        out = [check_input(rt, i, params, ev.schema_mgr) for i in inputs]
+        # oracle-served decisions carry source="oracle" from check_input;
+        # fold them into the hot-rule heatmap so attribution-rate and
+        # device-vs-oracle splits cover them too
+        hotrules.recorder().observe(out)
+        return out
+
     def _serve_oracle(
         self,
         inputs: Sequence[T.CheckInput],
@@ -510,21 +550,40 @@ class BatchingEvaluator:
         self.m_oracle_fallbacks.inc(reason)
         if wf is not None:
             wf.note_fallback(reason)
-        ev = self.evaluator
-        # read the table once: a cutover between inputs must not split this
-        # request across two tables; the epoch stamp travels with the table
-        rt = ev.rule_table
-        T.set_current_epoch(getattr(rt, "policy_epoch", None))
-        out = [
-            check_input(rt, i, params or T.EvalParams(), ev.schema_mgr)
-            for i in inputs
-        ]
-        # oracle-served decisions carry source="oracle" from check_input;
-        # fold them into the hot-rule heatmap so attribution-rate and
-        # device-vs-oracle splits cover the degraded path too
-        hotrules.recorder().observe(out)
+        out = self._oracle_outputs(inputs, params)
         if wf is not None:
             wf.mark(STAGE_ORACLE)
+        return out
+
+    def _serve_inline(
+        self,
+        inputs: Sequence[T.CheckInput],
+        params: Optional[T.EvalParams],
+        deadline: Optional[float],
+        wf: Optional[Waterfall],
+    ) -> list[T.CheckOutput]:
+        """Answer a request under ``min_device_batch`` here, on its own thread.
+        Not a fallback: the oracle is where ``submit()`` would have sent a
+        flight of this request alone, with the same table read and the same
+        epoch stamp. It books what has a reader and nothing of a flight, since
+        none was made: no batch size, window wait, flight record or batch id."""
+        span = current_span()
+        if span is not None and span.name == "engine.Check":
+            span.set_attribute("path", "inline")  # it said "device" on the way in
+        self._admit_wf(wf, deadline)
+        if wf is not None:
+            wf.mark(STAGE_QUEUE_WAIT)  # a true wait of nothing
+        t0 = time.perf_counter()
+        out = self._oracle_outputs(inputs, params)
+        self.m_stage_seconds.observe("oracle", time.perf_counter() - t0)
+        if wf is not None:
+            wf.mark(STAGE_EVALUATE)
+        sentinel = self.sentinel
+        if sentinel is not None:
+            # no replay (an oracle answer against the oracle proves nothing),
+            # but the rollout gate replays the sentinel's ring of live inputs
+            # before a cutover, and on a sidecar-only host it would go empty
+            sentinel.observe_inline(self.shard_id or 0, inputs)
         return out
 
     # -- request path -------------------------------------------------------
@@ -590,6 +649,20 @@ class BatchingEvaluator:
         if self._stop or self._dead is not None or not self._thread.is_alive():
             # drain loop gone (shutdown or crash): fail fast to the oracle
             return self._serve_oracle(inputs, params, "batcher_dead", wf=wf)
+        # A request crosses to the drain thread only when crossing can change
+        # the flight. The look at the queue and the barrier is a racy read
+        # without the lock, by design (as load()'s is): both routes give the
+        # same answer bit for bit, so a stale read costs one crossing, or
+        # spares one, and nothing else. An evaluator with no min_device_batch
+        # has no oracle of its own to be sent to: it never takes the route.
+        if (
+            len(inputs) < getattr(self.evaluator, "min_device_batch", 0)
+            and not self._queue
+            and self._swap_barrier is None
+        ):
+            self.m_checks.inc("inline")
+            return self._serve_inline(inputs, params, deadline, wf)
+        self.m_checks.inc("queued")
         with start_span("batcher.enqueue", inputs=len(inputs)) as span:
             fut: Future = Future()
             # the span context crosses the batcher thread hop in _Pending so

@@ -521,6 +521,21 @@ class ParitySentinel:
         except Exception:  # noqa: BLE001  (diagnostics must never hurt serving)
             _log.exception("parity sentinel observe_batch failed")
 
+    def observe_inline(self, shard: int, inputs: Sequence[T.CheckInput]) -> None:
+        """Called on a request's own thread after the batcher answered it from
+        the CPU oracle with no flight (``BatchingEvaluator._serve_inline``).
+        Replaying an oracle answer against the oracle proves nothing, so there
+        is no sample and no replay; but ``recent`` is what the rollout gate
+        replays before a cutover, and a host that serves one-resource checks
+        alone would leave it empty: the inputs are offered to the ring at the
+        sampler's own rate. Never raises, never blocks."""
+        try:
+            if self.should_sample(shard):
+                with self._lock:
+                    self.recent.extend(inputs)
+        except Exception:  # noqa: BLE001  (diagnostics must never hurt serving)
+            _log.exception("parity sentinel observe_inline failed")
+
     def should_sample_plan(self, shard: int) -> bool:
         """Plan-lane twin of :meth:`should_sample` — same deterministic
         fractional accumulator, separate per-shard state, same first-batch

@@ -306,11 +306,17 @@ def close_exporter() -> None:
         close()
 
 
+def current_span() -> Optional[Span]:
+    """The calling thread's active span, so a callee can set an attribute on
+    the span its caller opened (one dictionary read)."""
+    return _current.get(threading.get_ident())
+
+
 def current_span_context() -> Optional[SpanContext]:
     """Detach the active span's identity so another thread can parent or
     link to it (span parenting via ``_current`` is thread-local; the batcher
     hop carries this snapshot in ``_Pending`` instead)."""
-    span = _current.get(threading.get_ident())
+    span = current_span()
     return span.context if span is not None else None
 
 

@@ -571,7 +571,11 @@ class TestBootstrapWiring:
 
         (tmp_path / "album.yaml").write_text(POLICY)
         monkeypatch.setenv("CERBOS_TPU_FAULTS", "submit_raise:1.0")
-        config = Config.load(overrides=[f"storage.disk.directory={tmp_path}"])
+        # minDeviceBatch=1: a one-input request has to reach submit() to meet the
+        # fault; under the threshold it is answered before the queue (PR 30)
+        config = Config.load(
+            overrides=[f"storage.disk.directory={tmp_path}", "engine.tpu.minDeviceBatch=1"]
+        )
         core = initialize(config)
         try:
             batcher = core.engine.tpu_evaluator

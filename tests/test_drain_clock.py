@@ -349,7 +349,9 @@ def capture(tmp_path_factory):
         time.sleep(0.15)
         for k in range(6):
             b.check([inp(i) for i in range(8)])
-            b.check([inp(k)])  # under minDeviceBatch: the oracle state
+            # under minDeviceBatch through the door that always queues: the drain thread's oracle
+            # state (check() would answer it on this thread, with no flight: PR 30)
+            b.check_async([inp(k)]).result(timeout=30)
             b.plan([k])  # alone in the queue: the window state, 1 ms
             time.sleep(0.01)
         thread.join(timeout=60)
@@ -578,6 +580,20 @@ def test_device_calls_mean_is_jitted_calls_per_device_served_flight(calls, fligh
     after = f'{CALLS}_sum{{shard="0"}} {7 + calls}\n{CALLS}_count{{shard="0"}} {5 + flights}\n'
     assert read_metric("device_calls_mean.pages", before, after) == want
     assert read_metric("device_calls_mean.pages", PARENT, PARENT) is None
+
+
+ROUTES = "cerbos_tpu_batcher_checks_total"
+
+
+@pytest.mark.parametrize("cell", ["sidecar", "pages"])
+@pytest.mark.parametrize("inline, queued, want", [(300, 0, 100.0), (0, 40, 0.0), (3, 1, 75.0), (0, 0, None)])
+def test_inline_share_is_the_share_of_checks_answered_with_no_flight(cell, inline, queued, want):
+    """PR 30's files: the route counter's growth over the window; nothing from a
+    program without the counter, or in a window in which no check got past the ladder."""
+    before = f'{ROUTES}{{route="inline"}} 11\n{ROUTES}{{route="queued"}} 5\n'
+    after = f'{ROUTES}{{route="inline"}} {11 + inline}\n{ROUTES}{{route="queued"}} {5 + queued}\n'
+    assert read_metric(f"inline_share.{cell}", before, after) == want
+    assert read_metric(f"inline_share.{cell}", PARENT, PARENT) is None
 
 
 def test_every_new_metric_is_in_the_manifest_under_its_layer():

@@ -701,6 +701,9 @@ class TestBootstrapIntegration:
                 "engine.tpu.rollout.canaryBoost=100",
                 "engine.tpu.paritySentinel.sampleRate=1.0",
                 "engine.tpu.paritySentinel.stormThreshold=1000",
+                # the drill's one-input requests have to ride the device path
+                # that is poisoned; under the threshold they never would (PR 30)
+                "engine.tpu.minDeviceBatch=1",
             ],
         )
         try:

@@ -344,6 +344,10 @@ class TestRequestBatching:
         (policy_dir / "album.yaml").write_text(POLICY)
         config = Config.load(overrides=[
             f"storage.disk.directory={policy_dir}",
+            # a request under minDeviceBatch on an empty queue is answered on its
+            # own thread and never queues (PR 30): these one-resource requests are
+            # to coalesce, so the threshold is lowered to where they all queue
+            "engine.tpu.minDeviceBatch=1",
         ])
         core = initialize(config)  # tpu enabled (numpy fallback inside evaluator when jax off)
         core.tpu_evaluator.use_jax = False  # force numpy path for the test env

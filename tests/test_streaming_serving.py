@@ -73,7 +73,9 @@ class TestStreamingBatcher:
         batcher reach the device via submit/collect with >= 2 batches in
         flight, and every output is bit-exact vs the CPU oracle."""
         rt = table()
-        ev = TpuEvaluator(rt, use_jax=True, min_device_batch=4)
+        # min_device_batch=1: a request under the threshold that finds the queue
+        # empty is answered on its own thread (PR 30); these are to queue
+        ev = TpuEvaluator(rt, use_jax=True, min_device_batch=1)
         # max_batch=16 forces 64 requests to drain as 4+ tickets; the whole
         # burst queues behind a first flight that the gate holds, so the
         # submit loop demonstrably stacks tickets instead of racing the clients

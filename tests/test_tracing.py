@@ -51,10 +51,10 @@ class _CaptureExporter(obs.SpanExporter):
             return [s for s in self.spans if s.trace_id == trace_id]
 
 
-def _boot(tmp_path_factory, name):
+def _boot(tmp_path_factory, name, overrides=()):
     policy_dir = tmp_path_factory.mktemp(name)
     (policy_dir / "album.yaml").write_text(POLICY)
-    config = Config.load(overrides=[f"storage.disk.directory={policy_dir}"])
+    config = Config.load(overrides=[f"storage.disk.directory={policy_dir}", *overrides])
     core = initialize(config)
     core.tpu_evaluator.use_jax = False  # keep the test jax-independent
     return core
@@ -67,7 +67,9 @@ class TestEndToEndTracing:
         matching flight-recorder record."""
         from cerbos_tpu.server.server import Server, ServerConfig
 
-        core = _boot(tmp_path_factory, "tracing-policies")
+        # minDeviceBatch=1 so that the one-resource request makes a flight; at the
+        # default it is answered on its own thread (the next test)
+        core = _boot(tmp_path_factory, "tracing-policies", ["engine.tpu.minDeviceBatch=1"])
         cap = _CaptureExporter()
         old_exporter = obs._exporter
         obs.set_exporter(cap)
@@ -151,6 +153,62 @@ class TestEndToEndTracing:
             assert rec["occupancy"] is not None and rec["occupancy"] <= 1.0
             assert any(v > 0 for v in rec["timings"].values()), rec
             assert rec["requests"] >= 1 and rec["inputs"] >= 1
+        finally:
+            obs.set_exporter(old_exporter)
+            srv.stop()
+            core.close()
+
+
+    def test_a_one_resource_check_is_answered_with_no_flight_in_its_trace(self, tmp_path_factory):
+        """At the default ``minDeviceBatch`` a one-resource request that finds
+        the queue empty is answered on its own thread: its trace is the
+        request and the engine's span, and no flight record names it."""
+        from cerbos_tpu.server.server import Server, ServerConfig
+
+        core = _boot(tmp_path_factory, "inline-tracing-policies")
+        cap = _CaptureExporter()
+        old_exporter = obs._exporter
+        obs.set_exporter(cap)
+        srv = Server(
+            core.service,
+            ServerConfig(http_listen_addr="127.0.0.1:0", grpc_listen_addr="127.0.0.1:0"),
+        )
+        srv.start()
+        trace_id = obs.new_trace_id()
+        header = f"00-{trace_id}-{obs.new_span_id()}-01"
+        try:
+            body = {
+                "requestId": "tr-2",
+                "principal": {"id": "alice", "roles": ["user"]},
+                "resources": [
+                    {"actions": ["view"], "resource": {"kind": "album", "id": "a1", "attr": {"owner": "alice"}}}
+                ],
+            }
+            req = urllib.request.Request(
+                f"http://127.0.0.1:{srv.http_port}/api/check/resources",
+                data=json.dumps(body).encode(),
+                headers={"Content-Type": "application/json", "traceparent": header},
+                method="POST",
+            )
+            with urllib.request.urlopen(req) as resp:
+                results = json.loads(resp.read())["results"]
+                assert results[0]["actions"]["view"] == "EFFECT_ALLOW"
+            deadline = time.time() + 10
+            while time.time() < deadline and "request.CheckResources" not in {
+                s.name for s in cap.in_trace(trace_id)
+            }:
+                time.sleep(0.02)
+            time.sleep(0.1)  # a flight's spans would export on the drain thread just after the reply
+            trace = cap.in_trace(trace_id)
+            assert sorted(s.name for s in trace) == ["engine.Check", "request.CheckResources"]
+            spans = {s.name: s for s in trace}
+            assert spans["engine.Check"].attributes["path"] == "inline"
+            assert spans["engine.Check"].parent_id == spans["request.CheckResources"].span_id
+            with urllib.request.urlopen(
+                f"http://127.0.0.1:{srv.http_port}/_cerbos/debug/flight"
+            ) as resp:
+                dump = json.loads(resp.read())
+            assert not [r for r in dump["batches"] if trace_id in r["trace_ids"]]
         finally:
             obs.set_exporter(old_exporter)
             srv.stop()
