@@ -341,7 +341,8 @@ class BatchingEvaluator:
     ``_Pending``, no flight, no two thread crossings. Every other request
     queues, so a single that arrives behind a queued page still rides the
     device with it. ``cerbos_tpu_batcher_checks_total{route}`` counts both.
-    ``check_async()`` (the pool owner's door) always queues."""
+    ``check_async()`` (the pool owner's door) always queues, and is counted
+    as ``queued``."""
 
     # Engine forwards per-request deadlines only to evaluators that opt in.
     supports_deadline = True
@@ -463,8 +464,9 @@ class BatchingEvaluator:
         )
         self.m_checks = reg.counter_vec(
             "cerbos_tpu_batcher_checks_total",
-            "check() calls past the refusal ladder, by route: inline (under minDeviceBatch on an empty "
-            "queue: answered by the CPU oracle on the caller's thread, no flight) or queued",
+            "check() and check_async() calls past the refusal ladder, by route: inline (under minDeviceBatch on an "
+            "empty queue: answered by the CPU oracle on the caller's thread, no flight) or queued (check_async, the "
+            "pool owner's door, always queues)",
             label="route",
         )
         self.m_deadline_drops = reg.counter(
@@ -748,6 +750,7 @@ class BatchingEvaluator:
         if self._stop or self._dead is not None or not self._thread.is_alive():
             _settle(fut, error=_BatchFailed(self._dead, "batcher_dead"))
             return fut
+        self.m_checks.inc("queued")
         pending = _Pending(
             list(inputs), params, fut, deadline=deadline, ctx=ctx, wf=wf,
             pclass=pclass or "",

@@ -967,8 +967,12 @@ def relabel_metrics_text(text: str, label: str, value: str) -> str:
     exposition. Worker pools use this to stamp each process's scrape with
     its identity: a scrape against the shared SO_REUSEPORT port lands on a
     random sibling, and without the label its series would silently alias
-    the others' (docs/OBSERVABILITY.md, pooled scrape semantics)."""
+    the others' (docs/OBSERVABILITY.md, pooled scrape semantics). A sample
+    that already carries ``label`` keeps its own value as
+    ``exported_<label>``, as Prometheus does with a target's colliding
+    label: one name twice in a sample makes the whole scrape unparseable."""
     esc = value.replace("\\", "\\\\").replace('"', '\\"')
+    own = re.compile(rf'(?<![A-Za-z0-9_]){re.escape(label)}="')
     out = []
     for line in text.splitlines():
         if not line or line.startswith("#"):
@@ -979,7 +983,7 @@ def relabel_metrics_text(text: str, label: str, value: str) -> str:
             out.append(line)
             continue
         name, labels, val = m.groups()
-        inner = labels[1:-1] if labels else ""
+        inner = own.sub(f'exported_{label}="', labels[1:-1]) if labels else ""
         merged = f'{label}="{esc}"' + (f",{inner}" if inner else "")
         out.append(f"{name}{{{merged}}} {val}")
     return "\n".join(out) + ("\n" if out else "")

@@ -599,8 +599,12 @@ def test_inline_share_is_the_share_of_checks_answered_with_no_flight(cell, inlin
 def test_every_new_metric_is_in_the_manifest_under_its_layer():
     with open(os.path.join(REPO, "BENCHMARK.json")) as f:
         per_layer = {m["name"]: m for m in json.load(f)["per_layer"]}
+    # since PR 30 the single process makes no flight of one-resource checks, so the three of these that read a
+    # flight or the drain thread in the sidecar mix are held to the one accepted cell (PR 32): a pool's cell,
+    # where they are live, reads them through twins of its own
+    held = {"window_wait_mean_ms.sidecar", "batcher_busy_share.sidecar", "batcher_cpu_share.sidecar"}
     for name in EXPECTED:
         entry = per_layer[name]
-        assert "workloads" not in entry
+        assert entry.get("workloads") == (["classic-800.sidecar"] if name in held else None)
         assert entry["moves"] == ("check_p50_ms" if name.endswith(".sidecar") else "page_p50_ms")
         assert entry["source"] == ("program_counter" if "share" in name or name.startswith("warm_") else "program_span")

@@ -22,7 +22,7 @@ from cerbos_tpu import observability as obs
 from cerbos_tpu.compile import compile_policy_set
 from cerbos_tpu.engine import flight, hotrules
 from cerbos_tpu.engine import types as T
-from cerbos_tpu.engine.batcher import BatchingEvaluator, DeadlineExceeded
+from cerbos_tpu.engine.batcher import BatchingEvaluator, DeadlineExceeded, _BatchFailed
 from cerbos_tpu.engine.budget import (
     STAGE_ADMISSION,
     STAGE_EVALUATE,
@@ -147,10 +147,29 @@ def test_a_single_behind_a_queued_page_rides_that_pages_flight(evaluator, shard)
         plug.result(timeout=SOON), page.result(timeout=SOON)
     finally:
         b.close()
-    assert moved(before) == {"inline": 0, "queued": 1}  # check_async is not counted: it always queues
+    assert moved(before) == {"inline": 0, "queued": 3}  # the single, and the plug and the page through check_async
     assert sorted(r["inputs"] for r in flights_of(shard)) == [16, 21]
     assert sources(box["out"]) == {"device"}
     assert box["out"][0].actions["view"].effect == oracle(evaluator, [inp(7)])[0].actions["view"].effect
+
+
+@pytest.mark.parametrize("n", [1, 3, 16, 40])
+def test_check_async_always_queues_and_is_counted_as_queued(evaluator, shard, n):
+    """The pool owner's door: one increment of ``route="queued"`` per call past
+    the ladder, whatever the size, so ``inline_share`` reads 0.0 in a pool."""
+    b = BatchingEvaluator(evaluator, shard_id=shard)
+    inputs = [inp(i) for i in range(n)]
+    before, sizes = routes(), batch_sizes()
+    try:
+        out = b.check_async(inputs).result(timeout=SOON)
+        assert moved(before) == {"inline": 0, "queued": 1}
+        assert sans_source(out) == sans_source(oracle(evaluator, inputs))
+        assert batch_sizes() == sizes + 1  # a flight, even of one: the door never answers on the caller's thread
+        # check() beside it counts as it did: a single on the empty queue inline, a page queued
+        assert b.check([inp(0)]) and moved(before) == {"inline": 1, "queued": 1}
+        assert b.check([inp(i) for i in range(16)]) and moved(before) == {"inline": 1, "queued": 2}
+    finally:
+        b.close()
 
 
 class OpenBreaker:
@@ -193,6 +212,21 @@ def test_the_ladder_answers_before_the_route_is_chosen(shard, arrange, reason):
     assert fallbacks.get(reason) == fell + 1 and b.stats["oracle_fallbacks"] == 1
     assert wf.fallback_reason == reason and wf.served_by == "oracle"
     assert oracle_stage(shard).count == 0
+
+
+@pytest.mark.parametrize("arrange", [quarantined, breaker_open, loop_dead])
+def test_check_async_refused_by_the_ladder_is_not_counted(shard, arrange):
+    b = BatchingEvaluator(TpuEvaluator(table(), use_jax=False), shard_id=shard)
+    try:
+        arrange(b)
+        before = routes()
+        with pytest.raises(_BatchFailed):
+            b.check_async([inp(1)]).result(timeout=SOON)
+        with pytest.raises(DeadlineExceeded):
+            b.check_async([inp(1)], deadline=time.monotonic() - 0.001).result(timeout=SOON)
+    finally:
+        b.close()
+    assert moved(before) == {"inline": 0, "queued": 0}
 
 
 def test_an_expired_deadline_raises_before_the_route_is_chosen(shard):
@@ -313,7 +347,8 @@ def test_sixteen_threads_of_single_checks_all_get_the_oracles_answer(evaluator, 
         b.close()
     assert not wrong, wrong[:3]
     got = moved(before)
-    assert got["inline"] + got["queued"] == threads * each and got["inline"] > 0
+    pages = len(range(0, each, 20))  # thread 0's, through check_async: counted as queued
+    assert got["inline"] + got["queued"] == threads * each + pages and got["inline"] > 0
 
 
 def test_inline_traffic_keeps_the_sentinels_ring_of_live_inputs_and_queues_no_replay(shard):

@@ -607,6 +607,17 @@ class TestMetricsRelabel:
         assert 'b{worker="fe1",x="1"} 2' in out
         assert "# TYPE a counter" in out
 
+    def test_relabel_keeps_a_samples_own_label_as_exported(self):
+        """The owner's ``cerbos_tpu_ipc_enqueue_seconds`` is labelled by front
+        end under ``worker`` itself: stamped with ``worker="batcher"`` it held
+        the name twice (seen in a pool's scrape on the chip, PR 32)."""
+        text = 'e_bucket{worker="fe1",le="0.1"} 3\ne_sum{worker="fe1"} 0.2\nf{coworker="x",note="worker=\\"1\\""} 1\n'
+        out = relabel_metrics_text(text, "worker", "batcher")
+        assert 'e_bucket{worker="batcher",exported_worker="fe1",le="0.1"} 3' in out
+        assert 'e_sum{worker="batcher",exported_worker="fe1"} 0.2' in out
+        assert 'f{worker="batcher",coworker="x",note="worker=\\"1\\""} 1' in out  # another name, and a value, are left alone
+        assert all(line.count(' worker="') + line.count('{worker="') + line.count(',worker="') == 1 for line in out.splitlines())
+
     def test_merge_dedupes_family_comments(self):
         a = "# TYPE m counter\n# HELP m help\nm{worker=\"fe1\"} 1\n"
         b = "# TYPE m counter\n# HELP m help\nm{worker=\"batcher\"} 2\n"
