@@ -95,6 +95,11 @@ def prebuild(config: Config, use_tpu: Optional[bool] = None) -> Prebuilt:
     try:
         manager = RuleTableManager(store)
         rule_table = manager.rule_table
+        # the table's identity, once, before any fork: the owner publishes it
+        # as epoch 1's and every front end compares its own with it
+        from .engine.rollout import bundle_hash_of
+
+        bundle_hash_of(rule_table)
         engine_conf = config.section("engine")
         tpu_conf = engine_conf.get("tpu", {})
         tpu_enabled = tpu_conf.get("enabled", True) if use_tpu is None else use_tpu
@@ -122,7 +127,9 @@ def initialize(
       warmup; checks ride the ticket queue at ``ipc_socket`` to the shared
       batcher process via ``engine/ipc.RemoteBatcherClient``, readiness
       mirrors the batcher's, and the COW-shared rule table backs the local
-      CPU-oracle fallback when the batcher is down or refuses.
+      CPU oracle: the fallback when the batcher is down or refuses, and the
+      answer to a request under the owner's ``min_device_batch`` while that
+      table is the owner's committed one (engine/ipc.py).
 
     The batcher process itself uses :func:`build_batcher_ipc` on top of a
     standalone Core.
@@ -634,7 +641,13 @@ def build_batcher_ipc(core: Core, socket_path: str):
         max_outstanding=int(shared_conf.get("maxOutstanding", 4096)),
         faults=faults,
         transport=str(shared_conf.get("transport", "shm") or "shm"),
+        sentinel=core.sentinel,
     )
+    # the committed epoch, where a front end reads it per request: the boot
+    # epoch now, and both edges of every cutover from the controller
+    if core.rollout is not None:
+        server.publish_epoch(core.rollout.epoch)
+        core.rollout.on_cutover = server.publish_epoch
     # this process fronts the ticket ring: its occupancy is the ipc
     # pressure component (front ends see their own pending count instead)
     from .engine import pressure as _pressure

@@ -330,6 +330,41 @@ def _fingerprint(inp: T.CheckInput) -> int:
     )
 
 
+def oracle_walk(
+    rt: Any, epoch: Optional[int], inputs: Sequence[T.CheckInput], params: T.EvalParams, schema_mgr: Any
+) -> list[T.CheckOutput]:
+    """The CPU oracle's answer on the calling thread, from ONE table: the
+    caller read it once, so a cutover between inputs cannot split the request
+    across two, and ``epoch`` (stamped on the decisions) names that table.
+    Shared by this module's routes and a pool front end's (engine/ipc.py)."""
+    T.set_current_epoch(epoch)
+    return [check_input(rt, i, params, schema_mgr) for i in inputs]
+
+
+def route_families(reg: Any) -> tuple[Any, Any]:
+    """The two families that a request answered with no flight moves, for
+    whoever answers one: this module, and a pool's front end (whose series
+    carry its ``worker`` label in the pool's scrape). ``(checks_total by
+    route, batch_stage_seconds by stage and shard)``."""
+    checks = reg.counter_vec(
+        "cerbos_tpu_batcher_checks_total",
+        "check() and check_async() calls past the refusal ladder, by route: inline (under minDeviceBatch on an "
+        "empty queue, or in a pool's front end that holds the owner's committed policy set: answered by the CPU "
+        "oracle on the caller's thread, no flight) or queued (check_async, the pool owner's door, always queues)",
+        label="route",
+    )
+    stages = reg.histogram_vec(
+        "cerbos_tpu_batch_stage_seconds",
+        "device-batch pipeline stage seconds on the drain thread's clock, once per flight, by shard: "
+        "pack, submit (= stack + dispatch + compiles), device (host-clock GAP between submit "
+        "returning and collect starting, not device time), collect (= fetch + assemble), settle; "
+        "oracle (synchronous check of a flight or of a request under minDeviceBatch), post (after settle)",
+        label=("stage", "shard"),
+        buckets=[0.0001, 0.0005, 0.001, 0.002, 0.005, 0.01, 0.025, 0.05, 0.1, 0.5, 1.0],
+    )
+    return checks, stages
+
+
 class BatchingEvaluator:
     """Wraps a batch evaluator (TpuEvaluator) with cross-request batching
     and an in-flight streaming window over its submit/collect pipeline.
@@ -462,13 +497,7 @@ class BatchingEvaluator:
         self.m_requests = reg.counter(
             "cerbos_tpu_batcher_requests_total", "requests coalesced into device batches"
         )
-        self.m_checks = reg.counter_vec(
-            "cerbos_tpu_batcher_checks_total",
-            "check() and check_async() calls past the refusal ladder, by route: inline (under minDeviceBatch on an "
-            "empty queue: answered by the CPU oracle on the caller's thread, no flight) or queued (check_async, the "
-            "pool owner's door, always queues)",
-            label="route",
-        )
+        self.m_checks, self._m_stage_vec = route_families(reg)
         self.m_deadline_drops = reg.counter(
             "cerbos_tpu_batcher_deadline_drops_total",
             "requests dropped with DEADLINE_EXCEEDED before device work",
@@ -493,15 +522,6 @@ class BatchingEvaluator:
             "cerbos_tpu_admission_queue_budget_total",
             "requests refused because their priority class's lane queue budget was full, by class",
             label="pclass",
-        )
-        self._m_stage_vec = reg.histogram_vec(
-            "cerbos_tpu_batch_stage_seconds",
-            "device-batch pipeline stage seconds on the drain thread's clock, once per flight, by shard: "
-            "pack, submit (= stack + dispatch + compiles), device (host-clock GAP between submit "
-            "returning and collect starting, not device time), collect (= fetch + assemble), settle; "
-            "oracle (synchronous check of a flight or of a request under minDeviceBatch), post (after settle)",
-            label=("stage", "shard"),
-            buckets=[0.0001, 0.0005, 0.001, 0.002, 0.005, 0.01, 0.025, 0.05, 0.1, 0.5, 1.0],
         )
         self.m_stage_seconds = _ShardStageView(self._m_stage_vec, self._shard_label)
         self.m_device_calls = reg.histogram_vec(
@@ -529,12 +549,8 @@ class BatchingEvaluator:
         """The CPU oracle's answer on the calling thread, for a fallback and
         for a request that never needed a flight alike."""
         ev = self.evaluator
-        # read the table once: a cutover between inputs must not split this
-        # request across two tables; the epoch stamp travels with the table
-        rt = ev.rule_table
-        T.set_current_epoch(getattr(rt, "policy_epoch", None))
-        params = params or T.EvalParams()
-        out = [check_input(rt, i, params, ev.schema_mgr) for i in inputs]
+        rt = ev.rule_table  # read once; the epoch stamp travels with the table
+        out = oracle_walk(rt, getattr(rt, "policy_epoch", None), inputs, params or T.EvalParams(), ev.schema_mgr)
         # oracle-served decisions carry source="oracle" from check_input;
         # fold them into the hot-rule heatmap so attribution-rate and
         # device-vs-oracle splits cover them too

@@ -31,6 +31,16 @@ Transport: two interchangeable data planes under one control plane.
   it or refuses (HELLO_R), so a native-less peer on either end degrades the
   pair to uds automatically.
 
+Not every check crosses. A request of fewer inputs than the owner's
+``min_device_batch`` is one the owner would hand to the CPU oracle in a
+flight of its own, and every front end holds that oracle. The owner
+publishes its committed policy epoch (a generation word, the epoch's number,
+the policy set's identity) in each attached segment's descriptor page, and a
+front end whose own table has that identity, with no cutover pending,
+answers such a request itself on the request's thread
+(``RemoteBatcherClient._inline_route``; docs/ROBUSTNESS.md, "What a front
+end answers from"). The ``uds`` plane has no shared page and never does.
+
 All padding/stacking of decoded tickets stays on the batcher side via the
 evaluator's pooled ``_pad_stack`` staging buffers, so the marshalling cost
 the device cares about never leaves the device-owning process.
@@ -66,13 +76,23 @@ from concurrent.futures import TimeoutError as FutureTimeoutError
 from typing import Any, Callable, Optional, Sequence
 
 from .. import native
-from ..observability import current_span_context, parse_traceparent
-from ..ruletable import check_input
+from ..observability import current_span, current_span_context, parse_traceparent
+from . import hotrules
 from . import types as T
 from .admission import OverloadRefused
-from .batcher import DeadlineExceeded, _BatchFailed
-from .budget import FRONT_ENQUEUE, STAGE_IPC_ENCODE, STAGE_ORACLE, Waterfall
+from .batcher import DeadlineExceeded, _BatchFailed, oracle_walk, route_families
+from .budget import (
+    FRONT_ENQUEUE,
+    POINT_ENQUEUE,
+    STAGE_ADMISSION,
+    STAGE_EVALUATE,
+    STAGE_IPC_ENCODE,
+    STAGE_ORACLE,
+    STAGE_QUEUE_WAIT,
+    Waterfall,
+)
 from .budget import tracker as budget_tracker
+from .rollout import bundle_hash_of
 
 _log = logging.getLogger("cerbos_tpu.engine.ipc")
 
@@ -101,6 +121,7 @@ T_PROFILE = 18    # front end -> owner: run a profiler capture ({"seconds": s})
 T_PROFILE_R = 19
 T_SCRAPE = 20     # OWNER -> front end: send me your metrics text (the one reverse request)
 T_SCRAPE_R = 21
+T_OBSERVE = 22    # front end -> owner, no reply: inputs it answered from its own table, sampled for the sentinel's ring
 
 _SCRAPE_WAIT_S = 2.0  # a sibling that has not answered by then is left out of the pooled scrape
 
@@ -139,8 +160,9 @@ def _recv_frame(sock: socket.socket) -> tuple[int, int, bytes]:
 # -- shared-memory segment ---------------------------------------------------
 #
 # One file-backed mmap per front-end connection: a 4 KiB descriptor page
-# (magic / version / ring size) followed by two native byte rings — tickets
-# toward the batcher (c2s) and replies back (s2c). The FRONT END creates and
+# (magic / version / ring size, and on a cache line of its own the OWNER's
+# committed policy epoch: see ``publish_epoch``) followed by two native byte
+# rings — tickets toward the batcher (c2s) and replies back (s2c). The FRONT END creates and
 # sizes the segment, offers its path in HELLO, and unlinks the name as soon
 # as the handshake settles either way: from then on the mapping lives exactly
 # as long as the two processes that hold it, and a SIGKILL on either side
@@ -149,8 +171,26 @@ def _recv_frame(sock: socket.socket) -> tuple[int, int, bytes]:
 _SHM_MAGIC = 0x43544652
 _SHM_VER = 1
 _SHM_HDR = struct.Struct("<IIQ")
+# the owner's words, written by the owner alone, each an aligned 64-bit word
+# stored and loaded whole through a memoryview cast (``struct.pack_into``
+# zeroes its target before it fills it, which a reader in another process
+# can see): a generation (odd while a cutover is pending or the words are
+# being written), the committed epoch's number, and its policy set's identity
+# in two words (``_identity_words``; both zero: none). A segment no owner has
+# written reads generation 0 and no identity, which matches nothing.
+_SHM_EPOCH_AT = 64
+_SHM_EPOCH_WORDS = 4
+_NO_IDENTITY = (0, 0)
 _RING_HDR_BYTES = 256
 _shm_counter = 0
+
+
+def _identity_words(identity: str) -> tuple[int, int]:
+    """A policy set's identity (rollout.bundle_hash_of: 16 hex characters) as
+    the descriptor page holds it: two 64-bit words. No identity is
+    ``_NO_IDENTITY``, which no front end treats as a match."""
+    raw = identity.encode("ascii", "replace")[:16].ljust(16, b"\0")
+    return int.from_bytes(raw[:8], "little"), int.from_bytes(raw[8:], "little")
 
 
 def _align_page(n: int) -> int:
@@ -173,6 +213,7 @@ class _ShmSegment:
         span = _align_page(_RING_HDR_BYTES + ring_bytes)
         view = memoryview(mm)
         self._view = view
+        self._words = view[_SHM_EPOCH_AT : _SHM_EPOCH_AT + 8 * _SHM_EPOCH_WORDS].cast("Q")
         self.c2s = view[4096 : 4096 + _RING_HDR_BYTES + ring_bytes]
         self.s2c = view[4096 + span : 4096 + span + _RING_HDR_BYTES + ring_bytes]
 
@@ -229,8 +270,38 @@ class _ShmSegment:
         except OSError:
             pass
 
+    def publish_epoch(self, pending: bool, number: int, identity: str) -> None:
+        """The owner's side of the descriptor page: which policy epoch its
+        tickets are answered from. A sequence lock with one writer: the
+        generation goes odd, the words change, and it goes even again unless a
+        cutover is ``pending``, in which case it stays odd until the call that
+        publishes the committed epoch. Each word is one aligned store, and the
+        stores reach the other process in program order (x86); a reader that
+        saw an odd generation, or two different ones around its read, has read
+        nothing. Raises ValueError on a segment that is closed."""
+        w = self._words
+        odd = w[0] | 1
+        w[0] = odd
+        w[1] = number
+        w[2], w[3] = _identity_words(identity)
+        if not pending:
+            w[0] = odd + 1
+
+    def read_epoch(self) -> Optional[tuple[int, tuple[int, int]]]:
+        """The front end's side: the number of the owner's committed epoch and
+        its identity (``_identity_words``), or None while a cutover is pending
+        (or the words moved under the read). Five loads of shared memory, no
+        system call."""
+        w = self._words
+        gen = w[0]
+        if gen & 1:
+            return None
+        number, identity = w[1], (w[2], w[3])
+        return (number, identity) if w[0] == gen else None
+
     def close(self) -> None:
         try:
+            self._words.release()
             self.c2s.release()
             self.s2c.release()
             self._view.release()
@@ -461,10 +532,14 @@ class BatcherIpcServer:
         max_outstanding: int = 4096,
         faults: Optional[dict] = None,
         transport: str = "shm",
+        sentinel: Any = None,
     ):
         self.socket_path = socket_path
         self.batcher = batcher
         self.readiness = readiness
+        # the parity sentinel whose ring of recent inputs the rollout gate
+        # replays: front ends send it a sample of what they answer themselves
+        self.sentinel = sentinel
         self.max_outstanding = max(1, int(max_outstanding))
         self.faults = dict(faults or {})
         # the transport this server is WILLING to grant; a front end still
@@ -479,6 +554,12 @@ class BatcherIpcServer:
         self._scrapes: dict[int, Future] = {}
         self._scrape_id = 0
         self._lock = threading.Lock()
+        # what every attached segment's descriptor page says of the policy
+        # epoch (``publish_epoch``): pending, number, identity. Pending and
+        # nameless until the rollout controller's first word.
+        self._epoch_lock = threading.Lock()
+        self._epoch_words: tuple[bool, int, str] = (True, 0, "")
+        self._segs: list[_ShmSegment] = []
         self._outstanding = 0
         self._out_by = {"uds": 0, "shm": 0}
         self._checks_seen = 0
@@ -600,8 +681,9 @@ class BatcherIpcServer:
                     ):
                         try:
                             seg = _ShmSegment.attach(str(hello["shm_path"]))
+                            self._adopt_segment(seg)
                             grant = "shm"
-                        except (IpcError, OSError, struct.error):
+                        except (IpcError, OSError, ValueError, struct.error):
                             seg = None
                     if seg is not None:
                         self.stats["shm_conns"] += 1
@@ -620,11 +702,13 @@ class BatcherIpcServer:
                     # HELLO_R must be the first frame back on this connection:
                     # the client blocks on it before sending any traffic, so
                     # the writer queue is empty here by construction
-                    writer.send(T_HELLO_R, req_id, lambda g=grant: marshal.dumps({"transport": g}))
+                    writer.send(T_HELLO_R, req_id, lambda r=self._hello_reply(grant): marshal.dumps(r))
                     with self._lock:
                         self._peers[conn] = writer
                 elif mtype == T_CHECK:
                     self._handle_check(worker, req_id, payload, writer)
+                elif mtype == T_OBSERVE:
+                    self._handle_observe(payload)
                 elif mtype == T_STATUS:
                     snap = self._status_snapshot()
                     writer.send(T_STATUS_R, req_id, lambda s=snap: marshal.dumps(s))
@@ -671,6 +755,9 @@ class BatcherIpcServer:
             if shm_writer is not None:
                 shm_writer.close()
             if seg is not None:
+                with self._epoch_lock:
+                    if seg in self._segs:
+                        self._segs.remove(seg)
                 nat = native.get()
                 if nat is not None:
                     try:
@@ -689,6 +776,58 @@ class BatcherIpcServer:
 
     def _count_reply_drop(self) -> None:
         self.stats["reply_drops"] += 1
+
+    # -- the committed epoch, where a front end reads it per request ---------
+
+    def publish_epoch(self, epoch: Any) -> None:
+        """The rollout controller's cutover hook (``RolloutController.on_cutover``),
+        and its first word at boot. ``None``: a cutover is pending, from before
+        the drain barrier is requested; every front end takes the ticket route
+        from its next request on. An epoch: it is committed, its subscribers
+        have run; a front end whose own table has its identity may answer
+        requests under ``min_device_batch`` itself again. Writes every attached
+        segment's descriptor page; never per request."""
+        with self._epoch_lock:
+            if epoch is None:
+                self._epoch_words = (True, *self._epoch_words[1:])
+            elif epoch.number is None:  # an epoch with no number names no table: nothing to match
+                self._epoch_words = (False, 0, "")
+            else:
+                self._epoch_words = (False, int(epoch.number), str(epoch.bundle_hash or ""))
+            for seg in self._segs:
+                try:
+                    seg.publish_epoch(*self._epoch_words)
+                except ValueError:
+                    pass  # closed under us: its connection's teardown takes it off the list
+
+    def _adopt_segment(self, seg: _ShmSegment) -> None:
+        with self._epoch_lock:
+            seg.publish_epoch(*self._epoch_words)
+            self._segs.append(seg)
+
+    def _hello_reply(self, grant: str) -> dict:
+        """What a front end learns once per attach, beside the plane: the
+        owner's ``min_device_batch`` (a request under it is one the owner
+        would hand to the CPU oracle; 0 = never answer one yourself: no shared
+        page over uds, or an evaluator with no such floor) and the sentinel's
+        sample rate, at which it reports what it answered itself."""
+        lanes = getattr(self.batcher, "shards", None) or [self.batcher]
+        floor = min(int(getattr(getattr(b, "evaluator", None), "min_device_batch", 0) or 0) for b in lanes)
+        sentinel = self.sentinel
+        rate = float(sentinel.sample_rate) if sentinel is not None and sentinel.enabled else 0.0
+        return {"transport": grant, "min_device_batch": floor if grant == "shm" else 0, "sample_rate": rate}
+
+    def _handle_observe(self, payload: bytes) -> None:
+        """Inputs a front end answered from its own table, already sampled
+        there at the sentinel's rate: straight into the ring the rollout gate
+        replays. No reply, no flight, no route count."""
+        sentinel = self.sentinel
+        if sentinel is None:
+            return
+        try:
+            sentinel.remember(decode_inputs(marshal.loads(payload)))
+        except Exception:  # noqa: BLE001 — a malformed sample is dropped
+            pass
 
     def _shm_serve_loop(
         self,
@@ -983,6 +1122,17 @@ class RemoteBatcherClient:
     background reconnect loop so a respawned batcher picks traffic back up
     without restarting the front end.
 
+    A request of fewer inputs than the owner's ``min_device_batch`` is one the
+    owner would hand to the CPU oracle in a flight of its own. This process
+    holds that oracle, so it answers such a request itself, on the request's
+    own thread, when it can SEE that the answer is the owner's: attached over
+    shm, and the descriptor page says that no cutover is pending and that the
+    owner's committed policy set has the identity of the local table
+    (``_inline_route``). No ticket, no ring crossing, no flight. On any doubt
+    (uds, detached, a cutover pending, a bundle the owner refused or rolled
+    back, a watcher that lags on either side) the ticket route is taken as
+    before. ``cerbos_tpu_batcher_checks_total{route="inline"}`` counts them.
+
     Also exposes ``check_await`` — the asyncio-native path the HTTP front
     end uses to await tickets directly on the event loop, with no
     thread-pool hop per request (the single biggest per-call overhead the
@@ -1007,9 +1157,16 @@ class RemoteBatcherClient:
         ring_kib: int = 1024,
     ):
         self.socket_path = socket_path
-        self.rule_table = rule_table
         self.schema_mgr = schema_mgr
         self.params = params or T.EvalParams()
+        self.refresh_table(rule_table)
+        # learnt at each attach (HELLO_R) and forgotten at each detach: the
+        # owner's min_device_batch, 0 unless shm was granted, and the rate at
+        # which answers given here are sampled for the owner's sentinel
+        self._inline_under = 0
+        self._sample_rate = 0.0
+        self._sample_acc = 0.0
+        self._observed: deque = deque(maxlen=256)
         self.request_timeout = request_timeout_s
         self.worker_label = worker_label
         self.status_poll_s = status_poll_s
@@ -1036,6 +1193,7 @@ class RemoteBatcherClient:
         self.local_metrics_text: Callable[[], str] = self._registry_text
         self.stats = {
             "oracle_fallbacks": 0,
+            "inline": 0,
             "reconnects": 0,
             "checks": 0,
             "enc_ns": 0,
@@ -1091,6 +1249,10 @@ class RemoteBatcherClient:
             "requests served from the CPU oracle instead of the device path, by reason",
             label="reason",
         )
+        # what a request answered here with no ticket moves, as one answered
+        # with no flight does in a single process (this process is shard 0)
+        self.m_checks, stages = route_families(reg)
+        self._m_oracle_stage = stages.labels(("oracle", "0"))
         # rollout visibility (engine/rollout.py): the batcher's committed
         # epoch as observed from this front end, and how long each cutover
         # took to become visible here — the "bounded, measured skew window"
@@ -1150,8 +1312,10 @@ class RemoteBatcherClient:
                     mtype, _, payload = _recv_frame(sock)
                 finally:
                     sock.settimeout(None)
+                reply: dict = {}
                 if mtype == T_HELLO_R:
-                    granted = str(marshal.loads(payload).get("transport", "uds"))
+                    reply = marshal.loads(payload)
+                    granted = str(reply.get("transport", "uds"))
             except (IpcError, OSError, socket.timeout, ValueError, TypeError, EOFError):
                 if seg is not None:
                     seg.unlink()
@@ -1181,6 +1345,8 @@ class RemoteBatcherClient:
                 )
             self._shm = seg
             self._transport_active = "shm" if seg is not None else "uds"
+            self._inline_under = int(reply.get("min_device_batch", 0) or 0) if seg is not None else 0
+            self._sample_rate = float(reply.get("sample_rate", 0.0) or 0.0)
             self._sock = sock
             if shm_thread is not None:
                 shm_thread.start()
@@ -1197,6 +1363,7 @@ class RemoteBatcherClient:
             except (IpcError, OSError):
                 pass
             finally:
+                self._inline_under = 0
                 self._connected.clear()
                 self._sock = None
                 self._shm = None
@@ -1305,6 +1472,7 @@ class RemoteBatcherClient:
                         if snap.get("status") in ("ready", "degraded"):
                             self._ever_ready = True
                         self._note_epoch(snap)
+                    self._send_observed()
                 except (IpcError, OSError, FutureTimeoutError, TimeoutError, ValueError):
                     pass
             # fast cadence until the first frame lands, configured cadence after
@@ -1360,7 +1528,7 @@ class RemoteBatcherClient:
         finally:
             self._unregister(req_id)
 
-    # -- oracle fallback ----------------------------------------------------
+    # -- the local oracle: a fallback, or the owner's own answer --------------
 
     def _serve_oracle(
         self,
@@ -1373,18 +1541,87 @@ class RemoteBatcherClient:
         self.m_fallbacks.inc(reason)
         if wf is not None:
             wf.note_fallback(reason)
-        p = params or self.params
-        # single table read per request; the local COW table is never epoch-
-        # committed (the batcher owns epoch authority), so local fallbacks
-        # stamp None — honestly unversioned — rather than a guessed epoch
+        # the owner refused or is gone, so nothing says whose epoch the local
+        # table is: a fallback stamps None, honestly unversioned
         rt = self.rule_table
-        T.set_current_epoch(getattr(rt, "policy_epoch", None))
-        out = [check_input(rt, i, p, self.schema_mgr) for i in inputs]
+        out = oracle_walk(rt, getattr(rt, "policy_epoch", None), inputs, params or self.params, self.schema_mgr)
         if wf is not None:
             # books everything since the last mark — including any dead
             # round trip that preceded the fallback — as the oracle stage
             wf.mark(STAGE_ORACLE)
         return out
+
+    def _inline_route(self, n_inputs: int) -> Optional[tuple[Any, int]]:
+        """The rule, for ``check`` and ``check_await`` alike: ``(table,
+        epoch)`` when a request of ``n_inputs`` is to be answered here, from
+        ``table``, as the owner's epoch number ``epoch``; None for the ticket
+        route. A request at or over the owner's ``min_device_batch`` pays one
+        comparison; one under it five loads of the shared page. The page is
+        read AFTER the local pair, so a reload here between the two reads
+        costs a ticket, never an answer from a table the page did not name."""
+        if n_inputs >= self._inline_under:
+            return None  # also: over uds, while detached, before the first attach (0)
+        seg = self._shm
+        rt, identity = self._local
+        if seg is None or identity == _NO_IDENTITY:
+            return None
+        try:
+            committed = seg.read_epoch()
+        except ValueError:
+            return None  # the segment closed under the read: detached
+        if committed is None or committed[1] != identity:
+            return None
+        return rt, committed[0]
+
+    def _serve_inline(
+        self,
+        route: tuple[Any, int],
+        inputs: Sequence[T.CheckInput],
+        params: Optional[T.EvalParams],
+        deadline: Optional[float],
+        wf: Optional[Waterfall],
+    ) -> list[T.CheckOutput]:
+        """Answer a request here, on its own thread: what the owner's flight
+        of this request alone would have answered (its committed table's
+        identity is this one's), booked as ``BatchingEvaluator._serve_inline``
+        books it in a single process. Not a fallback, and counted as none."""
+        rt, epoch = route
+        self.stats["inline"] += 1
+        self.m_checks.inc("inline")
+        span = current_span()
+        if span is not None and span.name == "engine.Check":
+            span.set_attribute("path", "inline")  # it said "device" on the way in
+        if wf is not None:
+            wf.shard = 0
+            wf.mark(STAGE_ADMISSION, part=FRONT_ENQUEUE)
+        if deadline is not None:
+            budget_tracker().observe_budget(POINT_ENQUEUE, deadline - time.monotonic(), shard=0)
+        if wf is not None:
+            wf.mark(STAGE_QUEUE_WAIT)  # a true wait of nothing
+        t0 = time.perf_counter()
+        out = oracle_walk(rt, epoch, inputs, params or self.params, self.schema_mgr)
+        self._m_oracle_stage.observe(time.perf_counter() - t0)
+        hotrules.recorder().observe(out)  # this process's decision_source_total; the owner's heatmap sees none of it
+        if wf is not None:
+            wf.mark(STAGE_EVALUATE)
+        # the owner's sentinel never sees these inputs, and the rollout gate
+        # replays its ring before a cutover: sample here, at its rate, and let
+        # the status thread carry the sample over (``_send_observed``)
+        self._sample_acc += self._sample_rate
+        if self._sample_acc >= 1.0:
+            self._sample_acc -= 1.0
+            self._observed.append(inputs)
+        return out
+
+    def _send_observed(self) -> None:
+        """From the status thread, after the answers were handed back: what
+        ``_serve_inline`` sampled since the last poll, as ONE frame that
+        expects no reply."""
+        sampled: list[T.CheckInput] = []
+        while self._observed:
+            sampled.extend(self._observed.popleft())
+        if sampled:
+            self._send(T_OBSERVE, 0, marshal.dumps(encode_inputs(sampled)))
 
     # -- check surface ------------------------------------------------------
 
@@ -1562,6 +1799,9 @@ class RemoteBatcherClient:
         if deadline is not None and time.monotonic() >= deadline:
             raise DeadlineExceeded("request deadline expired before evaluation")
         self.stats["checks"] += 1
+        route = self._inline_route(len(inputs))
+        if route is not None:
+            return self._serve_inline(route, inputs, params, deadline, wf)
         if not self._connected.is_set():
             return self._serve_oracle(inputs, params, "batcher_down", wf=wf)
         # pin the plane for this request: a reconnect mid-flight may
@@ -1612,6 +1852,10 @@ class RemoteBatcherClient:
         if deadline is not None and time.monotonic() >= deadline:
             raise DeadlineExceeded("request deadline expired before evaluation")
         self.stats["checks"] += 1
+        route = self._inline_route(len(inputs))
+        if route is not None:
+            # on the loop, as Engine.check_await's serial walk is: a fifth of a millisecond
+            return self._serve_inline(route, inputs, params, deadline, wf)
         if not self._connected.is_set():
             return await oracle("batcher_down")
         tr = self._transport_active
@@ -1740,8 +1984,15 @@ class RemoteBatcherClient:
         return marshal.loads(data)
 
     def refresh_table(self, rule_table: Any) -> None:
-        """Policy-reload hook: keep the local oracle on the latest table."""
-        self.rule_table = rule_table
+        """Policy-reload hook (and the constructor's): keep the local oracle
+        on the latest table, with its identity beside it, computed here and
+        never per request (for the boot table the pool's parent computed it
+        before the fork). ONE attribute, so a request reads a matched pair."""
+        self._local = (rule_table, _identity_words(bundle_hash_of(rule_table)))
+
+    @property
+    def rule_table(self) -> Any:
+        return self._local[0]
 
     def close(self) -> None:
         self._stop = True

@@ -43,7 +43,10 @@ number, so every decision's ``policyEpoch`` stamp maps to exactly one table
 ever committed. The current epoch rides readiness snapshots and therefore
 IPC STATUS frames, which is how ``--frontends`` processes observe cutovers
 within a bounded, measured skew window
-(``cerbos_tpu_policy_epoch_skew_seconds``).
+(``cerbos_tpu_policy_epoch_skew_seconds``). What a front end may ANSWER from
+is decided without that window: ``on_cutover`` announces both edges of every
+commit to the pool's ticket server, which publishes them where each front
+end reads them per request (engine/ipc.py).
 """
 
 from __future__ import annotations
@@ -93,27 +96,76 @@ def epoch_of(rule_table: Any) -> Optional[int]:
 
 
 def bundle_hash_of(rule_table: Any) -> str:
-    """Stable content hash over the rule rows — the identity printed in
-    rollout reports and flight events so operators can tie an epoch back to
-    the bundle that produced it."""
+    """Stable content hash of everything ``check_input`` reads of a table:
+    every field of every rule row (its condition tree, its derived role's
+    condition, its output expressions, and the constants and variables both
+    resolve against), each policy's derived roles, schema references and
+    metadata, and the role policies' parent roles. It is the identity printed
+    in rollout reports and flight events, AND what a front end compares with
+    the owner's committed epoch before it answers from its own table
+    (engine/ipc.py): two tables of one identity give the same answers.
+    ``""`` on any error, which matches nothing. Computed once per table object
+    (a table is never edited once it serves; ``RuleTable`` drops the memo if
+    it is)."""
+    memo = getattr(rule_table, "bundle_hash_memo", None)
+    if memo:
+        return memo
     try:
-        h = hashlib.sha256()
-        rows = sorted(
-            rule_table.idx.get_all_rows(), key=lambda r: (r.origin_fqn, r.id)
-        )
-        for r in rows:
-            cond = r.condition
-            cond_src = ""
-            if cond is not None:
-                cond_src = getattr(getattr(cond, "expr", None), "original", "") or cond.kind
-            actions = r.action or "|".join(sorted(r.allow_actions or ()))
-            h.update(
-                f"{r.origin_fqn}|{r.id}|{r.evaluation_key}|{r.name}"
-                f"|{r.effect}|{r.role}|{actions}|{cond_src}\n".encode()
-            )
-        return h.hexdigest()[:16]
-    except Exception:  # noqa: BLE001 — identity is advisory, never fatal
+        digest = _table_digest(rule_table)
+    except Exception:  # noqa: BLE001 — no identity, never fatal
         return ""
+    rule_table.bundle_hash_memo = digest
+    return digest
+
+
+def _table_digest(rt: Any) -> str:
+    # conditions, outputs and params are shared by the rows of one rule or
+    # one policy: each is written out once and named by its position after
+    seen: dict[int, str] = {}
+
+    def expr(e: Any) -> str:
+        return repr(e.original) if e is not None else "-"
+
+    def shared(obj: Any, write: Callable[[Any], str]) -> str:
+        if obj is None:
+            return "-"
+        ref = seen.get(id(obj))
+        if ref is None:
+            ref = seen[id(obj)] = f"#{len(seen)}"
+            return f"{ref}={write(obj)}"
+        return ref
+
+    def cond(c: Any) -> str:
+        return shared(c, lambda c: f"{c.kind}<{expr(c.expr)}>[{','.join(cond(k) for k in c.children)}]")
+
+    def output(o: Any) -> str:
+        return shared(o, lambda o: f"{expr(o.rule_activated)}/{expr(o.condition_not_met)}")
+
+    def params(p: Any) -> str:
+        return shared(
+            p,
+            lambda p: f"{sorted(p.constants.items())!r};"
+            + ";".join(f"{v.name}={expr(v.expr)}" for v in p.ordered_variables),
+        )
+
+    h = hashlib.sha256()
+    for r in sorted(rt.idx.get_all_rows(), key=lambda r: (r.origin_fqn, r.id)):
+        actions = r.action if r.action is not None else sorted(r.allow_actions or ())
+        h.update(
+            f"{r.origin_fqn}|{r.id}|{r.evaluation_key}|{r.name}|{r.effect}|{r.role}|{actions!r}"
+            f"|{r.scope}|{r.version}|{r.policy_kind}|{r.resource}|{r.principal}|{r.scope_permissions}"
+            f"|{r.origin_derived_role}|{r.from_role_policy}|{r.no_match_for_scope_permissions}"
+            f"|{cond(r.condition)}|{cond(r.derived_role_condition)}|{output(r.emit_output)}"
+            f"|{params(r.params)}|{params(r.derived_role_params)}\n".encode()
+        )
+    for mod_id in sorted(rt.meta):
+        m = rt.meta[mod_id]
+        h.update(f"meta|{m.fqn}|{m.name}|{m.version}|{m.kind}|{m.source_attributes!r}|{m.annotations!r}\n".encode())
+        for name, dr in sorted((rt.policy_derived_roles.get(mod_id) or {}).items()):
+            h.update(f"dr|{name}|{sorted(dr.parent_roles)!r}|{cond(dr.condition)}|{params(dr.params)}\n".encode())
+        h.update(f"schemas|{rt.schemas.get(mod_id)!r}\n".encode())
+    h.update(f"parents|{sorted((s, sorted(p.items())) for s, p in rt.scope_parent_roles.items())!r}\n".encode())
+    return h.hexdigest()[:16]
 
 
 class RolloutFault(RuntimeError):
@@ -266,7 +318,9 @@ class RolloutController:
 
     ``mode="full"`` gates, versions, and canaries (device-owning roles);
     ``mode="passive"`` only runs the subscriber registry on each rebuild
-    (front ends — their epoch authority is the batcher's STATUS frames)."""
+    (front ends — their epoch authority is the batcher: its STATUS frames
+    for the gauges, and per request the words ``on_cutover`` has it publish
+    in the shared descriptor page, engine/ipc.py)."""
 
     def __init__(
         self,
@@ -304,6 +358,11 @@ class RolloutController:
         self.runs_max = max(1, int(conf.get("runHistory", 8)))
 
         self._subs: list[tuple[str, Callable[[Epoch], None]]] = []
+        # both edges of a cutover, for whoever publishes the committed epoch
+        # beyond this process (a pool's owner, to its front ends' shared
+        # pages): called with None before the drain barrier is requested,
+        # and with the epoch once its subscribers have run
+        self.on_cutover: Optional[Callable[[Optional[Epoch]], None]] = None
         self._lanes: list[Any] = []
         self._lock = threading.RLock()  # epoch / history / runs bookkeeping
         self._run_lock = threading.Lock()  # one rollout (or rollback) at a time
@@ -645,6 +704,13 @@ class RolloutController:
             except Exception:  # noqa: BLE001 — one bad subscriber, not a torn commit
                 log.exception("rollout: subscriber %r failed during cutover", name)
 
+    def _announce(self, epoch: Optional[Epoch]) -> None:
+        if self.on_cutover is not None:
+            try:
+                self.on_cutover(epoch)
+            except Exception:  # noqa: BLE001 — a reader left behind asks this process; the commit goes on
+                log.exception("rollout: cutover announcement failed")
+
     def _commit(self, epoch: Epoch, rollback: bool = False) -> None:
         """The atomic cutover: park every lane at a flight boundary, swap
         the world under the barrier, stamp lane epochs, resume."""
@@ -654,6 +720,7 @@ class RolloutController:
                 setattr(epoch.rule_table, EPOCH_ATTR, epoch.number)
             except Exception:  # noqa: BLE001
                 pass
+        self._announce(None)
         barrier = SwapBarrier(timeout_s=self.drain_timeout_s)
         parked = barrier.start(self._lanes)
         if not parked:
@@ -673,6 +740,9 @@ class RolloutController:
             self._notify_subscribers(epoch)
             for lane in self._lanes:
                 lane.epoch = epoch.number
+            # a commit that raised before this line stays announced as
+            # pending: its readers keep asking this process, which is safe
+            self._announce(epoch)
         finally:
             barrier.release()
         with self._lock:

@@ -531,10 +531,18 @@ class ParitySentinel:
         sampler's own rate. Never raises, never blocks."""
         try:
             if self.should_sample(shard):
-                with self._lock:
-                    self.recent.extend(inputs)
+                self.remember(inputs)
         except Exception:  # noqa: BLE001  (diagnostics must never hurt serving)
             _log.exception("parity sentinel observe_inline failed")
+
+    def remember(self, inputs: Sequence[T.CheckInput]) -> None:
+        """Put inputs into the ring of recently served ones, unsampled: the
+        caller sampled them (``observe_inline``; a pool's front end, which
+        samples what it answers itself at ``sample_rate`` and sends the owner
+        the sample, engine/ipc.py)."""
+        if self.enabled and not self._shed:
+            with self._lock:
+                self.recent.extend(inputs)
 
     def should_sample_plan(self, shard: int) -> bool:
         """Plan-lane twin of :meth:`should_sample` — same deterministic

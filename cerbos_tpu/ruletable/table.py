@@ -49,11 +49,15 @@ class RuleTable:
         # fqn -> chain source attributes (static per table build; hot on the
         # evaluator's cold-assembly path)
         self._chain_attr_memo: dict[str, dict[str, dict]] = {}
+        # the table's content identity (engine/rollout.bundle_hash_of), kept
+        # from its first computation until the table is edited
+        self.bundle_hash_memo: Optional[str] = None
 
     # -- build ------------------------------------------------------------
 
     def ingest_policy(self, p: CompiledPolicy) -> None:
         self._chain_attr_memo.clear()
+        self.bundle_hash_memo = None
         mod_id = namer.module_id(p.fqn)
         if isinstance(p, CompiledResourcePolicy):
             self.meta[mod_id] = PolicyMeta(
@@ -92,6 +96,7 @@ class RuleTable:
 
     def delete_policy(self, fqn: str) -> None:
         self._chain_attr_memo.clear()
+        self.bundle_hash_memo = None
         self.idx.delete_policy(fqn)
         mod_id = namer.module_id(fqn)
         meta = self.meta.pop(mod_id, None)
