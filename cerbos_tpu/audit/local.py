@@ -37,10 +37,13 @@ class LocalBackend:
             self._conn.commit()
 
     def write(self, entry: dict) -> None:
+        # a call's access entry and its decision entry share the call id
+        # (upstream keys them under a prefix per kind): the row's id is both
+        kind = entry.get("kind", "")
         with self._lock:
             self._conn.execute(
                 "INSERT OR REPLACE INTO audit_entries (id, kind, ts, entry) VALUES (?, ?, ?, ?)",
-                (entry.get("callId") or uuid.uuid4().hex, entry.get("kind", ""), entry.get("timestamp", ""), json.dumps(entry, default=str)),
+                (f"{kind}/{entry.get('callId') or uuid.uuid4().hex}", kind, entry.get("timestamp", ""), json.dumps(entry, default=str)),
             )
             self._conn.commit()
         self._maybe_expire()

@@ -4,13 +4,26 @@ Behavioral reference: internal/audit/{log,conf,decision_filter}.go —
 pluggable backends via a registry, decision log filters (accessLogsEnabled /
 decisionLogsEnabled, filter by action/kind), async buffered writes
 (log.go:142-195).
+
+An entry is built on the request's thread and queued; one writer thread
+serialises and writes it. The request never waits for the backend: a full
+queue drops the entry and the ``shed_audit`` rung of the brownout ladder
+refuses it at the door. Every entry is accounted for by kind:
+``cerbos_tpu_audit_entries_total{kind,outcome}`` (``queued``, ``written``,
+``filtered``) and ``cerbos_tpu_audit_lost_total{kind,reason}`` (``dropped``,
+``shed``, ``failed``), so ``queued`` = ``written`` + ``failed`` + what the
+queue still holds, and "nothing lost" is ``audit_lost_total`` not moving.
+``close()`` drains the queue before it closes the backend.
 """
 
 from __future__ import annotations
 
+import contextlib
 import datetime
+import logging
 import queue
 import threading
+import time
 from dataclasses import dataclass, field
 from typing import Any, Callable, Optional
 
@@ -161,6 +174,19 @@ def _entry_from_decision(
     )
 
 
+KIND_ACCESS = "access"
+KIND_DECISION = "decision"
+# how long close() lets the writer empty the queue: well inside the 60 s a
+# supervisor gives a SIGTERM, long against 4,096 entries at a millisecond each
+DRAIN_TIMEOUT_S = 30.0
+# an entry is some 1 KB (one check) to 30 KB (a page of 50 resources)
+_BYTES_BUCKETS = [256, 512, 1024, 2048, 4096, 8192, 16384, 32768, 65536, 131072, 524288]
+# serialise + write + flush: 40 us (one check) to a millisecond (a page), more where the disk stalls
+_WRITE_BUCKETS = [0.00001, 0.000025, 0.00005, 0.0001, 0.00025, 0.0005, 0.001, 0.0025, 0.005, 0.01, 0.05, 0.25, 1.0]
+
+_log = logging.getLogger("cerbos_tpu.audit")
+
+
 class AuditLog:
     """Async audit writer over a backend."""
 
@@ -170,8 +196,10 @@ class AuditLog:
         decision_filter: Optional[DecisionFilter] = None,
         access_logs_enabled: bool = True,
         decision_logs_enabled: bool = True,
+        backend_name: str = "",
     ):
         self.backend = backend
+        self.backend_name = backend_name or type(backend).__name__
         self.decision_filter = decision_filter or DecisionFilter()
         self.access_logs_enabled = access_logs_enabled
         self.decision_logs_enabled = decision_logs_enabled
@@ -179,6 +207,7 @@ class AuditLog:
         # entries are dropped at the door — the decision still happens,
         # only its record is lost, and each loss is counted as evidence
         self._shed = False
+        self._closed = False
         self._queue: "queue.Queue[Optional[dict]]" = queue.Queue(maxsize=4096)
         self._init_metrics()
         self._worker = threading.Thread(target=self._drain, daemon=True, name="audit-writer")
@@ -196,47 +225,120 @@ class AuditLog:
             "cerbos_tpu_audit_dropped_total",
             "audit entries dropped because the async queue was full (the hot path never blocks on audit)",
         )
+        self.m_entries = reg.counter_vec(
+            "cerbos_tpu_audit_entries_total",
+            "audit entries by kind (decision, access) and outcome: queued for the writer, written by the "
+            "backend, filtered by decisionLogFilters; queued = written + lost{reason=failed} + queue depth",
+            label=("kind", "outcome"),
+        )
+        self.m_lost = reg.counter_vec(
+            "cerbos_tpu_audit_lost_total",
+            "audit entries lost, by kind and reason: dropped (queue full, or queued after close), shed "
+            "(brownout stage shed_audit), failed (the backend's write raised); 0 growth = nothing lost",
+            label=("kind", "reason"),
+        )
+        self.m_write = reg.histogram_vec(
+            "cerbos_tpu_audit_write_seconds",
+            "writer thread, per entry by backend and kind: serialise, write, flush (rotation included "
+            "where one falls due); an access entry is some 1/40 of a page's decision entry",
+            label=("backend", "kind"),
+            buckets=_WRITE_BUCKETS,
+        )
+        self.m_bytes = reg.histogram_vec(
+            "cerbos_tpu_audit_entry_bytes",
+            "serialised size of a written entry by kind, where the backend reports it (file)",
+            label="kind",
+            buckets=_BYTES_BUCKETS,
+        )
+        self.m_writer = reg.counter_vec(
+            "cerbos_tpu_audit_writer_seconds_total",
+            "wall seconds of the writer thread by state (write: from taking an entry to the backend's "
+            "return; idle: waiting for one); over both states they grow by the seconds that pass",
+            label="state",
+        )
+        for kind in (KIND_DECISION, KIND_ACCESS):
+            for outcome in ("queued", "written", "filtered"):
+                self.m_entries.inc((kind, outcome), 0.0)
+            for reason in ("dropped", "shed", "failed"):
+                self.m_lost.inc((kind, reason), 0.0)
 
     def _drain(self) -> None:
+        from ..observability import region
+
+        t_from = time.monotonic()
         while True:
-            entry = self._queue.get()
+            try:
+                # the timeout only books the idle seconds so far: a scrape
+                # must see the two states add up to the seconds that passed
+                entry = self._queue.get(timeout=1.0)
+            except queue.Empty:
+                continue
+            finally:
+                t_from = self._book("idle", t_from)
             self.m_depth.set(float(self._queue.qsize()))
             if entry is None:
                 return
+            kind = entry["kind"]
             try:
-                if self.backend is not None:
-                    self.backend.write(entry)
+                with region("audit.write", kind=kind):
+                    size = self.backend.write(entry)
             except Exception:  # noqa: BLE001
-                import logging
+                self.m_lost.inc((kind, "failed"))
+                _log.exception("audit write failed")
+            else:
+                self.m_entries.inc((kind, "written"))
+                if isinstance(size, int):
+                    self.m_bytes.observe(kind, float(size))
+            t_done = self._book("write", t_from)
+            self.m_write.observe((self.backend_name, kind), t_done - t_from)
+            t_from = t_done
 
-                logging.getLogger("cerbos_tpu.audit").exception("audit write failed")
+    def _book(self, state: str, t_from: float) -> float:
+        now = time.monotonic()
+        self.m_writer.inc(state, now - t_from)
+        return now
 
     def set_shed(self, flag: bool) -> None:
         """Brownout applier (stage ``shed_audit``). Reversible: clearing the
         flag resumes writes with the queue and worker untouched."""
         self._shed = bool(flag)
 
-    def _shedding(self) -> bool:
+    def _shedding(self, kind: str) -> bool:
         if not self._shed:
             return False
         from ..engine import brownout
 
         brownout.controller().note_shed("audit")
+        self.m_lost.inc((kind, "shed"))
         return True
 
-    def _submit(self, entry: dict) -> None:
-        try:
-            self._queue.put_nowait(entry)
-            self.m_depth.set(float(self._queue.qsize()))
-        except queue.Full:
-            self.m_dropped.inc()  # drop rather than block the request path
+    def _submit(self, entry: dict) -> str:
+        """Queue the entry; what became of it: ``queued`` or ``dropped``."""
+        kind = entry["kind"]
+        if not self._closed:
+            try:
+                self._queue.put_nowait(entry)
+            except queue.Full:
+                self.m_dropped.inc()  # drop rather than block the request path
+            else:
+                self.m_entries.inc((kind, "queued"))
+                self.m_depth.set(float(self._queue.qsize()))
+                return "queued"
+        self.m_lost.inc((kind, "dropped"))
+        return "dropped"
 
-    def write_access(self, call_id: str, method: str, peer: str = "") -> None:
+    def write_access(self, call_id: str, method: str, peer: str = "", error: str = "") -> Optional[str]:
+        """One entry per call that reached the service (server/service.py:
+        ``_access_logged``), under the call id of its decision entry;
+        ``error`` names what a call that was not answered raised."""
         if not self.access_logs_enabled or self.backend is None:
-            return
-        if self._shedding():
-            return
-        self._submit({"callId": call_id, "timestamp": _now_iso(), "kind": "access", "method": method, "peer": peer})
+            return None
+        if self._shedding(KIND_ACCESS):
+            return "shed"
+        entry = {"callId": call_id, "timestamp": _now_iso(), "kind": KIND_ACCESS, "method": method, "peer": peer}
+        if error:
+            entry["error"] = error
+        return self._submit(entry)
 
     def write_decision(
         self,
@@ -246,14 +348,17 @@ class AuditLog:
         trace_id: str = "",
         shard: Optional[int] = None,
         epoch: Optional[int] = None,
-    ) -> None:
+    ) -> Optional[str]:
+        """What became of the entry (``queued``, ``filtered``, ``shed``,
+        ``dropped``), or None where decision logs are off."""
         if not self.decision_logs_enabled or self.backend is None:
-            return
-        if self._shedding():
-            return
+            return None
+        if self._shedding(KIND_DECISION):
+            return "shed"
         if not self.decision_filter.keep(inputs, outputs):
-            return
-        self._submit(
+            self.m_entries.inc((KIND_DECISION, "filtered"))
+            return "filtered"
+        return self._submit(
             _entry_from_decision(
                 call_id, inputs, outputs, trace_id=trace_id, shard=shard, epoch=epoch
             )
@@ -266,14 +371,14 @@ class AuditLog:
         auditTrail.effectivePolicies (engine.go:186-200)."""
         if not self.decision_logs_enabled or self.backend is None:
             return
-        if self._shedding():
+        if self._shedding(KIND_DECISION):
             return
         principal = getattr(plan_input, "principal", None)
         cond = getattr(plan_output, "condition", None)
         entry = {
             "callId": call_id,
             "timestamp": _now_iso(),
-            "kind": "decision",
+            "kind": KIND_DECISION,
             "planResources": {
                 "input": {
                     "requestId": getattr(plan_input, "request_id", ""),
@@ -310,8 +415,27 @@ class AuditLog:
         self._submit(entry)
 
     def close(self) -> None:
-        self._queue.put(None)
-        self._worker.join(timeout=5)
+        """Write what is queued, then close the backend. The listeners have
+        stopped by now (cli: ``server.stop()`` comes first); an entry that
+        still arrives is counted ``dropped``. A writer that cannot empty the
+        queue in ``DRAIN_TIMEOUT_S`` (a wedged backend) is left behind and
+        what it had not taken is counted ``dropped`` too, by kind."""
+        if self._closed:
+            return
+        self._closed = True
+        with contextlib.suppress(queue.Full):
+            self._queue.put(None, timeout=DRAIN_TIMEOUT_S)
+        self._worker.join(timeout=DRAIN_TIMEOUT_S)
+        if self._worker.is_alive():
+            left = 0
+            with contextlib.suppress(queue.Empty):
+                while True:
+                    entry = self._queue.get_nowait()
+                    if entry is not None:
+                        left += 1
+                        self.m_lost.inc((entry["kind"], "dropped"))
+            _log.error("audit writer did not drain in %.0f s: %d entries dropped", DRAIN_TIMEOUT_S, left)
+        self.m_depth.set(float(self._queue.qsize()))
         if self.backend is not None and hasattr(self.backend, "close"):
             self.backend.close()
 
@@ -351,4 +475,5 @@ def new_audit_log(conf: dict) -> Optional[AuditLog]:
         ),
         access_logs_enabled=bool(conf.get("accessLogsEnabled", True)),
         decision_logs_enabled=bool(conf.get("decisionLogsEnabled", True)),
+        backend_name=backend_name,
     )

@@ -47,9 +47,10 @@ number. The handlers stamp the same record at the seams inside them
 ``cerbos_tpu_request_front_seconds{part}`` (``validate``, ``auxdata``,
 ``convert``, ``admit``, ``span``, ``enqueue``: contiguous from the end of the
 decode to the mark that ends ``admission``, or ``ipc_encode`` in a front end)
-and ``cerbos_tpu_request_back_seconds{part}`` (``wake``, ``encode``, and
-``serialize``, which for gRPC lies after ``reply_encode`` and is observed by
-the response serializer). ``cerbos_tpu_request_handler_seconds`` is the
+and ``cerbos_tpu_request_back_seconds{part}`` (``wake``, ``audit``: the
+decision entry built and queued on the request's thread, 0 with audit off;
+``encode``, and ``serialize``, which for gRPC lies after ``reply_encode`` and
+is observed by the response serializer). ``cerbos_tpu_request_handler_seconds`` is the
 handler's whole extent, raw bytes in to bytes out. On with the waterfall,
 off with it; no option of their own.
 
@@ -99,8 +100,8 @@ STAGES = (
     STAGE_ORACLE,
 )
 
-# parts of the front (tile ``admission``) and of the back (``wake`` + ``encode``
-# tile ``reply_encode`` for gRPC; for HTTP ``serialize`` lies inside it too)
+# parts of the front (tile ``admission``) and of the back (``wake`` + ``audit``
+# + ``encode`` tile ``reply_encode`` for gRPC; for HTTP ``serialize`` lies inside it too)
 FRONT_VALIDATE = "validate"  # wire validation of the decoded request
 FRONT_AUXDATA = "auxdata"    # the token's extraction and verification (a field test where there is none)
 FRONT_CONVERT = "convert"    # message -> CheckInputs
@@ -111,9 +112,10 @@ FRONT_PARTS = (
     FRONT_VALIDATE, FRONT_AUXDATA, FRONT_CONVERT, FRONT_ADMIT, FRONT_SPAN, FRONT_ENQUEUE,
 )
 BACK_WAKE = "wake"            # the last mark of another thread (settle) -> the handler's thread running again
-BACK_ENCODE = "encode"        # outputs -> response message; span end, audit hand-off
+BACK_AUDIT = "audit"          # the decision entry built and queued for the audit writer (0 with audit off)
+BACK_ENCODE = "encode"        # span end, the access entry queued, outputs -> response message
 BACK_SERIALIZE = "serialize"  # response message -> bytes
-BACK_PARTS = (BACK_WAKE, BACK_ENCODE, BACK_SERIALIZE)
+BACK_PARTS = (BACK_WAKE, BACK_AUDIT, BACK_ENCODE, BACK_SERIALIZE)
 
 OUTCOME_MET = "deadline_met"
 OUTCOME_EXPIRED = "expired"
@@ -312,6 +314,7 @@ class BudgetTracker:
         m_back = reg.histogram_vec(
             "cerbos_tpu_request_back_seconds",
             "Per-request parts of the back half: wake (settle to the handler's thread running), "
+            "audit (the decision entry built and queued; 0 with audit off), "
             "encode (outputs to response message), serialize (message to bytes)",
             label="part",
             buckets=_PART_BUCKETS,

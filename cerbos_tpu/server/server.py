@@ -307,6 +307,12 @@ def aio_generic_handler(service_name: str, rpcs: dict, inline: bool = True):
     return grpc.method_handlers_generic_handler(service_name, wrapped)
 
 
+# the method an access entry names (audit.accessLogsEnabled; the service
+# queues the entry, service.py: _access_logged): upstream's HTTP gateway logs
+# the gRPC method it forwards to, so both listeners name the same
+_SVC = "/cerbos.svc.v1.CerbosService/"
+
+
 def _grpc_rpcs(svc: CerbosService):
     from ..api.cerbos.request.v1 import request_pb2
     from ..api.cerbos.response.v1 import response_pb2
@@ -370,7 +376,8 @@ def _grpc_rpcs(svc: CerbosService):
                 dict(meta_fn() or ()).get("traceparent") if meta_fn is not None else None
             )
             outputs, call_id = svc.check_resources(
-                inputs, deadline=deadline, trace_ctx=trace_ctx, wf=wf, pclass=pclass
+                inputs, deadline=deadline, trace_ctx=trace_ctx, wf=wf, pclass=pclass,
+                access=svc.access_of(_SVC + "CheckResources", lambda: ctx.peer()),
             )
             if trace_ctx is not None:
                 with contextlib.suppress(Exception):  # shim contexts may lack it
@@ -434,7 +441,7 @@ def _grpc_rpcs(svc: CerbosService):
                 },
                 "includeMeta": req.include_meta,
             }
-            resp_json, call_id = _plan_from_json(svc, body, aux)
+            resp_json, call_id = _plan_from_json(svc, body, aux, svc.access_of(_SVC + "PlanResources", lambda: ctx.peer()))
             budget_tracker().count(OUTCOME_MET, api="plan")
             return _plan_json_to_proto(resp_json, response_pb2)
         except OverloadRefused as e:
@@ -481,7 +488,7 @@ def _grpc_rpcs(svc: CerbosService):
                     actions=list(req.actions),
                     aux_data=aux,
                 ))
-            outputs, call_id = svc.check_resources(inputs)
+            outputs, call_id = svc.check_resources(inputs, access=svc.access_of(_SVC + "CheckResourceSet", lambda: ctx.peer()))
             resp = response_pb2.CheckResourceSetResponse(request_id=req.request_id, cerbos_call_id=call_id)
             from ..api.cerbos.effect.v1 import effect_pb2
 
@@ -526,7 +533,7 @@ def _grpc_rpcs(svc: CerbosService):
                 )
                 for entry in req.resources
             ]
-            outputs, call_id = svc.check_resources(inputs)
+            outputs, call_id = svc.check_resources(inputs, access=svc.access_of(_SVC + "CheckResourceBatch", lambda: ctx.peer()))
             resp = response_pb2.CheckResourceBatchResponse(request_id=req.request_id, cerbos_call_id=call_id)
             from ..api.cerbos.effect.v1 import effect_pb2
 
@@ -613,7 +620,9 @@ def _health_handler():
     return grpc.method_handlers_generic_handler("grpc.health.v1.Health", _health_rpcs())
 
 
-def _plan_from_json(svc: CerbosService, body: dict, aux: Optional[T.AuxData]) -> tuple[dict, str]:
+def _plan_from_json(
+    svc: CerbosService, body: dict, aux: Optional[T.AuxData], access: Optional[tuple[str, str]] = None
+) -> tuple[dict, str]:
     from ..plan.types import PlanInput
 
     pj = body.get("principal") or {}
@@ -639,7 +648,7 @@ def _plan_from_json(svc: CerbosService, body: dict, aux: Optional[T.AuxData]) ->
         aux_data=aux,
         include_meta=bool(body.get("includeMeta", False)),
     )
-    output, call_id = svc.plan_resources(plan_input)
+    output, call_id = svc.plan_resources(plan_input, access=access)
     j = output.to_json(call_id)
     if one_action:
         j.pop("actions", None)
@@ -1323,23 +1332,24 @@ class Server:
             if wf is not None:
                 wf.part(FRONT_ADMIT)
             trace_ctx = parse_traceparent(request.headers.get("traceparent"))
+            access = self.svc.access_of(_SVC + "CheckResources", lambda: request.remote)
             if getattr(self.svc.engine, "supports_async", False):
                 # front-end mode: the evaluator settles on this event loop
                 # (RemoteBatcherClient futures) — awaiting directly skips the
                 # per-request thread-pool hop entirely
                 outputs, call_id = await self.svc.check_resources_async(
-                    inputs, trace_ctx=trace_ctx, wf=wf, pclass=pclass
+                    inputs, trace_ctx=trace_ctx, wf=wf, pclass=pclass, access=access
                 )
             elif self.config.direct_dispatch:
                 outputs, call_id = self.svc.check_resources(
-                    inputs, trace_ctx=trace_ctx, wf=wf, pclass=pclass
+                    inputs, trace_ctx=trace_ctx, wf=wf, pclass=pclass, access=access
                 )
             else:
                 loop = asyncio.get_running_loop()
                 outputs, call_id = await loop.run_in_executor(
                     None,
                     lambda: self.svc.check_resources(
-                        inputs, trace_ctx=trace_ctx, wf=wf, pclass=pclass
+                        inputs, trace_ctx=trace_ctx, wf=wf, pclass=pclass, access=access
                     ),
                 )
             payload = convert.outputs_to_json(
@@ -1424,8 +1434,9 @@ class Server:
             if aux_j.get("token"):
                 aux = self.svc._extract_aux_data(aux_j["token"], aux_j.get("keySetId", ""))
             inputs, request_id, include_meta = convert.json_to_check_inputs(inner, aux)
+            access = self.svc.access_of(_SVC + "CheckResourceSet", lambda: request.remote)
             outputs, call_id = await asyncio.get_running_loop().run_in_executor(
-                None, self.svc.check_resources, inputs
+                None, lambda: self.svc.check_resources(inputs, access=access)
             )
             resource_instances = {}
             for entry, out in zip(inner["resources"], outputs):
@@ -1471,8 +1482,9 @@ class Server:
             if aux_j.get("token"):
                 aux = self.svc._extract_aux_data(aux_j["token"], aux_j.get("keySetId", ""))
             inputs, request_id, _ = convert.json_to_check_inputs(body, aux)
+            access = self.svc.access_of(_SVC + "CheckResourceBatch", lambda: request.remote)
             outputs, call_id = await asyncio.get_running_loop().run_in_executor(
-                None, self.svc.check_resources, inputs
+                None, lambda: self.svc.check_resources(inputs, access=access)
             )
             return web.json_response(
                 {
@@ -1519,8 +1531,9 @@ class Server:
             aux_j = (body.get("auxData") or {}).get("jwt") or {}
             if aux_j.get("token"):
                 aux = self.svc._extract_aux_data(aux_j["token"], aux_j.get("keySetId", ""))
+            access = self.svc.access_of(_SVC + "PlanResources", lambda: request.remote)
             resp, _call_id = await asyncio.get_running_loop().run_in_executor(
-                None, _plan_from_json, self.svc, body, aux
+                None, _plan_from_json, self.svc, body, aux, access
             )
             budget_tracker().count(OUTCOME_MET, api="plan")
             return web.json_response(resp)

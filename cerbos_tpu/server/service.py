@@ -6,6 +6,7 @@ PlanResources, ServerInfo; request limits cerbos_svc.go:346-362).
 
 from __future__ import annotations
 
+import contextlib
 import time
 import uuid
 from dataclasses import dataclass, field
@@ -13,7 +14,7 @@ from typing import Any, Optional
 
 from .. import __version__
 from ..engine import types as T
-from ..engine.budget import BACK_WAKE, FRONT_SPAN
+from ..engine.budget import BACK_AUDIT, BACK_WAKE, FRONT_SPAN
 from ..engine.engine import Engine
 from ..observability import SpanContext, start_span
 
@@ -99,42 +100,80 @@ class CerbosService:
         trace_ctx: Optional[SpanContext] = None,
         wf: Optional[Any] = None,
         pclass: Optional[str] = None,
+        access: Optional[tuple[str, str]] = None,
     ) -> tuple[list[T.CheckOutput], str]:
-        self._validate_check(inputs)
         call_id = uuid.uuid4().hex
-        t0 = time.perf_counter()
-        # trace_ctx is the caller's W3C traceparent (gRPC metadata / HTTP
-        # header); with parent=None this still roots a fresh local trace
-        with start_span(
-            "request.CheckResources", parent=trace_ctx, resources=len(inputs)
-        ) as span:
-            span.set_attribute("call_id", call_id)
-            # clear any shard/epoch affinity left by a previous request on
-            # this thread; the evaluator that resolves this request
-            # re-stamps both
-            T.set_current_shard(None)
-            T.set_current_epoch(None)
-            if wf is not None:
-                if not wf.trace_id:
-                    wf.trace_id = span.context.trace_id
-                wf.part(FRONT_SPAN)
-            outputs = self.engine.check(
-                inputs, params=params, deadline=deadline, wf=wf, pclass=pclass
-            )
-            if wf is not None:
-                wf.part(BACK_WAKE)
-            trace_id = span.context.trace_id
-        self.metrics.record_check((time.perf_counter() - t0) * 1000, len(inputs))
+        with self._access_logged(call_id, access):
+            self._validate_check(inputs)
+            t0 = time.perf_counter()
+            # trace_ctx is the caller's W3C traceparent (gRPC metadata / HTTP
+            # header); with parent=None this still roots a fresh local trace
+            with start_span(
+                "request.CheckResources", parent=trace_ctx, resources=len(inputs)
+            ) as span:
+                span.set_attribute("call_id", call_id)
+                # clear any shard/epoch affinity left by a previous request on
+                # this thread; the evaluator that resolves this request
+                # re-stamps both
+                T.set_current_shard(None)
+                T.set_current_epoch(None)
+                if wf is not None:
+                    if not wf.trace_id:
+                        wf.trace_id = span.context.trace_id
+                    wf.part(FRONT_SPAN)
+                outputs = self.engine.check(
+                    inputs, params=params, deadline=deadline, wf=wf, pclass=pclass
+                )
+                if wf is not None:
+                    wf.part(BACK_WAKE)
+                self._audit_decision(span, call_id, inputs, outputs)
+                if wf is not None:
+                    wf.part(BACK_AUDIT)
+            self.metrics.record_check((time.perf_counter() - t0) * 1000, len(inputs))
+        return outputs, call_id
+
+    def _audit_decision(
+        self, span: Any, call_id: str, inputs: list[T.CheckInput], outputs: list[T.CheckOutput]
+    ) -> None:
+        """The audit hand-off: the decision entry built and queued on this
+        thread (audit/log.py), inside the request's span and between the
+        ``wake`` and ``audit`` marks of its waterfall."""
         if self.audit_log is not None:
             self.audit_log.write_decision(
                 call_id,
                 inputs,
                 outputs,
-                trace_id=trace_id,
+                trace_id=span.context.trace_id,
                 shard=T.current_shard(),
                 epoch=T.current_epoch(),
             )
-        return outputs, call_id
+
+    def access_of(self, method: str, peer: Any) -> Optional[tuple[str, str]]:
+        """What a call's access entry names, for the listener to pass as
+        ``access=``: the RPC and the caller's address (``peer`` is called for
+        it), or None where no access entry is written, and nothing is read.
+        Read BEFORE the engine answers: gRPC's ``ctx.peer()`` leaves the
+        interpreter lock, and right after the decision entry is queued that
+        hands it to the audit writer for a whole serialisation."""
+        log = self.audit_log
+        if log is None or not log.access_logs_enabled:
+            return None
+        return method, peer() or ""
+
+    @contextlib.contextmanager
+    def _access_logged(self, call_id: str, access: Optional[tuple[str, str]]):
+        """One access entry for the call in the block, under the call id of
+        its decision entry, whether it is answered or raises (the entry then
+        names the error). A call refused before it reaches the service (wire
+        validation, admission, the ``shed_plan`` rung) writes none."""
+        try:
+            yield
+        except BaseException as e:
+            if access is not None:
+                self.audit_log.write_access(call_id, *access, error=type(e).__name__)
+            raise
+        if access is not None:
+            self.audit_log.write_access(call_id, *access)
 
     def _validate_check(self, inputs: list[T.CheckInput]) -> None:
         if len(inputs) > self.limits.max_resources_per_request:
@@ -157,45 +196,46 @@ class CerbosService:
         trace_ctx: Optional[SpanContext] = None,
         wf: Optional[Any] = None,
         pclass: Optional[str] = None,
+        access: Optional[tuple[str, str]] = None,
     ) -> tuple[list[T.CheckOutput], str]:
         """``check_resources`` for evaluators that settle on the event loop
         (front-end mode): the handler coroutine awaits the batcher ticket
         directly — no thread-pool hop per request."""
-        self._validate_check(inputs)
         call_id = uuid.uuid4().hex
-        t0 = time.perf_counter()
-        with start_span(
-            "request.CheckResources", parent=trace_ctx, resources=len(inputs)
-        ) as span:
-            span.set_attribute("call_id", call_id)
-            T.set_current_shard(None)
-            T.set_current_epoch(None)
-            if wf is not None:
-                if not wf.trace_id:
-                    wf.trace_id = span.context.trace_id
-                wf.part(FRONT_SPAN)
-            outputs = await self.engine.check_await(
-                inputs, params=params, deadline=deadline, wf=wf, pclass=pclass
-            )
-            if wf is not None:
-                wf.part(BACK_WAKE)
-            trace_id = span.context.trace_id
-        self.metrics.record_check((time.perf_counter() - t0) * 1000, len(inputs))
-        if self.audit_log is not None:
-            self.audit_log.write_decision(
-                call_id,
-                inputs,
-                outputs,
-                trace_id=trace_id,
-                shard=T.current_shard(),
-                epoch=T.current_epoch(),
-            )
+        with self._access_logged(call_id, access):
+            self._validate_check(inputs)
+            t0 = time.perf_counter()
+            with start_span(
+                "request.CheckResources", parent=trace_ctx, resources=len(inputs)
+            ) as span:
+                span.set_attribute("call_id", call_id)
+                T.set_current_shard(None)
+                T.set_current_epoch(None)
+                if wf is not None:
+                    if not wf.trace_id:
+                        wf.trace_id = span.context.trace_id
+                    wf.part(FRONT_SPAN)
+                outputs = await self.engine.check_await(
+                    inputs, params=params, deadline=deadline, wf=wf, pclass=pclass
+                )
+                if wf is not None:
+                    wf.part(BACK_WAKE)
+                self._audit_decision(span, call_id, inputs, outputs)
+                if wf is not None:
+                    wf.part(BACK_AUDIT)
+            self.metrics.record_check((time.perf_counter() - t0) * 1000, len(inputs))
         return outputs, call_id
 
-    def plan_resources(self, input: Any, params: Optional[T.EvalParams] = None) -> tuple[Any, str]:
+    def plan_resources(
+        self, input: Any, params: Optional[T.EvalParams] = None, access: Optional[tuple[str, str]] = None
+    ) -> tuple[Any, str]:
+        call_id = uuid.uuid4().hex
+        with self._access_logged(call_id, access):
+            return self._plan(call_id, input, params), call_id
+
+    def _plan(self, call_id: str, input: Any, params: Optional[T.EvalParams]) -> Any:
         if self.planner is None and self.plan_batcher is None:
             raise NotImplementedError("PlanResources is not configured")
-        call_id = uuid.uuid4().hex
         pb = self.plan_batcher
         if pb is not None and getattr(pb, "plan_planner", None) is not None:
             # plan-lane path: OverloadRefused propagates (the handlers turn
@@ -216,7 +256,7 @@ class CerbosService:
         self.metrics.plan_count += 1
         if self.audit_log is not None:
             self.audit_log.write_plan(call_id, input, output)
-        return output, call_id
+        return output
 
     def server_info(self) -> dict[str, str]:
         return {"version": f"cerbos-tpu {__version__}", "commit": "", "buildDate": ""}

@@ -51,6 +51,7 @@ from __future__ import annotations
 
 import argparse
 import base64
+import glob
 import hashlib
 import hmac
 import json
@@ -358,7 +359,7 @@ def write_policies(policy_dir: str, mods: int) -> int:
 
 
 class ServerProc:
-    def __init__(self, name: str, policy_dir: str, extra_args: list[str], tpu_conf: dict):
+    def __init__(self, name: str, policy_dir: str, extra_args: list[str], tpu_conf: dict, audit_path: str = ""):
         import yaml
 
         self.name = name
@@ -386,6 +387,13 @@ class ServerProc:
                 }
             },
         }
+        if audit_path:
+            # upstream's audit block, file backend, rotated often and nothing deleted, so
+            # that every entry of the run is still on disk when check_audit reads it
+            cfg["audit"] = {
+                "enabled": True, "backend": "file", "accessLogsEnabled": True, "decisionLogsEnabled": True,
+                "file": {"path": audit_path, "logRotation": {"maxFileSizeMB": AUDIT_ROTATE_MB, "maxFileCount": 1000}},
+            }
         cfg_path = os.path.join(os.path.dirname(policy_dir), f"{name}.cerbos.yaml")
         with open(cfg_path, "w") as f:
             yaml.safe_dump(cfg, f)
@@ -760,9 +768,70 @@ def run_pass(srv, singles, batches, workers, timeout: float, label: str) -> dict
 # -- one topology -------------------------------------------------------------
 
 
+AUDIT_ROTATE_MB = 4
+
+
+def check_audit(path: str, final: dict[tuple, float]) -> dict:
+    """``--audit``: what the server left in its audit files once it has exited,
+    against its own counters at the last scrape (no check is sent after it):
+    every line an entry, one entry on disk for each one queued, no call id
+    twice, one access entry for each decision entry (and one, naming the
+    error, for a call the service did not answer), none dropped and no write
+    failed. What the brownout ladder shed (a cold machine compiles its layouts
+    inside the first pass, and the compile storm engages ``shed_audit``) is
+    the ladder's to decide: reported, not judged. In a pool
+    the ``worker`` label says which process queued how many: the front ends
+    build and write the entries, all into the one path; the device owner opens
+    it and writes nothing."""
+    stem, ext = os.path.splitext(path)
+    files = sorted(glob.glob(f"{glob.escape(stem)}-*{glob.escape(ext)}")) + [path]
+    calls: dict[str, list[str]] = {"decision": [], "access": []}
+    broken = 0
+    unanswered: set[str] = set()
+    for p in files:
+        with open(p) as f:
+            for line in f:
+                try:
+                    e = json.loads(line)
+                    calls[e["kind"]].append(e["callId"])
+                    if "error" in e:
+                        unanswered.add(e["callId"])
+                except (ValueError, KeyError):
+                    broken += 1
+    queued = {k: int(msum(final, "cerbos_tpu_audit_entries_total", kind=k, outcome="queued")) for k in calls}
+    out = {
+        "files": len(files), "bytes": sum(os.path.getsize(p) for p in files), "largest_file": max(os.path.getsize(p) for p in files),
+        "entries": {k: len(v) for k, v in calls.items()}, "unanswered": len(unanswered), "queued": queued,
+        "queued_by_worker": by_label(
+            {k: v for k, v in final.items() if ("outcome", "queued") in k[1]}, "cerbos_tpu_audit_entries_total", "worker"
+        ),
+        "lost": by_label(final, "cerbos_tpu_audit_lost_total", "reason"),
+        "rotations": int(msum(final, "cerbos_tpu_audit_rotations_total")),
+    }
+    log(f"  audit: {out}")
+    failures = []
+    if broken:
+        failures.append(f"{broken} lines of the audit files are not entries")
+    if set(out["lost"]) - {"shed"}:
+        failures.append(f"cerbos_tpu_audit_lost_total reads {out['lost']}")
+    for kind, ids in calls.items():
+        if len(ids) != len(set(ids)):
+            failures.append(f"{len(ids) - len(set(ids))} {kind} entries share a call id")
+        if len(ids) != queued[kind]:
+            failures.append(f"{len(ids)} {kind} entries on disk, {queued[kind]} queued at the last scrape")
+    if not out["lost"] and set(calls["decision"]) != set(calls["access"]) - unanswered:
+        failures.append("the call ids of the access entries are not those of the decision entries")
+    if out["largest_file"] > AUDIT_ROTATE_MB << 20:
+        failures.append(f"a file of {out['largest_file']} B, over the {AUDIT_ROTATE_MB} MB it is rotated at")
+    if failures:
+        raise SmokeFailure("audit: " + "; ".join(failures))
+    return out
+
+
 def run_topology(name, extra_args, tpu_conf, workers, policy_dir, singles, batches, args) -> dict:
     log(f"== topology {name}: cerbos_tpu.cli server {' '.join(extra_args)} {tpu_conf or ''}")
-    srv = ServerProc(name, policy_dir, extra_args, tpu_conf)
+    audit_path = os.path.join(os.path.dirname(policy_dir), "audit", f"{name}.log") if args.audit else ""
+    srv = ServerProc(name, policy_dir, extra_args, tpu_conf, audit_path)
     try:
         srv.wait_serving(timeout=300)
         status, _ = srv.status()
@@ -859,6 +928,7 @@ def run_topology(name, extra_args, tpu_conf, workers, policy_dir, singles, batch
         if code != 0:
             raise SmokeFailure(f"server exit code on SIGTERM: {code} (None = had to be killed)")
         log(f"  server exited 0 on SIGTERM")
+        audit = check_audit(audit_path, final) if audit_path else None
         return {
             "device": {"platform": dev["platform"], "kind": dev["device_kind"], "count": dev["count"]},
             "xla_cache": status["dir"],
@@ -869,6 +939,7 @@ def run_topology(name, extra_args, tpu_conf, workers, policy_dir, singles, batch
             "split": checked["split"],
             "burst": burst,
             "capture": capture,
+            **({"audit": audit} if audit else {}),
         }
     except BaseException:
         if srv.proc.poll() is None:
@@ -960,6 +1031,11 @@ def main() -> int:
     ap.add_argument(
         "--workers-check", action="store_true",
         help="builder-run: also require that --workers 2 on the device path fails at boot",
+    )
+    ap.add_argument(
+        "--audit", action="store_true",
+        help="builder-run: boot both topologies with the audit log on (file backend, access and decision "
+        "logs, rotated) and hold what they leave on disk to their own counters",
     )
     args = ap.parse_args()
 

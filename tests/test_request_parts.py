@@ -162,9 +162,10 @@ def test_parts_tile_admission_and_reply_encode(tmp_path, tracker, finished, surf
     assert names[:6] == list(FRONT_PARTS)
     assert sum(parts[p] for p in FRONT_PARTS) == pytest.approx(stages[front_stage], abs=2e-6)
     assert all(d >= 0.0 for _, d in wf.parts)
-    # the back: wake and encode tile reply_encode for gRPC (the bytes are made
-    # after it); for HTTP the JSON dump is inside it as serialize
-    in_record = ["wake", "encode"] if surface == "grpc" else list(BACK_PARTS)
+    # the back: wake, audit (0 here: no audit log) and encode tile reply_encode
+    # for gRPC (the bytes are made after it); for HTTP the JSON dump is inside
+    # it as serialize
+    in_record = ["wake", "audit", "encode"] if surface == "grpc" else list(BACK_PARTS)
     assert names[6:] == in_record
     assert sum(parts[p] for p in in_record) == pytest.approx(stages[STAGE_REPLY_ENCODE], abs=2e-6)
     # every part observed once, the serializer's and the handler's included
@@ -207,7 +208,7 @@ def test_slow_ring_entry_carries_the_parts(tmp_path, tracker, finished):
         for close in closers:
             close()
     (entry,) = tracker.slow_dump()["requests"]
-    assert [p for p, _ in entry["parts"]] == list(FRONT_PARTS) + ["wake", "encode"]
+    assert [p for p, _ in entry["parts"]] == list(FRONT_PARTS) + ["wake", "audit", "encode"]
 
 
 def clock_cost_us(n: int = 20000) -> dict:
