@@ -18,7 +18,13 @@ cache in :mod:`jitcache` and answers, per process:
 - whether the layout keyspace is CHURNING: the recompile-storm detector
   fires when >= N distinct layouts compile within W seconds, meaning the
   shape-bucket ladder or variant budget no longer amortizes and the replica
-  is spending its time in XLA instead of serving.
+  is spending its time in XLA instead of serving;
+- what the layout preloader (``evaluator._LayoutPreloader``) brought in
+  ahead of traffic from the layout manifest
+  (``cerbos_tpu_xla_preloads_total{outcome}``,
+  ``cerbos_tpu_xla_preload_seconds``, flight event ``xla_preload_done``).
+  Its loads are compiles like any other to the three families above, and
+  are kept from the storm detector: a deliberate walk is not churn.
 
 Everything is process-global (like the metrics registry it feeds) so the
 serving batcher, a direct ``check()`` and bench all account into one place.
@@ -26,6 +32,7 @@ serving batcher, a direct ``check()`` and bench all account into one place.
 
 from __future__ import annotations
 
+import contextlib
 import logging
 import threading
 import time
@@ -44,6 +51,11 @@ _COMPILE_BUCKETS = [0.01, 0.05, 0.1, 0.25, 0.5, 1.0, 2.5, 5.0, 10.0, 20.0, 40.0,
 
 STORM_THRESHOLD = 8
 STORM_WINDOW_S = 120.0
+
+# what became of one manifest entry the preloader walked: brought in from the
+# persistent cache, compiled because the cache had no entry for it, already
+# in the jit cache (a flight met it first), or not buildable
+PRELOAD_OUTCOMES = ("loaded", "fresh", "held", "failed")
 
 # the dimensions of a jit key, in the order a compile is blamed on them
 NOVEL_DIMS = ("shape", "depth", "variant", "columns")
@@ -198,6 +210,19 @@ class CompileStats:
             "cerbos_tpu_device_memory_peak_bytes_in_use",
             "Peak device memory in use (device.memory_stats, 0 when the backend reports none)",
         )
+        self.m_preloads = reg.counter_vec(
+            "cerbos_tpu_xla_preloads_total",
+            "Layout manifest entries the preloader walked, by outcome: loaded (from the persistent cache), "
+            "fresh (XLA compiled it), held (the jit cache already held the layout), failed (not buildable)",
+            label="outcome",
+        )
+        for outcome in PRELOAD_OUTCOMES:
+            self.m_preloads.inc(outcome, 0.0)  # every series scrapes as 0 before the walk
+        self.m_preload_seconds = reg.histogram(
+            "cerbos_tpu_xla_preload_seconds",
+            "Wall time the preloader spent on each manifest entry it walked (trace, load or compile, first call)",
+            buckets=_COMPILE_BUCKETS,
+        )
         self.detector = RecompileStormDetector(
             threshold=storm_threshold, window_s=storm_window_s, clock=clock
         )
@@ -214,12 +239,13 @@ class CompileStats:
     # -- recording ---------------------------------------------------------
 
     def record_compile(
-        self, layout_key: str, seconds: float, source: str = "fresh", trace_key: Any = None
+        self, layout_key: str, seconds: float, source: str = "fresh", trace_key: Any = None, storm: bool = True
     ) -> None:
         """One compile completed. ``layout_key`` is the display shape
         signature (``B64xBA128``-style); ``trace_key`` is the exact jit-cache
         key, so cardinality/storm detection see variant and column-layout
-        churn that shares a shape bucket."""
+        churn that shares a shape bucket. ``storm=False`` (the preloader's
+        loads) keeps the compile from the storm detector."""
         tk = trace_key if trace_key is not None else layout_key
         self.m_compiles.inc(source)
         self.m_compile_seconds.observe(seconds)
@@ -250,7 +276,7 @@ class CompileStats:
         flight_recorder().record_event(
             "xla_compile", layout_key=layout_key, seconds=round(seconds, 4), source=source, **key_fields
         )
-        distinct = self.detector.observe(tk)
+        distinct = self.detector.observe(tk) if storm else None
         if distinct is not None:
             self.m_storms.inc()
             _log.warning(
@@ -283,6 +309,18 @@ class CompileStats:
 
     def record_variant_fallback(self) -> None:
         self.m_variant_fallbacks.inc()
+
+    def record_preload(self, outcome: str, seconds: float) -> None:
+        """The preloader is done with one manifest entry."""
+        self.m_preloads.inc(outcome)
+        self.m_preload_seconds.observe(seconds)
+
+    def record_preload_done(self, counts: dict, seconds: float, stopped: bool) -> None:
+        """The preloader's walk ended: at the manifest's last entry, or
+        (``stopped``) because the table it walked for was invalidated."""
+        flight_recorder().record_event(
+            "xla_preload_done", seconds=round(seconds, 3), stopped=stopped, **counts
+        )
 
     def refresh_device_memory(self) -> None:
         """Update the device memory gauges (summed over this process's local
@@ -334,24 +372,72 @@ def configure(storm_threshold: Optional[int] = None, storm_window_s: Optional[fl
     return _stats
 
 
+_CACHE_REQUEST = "/jax/compilation_cache/compile_requests_use_cache"
+_CACHE_HIT = "/jax/compilation_cache/cache_hits"
+_cache_watch = threading.local()
+_cache_listener_lock = threading.Lock()
+_cache_listener_on = False
+
+
+def _on_jax_event(event: str, **_kw: Any) -> None:
+    seen = getattr(_cache_watch, "seen", None)
+    if seen is not None:
+        seen.append(event)
+
+
+@contextlib.contextmanager
+def cache_events():
+    """What jax's persistent cache said on THIS thread while the block ran:
+    jax announces a cache request and a hit on the thread that compiles, so
+    two threads that compile at once (the drain thread and the preloader)
+    each read their own, where the cache directory's entry count is one
+    number for both. Yields the list the events are appended to."""
+    global _cache_listener_on
+    with _cache_listener_lock:  # once a compile, never contended for long: no need to look before it
+        if not _cache_listener_on:
+            import jax.monitoring
+
+            jax.monitoring.register_event_listener(_on_jax_event)
+            _cache_listener_on = True
+    _cache_watch.seen = seen = []
+    try:
+        yield seen
+    finally:
+        _cache_watch.seen = None
+
+
+def source_of(seen: list) -> Optional[str]:
+    """``persistent`` when every executable the thread asked the cache for
+    came from it, ``fresh`` when XLA ran for one, None when the cache was not
+    asked (it is off, or this jax announces nothing)."""
+    asked = seen.count(_CACHE_REQUEST)
+    if not asked:
+        return None
+    return "persistent" if seen.count(_CACHE_HIT) >= asked else "fresh"
+
+
 def timed_first_call(layout_key: str, fn: Callable[..., Any], kwargs: dict, trace_key: Any = None):
     """Invoke a FRESHLY BUILT jit function, timing its first call.
 
     ``jax.jit`` defers trace+compile to the first invocation (dispatch of
     the compiled program stays async, so the measured wall time is the
-    compile, not the device execution). The persistent-cache entry count
-    before/after classifies the source: a compile that writes no new entry
-    while the cache is enabled was loaded from disk."""
+    compile, not the device execution). The source is what jax's cache said
+    on this thread (:func:`cache_events`); where it said nothing, the
+    persistent-cache entry count before/after: a compile that writes no new
+    entry while the cache is enabled was loaded from disk."""
     from . import jitcache
 
     before = jitcache.entry_count()
     t0 = time.perf_counter()
-    out = fn(**kwargs)
+    with cache_events() as seen:
+        out = fn(**kwargs)
     dt = time.perf_counter() - t0
-    source = "fresh"
-    if before is not None:
-        after = jitcache.entry_count()
-        if after is not None and after <= before:
-            source = "persistent"
+    source = source_of(seen)
+    if source is None:
+        source = "fresh"
+        if before is not None:
+            after = jitcache.entry_count()
+            if after is not None and after <= before:
+                source = "persistent"
     _stats.record_compile(layout_key, dt, source=source, trace_key=trace_key)
     return out

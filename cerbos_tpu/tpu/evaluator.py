@@ -15,7 +15,9 @@ tensors are batch-aligned so the same jit works single-chip or multi-chip
 
 from __future__ import annotations
 
+import atexit
 import contextlib
+import logging
 import threading
 import time
 from typing import Any, Optional
@@ -38,6 +40,8 @@ from .lowering import (
     lower_table,
 )
 from .packer import PackedBatch, Packer, PT_PRINCIPAL, PT_RESOURCE
+
+_log = logging.getLogger("cerbos_tpu.evaluator")
 
 def _clone_output(template: "T.CheckOutput", inp: "T.CheckInput") -> "T.CheckOutput":
     """Fresh CheckOutput from a memoized assembly (ids swapped). ActionEffect
@@ -825,7 +829,227 @@ class _DeviceHandle:
         self.leased = ()
 
 
-def _device_dispatch(lt: LoweredTable, batch: PackedBatch, jit_cache: dict) -> _DeviceHandle:
+def _jit_run(compiler, K: int, J: int, D: int, variant, lay: _StackLayout, BA_pad: int):
+    """The jitted device program of one layout: everything static in the jit
+    key bound into the trace. The one spelling for a flight that meets a new
+    layout (:func:`_device_dispatch`) and for the preloader that builds it
+    ahead of traffic, so both ask XLA for the same program."""
+    import jax
+    import jax.numpy as jnp
+
+    def run(**kw):
+        with jax.named_scope("unstack"):
+            parts = _unstack_padded(jnp, lay, kw)
+        final, role_results, win_j, sat_arr = _compute(
+            jnp, compiler, K, J, D, variant=variant, **parts
+        )
+        with jax.named_scope("pack_result"):
+            out = jnp.concatenate(
+                [
+                    final.reshape(BA_pad, -1).astype(jnp.int8),
+                    role_results.reshape(BA_pad, -1).astype(jnp.int8),
+                    win_j.reshape(BA_pad, -1).astype(jnp.int8),
+                ],
+                axis=1,
+            )
+            return jnp.concatenate(
+                [out.ravel(), sat_arr.astype(jnp.int8).ravel()]
+            )
+
+    return jax.jit(run)
+
+
+def _manifest_entry(key: tuple, lay: _StackLayout, stacked: dict) -> dict:
+    """What the layout manifest keeps of one jit key: enough to build the
+    key, the layout and zero-filled arguments again without a batch."""
+    B_pad, BA_pad, K, J, D, variant, _sig = key
+    return {
+        "shape": [B_pad, BA_pad],
+        "depth": [K, J, D],
+        "variant": [[gi, None if sel is None else list(sel)] for gi, sel in variant],
+        "layout": {
+            "paths": [list(p) for p in lay.paths],
+            "ts_paths": [list(p) for p in lay.ts_paths],
+            "list_paths": [list(p) for p in lay.list_paths],
+            "list_widths": list(lay.list_widths),
+            "pred_ids": list(lay.pred_ids),
+            "D": lay.D,
+            "has_now": lay.has_now,
+        },
+        "args": {name: [list(a.shape), a.dtype.str] for name, a in stacked.items()},
+    }
+
+
+def _entry_parts(entry: dict):
+    """Inverse of :func:`_manifest_entry`: ``(key, layout, zero arguments)``.
+    Raises on an entry that does not have the form (the preloader counts it
+    as failed)."""
+    B_pad, BA_pad = (int(x) for x in entry["shape"])
+    K, J, D = (int(x) for x in entry["depth"])
+    variant = tuple(
+        (int(gi), None if sel is None else tuple(int(i) for i in sel)) for gi, sel in entry["variant"]
+    )
+    lay_doc = entry["layout"]
+    lay = _StackLayout(
+        tuple(tuple(p) for p in lay_doc["paths"]),
+        tuple(tuple(p) for p in lay_doc["ts_paths"]),
+        tuple(tuple(p) for p in lay_doc["list_paths"]),
+        tuple(int(w) for w in lay_doc["list_widths"]),
+        tuple(lay_doc["pred_ids"]),
+        int(lay_doc["D"]),
+        bool(lay_doc["has_now"]),
+    )
+    zeros = {
+        name: np.zeros(tuple(int(n) for n in shape), dtype=np.dtype(dtype))
+        for name, (shape, dtype) in entry["args"].items()
+    }
+    return (B_pad, BA_pad, K, J, D, variant, lay.sig), lay, zeros
+
+
+class _LayoutPreloader:
+    """Loads the layouts this table's traffic is known to meet, ahead of it.
+
+    One per evaluator. The first layout one of its flights has to build (a
+    process's first device flight always builds one) starts ONE daemon
+    thread, once that layout's key is in the jit cache, which walks the
+    layout manifest's entries for this
+    table's identity (:mod:`layoutmanifest`), most-met first, and for each
+    key the jit cache does not hold builds the function a flight would build
+    (:func:`_jit_run`), calls it once with zero-filled arguments of the
+    recorded shapes (its own arrays, never the buffer pool's), waits for the
+    result, discards it and publishes the function in the evaluator's jit
+    cache. Nothing starts at boot, before a fork, or in a process that never
+    dispatches: a mix the oracle serves never reads the manifest. The
+    interpreter's exit ends the walk and waits for the entry in hand
+    (:meth:`close`).
+
+    A flight that meets a key the walk has not reached builds it itself, as
+    it always did, and files it (:meth:`met`); whichever of the two finishes
+    second finds the key held and drops its copy. ``stop()`` (the
+    evaluator's ``invalidate()``, so every cutover) ends the walk: the
+    generation is checked under the lock that publishes, so a function built
+    for one table is never published once another is in place. The walk is
+    not started again for the new table.
+    """
+
+    def __init__(self, evaluator: "TpuEvaluator"):
+        self._ev = evaluator
+        self._lock = threading.Lock()
+        self._gen = 0
+        self._started = False
+        self.thread: Optional[threading.Thread] = None
+
+    def flight(self) -> None:
+        """Called by every flight that has to build a layout, once its key is
+        in the jit cache; the first call starts the walk."""
+        if self._started:
+            return
+        self._started = True
+        from . import layoutmanifest
+
+        if layoutmanifest.path() is None:
+            return
+        self.thread = threading.Thread(target=self._walk, args=(self._gen,), name="xla-preload", daemon=True)
+        # a daemon thread that the interpreter's exit finds inside XLA is torn
+        # down with it and aborts the process: an exit waits for the entry in hand
+        atexit.register(self.close)
+        self.thread.start()
+
+    def stop(self) -> None:
+        with self._lock:
+            self._gen += 1
+
+    def close(self) -> None:
+        """End the walk and wait for the entry it has in hand."""
+        self.stop()
+        if self.thread is not None and self.thread is not threading.current_thread():
+            self.thread.join()
+
+    def _scope(self) -> Optional[str]:
+        import jax
+
+        from ..engine.rollout import bundle_hash_of
+        from . import layoutmanifest
+
+        identity = bundle_hash_of(self._ev.rule_table)
+        if not identity:
+            return None
+        dev = self._ev.device if self._ev.device is not None else jax.devices()[0]
+        return layoutmanifest.scope(identity, dev.device_kind)
+
+    def met(self, key: tuple, lay: _StackLayout, stacked: dict) -> None:
+        """A flight built ``key`` itself: file it for the next process."""
+        from . import layoutmanifest
+
+        if layoutmanifest.path() is None:
+            return
+        try:
+            scope = self._scope()
+            if scope is not None:
+                layoutmanifest.record(scope, _manifest_entry(key, lay, stacked))
+        except Exception:  # noqa: BLE001  (the manifest is never worth a request)
+            _log.debug("layout manifest: entry not recorded", exc_info=True)
+
+    def _walk(self, gen: int) -> None:
+        from . import layoutmanifest
+
+        stats = compilestats.stats()
+        counts = dict.fromkeys(compilestats.PRELOAD_OUTCOMES, 0)
+        t0 = time.perf_counter()
+        try:
+            scope = self._scope()
+            for entry in layoutmanifest.entries(scope) if scope is not None else ():
+                if self._gen != gen:
+                    break
+                e0 = time.perf_counter()
+                outcome = self._load(entry, gen)
+                if outcome is not None:
+                    counts[outcome] += 1
+                    stats.record_preload(outcome, time.perf_counter() - e0)
+        except Exception:  # noqa: BLE001  (a walk that fails is a process without a manifest)
+            _log.warning("layout preload: walk abandoned", exc_info=True)
+        atexit.unregister(self.close)
+        stats.record_preload_done(counts, time.perf_counter() - t0, stopped=self._gen != gen)
+
+    def _load(self, entry: dict, gen: int) -> Optional[str]:
+        """One entry: its outcome, or None when the walk was stopped under it."""
+        ev = self._ev
+        try:
+            key, lay, zeros = _entry_parts(entry)
+            if key in ev._jit_cache:
+                return "held"
+            B_pad, BA_pad, K, J, D, variant, _sig = key
+            fn = _jit_run(ev.lowered.compiler, K, J, D, variant, lay, BA_pad)
+            t0 = time.perf_counter()
+            with ev._device_scope(), compilestats.cache_events() as seen:
+                fn(**zeros).block_until_ready()
+            dt = time.perf_counter() - t0
+        except Exception:  # noqa: BLE001  (a stale or foreign entry: skip it)
+            if self._gen != gen:
+                return None
+            _log.debug("layout preload: entry not buildable", exc_info=True)
+            return "failed"
+        source = compilestats.source_of(seen) or "fresh"
+        with self._lock:
+            stopped = self._gen != gen
+            publish = not stopped and key not in ev._jit_cache
+            if publish:
+                ev._jit_cache[key] = fn
+        # XLA ran or loaded whatever became of the function: since boot, like
+        # a flight's own compile, but a deliberate walk is not a storm
+        compilestats.stats().record_compile(
+            f"B{B_pad}xBA{BA_pad}", dt, source=source, trace_key=key, storm=False
+        )
+        if stopped:
+            return None
+        if not publish:
+            return "held"
+        return "loaded" if source == "persistent" else "fresh"
+
+
+def _device_dispatch(
+    lt: LoweredTable, batch: PackedBatch, jit_cache: dict, preloader: _LayoutPreloader
+) -> _DeviceHandle:
     """Queue one packed batch on the single device WITHOUT blocking.
 
     FUSE TRANSFERS: every host->device put and device->host fetch is its
@@ -842,10 +1066,13 @@ def _device_dispatch(lt: LoweredTable, batch: PackedBatch, jit_cache: dict) -> _
     ``copy_to_host_async``, so the caller can pack/assemble other batches
     while this one's transfers and compute are in flight; only
     ``_device_finalize`` blocks.
-    """
-    import jax
-    import jax.numpy as jnp
 
+    ``preloader`` (the evaluator's) is told of every layout the flight has
+    to build itself: before the build, which starts its walk of the layout
+    manifest the first time (a process's first device flight always builds),
+    and after it, to file the layout. A flight that finds its layout in the
+    cache does not touch it.
+    """
     drainclock.to(drainclock.STACK)
     compiler = lt.compiler
     K, J, D = batch.K, batch.J, batch.D
@@ -872,30 +1099,11 @@ def _device_dispatch(lt: LoweredTable, batch: PackedBatch, jit_cache: dict) -> _
     key = (B_pad, BA_pad, K, J, D, variant_key, layout.sig)
     fn = jit_cache.get(key)
     if fn is None:
-        vt = variant_key
-        lay = layout
-
-        def run(**kw):
-            with jax.named_scope("unstack"):
-                parts = _unstack_padded(jnp, lay, kw)
-            final, role_results, win_j, sat_arr = _compute(
-                jnp, compiler, K, J, D, variant=vt, **parts
-            )
-            with jax.named_scope("pack_result"):
-                out = jnp.concatenate(
-                    [
-                        final.reshape(BA_pad, -1).astype(jnp.int8),
-                        role_results.reshape(BA_pad, -1).astype(jnp.int8),
-                        win_j.reshape(BA_pad, -1).astype(jnp.int8),
-                    ],
-                    axis=1,
-                )
-                return jnp.concatenate(
-                    [out.ravel(), sat_arr.astype(jnp.int8).ravel()]
-                )
-
-        fn = jax.jit(run)
+        fn = _jit_run(compiler, K, J, D, variant_key, layout, BA_pad)
         jit_cache[key] = fn
+        # a process's first device flight always lands here: the walk starts
+        # once the flight's own key is in the cache, and never builds it too
+        preloader.flight()
         compilestats.stats().record_miss()
         # jit defers trace+compile to the first call: time it there so the
         # compile histogram sees the real XLA cost (dispatch of the compiled
@@ -904,6 +1112,7 @@ def _device_dispatch(lt: LoweredTable, batch: PackedBatch, jit_cache: dict) -> _
         out = compilestats.timed_first_call(
             f"B{B_pad}xBA{BA_pad}", fn, stacked, trace_key=key
         )
+        preloader.met(key, layout, stacked)
         drainclock.to(drainclock.DISPATCH)
     else:
         compilestats.stats().record_hit()
@@ -1004,6 +1213,7 @@ class TpuEvaluator:
             _enable_jit_cache()  # persistent XLA cache: restart = load, not recompile
         self.stats = {"device_inputs": 0, "oracle_inputs": 0, "trivial_inputs": 0}
         self._jit_cache: dict = {}
+        self._preloader = _LayoutPreloader(self)
         self._dr_table_cache: dict = {}
         self._roles_cache: dict = {}
         self._edr_memo: dict = {}
@@ -1021,6 +1231,7 @@ class TpuEvaluator:
         ``refresh()`` re-lowers and then calls this; shard clones sharing the
         lowered table call only this after the owner re-lowered."""
         self.packer.invalidate()
+        self._preloader.stop()  # before the clear: nothing built for the old table is published after it
         self._jit_cache.clear()
         self._dr_table_cache.clear()
         self._roles_cache.clear()
@@ -1125,7 +1336,9 @@ class TpuEvaluator:
                 p0 = time.perf_counter()
                 batch = self.packer.pack(ch, params)
                 t.pack_s += time.perf_counter() - p0
-                t.parts.append((batch, _device_dispatch(self.lowered, batch, self._jit_cache)))
+                t.parts.append(
+                    (batch, _device_dispatch(self.lowered, batch, self._jit_cache, self._preloader))
+                )
         # a chunk with no candidate rows (every input trivial) never reached the device
         sent = [h for _, h in t.parts if h.ready is None]
         padded = sum(h.B_pad for h in sent)

@@ -175,8 +175,10 @@ def directory() -> str | None:
 
 def entry_count() -> int | None:
     """Files currently in the cache directory (None when disabled or
-    unreadable). Cheap relative to any compile, and the before/after delta
-    is what classifies a compile as fresh vs persistent-loaded."""
+    unreadable); the layout manifest lives in a subdirectory and is not one
+    of them. Cheap relative to any compile, and the before/after delta is
+    what classifies a compile as fresh vs persistent-loaded where jax's own
+    cache events say nothing (``compilestats.timed_first_call``)."""
     d = directory()
     if not d:
         return None
@@ -191,8 +193,11 @@ def status() -> dict:
     ``/_cerbos/debug/flight`` (``X-Cerbos-Jitcache``), and operators asking
     "did the restart actually skip the compile?" or "what is this replica
     running on?": the directory, whether it held entries when we enabled it
-    (a warm restart), how many compiles this process loaded from it, and the
-    device this process opened (None in a process that owns none)."""
+    (a warm restart), how many compiles this process loaded from it, the
+    size of the layout manifest kept beside it (:mod:`layoutmanifest`: what a
+    restart loads ahead of traffic), and the device this process opened
+    (None in a process that owns none)."""
+    from . import layoutmanifest
     from .compilestats import stats as _compile_stats
 
     return {
@@ -205,6 +210,7 @@ def status() -> dict:
         # instead of compile; persistent_loads counts the times it did
         "warm_at_enable": bool(_entries_at_enable),
         "persistent_loads": _compile_stats().snapshot()["persistent_loads"],
+        "manifest": layoutmanifest.size(),
         "device": device(),
         "device_memory": device_memory(),
     }

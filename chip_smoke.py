@@ -26,7 +26,13 @@ server process:
 - under ``--frontends 2``, that the pool is one server to its operator: every
   scrape is ONE request and must hold ``fe1``, ``fe2`` and ``batcher``, and one
   profiler capture asked of the served HTTP port (a front end, which holds no
-  device) comes back from the device owner and holds TPU operations.
+  device) comes back from the device owner and holds TPU operations;
+- after each topology, the same topology booted ONCE MORE on the same cache
+  directory (``run_restart``; not under ``--lanes`` or ``--audit``): the
+  second process must load layouts ahead of traffic from the layout manifest
+  the first one filed (``xla_preloads_total{outcome="loaded"}`` > 0), build
+  fewer layouts inside requests over the same pass than a first boot that
+  found no manifest did, and raise no recompile storm by its walk.
 
 Traffic: (a) upstream-shaped requests, 1 resource x its actions, from 64
 concurrent connections — these coalesce by timing, so how many reach the
@@ -940,7 +946,84 @@ def run_topology(name, extra_args, tpu_conf, workers, policy_dir, singles, batch
             "burst": burst,
             "capture": capture,
             **({"audit": audit} if audit else {}),
+            # for run_restart: what this boot built inside requests over its first pass
+            "first_pass": {
+                "built": int(msum(cold["after"], "cerbos_tpu_jit_cache_misses_total")),
+                "loaded": int(msum(final, "cerbos_tpu_xla_preloads_total", outcome="loaded")),
+            },
         }
+    except BaseException:
+        if srv.proc.poll() is None:
+            srv.kill()
+        log(f"--- last lines of {srv.stderr_path}:\n{srv.stderr_tail()}---")
+        raise
+
+
+RESTART_WALK_S = 180.0  # how long a restarted server's preload walk may take
+STORM_THRESHOLD = 8  # compilestats.STORM_THRESHOLD, the server's default: distinct layouts compiled in 120 s
+
+
+def run_restart(name, extra_args, tpu_conf, workers, policy_dir, singles, batches, first: dict) -> dict:
+    """The served phase once more: the same topology booted again on the same
+    cache directory, one pass of the same requests. The first boot filed the
+    layouts its flights built in the layout manifest beside the compile cache;
+    this one must load them ahead of traffic (``xla_preloads_total{outcome=
+    "loaded"}`` > 0) and build fewer inside requests than the first did over
+    the same pass (``jit_cache_misses_total``: one miss is one layout a flight
+    built itself). Where the first boot already found a manifest (an earlier
+    call's, kept with the machine's cache) it had little left to build, and
+    only the loads are held to."""
+    log(f"== topology {name}, restarted on the same cache directory")
+    srv = ServerProc(f"{name}-restart", policy_dir, extra_args, tpu_conf)
+    try:
+        srv.wait_serving(timeout=300)
+        status, _ = srv.status()
+        check_platform(status)
+        log(f"  layout manifest at boot: {status.get('manifest')}")
+        run = run_pass(srv, singles, batches, workers, timeout=900, label="restart")
+        deadline = time.monotonic() + RESTART_WALK_S
+        done = []
+        while not done and time.monotonic() < deadline:
+            _, flight = srv.status()
+            done = [e for e in flight.get("events", []) if e.get("kind") == "xla_preload_done"]
+            if not done:
+                time.sleep(1.0)
+        if not done:
+            raise SmokeFailure(f"restart: no xla_preload_done event {RESTART_WALK_S:.0f} s after the pass: the walk did not end")
+        final = srv.scrape(workers)
+        with open(os.path.join(OUT_DIR, f"{name}-restart.metrics.txt"), "w") as f:
+            f.write(srv.last_scrape)
+        preloads = by_label(final, "cerbos_tpu_xla_preloads_total", "outcome")
+        built = int(msum(run["after"], "cerbos_tpu_jit_cache_misses_total"))
+        log(
+            f"  restart: preloads {preloads}, {msum(final, 'cerbos_tpu_xla_preload_seconds_sum'):.2f} s in the walk's "
+            f"loads ({done[-1]['seconds']:.1f} s wall); layouts built inside requests {built} "
+            f"(first boot, same pass: {first['built']}, with {first['loaded']} loaded ahead); compiles by source "
+            f"{by_label(final, 'cerbos_tpu_xla_compiles_total', 'source')}"
+        )
+        failures = []
+        if preloads.get("loaded", 0) <= 0:
+            failures.append(f'restart: xla_preloads_total{{outcome="loaded"}} is {preloads.get("loaded", 0)}: nothing was loaded ahead of traffic')
+        # shape (a) coalesces by timing, so two boots that both loaded ahead differ by a layout
+        # or two either way: the count is held to the first boot's only where that one loaded none
+        if first["loaded"] == 0 and built >= first["built"]:
+            failures.append(
+                f"restart: {built} layouts built inside requests, the first boot built {first['built']} "
+                "with none loaded ahead: the manifest bought nothing"
+            )
+        storms = msum(final, "cerbos_tpu_recompile_storms_total")
+        if storms > 0 and built < STORM_THRESHOLD:
+            failures.append(
+                f"restart: recompile_storms_total is {storms:.0f} with {built} layouts built inside requests "
+                f"(a storm takes {STORM_THRESHOLD}): the walk fed the storm detector"
+            )
+        if failures:
+            raise SmokeFailure("; ".join(failures))
+        code = srv.stop()
+        if code != 0:
+            raise SmokeFailure(f"restart: server exit code on SIGTERM: {code} (None = had to be killed)")
+        return {"preloads": preloads, "built_in_requests": built, "first_built_in_requests": first["built"],
+                "walk_s": done[-1]["seconds"]}
     except BaseException:
         if srv.proc.poll() is None:
             srv.kill()
@@ -1078,6 +1161,10 @@ def main() -> int:
         results = {}
         for name, extra, tpu_conf, workers in topologies:
             results[name] = run_topology(name, extra, tpu_conf, workers, policy_dir, singles, batches, args)
+            if not args.lanes and not args.audit:
+                results[name]["restart"] = run_restart(
+                    name, extra, tpu_conf, workers, policy_dir, singles, batches, results[name].pop("first_pass")
+                )
         if args.workers_check:
             check_workers_refused(policy_dir)
     except SmokeFailure as e:
