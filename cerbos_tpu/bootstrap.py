@@ -11,6 +11,7 @@ import logging
 from dataclasses import dataclass
 from typing import Any, Optional
 
+from . import bootclock
 from .audit import new_audit_log
 from .auxdata import AuxDataManager
 from .config import Config
@@ -76,7 +77,8 @@ def _make_evaluator(rule_table: Any, engine_conf: dict, schema_mgr: Any = None) 
 
     tpu_conf = engine_conf.get("tpu", {})
     backend = _os.environ.get("CERBOS_TPU_BACKEND", tpu_conf.get("backend", "jax"))
-    return TpuEvaluator(
+    bootclock.mark(bootclock.OTHER)
+    evaluator = TpuEvaluator(
         rule_table,
         globals_=engine_conf.get("globals", {}) or {},
         schema_mgr=schema_mgr,
@@ -87,11 +89,15 @@ def _make_evaluator(rule_table: Any, engine_conf: dict, schema_mgr: Any = None) 
         min_device_batch=int(tpu_conf.get("minDeviceBatch", 16)),
         pipeline_chunk=int(tpu_conf.get("pipelineChunk", 4096)),
     )
+    bootclock.mark(bootclock.LOWER)
+    return evaluator
 
 
 def prebuild(config: Config, use_tpu: Optional[bool] = None) -> Prebuilt:
     """Parse → compile → build → lower, with no threads or listeners."""
+    bootclock.mark(bootclock.OTHER)
     store = new_store(config.section("storage"))
+    bootclock.mark(bootclock.LOAD)
     try:
         manager = RuleTableManager(store)
         rule_table = manager.rule_table
@@ -133,9 +139,16 @@ def initialize(
 
     The batcher process itself uses :func:`build_batcher_ipc` on top of a
     standalone Core.
+
+    The boot clock's marks (``bootclock.py``) do nothing in a process that
+    began none; a front end drops the one it inherited from the pool's parent.
     """
+    if role == "frontend":
+        bootclock.abandon()
     audit_log = new_audit_log(config.section("audit"))
+    bootclock.mark(bootclock.OTHER)
     store = new_store(config.section("storage"))
+    bootclock.mark(bootclock.LOAD)
 
     schema_mgr = SchemaManager(store, enforcement=config.get("schema.enforcement", "none"))
 
@@ -274,7 +287,9 @@ def initialize(
             # Only faults after a successful boot are the breaker's.
             from .tpu import jitcache
 
+            bootclock.mark(bootclock.OTHER)
             jitcache.open_device()
+            bootclock.mark(bootclock.DEVICE)
 
         def _sub_evaluator(ep, _ev=tpu_evaluator) -> None:
             # re-lower the SHARED lowered table first; every later subscriber

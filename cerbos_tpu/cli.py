@@ -96,6 +96,9 @@ def _native_field() -> str:
 
 
 def cmd_server(args: argparse.Namespace) -> int:
+    from . import bootclock
+
+    bootclock.begin()  # boot to ready, by phase: from the process's start, which this call reads
     from .bootstrap import initialize
     from .config import Config
 
@@ -107,6 +110,7 @@ def cmd_server(args: argparse.Namespace) -> int:
         metrics_exporter,
     )
 
+    bootclock.mark(bootclock.IMPORT)  # the command's own imports (gRPC, aiohttp, numpy) are most of it
     config = Config.load(args.config, overrides=args.set or [])
     server_conf = config.section("server")
 
@@ -215,6 +219,7 @@ def cmd_server(args: argparse.Namespace) -> int:
     try:
         if not stop.is_set():
             server.start()
+            bootclock.listening()
             print(
                 f"cerbos-tpu serving: http={server.http_port} grpc={server.grpc_port} "
                 f"{_device_fields() or 'platform=none'} {_native_field()}",

@@ -356,13 +356,20 @@ def route_families(reg: Any) -> tuple[Any, Any]:
     stages = reg.histogram_vec(
         "cerbos_tpu_batch_stage_seconds",
         "device-batch pipeline stage seconds on the drain thread's clock, once per flight, by shard: "
-        "pack, submit (= stack + dispatch + compiles), device (host-clock GAP between submit "
+        "pack (= pack_plan + pack_gather + pack_scalars + pack_lists + pack_ts + pack_preds), submit (= stack + "
+        "dispatch + compiles; dispatch = dispatch_call + dispatch_copy), device (host-clock GAP between submit "
         "returning and collect starting, not device time), collect (= fetch + assemble), settle; "
         "oracle (synchronous check of a flight or of a request under minDeviceBatch), post (after settle)",
         label=("stage", "shard"),
         buckets=[0.0001, 0.0005, 0.001, 0.002, 0.005, 0.01, 0.025, 0.05, 0.1, 0.5, 1.0],
     )
     return checks, stages
+
+
+# a flight's parts of pack and of dispatch (engine/drainclock.py), and every
+# stage that is observed once per flight where its submit returns
+_FLIGHT_PARTS = drainclock.PARTS[drainclock.PACK] + drainclock.PARTS[drainclock.DISPATCH]
+_SUBMIT_STAGES = ("pack", "submit", "stack", "dispatch", "oracle") + _FLIGHT_PARTS
 
 
 class BatchingEvaluator:
@@ -1099,10 +1106,11 @@ class BatchingEvaluator:
             self.m_batches.inc()
             self.m_requests.inc(len(group))
             self.m_batch_size.observe(len(all_inputs))
-            # stage timings: pack happens inside the evaluator's submit, which
-            # reports it (plus layout economics) as ticket attributes; sync
-            # evaluators have no packed device layout, so occupancy is 1.0
-            pack_s = float(getattr(ticket, "pack_s", 0.0) or 0.0)
+            # stage timings: pack, like stack and dispatch, happens inside the
+            # evaluator's submit and is read from the thread's clock; layout
+            # economics come as ticket attributes (sync evaluators have no
+            # packed device layout, so occupancy is 1.0)
+            pack_s = lap.get(drainclock.PACK, 0.0)
             occupancy = getattr(ticket, "occupancy", None)
             if occupancy is None:
                 occupancy = 1.0
@@ -1122,6 +1130,10 @@ class BatchingEvaluator:
                     "stack": lap.get(drainclock.STACK, 0.0),
                     "dispatch": lap.get(drainclock.DISPATCH, 0.0),
                     "oracle": lap.get(drainclock.ORACLE, 0.0),
+                    # the parts of pack and of dispatch, which tile them (0.0
+                    # for a flight that had none: oracle-served, or its call
+                    # was the layout's compile)
+                    **{part: lap.get(part, 0.0) for part in _FLIGHT_PARTS},
                 },
                 submitted_at=time.perf_counter(),
                 submitted_wall_ns=time.time_ns(),
@@ -1131,7 +1143,7 @@ class BatchingEvaluator:
             )
             window_s = 0.0  # a drain that splits into several flights waited once
             self.m_window_wait.observe(flight.timings["window"])
-            for stage in ("pack", "submit", "stack", "dispatch", "oracle"):
+            for stage in _SUBMIT_STAGES:
                 self.m_stage_seconds.observe(stage, flight.timings[stage])
             self.m_occupancy.set(float(occupancy))
             parts = getattr(ticket, "parts", None)

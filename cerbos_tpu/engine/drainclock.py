@@ -22,6 +22,26 @@ through :func:`to`, which does nothing on any other thread (probes, the
 bisect thread, direct ``check()`` callers), so ``submit``/``collect`` grow
 no parameter. While the profiler has a capture open every state is also a
 region on the device trace (``observability.region``).
+
+Below a state: its PARTS, on a second cursor inside it (PR 38). A state is
+entered with the name of its first part (``to(PACK, PACK_PLAN)``) and
+:func:`part` moves on to the next; each reads ``perf_counter`` once and books
+the seconds since the last such read to the part being left, into the lap
+alone. ``to()`` closes the open part with the very reading that closes the
+state, so the parts of a state tile it by construction: ``pack`` and
+``dispatch`` of a flight ARE the sums of their parts, to the rounding of a
+float addition. Sub-states that also add into their parent were the other
+choice; a second cursor was taken because it leaves ``to()``, the states and
+``batcher_thread_seconds_total{state="pack"}`` exactly what they were (the
+same calls at the same places: nothing a reader of the states sees can move),
+costs no counter update under a lock, and needs no guard for the one place
+where the packer runs inside another state: ``part()`` does nothing in a
+state that was entered without a part (the numpy backend and a mesh pack
+inside ``oracle``), as it does nothing on a thread without a clock (the layout
+preloader, probes, direct ``check()`` callers, a front end's inline route).
+While a capture is open the region on the trace is the PART's
+(``batch.pack_gather``, in place of ``batch.pack``), so an idle gap of the
+device names the part the drain thread was in.
 """
 
 from __future__ import annotations
@@ -45,6 +65,21 @@ SETTLE = "settle"      # futures resolved, waterfalls booked
 POST = "post"          # after settle: flight record, hot rules, sentinel hand-off
 OTHER = "other"        # whatever is left: locks, queue pops, metric updates, plan flights
 
+# the parts of ``pack`` (tpu/packer.py) and of ``dispatch`` (tpu/evaluator.py): observed once a flight as
+# ``cerbos_tpu_batch_stage_seconds{stage=<part>}``, carried in the flight record's ``timings``
+PACK_PLAN = "pack_plan"        # the per-input loop: shape key, shape-memo hit (or the shape's build), InputPlan
+PACK_GATHER = "pack_gather"    # K/J/D, the candidate blocks' stack and its six gathers, the scope-permission rows
+PACK_SCALARS = "pack_scalars"  # the scalar attribute columns: the native encoder, or the per-path loop without it
+PACK_LISTS = "pack_lists"      # the list columns
+PACK_TS = "pack_ts"            # the timestamp columns and the batch's now()
+PACK_PREDS = "pack_preds"      # the host-evaluated predicates, then the PackedBatch itself
+DISPATCH_CALL = "dispatch_call"  # fn(**stacked): JAX's handling of a keyword call, the eight puts, the enqueue
+DISPATCH_COPY = "dispatch_copy"  # copy_to_host_async() and the handle
+PARTS = {
+    PACK: (PACK_PLAN, PACK_GATHER, PACK_SCALARS, PACK_LISTS, PACK_TS, PACK_PREDS),
+    DISPATCH: (DISPATCH_CALL, DISPATCH_COPY),
+}
+
 ALL = "all"            # the label of the CPU series, which is not split by state
 CPU_EVERY_S = 0.1      # the thread's CPU clock is read at most this often
 
@@ -54,15 +89,18 @@ WAIT = frozenset((IDLE, WINDOW, FETCH))
 # names on the profiler's trace: the loop's own waits under ``batcher.``, a
 # flight's states under ``batch.`` beside the spans start_span emits there
 REGIONS = {s: ("batcher." if s in (IDLE, WINDOW, OTHER) else "batch.") + s for s in STATES}
+REGIONS.update({p: "batch." + p for parts in PARTS.values() for p in parts})
 
 _tls = threading.local()
 
 
 class DrainClock:
-    """Owned by one thread. ``lap`` holds the wall seconds per state since the
-    last :meth:`take_lap`: the batcher reads a flight's stages from it."""
+    """Owned by one thread. ``lap`` holds the wall seconds per state, and per
+    part of a state entered with one, since the last :meth:`take_lap`: the
+    batcher reads a flight's stages from it."""
 
-    __slots__ = ("state", "lap", "_wall", "_cpu", "_cpu_due", "_vec", "_keys", "_cpu_key", "_region")
+    __slots__ = ("state", "lap", "_wall", "_cpu", "_cpu_due", "_vec", "_keys", "_cpu_key", "_region",
+                 "_part", "_part_wall")
 
     def __init__(self, shard: str = "0"):
         self._vec = observability.metrics().counter_vec(
@@ -76,27 +114,50 @@ class DrainClock:
         self.state = OTHER
         self.lap: dict[str, float] = {}
         self._region = None  # the open region of the profiler's trace, while a capture is open
-        self._wall = time.perf_counter()
+        self._part: Optional[str] = None  # the open part of the state, where the state was entered with one
+        self._wall = self._part_wall = time.perf_counter()
         self._cpu = time.thread_time()
         self._cpu_due = self._wall + CPU_EVERY_S
 
-    def to(self, state: str) -> float:
-        """Returns the wall seconds booked to the state being left."""
+    def to(self, state: str, part: Optional[str] = None) -> float:
+        """Returns the wall seconds booked to the state being left. ``part``:
+        the first part of ``state``, for a state that is tiled by parts."""
         wall = time.perf_counter()
         left = self.state
         d_wall = wall - self._wall
         self._vec.inc(self._keys[left], d_wall)
-        self.lap[left] = self.lap.get(left, 0.0) + d_wall
+        lap = self.lap
+        lap[left] = lap.get(left, 0.0) + d_wall
+        if self._part is not None:
+            lap[self._part] = lap.get(self._part, 0.0) + (wall - self._part_wall)
         self._wall, self.state = wall, state
+        self._part, self._part_wall = part, wall
         if wall >= self._cpu_due:
             self.book_cpu()
+        if self._region is not None or observability.capture_open:
+            self._mark_region(state if part is None else part)
+        return d_wall
+
+    def part(self, name: str) -> None:
+        """Move on to the next part of the state; nothing in a state that was
+        entered without one."""
+        left = self._part
+        if left is None:
+            return
+        wall = time.perf_counter()
+        self.lap[left] = self.lap.get(left, 0.0) + (wall - self._part_wall)
+        self._part, self._part_wall = name, wall
+        if self._region is not None or observability.capture_open:
+            self._mark_region(name)
+
+    def _mark_region(self, name: str) -> None:
+        """Only while a capture is open, or to close the region one left open."""
         if self._region is not None:
             self._region.__exit__(None, None, None)
             self._region = None
         if observability.capture_open:
-            self._region = observability.region(REGIONS[state])
+            self._region = observability.region(REGIONS[name])
             self._region.__enter__()
-        return d_wall
 
     def book_cpu(self) -> None:
         cpu = time.thread_time()
@@ -114,8 +175,16 @@ def install(shard: str = "0") -> DrainClock:
     return clock
 
 
-def to(state: str) -> None:
+def to(state: str, part: Optional[str] = None) -> None:
     """Move the calling thread's clock to ``state``; nothing where it has none."""
     clock: Optional[DrainClock] = getattr(_tls, "clock", None)
     if clock is not None:
-        clock.to(state)
+        clock.to(state, part)
+
+
+def part(name: str) -> None:
+    """Move the calling thread's clock to the next part of its state; nothing
+    where it has no clock, or in a state that is not tiled by parts."""
+    clock: Optional[DrainClock] = getattr(_tls, "clock", None)
+    if clock is not None:
+        clock.part(name)

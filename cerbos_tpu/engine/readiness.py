@@ -24,6 +24,7 @@ import threading
 import time
 from typing import Callable, Optional
 
+from .. import bootclock
 from ..observability import metrics
 
 _STATUS_CODE = {"warming": 0.0, "ready": 1.0, "degraded": 2.0}
@@ -90,6 +91,8 @@ class ReadinessState:
             self._ready = True
             self._warmup_error = error
             self._warmed_at = self._clock()
+        # a warm-up that ends after the listeners are up is where boot ends
+        bootclock.readiness_changed()
 
     def bind_health(self, provider: Optional[Callable[[], str]]) -> None:
         """Wire the device breaker's state in: an open breaker after warmup

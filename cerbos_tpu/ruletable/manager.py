@@ -13,6 +13,7 @@ import logging
 import threading
 from typing import Any, Callable, Optional
 
+from .. import bootclock
 from ..compile import CompileError, compile_policy_set
 from ..storage.store import Event, Store
 from .table import RuleTable, build_rule_table
@@ -47,13 +48,20 @@ class RuleTableManager:
         with gctune.build_phase():
             # a BinaryStore-style bundle can carry the compiled IR, skipping
             # the parse+compile pipeline (the RuleTableStore fast path)
+            # the marks book a process's FIRST build to its boot clock and
+            # do nothing once it is ready (a rebuild after a push)
             get_compiled = getattr(self.store, "get_compiled", None)
-            if get_compiled is not None:
-                compiled = get_compiled()
-                if compiled is not None:
-                    return build_rule_table(compiled)
-            policies = self.store.get_all()
-            return build_rule_table(compile_policy_set(policies))
+            compiled = get_compiled() if get_compiled is not None else None
+            if compiled is None:
+                policies = self.store.get_all()
+                bootclock.mark(bootclock.LOAD)
+                compiled = compile_policy_set(policies)
+                bootclock.mark(bootclock.COMPILE)
+            else:
+                bootclock.mark(bootclock.LOAD)
+            table = build_rule_table(compiled)
+            bootclock.mark(bootclock.TABLE)
+            return table
 
     def build_table(self) -> RuleTable:
         """Build a fresh table off the serving path (the rollout

@@ -11,6 +11,7 @@ from __future__ import annotations
 from dataclasses import dataclass
 from typing import Optional
 
+from . import bootclock
 from .bootstrap import Core, initialize
 from .config import Config
 from .server.server import Server, ServerConfig
@@ -45,6 +46,9 @@ def serve(
     use_tpu: Optional[bool] = None,
 ) -> Handle:
     """Start a full PDP (gRPC + HTTP) and return a handle."""
+    # boot to ready from this call on: the host application's own life
+    # before it is not this program's boot (bootclock.py)
+    bootclock.begin(process_start=False)
     config = Config.load(config_file, overrides=overrides or [])
     core = initialize(config, use_tpu=use_tpu)
     server_conf = config.section("server")
@@ -59,6 +63,7 @@ def serve(
     # no in-flight request's transients get frozen (util/gctune)
     gctune.tune_for_serving()
     server.start()
+    bootclock.listening()
     return Handle(core=core, server=server)
 
 

@@ -26,6 +26,7 @@ import sys
 import time
 from typing import Callable, Optional
 
+from .. import bootclock
 from ..util import gctune
 
 _RESTART_LIMIT = 10  # per worker slot; a crash-looping config must not spin forever
@@ -227,6 +228,8 @@ def run_server_pool(
             stop["flag"] = True
 
         signal.signal(signal.SIGTERM, on_term)
+        if respawn:
+            bootclock.begin()  # its own boot, from its fork; the first workers carry on the parent's clock
         if post_fork is not None:
             post_fork()
         # a respawned worker rebuilds from the store: the boot-time prebuilt
@@ -244,6 +247,7 @@ def run_server_pool(
         try:
             if not stop["flag"]:
                 server.start()
+                bootclock.listening()
             while not stop["flag"]:
                 time.sleep(0.2)
         finally:
@@ -321,6 +325,8 @@ def run_frontdoor_pool(
             stop["flag"] = True
 
         signal.signal(signal.SIGTERM, on_term)
+        if respawn:
+            bootclock.begin()  # its own boot, from its fork; the first owner carries on the parent's clock
         if post_fork is not None:
             post_fork()
         core = initialize(config, use_tpu=use_tpu, prebuilt=None if respawn else prebuilt)
@@ -328,6 +334,9 @@ def run_frontdoor_pool(
             post_init(core)
         gctune.tune_for_serving()
         ipc_server = build_batcher_ipc(core, socket_path)
+        # the pool's boot ends where its device owner takes tickets and serves;
+        # a front end's bind, later, is in no series (bootclock.py)
+        bootclock.listening()
         try:
             while not stop["flag"]:
                 time.sleep(0.2)

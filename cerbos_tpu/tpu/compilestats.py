@@ -239,13 +239,22 @@ class CompileStats:
     # -- recording ---------------------------------------------------------
 
     def record_compile(
-        self, layout_key: str, seconds: float, source: str = "fresh", trace_key: Any = None, storm: bool = True
+        self,
+        layout_key: str,
+        seconds: float,
+        source: str = "fresh",
+        trace_key: Any = None,
+        storm: bool = True,
+        put_bytes: Optional[int] = None,
+        fetch_bytes: Optional[int] = None,
     ) -> None:
         """One compile completed. ``layout_key`` is the display shape
         signature (``B64xBA128``-style); ``trace_key`` is the exact jit-cache
         key, so cardinality/storm detection see variant and column-layout
         churn that shares a shape bucket. ``storm=False`` (the preloader's
-        loads) keeps the compile from the storm detector."""
+        loads) keeps the compile from the storm detector. ``put_bytes`` and
+        ``fetch_bytes``, where the caller knows them: what a call of this
+        layout hands the device and what it fetches back, into the event."""
         tk = trace_key if trace_key is not None else layout_key
         self.m_compiles.inc(source)
         self.m_compile_seconds.observe(seconds)
@@ -270,6 +279,10 @@ class CompileStats:
         self.m_cardinality.set(card)
         if key_fields:
             self.m_novel.inc(key_fields["novel"])
+        if put_bytes is not None:
+            key_fields["put_bytes"] = int(put_bytes)
+        if fetch_bytes is not None:
+            key_fields["fetch_bytes"] = int(fetch_bytes)
         # every compile is a flight event: /_cerbos/debug/flight then answers
         # "which layout, how long, fresh or from the persistent cache", and,
         # with the key's seven components, "what about it was new"
@@ -424,7 +437,9 @@ def timed_first_call(layout_key: str, fn: Callable[..., Any], kwargs: dict, trac
     compile, not the device execution). The source is what jax's cache said
     on this thread (:func:`cache_events`); where it said nothing, the
     persistent-cache entry count before/after: a compile that writes no new
-    entry while the cache is enabled was loaded from disk."""
+    entry while the cache is enabled was loaded from disk. Where the result is
+    one array (the single-device path) the event also says what the call
+    weighs: the bytes of ``kwargs`` and of the result."""
     from . import jitcache
 
     before = jitcache.entry_count()
@@ -439,5 +454,9 @@ def timed_first_call(layout_key: str, fn: Callable[..., Any], kwargs: dict, trac
             after = jitcache.entry_count()
             if after is not None and after <= before:
                 source = "persistent"
-    _stats.record_compile(layout_key, dt, source=source, trace_key=trace_key)
+    fetch_bytes = getattr(out, "nbytes", None)  # the mesh path returns several arrays: no one figure
+    put_bytes = sum(a.nbytes for a in kwargs.values()) if fetch_bytes is not None else None
+    _stats.record_compile(
+        layout_key, dt, source=source, trace_key=trace_key, put_bytes=put_bytes, fetch_bytes=fetch_bytes
+    )
     return out

@@ -27,7 +27,7 @@ import numpy as np
 from .. import namer
 from ..engine import drainclock
 from ..engine import types as T
-from ..observability import start_span
+from ..observability import metrics, start_span
 from ..ruletable.check import EvalContext, build_request_messages, check_input
 from ..ruletable.table import RuleTable
 from . import compilestats
@@ -821,12 +821,14 @@ class _DeviceHandle:
     already started) plus everything needed to slice results back apart.
     ``ready`` short-circuits degenerate batches that never touch the device."""
 
-    __slots__ = ("ready", "out", "BA", "B", "K", "BA_pad", "B_pad", "col_map", "leased")
+    __slots__ = ("ready", "out", "BA", "B", "K", "BA_pad", "B_pad", "col_map", "leased", "put_bytes", "fetch_bytes")
 
     def __init__(self):
         self.ready = None
         self.out = None
         self.leased = ()
+        self.put_bytes = 0    # what the jitted call was handed: the eight stacked arrays
+        self.fetch_bytes = 0  # the one result vector, once _device_finalize has fetched it
 
 
 def _jit_run(compiler, K: int, J: int, D: int, variant, lay: _StackLayout, BA_pad: int):
@@ -1022,7 +1024,8 @@ class _LayoutPreloader:
             fn = _jit_run(ev.lowered.compiler, K, J, D, variant, lay, BA_pad)
             t0 = time.perf_counter()
             with ev._device_scope(), compilestats.cache_events() as seen:
-                fn(**zeros).block_until_ready()
+                out = fn(**zeros)
+                out.block_until_ready()
             dt = time.perf_counter() - t0
         except Exception:  # noqa: BLE001  (a stale or foreign entry: skip it)
             if self._gen != gen:
@@ -1038,7 +1041,8 @@ class _LayoutPreloader:
         # XLA ran or loaded whatever became of the function: since boot, like
         # a flight's own compile, but a deliberate walk is not a storm
         compilestats.stats().record_compile(
-            f"B{B_pad}xBA{BA_pad}", dt, source=source, trace_key=key, storm=False
+            f"B{B_pad}xBA{BA_pad}", dt, source=source, trace_key=key, storm=False,
+            put_bytes=sum(a.nbytes for a in zeros.values()), fetch_bytes=out.nbytes,
         )
         if stopped:
             return None
@@ -1097,6 +1101,7 @@ def _device_dispatch(
         batch, batch.columns, cand_cond_c, cand_drcond_c, B_pad, BA_pad
     )
     key = (B_pad, BA_pad, K, J, D, variant_key, layout.sig)
+    h.put_bytes = sum(a.nbytes for a in stacked.values())
     fn = jit_cache.get(key)
     if fn is None:
         fn = _jit_run(compiler, K, J, D, variant_key, layout, BA_pad)
@@ -1113,11 +1118,13 @@ def _device_dispatch(
             f"B{B_pad}xBA{BA_pad}", fn, stacked, trace_key=key
         )
         preloader.met(key, layout, stacked)
-        drainclock.to(drainclock.DISPATCH)
+        # the call was the compile's: of ``dispatch`` this flight has the copy alone
+        drainclock.to(drainclock.DISPATCH, drainclock.DISPATCH_COPY)
     else:
         compilestats.stats().record_hit()
-        drainclock.to(drainclock.DISPATCH)
+        drainclock.to(drainclock.DISPATCH, drainclock.DISPATCH_CALL)
         out = fn(**stacked)
+        drainclock.part(drainclock.DISPATCH_COPY)
     out.copy_to_host_async()  # start the (single) fetch immediately
     h.out = out
     h.BA, h.B, h.K = BA, B, K
@@ -1136,6 +1143,7 @@ def _device_finalize(h: _DeviceHandle):
     drainclock.to(drainclock.FETCH)
     flat = np.asarray(h.out)  # ONE device->host fetch: the wait for the device is here
     drainclock.to(drainclock.ASSEMBLE)
+    h.fetch_bytes = flat.nbytes
     if h.leased:
         # the output is materialized, so every transfer that read the staging
         # buffers has completed — recycle them for the next batch
@@ -1155,15 +1163,15 @@ def _device_finalize(h: _DeviceHandle):
 class CheckTicket:
     """An in-flight batch submitted via TpuEvaluator.submit."""
 
-    __slots__ = ("parts", "ready", "params", "pack_s", "occupancy", "layout_key", "padded_rows")
+    __slots__ = ("parts", "ready", "params", "occupancy", "layout_key", "padded_rows")
 
     def __init__(self):
         self.parts = None  # [(PackedBatch, _DeviceHandle)]
         self.ready = None
         self.params = None
-        # device-economics attribution read by the serving batcher: host
-        # pack time, real/padded row ratio, and the padded layout shape
-        self.pack_s = 0.0
+        # device-economics attribution read by the serving batcher: real/padded
+        # row ratio and the padded layout shape (a flight's pack seconds are the
+        # drain clock's: engine/drainclock.py)
         self.occupancy = None  # None = no packed device layout (sync path)
         self.layout_key = None
         self.padded_rows = None
@@ -1212,6 +1220,17 @@ class TpuEvaluator:
 
             _enable_jit_cache()  # persistent XLA cache: restart = load, not recompile
         self.stats = {"device_inputs": 0, "oracle_inputs": 0, "trivial_inputs": 0}
+        transfer = metrics().histogram_vec(
+            "cerbos_tpu_batch_transfer_bytes",
+            "bytes of one device-served call, observed as its result is collected: dir=put, the eight stacked "
+            "arrays the jitted call was handed (sum of nbytes, padding included); dir=fetch, the one result "
+            "vector; by shard",
+            label=("dir", "shard"),
+            buckets=[4096, 16384, 65536, 262144, 1048576, 4194304, 16777216, 67108864],
+        )
+        shard_label = str(shard_id) if shard_id is not None else "0"
+        self._m_put_bytes = transfer.labels(("put", shard_label))
+        self._m_fetch_bytes = transfer.labels(("fetch", shard_label))
         self._jit_cache: dict = {}
         self._preloader = _LayoutPreloader(self)
         self._dr_table_cache: dict = {}
@@ -1332,10 +1351,8 @@ class TpuEvaluator:
         t.parts = []
         with start_span("batch.pack", inputs=len(inputs), chunks=len(chunks)), self._device_scope():
             for ch in chunks:
-                drainclock.to(drainclock.PACK)
-                p0 = time.perf_counter()
+                drainclock.to(drainclock.PACK, drainclock.PACK_PLAN)
                 batch = self.packer.pack(ch, params)
-                t.pack_s += time.perf_counter() - p0
                 t.parts.append(
                     (batch, _device_dispatch(self.lowered, batch, self._jit_cache, self._preloader))
                 )
@@ -1355,7 +1372,12 @@ class TpuEvaluator:
             return ticket.ready
         out: list[T.CheckOutput] = []
         for batch, handle in ticket.parts:
-            out.extend(self._assemble_batch(batch, *_device_finalize(handle), ticket.params))
+            res = _device_finalize(handle)
+            if handle.fetch_bytes:
+                # once per device-served call: what it put and what it fetched
+                self._m_put_bytes.observe(handle.put_bytes)
+                self._m_fetch_bytes.observe(handle.fetch_bytes)
+            out.extend(self._assemble_batch(batch, *res, ticket.params))
         ticket.ready = out
         ticket.parts = None
         return out

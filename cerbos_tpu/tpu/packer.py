@@ -18,6 +18,7 @@ from typing import Any, Optional
 import numpy as np
 
 from .. import namer
+from ..engine import drainclock
 from ..engine import types as T
 from ..ruletable.rows import KIND_PRINCIPAL, KIND_RESOURCE, RuleRow
 from ..ruletable.check import EvalContext, build_request_messages
@@ -272,6 +273,11 @@ class Packer:
     # -- packing -----------------------------------------------------------
 
     def pack(self, inputs: list[T.CheckInput], params: T.EvalParams) -> PackedBatch:
+        """On the batcher's drain thread a flight's ``pack`` state is entered
+        with its first part, ``pack_plan`` (``TpuEvaluator.submit``), and each
+        ``drainclock.part`` below moves the state's second cursor on: the six
+        parts tile ``pack``. On any other thread, and inside a state entered
+        without a part, the stamps do nothing."""
         plans: list[InputPlan] = []
         # everything except the input reference depends only on the REQUEST
         # SHAPE — (principal id/scope/version, resource kind/scope/version,
@@ -354,6 +360,7 @@ class Packer:
             sp_uids.append(sp_uid)
             ba_count += n
 
+        drainclock.part(drainclock.PACK_GATHER)
         BA = ba_count
         # the depth axis buckets to the batch's real max scope-chain length
         # (pow2 so jit traces are reused), not the configured cap — shallow
@@ -390,6 +397,7 @@ class Packer:
         else:
             scope_sp = np.zeros((0, 2, D), dtype=np.int8)
 
+        drainclock.part(drainclock.PACK_SCALARS)
         columns = self._encode_columns(plans, params)
         return PackedBatch(
             plans=plans,
@@ -734,9 +742,7 @@ class Packer:
         active = [(bi, plan) for bi, plan in enumerate(plans) if not (plan.trivial or plan.oracle)]
         if native is not None and hasattr(native, "encode_column"):
             self._encode_columns_native(cb, plans, active, paths, native)
-            self._encode_list_columns(cb, plans, active)
-            self._encode_ts_columns(cb, plans, active, params)
-            self._encode_preds(cb, plans, active, params)
+            self._encode_rest(cb, plans, active, params)
             return cb
         for p in paths:
             t = np.zeros(B, dtype=np.int8)
@@ -788,10 +794,19 @@ class Packer:
                 nn[idx] = np.frombuffer(nan_b, dtype=np.uint8).astype(bool)
             cb.tags[p], cb.his[p], cb.los[p], cb.sids[p], cb.nans[p] = t, h, l, s, nn
 
-        self._encode_list_columns(cb, plans, active)
-        self._encode_ts_columns(cb, plans, active, params)
-        self._encode_preds(cb, plans, active, params)
+        self._encode_rest(cb, plans, active, params)
         return cb
+
+    def _encode_rest(self, cb: ColumnBatch, plans, active, params) -> None:
+        """What follows the scalar columns, with or without the native
+        encoder: one part of the drain clock each (``pack_preds`` runs on to
+        the end of ``pack``)."""
+        drainclock.part(drainclock.PACK_LISTS)
+        self._encode_list_columns(cb, plans, active)
+        drainclock.part(drainclock.PACK_TS)
+        self._encode_ts_columns(cb, plans, active, params)
+        drainclock.part(drainclock.PACK_PREDS)
+        self._encode_preds(cb, plans, active, params)
 
     def _encode_ts_columns(self, cb: ColumnBatch, plans, active, params) -> None:
         """Parsed-timestamp key columns for paths used inside timestamp(...)

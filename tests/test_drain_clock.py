@@ -2,7 +2,9 @@
 
 One cursor on one thread: the states tile the thread's life; a flight's new
 stages (stack, dispatch, oracle, fetch, assemble, post) are observed once per
-flight and lie inside the stages they split (submit, collect); the window
+flight and lie inside the stages they split (submit, collect); ``pack`` and
+``dispatch`` are tiled by their parts on a second cursor (PR 38), and a
+device-served call's bytes are what it stacked and what it fetched; the window
 wait is per flight; a compile is blamed on the first new dimension of its jit
 key; the profiler runs with the Python tracer off and the program's regions
 land on its trace with the clock readings at both ends. CPU backend, no
@@ -17,6 +19,7 @@ import sys
 import threading
 import time
 
+import numpy as np
 import pytest
 from flightgate import EchoPlanner
 
@@ -38,6 +41,9 @@ STAGE = "cerbos_tpu_batch_stage_seconds"
 WINDOW = "cerbos_tpu_batcher_window_wait_seconds"
 NOVEL = "cerbos_tpu_xla_compile_novel_total"
 NEW_STAGES = ("stack", "dispatch", "oracle", "fetch", "assemble", "post")
+PACK_PARTS, DISPATCH_PARTS = dc.PARTS[dc.PACK], dc.PARTS[dc.DISPATCH]
+TRANSFER = "cerbos_tpu_batch_transfer_bytes"
+ROUNDING = 0.5001e-6  # a flight record's timings are rounded to a microsecond each
 
 
 def spin(seconds: float) -> None:
@@ -50,7 +56,6 @@ def spin(seconds: float) -> None:
 class Ticket:
     def __init__(self, inputs):
         self.inputs = inputs
-        self.pack_s = 0.0
         self.occupancy = 1.0
         self.padded_rows = None
         self.layout_key = "B16xBA16"
@@ -71,14 +76,17 @@ class FakeStreamingEvaluator:
             self.gate.wait(timeout=10)
             self.gate = None
         t = Ticket(inputs)
-        dc.to(dc.PACK)
-        p0 = time.perf_counter()
-        spin(0.0004)
-        t.pack_s = time.perf_counter() - p0
+        dc.to(dc.PACK, dc.PACK_PLAN)
+        spin(0.00015)
+        for part in PACK_PARTS[1:]:
+            dc.part(part)
+            spin(0.00005)
         dc.to(dc.STACK)
         spin(0.0003)
-        dc.to(dc.DISPATCH)
-        spin(0.0002)
+        dc.to(dc.DISPATCH, dc.DISPATCH_CALL)
+        spin(0.00015)
+        dc.part(dc.DISPATCH_COPY)
+        spin(0.00005)
         return t
 
     def collect(self, ticket):
@@ -189,6 +197,66 @@ def test_a_flight_without_a_streaming_evaluator_is_booked_as_oracle(shard):
 
 def flights_of(shard: int) -> list[dict]:
     return [r for r in flight.recorder().dump()["batches"] if r["shard"] == shard]
+
+
+def test_the_parts_tile_pack_and_dispatch_of_the_same_flight_and_are_observed_once_each(shard):
+    before = scrape()
+    b = BatchingEvaluator(FakeStreamingEvaluator(), max_wait_ms=0.5, shard_id=shard)
+    try:
+        fly(b, 20)
+    finally:
+        b.close()
+    d = prom.delta(before, scrape())
+    records = flights_of(shard)
+    assert len(records) == 20
+    for rec in records:
+        t = rec["timings"]
+        # booked from the same readings of the cursor: equal, but that the record rounds each to a microsecond
+        assert sum(t[p] for p in PACK_PARTS) == pytest.approx(t["pack"], abs=ROUNDING * (len(PACK_PARTS) + 1))
+        assert sum(t[p] for p in DISPATCH_PARTS) == pytest.approx(t["dispatch"], abs=ROUNDING * (len(DISPATCH_PARTS) + 1))
+        assert t["pack_plan"] >= 0.00015 and t["dispatch_call"] >= 0.00015
+        assert all(t[p] >= 0.00005 for p in PACK_PARTS[1:] + DISPATCH_PARTS[1:])
+
+    def seconds(stage):
+        return prom.total(d, STAGE + "_sum", stage=stage, shard=str(shard))
+
+    for stage in PACK_PARTS + DISPATCH_PARTS:
+        assert prom.total(d, STAGE + "_count", stage=stage, shard=str(shard)) == 20, stage
+    # the histograms take the unrounded seconds: over 20 flights the parts ARE the whole
+    assert sum(seconds(p) for p in PACK_PARTS) == pytest.approx(seconds("pack"), abs=1e-9)
+    assert sum(seconds(p) for p in DISPATCH_PARTS) == pytest.approx(seconds("dispatch"), abs=1e-9)
+    # the states read what they read before there were parts: the thread's pack seconds are the flights' own
+    assert prom.total(d, THREAD, state="pack", clock="wall", shard=str(shard)) == pytest.approx(seconds("pack"))
+    assert prom.total(d, THREAD, state="dispatch", clock="wall", shard=str(shard)) == pytest.approx(seconds("dispatch"))
+    assert not [k for k in d if k[0] == THREAD and dict(k[1])["state"] not in dc.STATES + (dc.ALL,)]
+
+
+def test_a_part_is_nothing_in_a_state_entered_without_one_and_an_oracle_flight_has_none(shard):
+    clock = dc.install(str(shard))
+    try:
+        clock.to(dc.ORACLE)  # the numpy backend and a mesh pack inside this state
+        for part in PACK_PARTS:
+            dc.part(part)
+        clock.to(dc.PACK, dc.PACK_PLAN)
+        dc.part(dc.PACK_GATHER)
+        clock.to(dc.OTHER)
+        dc.part(dc.PACK_PREDS)  # after the state was left: nothing
+        lap = clock.take_lap()
+    finally:
+        del dc._tls.clock
+    assert set(lap) == {dc.OTHER, dc.ORACLE, dc.PACK, dc.PACK_PLAN, dc.PACK_GATHER}
+    assert lap[dc.PACK_PLAN] + lap[dc.PACK_GATHER] == pytest.approx(lap[dc.PACK], abs=1e-9)
+    # a flight with no streaming evaluator: every part is observed, as nothing
+    before = scrape()
+    b = BatchingEvaluator(SyncEvaluator(), max_wait_ms=0.5, shard_id=shard)
+    try:
+        fly(b, 5, inputs=1)
+    finally:
+        b.close()
+    d = prom.delta(before, scrape())
+    for stage in PACK_PARTS + DISPATCH_PARTS:
+        assert prom.total(d, STAGE + "_count", stage=stage, shard=str(shard)) == 5
+        assert prom.total(d, STAGE + "_sum", stage=stage, shard=str(shard)) == 0
 
 
 def test_window_wait_is_nothing_for_a_lone_check(shard):
@@ -335,9 +403,29 @@ def capture(tmp_path_factory):
 
     from cerbos_tpu.tpu import TpuEvaluator, jitcache
 
+    from cerbos_tpu.tpu import evaluator as evaluator_mod
+
     jitcache.open_device()
-    ev = TpuEvaluator(table(), use_jax=True, min_device_batch=4)
+    ev = TpuEvaluator(table(), use_jax=True, min_device_batch=4, shard_id=77)
     b = BatchingEvaluator(ev, max_wait_ms=1.0, shard_id=77)
+    stacked_bytes, fetched_bytes = [], []
+    pad_stack, finalize = evaluator_mod._pad_stack, evaluator_mod._device_finalize
+
+    def spy_pad_stack(*a, **kw):
+        out = pad_stack(*a, **kw)
+        stacked_bytes.append(sum(v.nbytes for v in out[0].values()))
+        return out
+
+    def spy_finalize(h):
+        res = finalize(h)
+        if h.out is not None:
+            fetched_bytes.append(np.asarray(h.out).nbytes)
+        return res
+
+    mp = pytest.MonkeyPatch()
+    mp.setattr(evaluator_mod, "_pad_stack", spy_pad_stack)
+    mp.setattr(evaluator_mod, "_device_finalize", spy_finalize)
+    before = scrape()
     base = tmp_path_factory.mktemp("profiles")
     profiler.configure(enabled=True, dir=str(base))
     try:
@@ -357,6 +445,7 @@ def capture(tmp_path_factory):
         thread.join(timeout=60)
         assert not thread.is_alive()
     finally:
+        mp.undo()
         profiler.configure()
         b.close()
     path = trace_reduce.find_xplane(box["path"])
@@ -364,7 +453,8 @@ def capture(tmp_path_factory):
     with open(path, "rb") as f:
         planes = trace_reduce.read_planes(f.read())
     records = [r for r in flights_of(77) if r["inputs"] == 8]
-    return {"reply": box, "planes": planes, "bytes": os.path.getsize(path), "flights": records}
+    return {"reply": box, "planes": planes, "bytes": os.path.getsize(path), "flights": records,
+            "stacked_bytes": stacked_bytes, "fetched_bytes": fetched_bytes, "moved": prom.delta(before, scrape())}
 
 
 def host_event_names(planes) -> dict[str, int]:
@@ -379,13 +469,39 @@ def host_event_names(planes) -> dict[str, int]:
 
 def test_a_real_capture_holds_the_programs_regions_and_no_python_frame(capture):
     names = host_event_names(capture["planes"])
-    for want in ("batch.pack", "batch.stack", "batch.dispatch", "batch.fetch", "batch.assemble", "batch.post",
-                 "batch.oracle", "batcher.idle", "batcher.window", "batch.submit", "batch.collect", "request.settle"):
+    # ``batch.pack`` is still there as the span's region; the STATES pack and dispatch show as their parts
+    for want in ("batch.pack", "batch.stack", "batch.fetch", "batch.assemble", "batch.post",
+                 "batch.oracle", "batcher.idle", "batcher.window", "batch.submit", "batch.collect", "request.settle"
+                 ) + tuple("batch." + p for p in PACK_PARTS + DISPATCH_PARTS):
         assert names.get(want, 0) >= 6, (want, names)
+    assert "batch.dispatch" not in names  # a part's region takes the state's place, it does not nest in it
     assert names["cerbos.clock"] == 2
     frames = [n for n in names if ".py" in n or n.startswith("$")]
     assert not frames, frames
     assert capture["bytes"] < 2 << 20  # six flights: hundreds of kilobytes, not the Python tracer's megabytes
+
+
+def test_the_real_packers_parts_tile_pack_and_the_real_dispatchs_tile_dispatch(capture):
+    assert len(capture["flights"]) == 7
+    for rec in capture["flights"]:
+        t = rec["timings"]
+        assert sum(t[p] for p in PACK_PARTS) == pytest.approx(t["pack"], abs=ROUNDING * (len(PACK_PARTS) + 1))
+        assert sum(t[p] for p in DISPATCH_PARTS) == pytest.approx(t["dispatch"], abs=ROUNDING * (len(DISPATCH_PARTS) + 1))
+        assert all(t[p] > 0 for p in PACK_PARTS) and t["dispatch_copy"] > 0
+    # the first flight's call was its layout's compile (state ``compile``): the copy is all its dispatch had
+    first, rest = capture["flights"][0], capture["flights"][1:]
+    assert first["timings"]["dispatch_call"] == 0.0 and all(r["timings"]["dispatch_call"] > 0 for r in rest)
+
+
+def test_a_flights_put_and_fetch_bytes_are_what_it_stacked_and_what_it_fetched(capture):
+    d, stacked, fetched = capture["moved"], capture["stacked_bytes"], capture["fetched_bytes"]
+    assert len(stacked) == len(fetched) == 7 and min(stacked) > 0 and min(fetched) > 0
+    for direction, want in (("put", stacked), ("fetch", fetched)):
+        assert prom.total(d, TRANSFER + "_count", dir=direction, shard="77") == 7
+        assert prom.total(d, TRANSFER + "_sum", dir=direction, shard="77") == sum(want)
+    # the layout's compile event says the same of its layout
+    event = [e for e in flight.recorder().dump()["events"] if e["kind"] == "xla_compile" and "put_bytes" in e][-1]
+    assert event["put_bytes"] in stacked and event["fetch_bytes"] in fetched
 
 
 def test_the_captures_reply_places_flights_on_the_trace(capture):
@@ -420,12 +536,13 @@ def test_region_with_no_capture_open_imports_no_jax_and_emits_nothing():
     assert out.returncode == 0 and out.stdout.strip() == "ok", out.stderr
 
 
-def test_to_is_nothing_on_a_thread_without_a_clock():
+def test_to_and_part_are_nothing_on_a_thread_without_a_clock():
     box = {}
 
     def other_thread():
         box["clock"] = getattr(dc._tls, "clock", None)
-        dc.to(dc.PACK)
+        dc.to(dc.PACK, dc.PACK_PLAN)
+        dc.part(dc.PACK_GATHER)
         box["done"] = True
 
     t = threading.Thread(target=other_thread)
@@ -456,7 +573,8 @@ def test_span_export_builds_nothing_when_debug_logging_is_off():
 
 
 def test_one_flights_worth_of_the_clock_costs_microseconds(shard):
-    """12 ``to()``, 2 ``take_lap`` and 7 histogram observes: what a flight
+    """12 ``to()``, 6 ``part()``, 2 ``take_lap`` and 15 histogram observes (8 of
+    them the parts', PR 38): what a flight
     pays with no capture open (the CPU clock, read ten times a second, apart). Printed for PERF.md (``pytest -s``); the limit
     is loose, a tenth of the cheapest stage."""
     from cerbos_tpu.engine.batcher import _ShardStageView
@@ -471,11 +589,18 @@ def test_one_flights_worth_of_the_clock_costs_microseconds(shard):
                 dc.FETCH, dc.ASSEMBLE, dc.SETTLE, dc.POST)
 
         def one_flight():
-            for state in walk[:8]:
+            for state in walk[:4]:
                 clock.to(state)
+            clock.to(dc.PACK, dc.PACK_PLAN)
+            for part in PACK_PARTS[1:]:
+                dc.part(part)
+            clock.to(dc.STACK)
+            clock.to(dc.DISPATCH, dc.DISPATCH_CALL)
+            dc.part(dc.DISPATCH_COPY)
+            clock.to(dc.OTHER)
             lap = clock.take_lap()
             b.m_window_wait.observe(0.002)
-            for stage in ("stack", "dispatch", "oracle"):
+            for stage in ("stack", "dispatch", "oracle") + PACK_PARTS + DISPATCH_PARTS:
                 stages.observe(stage, lap.get(stage, 0.0))
             for state in walk[8:]:
                 dc.to(state)
