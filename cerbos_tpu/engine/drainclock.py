@@ -69,11 +69,11 @@ OTHER = "other"        # whatever is left: locks, queue pops, metric updates, pl
 # ``cerbos_tpu_batch_stage_seconds{stage=<part>}``, carried in the flight record's ``timings``
 PACK_PLAN = "pack_plan"        # the per-input loop: shape key, shape-memo hit (or the shape's build), InputPlan
 PACK_GATHER = "pack_gather"    # K/J/D, the candidate blocks' stack and its six gathers, the scope-permission rows
-PACK_SCALARS = "pack_scalars"  # the scalar attribute columns: the native encoder, or the per-path loop without it
+PACK_SCALARS = "pack_scalars"  # the scalar attribute columns: the native encoders and the one store, or the per-path loop without them
 PACK_LISTS = "pack_lists"      # the list columns
 PACK_TS = "pack_ts"            # the timestamp columns and the batch's now()
 PACK_PREDS = "pack_preds"      # the host-evaluated predicates, then the PackedBatch itself
-DISPATCH_CALL = "dispatch_call"  # fn(**stacked): JAX's handling of a keyword call, the eight puts, the enqueue
+DISPATCH_CALL = "dispatch_call"  # fn(**stacked): JAX's handling of a keyword call, the one put, the enqueue
 DISPATCH_COPY = "dispatch_copy"  # copy_to_host_async() and the handle
 PARTS = {
     PACK: (PACK_PLAN, PACK_GATHER, PACK_SCALARS, PACK_LISTS, PACK_TS, PACK_PREDS),

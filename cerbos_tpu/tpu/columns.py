@@ -168,6 +168,11 @@ class ColumnBatch:
     # per batch without retriggering jit tracing)
     now_hi: np.ndarray = field(default_factory=lambda: np.zeros((), dtype=np.int32))
     now_lo: np.ndarray = field(default_factory=lambda: np.zeros((), dtype=np.int32))
+    # where the packer stored the scalar columns as whole matrices:
+    # (paths, (3, P, B) int32 of his/los/sids, (P, B) int8 tags, (P, B) bool
+    # nans), row i the column of paths[i]; the five dictionaries above then
+    # hold row VIEWS of them. None where the columns were made one by one.
+    scalars: Optional[tuple] = None
 
 
 def resolve_path(input_obj: Any, path: tuple[str, ...]) -> tuple[bool, Any]:

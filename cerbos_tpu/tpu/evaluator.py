@@ -18,6 +18,7 @@ from __future__ import annotations
 import atexit
 import contextlib
 import logging
+import math
 import threading
 import time
 from typing import Any, Optional
@@ -25,6 +26,7 @@ from typing import Any, Optional
 import numpy as np
 
 from .. import namer
+from .. import native as native_mod
 from ..engine import drainclock
 from ..engine import types as T
 from ..observability import metrics, start_span
@@ -285,7 +287,7 @@ class _StackLayout:
     when the path orders, list widths and presence flags line up."""
 
     __slots__ = ("paths", "ts_paths", "list_paths", "list_widths", "pred_ids",
-                 "D", "has_now", "sig")
+                 "D", "has_now", "sig", "cuts")
 
     def __init__(self, paths, ts_paths, list_paths, list_widths, pred_ids, D, has_now):
         self.paths = paths
@@ -296,17 +298,90 @@ class _StackLayout:
         self.D = D
         self.has_now = has_now
         self.sig = (paths, ts_paths, list_paths, list_widths, pred_ids, D, has_now)
+        self.cuts: dict = {}  # (B_pad, BA_pad, K, J) -> _TransferCut: see _TransferCut.of
 
 
-def _unstack_padded(xp, lay: _StackLayout, kw: dict) -> dict:
+class _TransferCut:
+    """Where the sections of a flight's ONE staging buffer lie.
+
+    The buffer is a vector of int32 words, and its length and its cut follow
+    from the jit key alone (``B_pad``, ``BA_pad``, ``K``, ``J`` and the
+    layout), so the flight that fills it, the trace that cuts it and the
+    preloader that builds a zero one from a manifest entry agree with no word
+    passed between them. The int32 sections come first and are plain slices,
+    on the host and in the trace; the one-byte sections follow, each a whole
+    number of words because ``B_pad`` is (a bucket is a power of two from 16
+    up), so every host view is aligned and nothing lies between sections."""
+
+    __slots__ = ("lay", "B_pad", "BA_pad", "K", "J", "sections", "words")
+
+    def __init__(self, lay: _StackLayout, B_pad: int, BA_pad: int, K: int, J: int):
+        if B_pad % 4:
+            raise ValueError(f"B_pad {B_pad} is not a whole number of words")
+        self.lay = lay
+        self.B_pad, self.BA_pad, self.K, self.J = B_pad, BA_pad, K, J
+        P, Tn, L, Q = len(lay.paths), len(lay.ts_paths), len(lay.list_paths), len(lay.pred_ids)
+        at = 0
+        self.sections = {}
+        for name, dtype, shape in (
+            ("i32_cols", np.int32, (3 * P + 2 * Tn, B_pad)),
+            ("lists", np.int32, (L, B_pad, max(lay.list_widths, default=1))),
+            ("cand_i32", np.int32, (2, BA_pad, K, J)),
+            ("ba_input", np.int32, (BA_pad,)),
+            ("now", np.int32, (2,)),
+            ("i8_cols", np.int8, (P + Tn + L + 2 * lay.D, B_pad)),
+            ("cand_i8", np.int8, (4, BA_pad, K, J)),
+            ("bool_cols", np.bool_, (P + 2 * Q, B_pad)),
+        ):
+            dtype = np.dtype(dtype)
+            n = math.prod(shape) * dtype.itemsize // 4
+            self.sections[name] = (at, at + n, dtype, shape)
+            at += n
+        self.words = at
+
+    @classmethod
+    def of(cls, lay: _StackLayout, B_pad: int, BA_pad: int, K: int, J: int) -> "_TransferCut":
+        """The layout's cut for one shape bucket, made once (layouts are
+        memoized, and a flight meets a handful of buckets)."""
+        key = (B_pad, BA_pad, K, J)
+        cut = lay.cuts.get(key)
+        if cut is None:
+            cut = lay.cuts[key] = cls(lay, *key)
+        return cut
+
+    @property
+    def sig(self):
+        return self.lay.sig
+
+    def split(self, xp, buf) -> dict:
+        """The typed sections of ``buf``: host views to fill with ``xp`` numpy,
+        and inside the trace static slices and ``lax.bitcast_convert_type``
+        (free: XLA fuses them into the consumers, as it does the row slices
+        that follow)."""
+        if xp is np:
+            return {name: buf[lo:hi].view(dtype).reshape(shape) for name, (lo, hi, dtype, shape) in self.sections.items()}
+        from jax import lax
+
+        out = {}
+        for name, (lo, hi, dtype, shape) in self.sections.items():
+            words = buf[lo:hi]
+            if dtype.itemsize == 1 and hi > lo:
+                words = lax.bitcast_convert_type(words, xp.int8)  # (words, 4) int8, low byte first
+            out[name] = words.reshape(shape).astype(dtype)
+        return out
+
+
+def _unstack_padded(xp, cut: _TransferCut, kw: dict) -> dict:
     """Inverse of _pad_stack, executed INSIDE the traced graph (slices of
     traced arrays are free — XLA fuses them into the consumers)."""
-    i32 = kw["i32_cols"]
-    i8 = kw["i8_cols"]
-    bools = kw["bool_cols"]
-    lists = kw["lists"]
-    cand_i32 = kw["cand_i32"]
-    cand_i8 = kw["cand_i8"]
+    lay = cut.lay
+    sec = cut.split(xp, kw["buf"])
+    i32 = sec["i32_cols"]
+    i8 = sec["i8_cols"]
+    bools = sec["bool_cols"]
+    lists = sec["lists"]
+    cand_i32 = sec["cand_i32"]
+    cand_i8 = sec["cand_i8"]
     P = len(lay.paths)
     T = len(lay.ts_paths)
     L = len(lay.list_paths)
@@ -327,12 +402,12 @@ def _unstack_padded(xp, lay: _StackLayout, kw: dict) -> dict:
     list_sids = {
         p: lists[i][:, : lay.list_widths[i]] for i, p in enumerate(lay.list_paths)
     }
-    now_hi = kw["now"][0] if lay.has_now else None
-    now_lo = kw["now"][1] if lay.has_now else None
+    now_hi = sec["now"][0] if lay.has_now else None
+    now_lo = sec["now"][1] if lay.has_now else None
     return dict(
         tags=tags, his=his, los=los, sids=sids, nans=nans,
         pred_vals=pred_vals, pred_errs=pred_errs,
-        ba_input=kw["ba_input"],
+        ba_input=sec["ba_input"],
         cand_cond=cand_i32[0], cand_drcond=cand_i32[1],
         cand_effect=cand_i8[0], cand_pt=cand_i8[1], cand_depth=cand_i8[2],
         cand_valid=cand_i8[3].astype(bool),
@@ -481,8 +556,6 @@ def _host_or_mesh_eval(lt: LoweredTable, batch: PackedBatch, mesh, jit_cache: di
     cols = batch.columns
 
     if mesh is None:
-        from .. import native as native_mod
-
         native = native_mod.get()
         if native is not None and hasattr(native, "resolve_effects"):
             # fused C lattice: sat via the template groups as usual, then one
@@ -611,13 +684,12 @@ def _pad_arrays(batch: PackedBatch, cols, cand_cond_c, cand_drcond_c, B_pad: int
 class _BufferPool:
     """Bounded free-lists of host staging buffers keyed by (shape, dtype).
 
-    The padded transfer matrices built per device batch dominate the host
-    dispatch path's allocations; batches in the same shape bucket need
-    byte-identical buffers, so recycle them instead of reallocating. A
-    buffer is leased at dispatch and released at finalize — by then the
-    single output fetch has completed, so every host->device transfer that
-    read the buffer is done (and outputs never alias inputs: nothing is
-    donated)."""
+    A device batch is laid into ONE such buffer (:func:`_pad_stack`), whose
+    length follows from the batch's jit key, so batches of one layout need
+    byte-identical buffers: recycle them instead of reallocating. A buffer
+    is leased at dispatch and released at finalize — by then the single
+    output fetch has completed, so the host->device transfer that read the
+    buffer is done (and outputs never alias inputs: nothing is donated)."""
 
     MAX_FREE = 4  # per key: bounds idle memory at ~one in-flight window
 
@@ -694,18 +766,29 @@ def _fill_rows(dst: np.ndarray, rows: list, native) -> None:
         dst[i, nv:] = 0
 
 
+def _fill_block(dst: np.ndarray, block: np.ndarray) -> None:
+    """Copy a whole ``(..., P, B)`` matrix into the leading columns of dst's
+    ``(..., P, B_pad)`` rows and zero the padded tails: what :func:`_fill_rows`
+    does for ``P`` rows one by one, in two stores."""
+    B = block.shape[-1]
+    dst[..., :B] = block
+    if B < dst.shape[-1]:
+        dst[..., B:] = 0
+
+
 def _pad_stack(batch: PackedBatch, cols, cand_cond_c, cand_drcond_c, B_pad: int, BA_pad: int):
     """The transfer format of the single-device path: every column padded to
-    its shape bucket (the fills of _pad_arrays) and stacked into a handful of
-    typed matrices, so a device dispatch costs O(1) host->device transfers
-    (see _device_dispatch); _unstack_padded reads it back inside the trace.
+    its shape bucket (the fills of _pad_arrays) and laid, section by section,
+    into ONE pooled staging buffer, so a device dispatch costs one
+    host->device transfer (see _device_dispatch); _unstack_padded cuts the
+    sections back out inside the trace.
 
-    Each column's bytes are written exactly once, straight into pooled
-    padded matrices. Returns (stacked, layout, leased); hand ``leased`` back
-    to ``_buffer_pool`` once the device is done with the batch (see
-    _device_finalize)."""
-    from .. import native as native_mod
-
+    Each column's bytes are written exactly once, straight into typed views
+    of the buffer (:class:`_TransferCut`). Where the packer kept its scalar
+    matrices and their rows are in the layout's path order, the five scalar
+    families are five block copies; else the rows go one by one. Returns
+    (stacked, cut, leased); hand ``leased`` back to ``_buffer_pool`` once the
+    device is done with the batch (see _device_finalize)."""
     native = native_mod.get()
     if native is not None and not hasattr(native, "stack_pad_rows"):
         native = None
@@ -713,80 +796,69 @@ def _pad_stack(batch: PackedBatch, cols, cand_cond_c, cand_drcond_c, B_pad: int,
     D = batch.scope_sp.shape[2]
     B = batch.scope_sp.shape[0]
     lay = _marshal_layout(cols, D, has_now)
-    P, Tn, L, Q = len(lay.paths), len(lay.ts_paths), len(lay.list_paths), len(lay.pred_ids)
-    leased: list[np.ndarray] = []
+    P, Tn, L = len(lay.paths), len(lay.ts_paths), len(lay.list_paths)
+    cut = _TransferCut.of(lay, B_pad, BA_pad, *cand_cond_c.shape[1:])
+    buf = _buffer_pool.lease((cut.words,), np.int32)
+    sec = cut.split(np, buf)
+    scalars = cols.scalars if cols.scalars is not None and cols.scalars[0] == lay.paths else None
 
-    def lease(shape, dtype):
-        a = _buffer_pool.lease(shape, dtype)
-        leased.append(a)
-        return a
-
-    n_i32 = 3 * P + 2 * Tn
-    if n_i32:
-        i32_cols = lease((n_i32, B_pad), np.int32)
-        _fill_rows(
-            i32_cols,
+    i32_cols = sec["i32_cols"]
+    rows = [cols.ts_his[p] for p in lay.ts_paths] + [cols.ts_los[p] for p in lay.ts_paths]
+    if scalars is not None:
+        _fill_block(i32_cols[: 3 * P].reshape(3, P, B_pad), scalars[1])
+        i32_cols = i32_cols[3 * P :]
+    else:
+        rows = (
             [cols.his[p] for p in lay.paths]
             + [cols.los[p] for p in lay.paths]
             + [cols.sids[p] for p in lay.paths]
-            + [cols.ts_his[p] for p in lay.ts_paths]
-            + [cols.ts_los[p] for p in lay.ts_paths],
-            native,
+            + rows
         )
-    else:
-        i32_cols = np.zeros((0, B_pad), dtype=np.int32)
+    if rows:
+        _fill_rows(i32_cols, rows, native)
 
-    n_i8 = P + Tn + L + 2 * D
-    if n_i8:
-        i8_cols = lease((n_i8, B_pad), np.int8)
-        if P + Tn + L:
-            _fill_rows(
-                i8_cols[: P + Tn + L],
-                [cols.tags[p] for p in lay.paths]
-                + [cols.ts_states[p] for p in lay.ts_paths]
-                + [cols.list_states[p] for p in lay.list_paths],
-                native,
-            )
-        if D:
-            sp = i8_cols[P + Tn + L :]
-            sp[:, :B] = batch.scope_sp.transpose(1, 2, 0).reshape(2 * D, B)
-            sp[:, B:] = 0
+    i8_cols = sec["i8_cols"]
+    if D:
+        sp = i8_cols[P + Tn + L :]
+        sp[:, :B] = batch.scope_sp.transpose(1, 2, 0).reshape(2 * D, B)
+        sp[:, B:] = 0
+    i8_cols = i8_cols[: P + Tn + L]
+    rows = [cols.ts_states[p] for p in lay.ts_paths] + [cols.list_states[p] for p in lay.list_paths]
+    if scalars is not None:
+        _fill_block(i8_cols[:P], scalars[2])
+        i8_cols = i8_cols[P:]
     else:
-        i8_cols = np.zeros((0, B_pad), dtype=np.int8)
+        rows = [cols.tags[p] for p in lay.paths] + rows
+    if rows:
+        _fill_rows(i8_cols, rows, native)
 
-    n_bool = P + 2 * Q
-    if n_bool:
-        bool_cols = lease((n_bool, B_pad), np.bool_)
-        _fill_rows(
-            bool_cols,
-            [cols.nans[p] for p in lay.paths]
-            + [cols.pred_vals[q] for q in lay.pred_ids]
-            + [cols.pred_errs[q] for q in lay.pred_ids],
-            native,
-        )
+    bool_cols = sec["bool_cols"]
+    rows = [cols.pred_vals[q] for q in lay.pred_ids] + [cols.pred_errs[q] for q in lay.pred_ids]
+    if scalars is not None:
+        _fill_block(bool_cols[:P], scalars[3])
+        bool_cols = bool_cols[P:]
     else:
-        bool_cols = np.zeros((0, B_pad), dtype=bool)
+        rows = [cols.nans[p] for p in lay.paths] + rows
+    if rows:
+        _fill_rows(bool_cols, rows, native)
 
-    if L:
-        wmax = max(lay.list_widths)
-        lists = lease((L, B_pad, wmax), np.int32)
-        for i, p in enumerate(lay.list_paths):
-            a = cols.list_sids[p]
-            nb, w = a.shape
-            lists[i, :nb, :w] = a
-            if w < wmax:
-                lists[i, :nb, w:] = 0
-            if nb < B_pad:
-                lists[i, nb:] = 0
-    else:
-        lists = np.zeros((0, B_pad, 1), dtype=np.int32)
+    lists = sec["lists"]
+    wmax = lists.shape[2]
+    for i, p in enumerate(lay.list_paths):
+        a = cols.list_sids[p]
+        nb, w = a.shape
+        lists[i, :nb, :w] = a
+        if w < wmax:
+            lists[i, :nb, w:] = 0
+        if nb < B_pad:
+            lists[i, nb:] = 0
 
     BA = cand_cond_c.shape[0]
-    cand_i32 = lease((2, BA_pad) + cand_cond_c.shape[1:], np.int32)
+    cand_i32 = sec["cand_i32"]
     cand_i32[0, :BA] = cand_cond_c
     cand_i32[1, :BA] = cand_drcond_c
     cand_i32[:, BA:] = -1  # pad_ba fill for cond ids
-    cand_i8 = lease((4, BA_pad) + batch.cand_effect.shape[1:], np.int8)
+    cand_i8 = sec["cand_i8"]
     cand_i8[0, :BA] = batch.cand_effect
     cand_i8[1, :BA] = batch.cand_pt
     cand_i8[2, :BA] = batch.cand_depth
@@ -794,26 +866,12 @@ def _pad_stack(batch: PackedBatch, cols, cand_cond_c, cand_drcond_c, B_pad: int,
     cand_i8[:, BA:] = 0
     cand_i8[2, BA:] = -1  # pad_ba fill for depth
 
-    ba_input = lease((BA_pad,) + batch.ba_input.shape[1:], batch.ba_input.dtype)
+    ba_input = sec["ba_input"]
     ba_input[:BA] = batch.ba_input
     ba_input[BA:] = 0
 
-    now = (
-        np.asarray([int(cols.now_hi), int(cols.now_lo)], dtype=np.int32)
-        if has_now
-        else np.zeros(2, dtype=np.int32)
-    )
-    stacked = dict(
-        i32_cols=i32_cols,
-        i8_cols=i8_cols,
-        bool_cols=bool_cols,
-        lists=lists,
-        cand_i32=cand_i32,
-        cand_i8=cand_i8,
-        ba_input=ba_input,
-        now=now,
-    )
-    return stacked, lay, leased
+    sec["now"][:] = (int(cols.now_hi), int(cols.now_lo)) if has_now else 0
+    return {"buf": buf}, cut, [buf]
 
 
 class _DeviceHandle:
@@ -821,27 +879,32 @@ class _DeviceHandle:
     already started) plus everything needed to slice results back apart.
     ``ready`` short-circuits degenerate batches that never touch the device."""
 
-    __slots__ = ("ready", "out", "BA", "B", "K", "BA_pad", "B_pad", "col_map", "leased", "put_bytes", "fetch_bytes")
+    __slots__ = ("ready", "out", "BA", "B", "K", "BA_pad", "B_pad", "col_map", "leased", "puts", "put_bytes",
+                 "fetch_bytes")
 
     def __init__(self):
         self.ready = None
         self.out = None
         self.leased = ()
-        self.put_bytes = 0    # what the jitted call was handed: the eight stacked arrays
+        self.puts = 0         # how many arrays the jitted call was handed: the one staging buffer
+        self.put_bytes = 0    # and their bytes, padding included
         self.fetch_bytes = 0  # the one result vector, once _device_finalize has fetched it
 
 
-def _jit_run(compiler, K: int, J: int, D: int, variant, lay: _StackLayout, BA_pad: int):
+def _jit_run(compiler, D: int, variant, cut: _TransferCut):
     """The jitted device program of one layout: everything static in the jit
-    key bound into the trace. The one spelling for a flight that meets a new
-    layout (:func:`_device_dispatch`) and for the preloader that builds it
-    ahead of traffic, so both ask XLA for the same program."""
+    key bound into the trace, the staging buffer's cut included. The one
+    spelling for a flight that meets a new layout (:func:`_device_dispatch`)
+    and for the preloader that builds it ahead of traffic, so both ask XLA
+    for the same program."""
     import jax
     import jax.numpy as jnp
 
+    K, J, BA_pad = cut.K, cut.J, cut.BA_pad
+
     def run(**kw):
         with jax.named_scope("unstack"):
-            parts = _unstack_padded(jnp, lay, kw)
+            parts = _unstack_padded(jnp, cut, kw)
         final, role_results, win_j, sat_arr = _compute(
             jnp, compiler, K, J, D, variant=variant, **parts
         )
@@ -861,9 +924,10 @@ def _jit_run(compiler, K: int, J: int, D: int, variant, lay: _StackLayout, BA_pa
     return jax.jit(run)
 
 
-def _manifest_entry(key: tuple, lay: _StackLayout, stacked: dict) -> dict:
+def _manifest_entry(key: tuple, lay: _StackLayout) -> dict:
     """What the layout manifest keeps of one jit key: enough to build the
-    key, the layout and zero-filled arguments again without a batch."""
+    key, the layout and with them the staging buffer's cut (so a zero buffer
+    of the right length) again without a batch."""
     B_pad, BA_pad, K, J, D, variant, _sig = key
     return {
         "shape": [B_pad, BA_pad],
@@ -878,12 +942,11 @@ def _manifest_entry(key: tuple, lay: _StackLayout, stacked: dict) -> dict:
             "D": lay.D,
             "has_now": lay.has_now,
         },
-        "args": {name: [list(a.shape), a.dtype.str] for name, a in stacked.items()},
     }
 
 
 def _entry_parts(entry: dict):
-    """Inverse of :func:`_manifest_entry`: ``(key, layout, zero arguments)``.
+    """Inverse of :func:`_manifest_entry`: ``(key, cut, zero arguments)``.
     Raises on an entry that does not have the form (the preloader counts it
     as failed)."""
     B_pad, BA_pad = (int(x) for x in entry["shape"])
@@ -901,11 +964,8 @@ def _entry_parts(entry: dict):
         int(lay_doc["D"]),
         bool(lay_doc["has_now"]),
     )
-    zeros = {
-        name: np.zeros(tuple(int(n) for n in shape), dtype=np.dtype(dtype))
-        for name, (shape, dtype) in entry["args"].items()
-    }
-    return (B_pad, BA_pad, K, J, D, variant, lay.sig), lay, zeros
+    cut = _TransferCut(lay, B_pad, BA_pad, K, J)
+    return (B_pad, BA_pad, K, J, D, variant, lay.sig), cut, {"buf": np.zeros(cut.words, dtype=np.int32)}
 
 
 class _LayoutPreloader:
@@ -979,7 +1039,7 @@ class _LayoutPreloader:
         dev = self._ev.device if self._ev.device is not None else jax.devices()[0]
         return layoutmanifest.scope(identity, dev.device_kind)
 
-    def met(self, key: tuple, lay: _StackLayout, stacked: dict) -> None:
+    def met(self, key: tuple, lay: _StackLayout) -> None:
         """A flight built ``key`` itself: file it for the next process."""
         from . import layoutmanifest
 
@@ -988,7 +1048,7 @@ class _LayoutPreloader:
         try:
             scope = self._scope()
             if scope is not None:
-                layoutmanifest.record(scope, _manifest_entry(key, lay, stacked))
+                layoutmanifest.record(scope, _manifest_entry(key, lay))
         except Exception:  # noqa: BLE001  (the manifest is never worth a request)
             _log.debug("layout manifest: entry not recorded", exc_info=True)
 
@@ -1017,11 +1077,11 @@ class _LayoutPreloader:
         """One entry: its outcome, or None when the walk was stopped under it."""
         ev = self._ev
         try:
-            key, lay, zeros = _entry_parts(entry)
+            key, cut, zeros = _entry_parts(entry)
             if key in ev._jit_cache:
                 return "held"
-            B_pad, BA_pad, K, J, D, variant, _sig = key
-            fn = _jit_run(ev.lowered.compiler, K, J, D, variant, lay, BA_pad)
+            B_pad, BA_pad, _K, _J, D, variant, _sig = key
+            fn = _jit_run(ev.lowered.compiler, D, variant, cut)
             t0 = time.perf_counter()
             with ev._device_scope(), compilestats.cache_events() as seen:
                 out = fn(**zeros)
@@ -1059,10 +1119,10 @@ def _device_dispatch(
     FUSE TRANSFERS: every host->device put and device->host fetch is its
     own transfer with a fixed per-transfer cost (PERF.md records the
     measured put/fetch figures), and the naive call ships ~5 arrays per
-    column path (100+ puts) and fetches 4 results. Stack all per-path
-    columns into a handful of typed matrices host-side — slicing them back
-    apart INSIDE the traced graph is free (XLA fuses) — and pack every
-    result into one int8 vector on device, so a batch costs 8 puts + 1
+    column path (100+ puts) and fetches 4 results. Lay every column and
+    every candidate array into ONE staging buffer host-side — cutting it
+    back apart INSIDE the traced graph is free (XLA fuses) — and pack every
+    result into one int8 vector on device, so a batch costs 1 put + 1
     fetch regardless of how many columns the table has.
 
     HIDE LATENCY: jax dispatch is async — ``fn(**stacked)`` returns before
@@ -1097,14 +1157,15 @@ def _device_dispatch(
     col_map, cand_cond_c, cand_drcond_c = _variant_remap(
         variant_key, compiler, C, batch.cand_cond, batch.cand_drcond
     )
-    stacked, layout, leased = _pad_stack(
+    stacked, cut, leased = _pad_stack(
         batch, batch.columns, cand_cond_c, cand_drcond_c, B_pad, BA_pad
     )
-    key = (B_pad, BA_pad, K, J, D, variant_key, layout.sig)
+    key = (B_pad, BA_pad, K, J, D, variant_key, cut.sig)
+    h.puts = len(stacked)
     h.put_bytes = sum(a.nbytes for a in stacked.values())
     fn = jit_cache.get(key)
     if fn is None:
-        fn = _jit_run(compiler, K, J, D, variant_key, layout, BA_pad)
+        fn = _jit_run(compiler, D, variant_key, cut)
         jit_cache[key] = fn
         # a process's first device flight always lands here: the walk starts
         # once the flight's own key is in the cache, and never builds it too
@@ -1117,7 +1178,7 @@ def _device_dispatch(
         out = compilestats.timed_first_call(
             f"B{B_pad}xBA{BA_pad}", fn, stacked, trace_key=key
         )
-        preloader.met(key, layout, stacked)
+        preloader.met(key, cut.lay)
         # the call was the compile's: of ``dispatch`` this flight has the copy alone
         drainclock.to(drainclock.DISPATCH, drainclock.DISPATCH_COPY)
     else:
@@ -1222,8 +1283,8 @@ class TpuEvaluator:
         self.stats = {"device_inputs": 0, "oracle_inputs": 0, "trivial_inputs": 0}
         transfer = metrics().histogram_vec(
             "cerbos_tpu_batch_transfer_bytes",
-            "bytes of one device-served call, observed as its result is collected: dir=put, the eight stacked "
-            "arrays the jitted call was handed (sum of nbytes, padding included); dir=fetch, the one result "
+            "bytes of one device-served call, observed as its result is collected: dir=put, the one staging "
+            "buffer the jitted call was handed (nbytes, padding included); dir=fetch, the one result "
             "vector; by shard",
             label=("dir", "shard"),
             buckets=[4096, 16384, 65536, 262144, 1048576, 4194304, 16777216, 67108864],
@@ -1231,6 +1292,13 @@ class TpuEvaluator:
         shard_label = str(shard_id) if shard_id is not None else "0"
         self._m_put_bytes = transfer.labels(("put", shard_label))
         self._m_fetch_bytes = transfer.labels(("fetch", shard_label))
+        self._m_puts = metrics().histogram_vec(
+            "cerbos_tpu_batch_device_puts",
+            "arrays one device-served call handed the device (host->device transfers of one flight's inputs), "
+            "observed beside batch_transfer_bytes as the call's result is collected; by shard",
+            label="shard",
+            buckets=[1, 2, 4, 8, 16, 32, 64, 128],
+        ).labels(shard_label)
         self._jit_cache: dict = {}
         self._preloader = _LayoutPreloader(self)
         self._dr_table_cache: dict = {}
@@ -1375,6 +1443,7 @@ class TpuEvaluator:
             res = _device_finalize(handle)
             if handle.fetch_bytes:
                 # once per device-served call: what it put and what it fetched
+                self._m_puts.observe(handle.puts)
                 self._m_put_bytes.observe(handle.put_bytes)
                 self._m_fetch_bytes.observe(handle.fetch_bytes)
             out.extend(self._assemble_batch(batch, *res, ticket.params))

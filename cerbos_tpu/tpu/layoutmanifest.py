@@ -38,7 +38,9 @@ from . import jitcache
 
 _log = logging.getLogger("cerbos_tpu.layoutmanifest")
 
-FORMAT = 1
+# 2: an entry is the jit key's parts alone; the device program takes ONE staging buffer whose cut follows
+# from them (1 listed the eight arguments of the program it described, which no process builds any more)
+FORMAT = 2
 MAX_ENTRIES = 512
 _SUBDIR = "layouts"
 _FILE = "manifest.json"

@@ -18,16 +18,18 @@ from typing import Any, Optional
 import numpy as np
 
 from .. import namer
+from .. import native as native_mod
 from ..engine import drainclock
 from ..engine import types as T
 from ..ruletable.rows import KIND_PRINCIPAL, KIND_RESOURCE, RuleRow
 from ..ruletable.check import EvalContext, build_request_messages
 from .columns import (
     ColumnBatch,
+    TAG_NUM,
     TAG_OTHER,
     encode_value,
 )
-from .condcompile import evaluate_pred_host
+from .condcompile import TAG_ERR, evaluate_pred_host
 from .lowering import (
     EFFECT_DENY_CODE,
     EFFECT_NONE,
@@ -99,6 +101,35 @@ class PackedBatch:
 
 
 
+class _ScalarPlan:
+    """What the scalar store needs of the table and not of the batch: the
+    paths in the order of the matrices' rows, which of them the fused C pass
+    encodes, and the fallback-tag test as one lookup table. Built on the
+    packer's first batch, dropped by ``invalidate()`` (``lt.paths`` and
+    ``lt.fallback_tags`` are rebuilt with the table)."""
+
+    __slots__ = ("paths", "fused_ix", "specs", "rest", "trig_rows", "trig_lut", "trig_ix")
+
+    def __init__(self, lt: LoweredTable, fused_mode, native):
+        self.paths = tuple(sorted(lt.paths))
+        modes = [fused_mode(p) for p in self.paths]
+        if not hasattr(native, "encode_attr_columns_multi"):
+            modes = [None] * len(modes)
+        # rows the fused C pass writes, with their (mode, root, leaf); the rest go through encode_column
+        self.fused_ix = np.asarray([i for i, m in enumerate(modes) if m is not None], dtype=np.int64)
+        self.specs = [m for m in modes if m is not None]
+        self.rest = [i for i, m in enumerate(modes) if m is None]
+        # one 256-entry lookup row per path that has trigger tags; a path
+        # without any is not tested at all
+        rows = [i for i, p in enumerate(self.paths) if lt.fallback_tags.get(p)]
+        self.trig_lut = np.zeros((len(rows), 256), dtype=bool)
+        for r, i in enumerate(rows):
+            self.trig_lut[r, np.fromiter(lt.fallback_tags[self.paths[i]], dtype=np.uint8)] = True
+        self.trig_ix = np.arange(len(rows))[:, None]
+        # every row tested: the whole matrix is the lookup's index, no row gather
+        self.trig_rows = slice(None) if len(rows) == len(self.paths) else np.asarray(rows, dtype=np.int64)
+
+
 def _memo_put(memo: dict, key, val):
     """Bounded memo insert: wholesale clear past the cap (simple, O(1)
     amortized; the caches re-warm in one batch)."""
@@ -143,6 +174,7 @@ class Packer:
         self._pred_scratch: dict[str, int] = {}
         # pred_id -> fastpred program (None = outside the fast grammar)
         self._fast_preds: dict[int, Any] = {}
+        self._scalar_plan: Optional[_ScalarPlan] = None
 
     def invalidate(self) -> None:
         self._cand_cache.clear()
@@ -164,6 +196,7 @@ class Packer:
         self._sp_store.clear()
         self._sp_stacked = None
         self._fast_preds.clear()
+        self._scalar_plan = None
 
     def _get_all_scopes(self, kind: str, scope: str, name: str, version: str, lenient: bool):
         key = (kind, scope, name, version, lenient)
@@ -727,23 +760,18 @@ class Packer:
         return fn
 
     def _encode_columns(self, plans: list[InputPlan], params: T.EvalParams) -> ColumnBatch:
-        from .condcompile import TAG_ERR
-
-        from .. import native as native_mod
-        from .columns import TAG_NUM
-
         B = len(plans)
         cb = ColumnBatch(size=B)
-        interner = self.lt.interner
-        paths = sorted(self.lt.paths)
-        encode_cache = self._encode_cache
         native = native_mod.get()
         # filter once, not once per path
         active = [(bi, plan) for bi, plan in enumerate(plans) if not (plan.trivial or plan.oracle)]
         if native is not None and hasattr(native, "encode_column"):
-            self._encode_columns_native(cb, plans, active, paths, native)
+            self._encode_columns_native(cb, plans, active, native)
             self._encode_rest(cb, plans, active, params)
             return cb
+        interner = self.lt.interner
+        encode_cache = self._encode_cache
+        paths = sorted(self.lt.paths)
         for p in paths:
             t = np.zeros(B, dtype=np.int8)
             h = np.zeros(B, dtype=np.int32)
@@ -872,8 +900,6 @@ class Packer:
         B = cb.size
         interner = self.lt.interner
         memo = self._list_memo
-        from .. import native as native_mod
-
         native = native_mod.get()
         use_native = native is not None and hasattr(native, "encode_list_column")
         for p in sorted(self.lt.list_paths):
@@ -959,8 +985,6 @@ class Packer:
         preds = self.lt.compiler.preds
         if not preds:
             return
-        from .. import native as native_mod
-
         live = [(bi, plan) for bi, plan in active if not plan.oracle]
         out = {
             spec.pred_id: (np.zeros(B, dtype=bool), np.zeros(B, dtype=bool))
@@ -1114,127 +1138,93 @@ class Packer:
             return (2, path[0], leaf)
         return None
 
-    def _encode_columns_native(self, cb: ColumnBatch, plans, active, paths, native) -> None:
-        """Whole-column encoding in C: for the common path shapes the value
-        gather (attribute access on input objects) AND the type dispatch +
-        key/interning loop both run natively (encode_attr_column); other
-        paths gather values in Python and encode via encode_column."""
-        B = cb.size
+    def _encode_columns_native(self, cb: ColumnBatch, plans, active, native) -> None:
+        """Whole-column encoding in C, stored ONCE: the matrices the encoders
+        write ARE the columns. For the common path shapes the value gather
+        (attribute access on input objects) AND the type dispatch +
+        key/interning loop both run natively, one pass over the batch for
+        every such path at once (encode_attr_columns_multi: the per-input
+        resolution of principal/resource objects, attr and jwt dicts is
+        shared by all of them); other paths (scope, deep paths) gather values
+        in Python and encode via encode_column, into their rows of the same
+        matrices. ``cb``'s dictionaries get row views, and the matrices stay
+        on ``cb.scalars`` for the transfer format's block copies."""
+        sp = self._scalar_plan
+        if sp is None:
+            sp = self._scalar_plan = _ScalarPlan(self.lt, self._fused_mode, native)
+        paths = sp.paths
+        P, B, na = len(paths), cb.size, len(active)
+        if not P:
+            return
         interner = self.lt.interner
-        all_active = len(active) == B
-        fused_ok = hasattr(native, "encode_attr_column")
+        M32 = np.zeros((3, P, B), dtype=np.int32)  # his, los, sids
+        MT = np.zeros((P, B), dtype=np.int8)
+        MN = np.zeros((P, B), dtype=bool)
+        all_active = na == B
         # only ACTIVE inputs are gathered/encoded: trivial/oracle inputs stay
         # TAG_MISSING and must not intern their strings into the device
         # string space
-        act_inputs = None
-        act_ix = None
-        if fused_ok:
+        if sp.specs and na:
+            act_ix = None
             if all_active:
                 act_inputs = [plan.input for plan in plans]
             else:
                 act_inputs = [plan.input for _, plan in active]
-                act_ix = np.fromiter((bi for bi, _ in active), dtype=np.int64, count=len(active))
-        na = len(active)
-
-        # one C pass over the batch for every fused path at once: the
-        # per-input attribute resolution (principal/resource objects, attr
-        # and jwt dicts) is shared by all P columns instead of repeated P
-        # times (encode_attr_columns_multi)
-        done: set = set()
-        if fused_ok and hasattr(native, "encode_attr_columns_multi") and act_inputs:
-            fused_paths = [p for p in paths if self._fused_mode(p) is not None]
-            if fused_paths:
-                P = len(fused_paths)
-                MT = np.zeros((P, na), dtype=np.uint8)
-                MH = np.zeros((P, na), dtype=np.int32)
-                ML = np.zeros((P, na), dtype=np.int32)
-                MS = np.zeros((P, na), dtype=np.int32)
-                MN = np.zeros((P, na), dtype=np.uint8)
-                native.encode_attr_columns_multi(
-                    act_inputs,
-                    [self._fused_mode(p) for p in fused_paths],
-                    interner.ids, _MISSING_SENTINEL, _ERR_SENTINEL,
-                    memoryview(MT), memoryview(MH), memoryview(ML),
-                    memoryview(MS), memoryview(MN),
-                )
-                for pi, p in enumerate(fused_paths):
-                    if all_active:
-                        t, h, l, s, nn = MT[pi], MH[pi], ML[pi], MS[pi], MN[pi]
-                    else:
-                        t = np.zeros(B, dtype=np.uint8)
-                        h = np.zeros(B, dtype=np.int32)
-                        l = np.zeros(B, dtype=np.int32)
-                        s = np.zeros(B, dtype=np.int32)
-                        nn = np.zeros(B, dtype=np.uint8)
-                        t[act_ix] = MT[pi]
-                        h[act_ix] = MH[pi]
-                        l[act_ix] = ML[pi]
-                        s[act_ix] = MS[pi]
-                        nn[act_ix] = MN[pi]
-                    self._store_scalar_column(cb, plans, p, t, h, l, s, nn)
-                    done.add(p)
-
-        for p in paths:
-            if p in done:
-                continue
-            t = np.zeros(B, dtype=np.uint8)
-            h = np.zeros(B, dtype=np.int32)
-            l = np.zeros(B, dtype=np.int32)
-            s = np.zeros(B, dtype=np.int32)
-            nn = np.zeros(B, dtype=np.uint8)
-            fused = self._fused_mode(p) if fused_ok else None
-            if fused is not None:
-                mode, root, leaf = fused
-                if act_ix is None:
-                    native.encode_attr_column(
-                        act_inputs, mode, root, leaf,
-                        interner.ids, _MISSING_SENTINEL, _ERR_SENTINEL,
-                        memoryview(t), memoryview(h), memoryview(l), memoryview(s), memoryview(nn),
-                    )
-                else:
-                    ct = np.zeros(na, dtype=np.uint8)
-                    ch = np.zeros(na, dtype=np.int32)
-                    cl = np.zeros(na, dtype=np.int32)
-                    cs = np.zeros(na, dtype=np.int32)
-                    cn = np.zeros(na, dtype=np.uint8)
-                    native.encode_attr_column(
-                        act_inputs, mode, root, leaf,
-                        interner.ids, _MISSING_SENTINEL, _ERR_SENTINEL,
-                        memoryview(ct), memoryview(ch), memoryview(cl), memoryview(cs), memoryview(cn),
-                    )
-                    t[act_ix] = ct
-                    h[act_ix] = ch
-                    l[act_ix] = cl
-                    s[act_ix] = cs
-                    nn[act_ix] = cn
+                act_ix = np.fromiter((bi for bi, _ in active), dtype=np.int64, count=na)
+            Pf = len(sp.specs)
+            whole = all_active and Pf == P
+            if whole:
+                F32, FT, FN = M32, MT, MN
             else:
-                accessor = self._path_accessor(p)
+                F32 = np.zeros((3, Pf, na), dtype=np.int32)
+                FT = np.zeros((Pf, na), dtype=np.int8)
+                FN = np.zeros((Pf, na), dtype=bool)
+            # the C pass writes bytes: tags and nans go to it as uint8 views
+            native.encode_attr_columns_multi(
+                act_inputs, sp.specs, interner.ids, _MISSING_SENTINEL, _ERR_SENTINEL,
+                memoryview(FT.view(np.uint8)), memoryview(F32[0]), memoryview(F32[1]),
+                memoryview(F32[2]), memoryview(FN.view(np.uint8)),
+            )
+            if not whole:
+                # one scatter of the whole matrix, not five a path
                 if all_active:
-                    values = [accessor(plan.input) for plan in plans]
+                    ix: tuple = (sp.fused_ix,)
+                elif Pf == P:
+                    ix = (slice(None), act_ix)
                 else:
-                    values = [_MISSING_SENTINEL] * B
-                    for bi, plan in active:
-                        values[bi] = accessor(plan.input)
-                native.encode_column(
-                    values, interner.ids, _MISSING_SENTINEL, _ERR_SENTINEL,
-                    memoryview(t), memoryview(h), memoryview(l), memoryview(s), memoryview(nn),
-                )
-            self._store_scalar_column(cb, plans, p, t, h, l, s, nn)
+                    ix = (sp.fused_ix[:, None], act_ix)
+                MT[ix] = FT
+                MN[ix] = FN
+                M32[(slice(None),) + ix] = F32
 
-    def _store_scalar_column(self, cb: ColumnBatch, plans, p, t, h, l, s, nn) -> None:
-        """Fallback-tag oracle routing + dtype-normalized store of one
-        encoded scalar column."""
-        trig = self.lt.fallback_tags.get(p)
-        if trig:
-            bad = np.isin(t, np.fromiter(trig, dtype=np.uint8))
-            if bad.any():
-                for bi in np.nonzero(bad)[0]:
-                    plan = plans[int(bi)]
-                    if not (plan.trivial or plan.oracle):
-                        plan.oracle = True
-        cb.tags[p] = t.astype(np.int8)
-        cb.his[p], cb.los[p], cb.sids[p] = h, l, s
-        cb.nans[p] = nn.astype(bool)
+        for i in sp.rest if na else ():
+            accessor = self._path_accessor(paths[i])
+            if all_active:
+                values = [accessor(plan.input) for plan in plans]
+            else:
+                values = [_MISSING_SENTINEL] * B
+                for bi, plan in active:
+                    values[bi] = accessor(plan.input)
+            native.encode_column(
+                values, interner.ids, _MISSING_SENTINEL, _ERR_SENTINEL,
+                memoryview(MT[i].view(np.uint8)), memoryview(M32[0, i]), memoryview(M32[1, i]),
+                memoryview(M32[2, i]), memoryview(MN[i].view(np.uint8)),
+            )
+
+        # fallback-tag oracle routing, once a flight on the whole matrix
+        if len(sp.trig_lut):
+            bad = sp.trig_lut[sp.trig_ix, MT.view(np.uint8)[sp.trig_rows]].any(axis=0)
+            for bi in np.nonzero(bad)[0]:
+                plan = plans[int(bi)]
+                if not (plan.trivial or plan.oracle):
+                    plan.oracle = True
+
+        cb.tags.update(zip(paths, MT))
+        cb.his.update(zip(paths, M32[0]))
+        cb.los.update(zip(paths, M32[1]))
+        cb.sids.update(zip(paths, M32[2]))
+        cb.nans.update(zip(paths, MN))
+        cb.scalars = (paths, M32, MT, MN)
 
     def _pred_key_accessors(self, spec):
         accs = self._pred_accessors.get(spec.pred_id)

@@ -28,6 +28,7 @@ DISPATCH = ["dispatch_call_mean_ms.pages", "dispatch_copy_mean_ms.pages"]
 BYTES = ["put_kb_mean.pages", "fetch_kb_mean.pages"]
 BOOT = [f"boot_{p}_s" for p in ("import", "load", "compile", "table", "lower", "ready")]
 NEW = PACK + DISPATCH + BYTES + BOOT + ["pool_wait_mean_ms.pages", "pool_wait_mean_ms.sidecar"]
+PUTS = "device_puts_mean.pages"  # PR 40: arrays a device-served call hands the device
 
 
 def tiny_root(tmp_path) -> str:
@@ -40,7 +41,7 @@ def tiny_root(tmp_path) -> str:
         manifest = json.load(f)
     entries = {m["name"]: m for m in manifest["per_layer"]}
     assert len(NEW) == 18 and set(NEW) <= set(entries)
-    for name in NEW:
+    for name in NEW + [PUTS]:
         for twin, tiny in (("classic-800.pages", "tiny.pages"), ("classic-800.sidecar", "tiny.sidecar")):
             if twin in entries[name]["workloads"]:
                 entries[name]["workloads"].append(tiny)
@@ -75,6 +76,7 @@ def test_traced_pages_run_reads_all_eighteen(tmp_path, monkeypatch):
     assert sum(got[n] for n in DISPATCH) == pytest.approx(value(m, "dispatch_mean_ms.pages"), rel=1e-6)
     assert all(got[n] > 0 for n in PACK + DISPATCH + BYTES)
     assert m["put_kb_mean.pages"]["unit"] == "KB" and got["put_kb_mean.pages"] > got["fetch_kb_mean.pages"]
+    assert value(m, PUTS) == 1.0 and m[PUTS]["unit"] == "puts"  # one staging buffer a call
     assert 0 < got["pool_wait_mean_ms.pages"] < 250
     boot = [got[n] for n in BOOT[:-1]]
     assert all(s > 0 for s in boot) and sum(boot) <= got["boot_ready_s"] < setup_s(tmp_path / "out")
@@ -89,4 +91,4 @@ def test_traced_sidecar_run_reads_the_boot_phases_and_the_pool_wait(tmp_path, mo
     boot = [value(m, n) for n in BOOT]
     assert sum(boot[:-1]) <= boot[-1] < setup_s(tmp_path / "out")
     assert 0 < value(m, "pool_wait_mean_ms.sidecar") < 250
-    assert not set(m) & set(PACK + DISPATCH + BYTES + ["pool_wait_mean_ms.pages"])
+    assert not set(m) & set(PACK + DISPATCH + BYTES + ["pool_wait_mean_ms.pages", PUTS])

@@ -200,7 +200,23 @@ def _a_list(p, first):
     p.write_text("[1, 2, 3]")
 
 
-@pytest.mark.parametrize("spoil", [_corrupt, _another_table, _another_jax, _another_format, _a_list])
+OLD_ARGS = ("i32_cols", "i8_cols", "bool_cols", "lists", "cand_i32", "cand_i8", "ba_input", "now")
+
+
+def _the_format_before_one_buffer(p, first):
+    """The file as the commit before PR 40 wrote it: format 1, each entry with
+    the eight arguments of the program it described."""
+    doc = json.loads(p.read_text())
+    doc["format"] = 1
+    for t in doc["tables"].values():
+        for e in t.values():
+            e["args"] = {name: [[2, 16], "<i4"] for name in OLD_ARGS}
+    p.write_text(json.dumps(doc))
+
+
+@pytest.mark.parametrize(
+    "spoil", [_corrupt, _another_table, _another_jax, _another_format, _a_list, _the_format_before_one_buffer]
+)
 def test_a_manifest_that_is_not_this_process_s_loads_nothing_and_fails_nothing(layout_manifest, spoil):
     first, answers = first_process()
     spoil(layout_manifest, first)
@@ -214,6 +230,24 @@ def test_a_manifest_that_is_not_this_process_s_loads_nothing_and_fails_nothing(l
     for n in SIZES[1:]:
         assert second.check(inputs(n), EvalParams()) == answers[n]
     assert keys(second) == keys(first)
+
+
+def test_a_manifest_of_the_old_format_is_ignored_not_walked_and_the_next_record_starts_anew(layout_manifest, caplog):
+    first_process()
+    _the_format_before_one_buffer(layout_manifest, None)
+    (scope,) = json.loads(layout_manifest.read_text())["tables"]
+    before = preloads()
+    with caplog.at_level("WARNING", logger="cerbos_tpu.layoutmanifest"):
+        assert layoutmanifest.entries(scope) == []
+    assert any("not of format 2" in r.getMessage() for r in caplog.records)
+    second = TpuEvaluator(table(), use_jax=True)
+    second.check(inputs(SIZES[1]), EvalParams())
+    walked(second)
+    assert grown(before) == dict.fromkeys(compilestats.PRELOAD_OUTCOMES, 0)  # nothing walked: no ``failed`` either
+    doc = json.loads(layout_manifest.read_text())
+    assert doc["format"] == layoutmanifest.FORMAT == 2
+    (entry,) = doc["tables"][scope].values()
+    assert entry["shape"][0] == 64 and entry["met"] == 1 and "args" not in entry
 
 
 def test_an_entry_that_cannot_be_built_is_counted_and_skipped(layout_manifest):
@@ -327,7 +361,7 @@ def test_entry_count_is_the_same_with_and_without_a_manifest(layout_manifest, tm
 
 
 def _entry(n: int) -> dict:
-    return {"shape": [n, n], "depth": [1, 1, 1], "variant": [], "layout": {}, "args": {}}
+    return {"shape": [n, n], "depth": [1, 1, 1], "variant": [], "layout": {}}
 
 
 def test_the_manifest_is_bounded_and_the_least_met_go_first(layout_manifest, monkeypatch):
@@ -383,10 +417,10 @@ def test_an_entry_round_trips_to_the_key_a_flight_computes(layout_manifest):
     (entries,) = doc["tables"].values()
     rebuilt = set()
     for entry in entries.values():
-        key, lay, zeros = evmod._entry_parts(entry)
+        key, cut, zeros = evmod._entry_parts(entry)
         rebuilt.add(key)
-        assert key[6] == lay.sig
-        assert evmod._manifest_entry(key, lay, zeros) == {k: v for k, v in entry.items() if k not in ("met", "seq")}
+        assert key[6] == cut.sig
+        assert evmod._manifest_entry(key, cut.lay) == {k: v for k, v in entry.items() if k not in ("met", "seq")}
     assert rebuilt == keys(first)
 
 
