@@ -241,6 +241,11 @@ def initialize(
     )
     manager.rollout = rollout_ctl
     _rollout.install(rollout_ctl)
+    # validators of the schemas the policies name: built now and at every
+    # cutover, not inside the first request that meets a ref (a store event
+    # has emptied the cache by the time its table is cut over to)
+    schema_mgr.load(manager.rule_table)
+    rollout_ctl.subscribe("schemas", lambda ep: schema_mgr.load(ep.rule_table))
 
     tpu_enabled = tpu_conf.get("enabled", True) if use_tpu is None else use_tpu
     tpu_evaluator = None

@@ -331,14 +331,20 @@ def _fingerprint(inp: T.CheckInput) -> int:
 
 
 def oracle_walk(
-    rt: Any, epoch: Optional[int], inputs: Sequence[T.CheckInput], params: T.EvalParams, schema_mgr: Any
+    rt: Any,
+    epoch: Optional[int],
+    inputs: Sequence[T.CheckInput],
+    params: T.EvalParams,
+    schema_mgr: Any,
+    route: str = "oracle",
 ) -> list[T.CheckOutput]:
     """The CPU oracle's answer on the calling thread, from ONE table: the
     caller read it once, so a cutover between inputs cannot split the request
     across two, and ``epoch`` (stamped on the decisions) names that table.
-    Shared by this module's routes and a pool front end's (engine/ipc.py)."""
+    Shared by this module's routes and a pool front end's (engine/ipc.py);
+    ``route`` is what the schema manager counts its validations under."""
     T.set_current_epoch(epoch)
-    return [check_input(rt, i, params, schema_mgr) for i in inputs]
+    return [check_input(rt, i, params, schema_mgr, route) for i in inputs]
 
 
 def route_families(reg: Any) -> tuple[Any, Any]:
@@ -358,7 +364,8 @@ def route_families(reg: Any) -> tuple[Any, Any]:
         "device-batch pipeline stage seconds on the drain thread's clock, once per flight, by shard: "
         "pack (= pack_plan + pack_gather + pack_scalars + pack_lists + pack_ts + pack_preds), submit (= stack + "
         "dispatch + compiles; dispatch = dispatch_call + dispatch_copy), device (host-clock GAP between submit "
-        "returning and collect starting, not device time), collect (= fetch + assemble), settle; "
+        "returning and collect starting, not device time), collect (= fetch + assemble; assemble = assemble_schema "
+        "+ assemble_outputs, the first observed only by a flight that validated inputs), settle; "
         "oracle (synchronous check of a flight or of a request under minDeviceBatch), post (after settle)",
         label=("stage", "shard"),
         buckets=[0.0001, 0.0005, 0.001, 0.002, 0.005, 0.01, 0.025, 0.05, 0.1, 0.5, 1.0],
@@ -531,6 +538,7 @@ class BatchingEvaluator:
             label="pclass",
         )
         self.m_stage_seconds = _ShardStageView(self._m_stage_vec, self._shard_label)
+        self._m_stage_vec.labels((drainclock.ASSEMBLE_SCHEMA, self._shard_label))  # at 0 from boot: no flight may ever observe it
         self.m_device_calls = reg.histogram_vec(
             "cerbos_tpu_batch_device_calls",
             "jitted device calls per device-served flight (one pack, dispatch, fetch and assemble each): "
@@ -551,13 +559,15 @@ class BatchingEvaluator:
     # -- oracle fallback ----------------------------------------------------
 
     def _oracle_outputs(
-        self, inputs: Sequence[T.CheckInput], params: Optional[T.EvalParams]
+        self, inputs: Sequence[T.CheckInput], params: Optional[T.EvalParams], route: str = "oracle"
     ) -> list[T.CheckOutput]:
         """The CPU oracle's answer on the calling thread, for a fallback and
-        for a request that never needed a flight alike."""
+        for a request that never needed a flight (``route="inline"``) alike."""
         ev = self.evaluator
         rt = ev.rule_table  # read once; the epoch stamp travels with the table
-        out = oracle_walk(rt, getattr(rt, "policy_epoch", None), inputs, params or T.EvalParams(), ev.schema_mgr)
+        out = oracle_walk(
+            rt, getattr(rt, "policy_epoch", None), inputs, params or T.EvalParams(), ev.schema_mgr, route
+        )
         # oracle-served decisions carry source="oracle" from check_input;
         # fold them into the hot-rule heatmap so attribution-rate and
         # device-vs-oracle splits cover them too
@@ -599,7 +609,7 @@ class BatchingEvaluator:
         if wf is not None:
             wf.mark(STAGE_QUEUE_WAIT)  # a true wait of nothing
         t0 = time.perf_counter()
-        out = self._oracle_outputs(inputs, params)
+        out = self._oracle_outputs(inputs, params, route="inline")
         self.m_stage_seconds.observe("oracle", time.perf_counter() - t0)
         if wf is not None:
             wf.mark(STAGE_EVALUATE)
@@ -1272,9 +1282,15 @@ class BatchingEvaluator:
         # parts of collect, from the thread's clock: the wait for the device
         # and the one fetch, then slicing and assembly
         lap = clock.take_lap()
-        for stage in (drainclock.FETCH, drainclock.ASSEMBLE):
+        for stage in (drainclock.FETCH, drainclock.ASSEMBLE, drainclock.ASSEMBLE_OUTPUTS):
             flight.timings[stage] = lap.get(stage, 0.0)
             self.m_stage_seconds.observe(stage, flight.timings[stage])
+        # assemble's other part, for the flights that entered it: with schema
+        # enforcement none there is no such flight, and assemble_outputs is assemble
+        schema_s = lap.get(drainclock.ASSEMBLE_SCHEMA)
+        if schema_s is not None:
+            flight.timings[drainclock.ASSEMBLE_SCHEMA] = schema_s
+            self.m_stage_seconds.observe(drainclock.ASSEMBLE_SCHEMA, schema_s)
         if self.health is not None:
             self.health.record_success()
         settle_start = time.perf_counter()

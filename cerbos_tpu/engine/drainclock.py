@@ -28,9 +28,12 @@ entered with the name of its first part (``to(PACK, PACK_PLAN)``) and
 :func:`part` moves on to the next; each reads ``perf_counter`` once and books
 the seconds since the last such read to the part being left, into the lap
 alone. ``to()`` closes the open part with the very reading that closes the
-state, so the parts of a state tile it by construction: ``pack`` and
-``dispatch`` of a flight ARE the sums of their parts, to the rounding of a
-float addition. Sub-states that also add into their parent were the other
+state, so the parts of a state tile it by construction: ``pack``,
+``dispatch`` and ``assemble`` of a flight ARE the sums of their parts, to the
+rounding of a float addition (``assemble`` is entered as ``assemble_outputs``
+and leaves it for ``assemble_schema`` only while a flight's device-served
+inputs are validated: with ``schema.enforcement: none`` that part is never
+entered and ``assemble_outputs`` is all of ``assemble``). Sub-states that also add into their parent were the other
 choice; a second cursor was taken because it leaves ``to()``, the states and
 ``batcher_thread_seconds_total{state="pack"}`` exactly what they were (the
 same calls at the same places: nothing a reader of the states sees can move),
@@ -65,7 +68,7 @@ SETTLE = "settle"      # futures resolved, waterfalls booked
 POST = "post"          # after settle: flight record, hot rules, sentinel hand-off
 OTHER = "other"        # whatever is left: locks, queue pops, metric updates, plan flights
 
-# the parts of ``pack`` (tpu/packer.py) and of ``dispatch`` (tpu/evaluator.py): observed once a flight as
+# the parts of ``pack`` (tpu/packer.py), of ``dispatch`` and of ``assemble`` (tpu/evaluator.py): observed once a flight as
 # ``cerbos_tpu_batch_stage_seconds{stage=<part>}``, carried in the flight record's ``timings``
 PACK_PLAN = "pack_plan"        # the per-input loop: shape key, shape-memo hit (or the shape's build), InputPlan
 PACK_GATHER = "pack_gather"    # K/J/D, the candidate blocks' stack and its six gathers, the scope-permission rows
@@ -75,9 +78,12 @@ PACK_TS = "pack_ts"            # the timestamp columns and the batch's now()
 PACK_PREDS = "pack_preds"      # the host-evaluated predicates, then the PackedBatch itself
 DISPATCH_CALL = "dispatch_call"  # fn(**stacked): JAX's handling of a keyword call, the one put, the enqueue
 DISPATCH_COPY = "dispatch_copy"  # copy_to_host_async() and the handle
+ASSEMBLE_SCHEMA = "assemble_schema"    # schema validation of the flight's device-served inputs; never entered with enforcement none
+ASSEMBLE_OUTPUTS = "assemble_outputs"  # everything else of assemble: the decision rows' bytes, the memo, the CheckOutputs
 PARTS = {
     PACK: (PACK_PLAN, PACK_GATHER, PACK_SCALARS, PACK_LISTS, PACK_TS, PACK_PREDS),
     DISPATCH: (DISPATCH_CALL, DISPATCH_COPY),
+    ASSEMBLE: (ASSEMBLE_SCHEMA, ASSEMBLE_OUTPUTS),
 }
 
 ALL = "all"            # the label of the CPU series, which is not split by state

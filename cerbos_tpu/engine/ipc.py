@@ -1599,7 +1599,7 @@ class RemoteBatcherClient:
         if wf is not None:
             wf.mark(STAGE_QUEUE_WAIT)  # a true wait of nothing
         t0 = time.perf_counter()
-        out = oracle_walk(rt, epoch, inputs, params or self.params, self.schema_mgr)
+        out = oracle_walk(rt, epoch, inputs, params or self.params, self.schema_mgr, route="inline")
         self._m_oracle_stage.observe(time.perf_counter() - t0)
         hotrules.recorder().observe(out)  # this process's decision_source_total; the owner's heatmap sees none of it
         if wf is not None:

@@ -60,6 +60,7 @@ from dataclasses import dataclass, field
 from typing import Any, Callable, Optional
 
 from ..ruletable import check_input
+from ..schema import ROUTE_SHADOW
 from . import flight
 from . import types as T
 
@@ -661,8 +662,8 @@ class RolloutController:
         errors = 0
         for inp in inputs:
             try:
-                before = effect_rows([check_input(old_rt, inp, params, self.schema_mgr)])[0]
-                after = effect_rows([check_input(new_rt, inp, params, self.schema_mgr)])[0]
+                before = effect_rows([check_input(old_rt, inp, params, self.schema_mgr, ROUTE_SHADOW)])[0]
+                after = effect_rows([check_input(new_rt, inp, params, self.schema_mgr, ROUTE_SHADOW)])[0]
             except Exception:  # noqa: BLE001 — replay is advisory
                 errors += 1
                 continue

@@ -47,6 +47,7 @@ from dataclasses import dataclass, field
 from typing import Any, Callable, Optional, Sequence
 
 from ..ruletable import check_input
+from ..schema import ROUTE_SHADOW
 from . import types as T
 from .flight import recorder as flight_recorder
 
@@ -629,7 +630,7 @@ class ParitySentinel:
         replay_error = ""
         try:
             oracle_outputs = [
-                check_input(s.rule_table, i, params, s.schema_mgr) for i in s.inputs
+                check_input(s.rule_table, i, params, s.schema_mgr, ROUTE_SHADOW) for i in s.inputs
             ]
             oracle = effect_rows(oracle_outputs)
             oracle_prov = provenance_rows(oracle_outputs)

@@ -569,6 +569,22 @@ class Histogram:
                     return
             self._counts[-1] += 1
 
+    def observe_many(self, values: list[float]) -> None:
+        """``observe`` for each of ``values`` under ONE take of the lock: for a
+        hot loop that gathers its observations and books them once (a flight's
+        schema validations)."""
+        buckets, counts = self.buckets, self._counts
+        with self._lock:
+            for v in values:
+                self._sum += v
+                self._count += 1
+                for i, b in enumerate(buckets):
+                    if v <= b:
+                        counts[i] += 1
+                        break
+                else:
+                    counts[-1] += 1
+
     @property
     def count(self) -> int:
         return self._count

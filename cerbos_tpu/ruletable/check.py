@@ -216,9 +216,12 @@ def check_input(
     input: T.CheckInput,
     params: Optional[T.EvalParams] = None,
     schema_mgr: Any = None,
+    route: str = "oracle",
 ) -> T.CheckOutput:
+    """``route``: who answers the input, for the schema manager's counters
+    alone (``oracle``, or ``inline`` from a request's own thread)."""
     params = params or T.EvalParams()
-    result = _check(rt, input, params, schema_mgr)
+    result = _check(rt, input, params, schema_mgr, route)
 
     output = T.CheckOutput(request_id=input.request_id, resource_id=input.resource.id)
     for action in input.actions:
@@ -242,7 +245,9 @@ def check_input(
     return output
 
 
-def _check(rt: RuleTable, input: T.CheckInput, params: T.EvalParams, schema_mgr: Any) -> PolicyEvalResult:
+def _check(
+    rt: RuleTable, input: T.CheckInput, params: T.EvalParams, schema_mgr: Any, route: str = "oracle"
+) -> PolicyEvalResult:
     principal_scope = T.effective_scope(input.principal.scope, params)
     principal_version = T.effective_version(input.principal.policy_version, params)
     resource_scope = T.effective_scope(input.resource.scope, params)
@@ -262,7 +267,7 @@ def _check(rt: RuleTable, input: T.CheckInput, params: T.EvalParams, schema_mgr:
 
     # schema validation (check.go:129-151)
     if schema_mgr is not None:
-        vr_errors, reject = schema_mgr.validate_check_input(rt.get_schema(resource_policy_fqn), input)
+        vr_errors, reject = schema_mgr.validate_check_input(rt.get_schema(resource_policy_fqn), input, route=route)
         if vr_errors:
             result.validation_errors = vr_errors
             if reject:

@@ -124,6 +124,7 @@ class CerbosService:
                 outputs = self.engine.check(
                     inputs, params=params, deadline=deadline, wf=wf, pclass=pclass
                 )
+                self._note_validation(span, outputs)
                 if wf is not None:
                     wf.part(BACK_WAKE)
                 self._audit_decision(span, call_id, inputs, outputs)
@@ -131,6 +132,13 @@ class CerbosService:
                     wf.part(BACK_AUDIT)
             self.metrics.record_check((time.perf_counter() - t0) * 1000, len(inputs))
         return outputs, call_id
+
+    def _note_validation(self, span: Any, outputs: list[T.CheckOutput]) -> None:
+        """The request's count of schema validation errors, on its span; not
+        counted, and no attribute, with ``schema.enforcement: none``."""
+        mgr = getattr(self.engine, "schema_mgr", None)
+        if mgr is not None and mgr.enabled:
+            span.set_attribute("validation_errors", sum(len(o.validation_errors) for o in outputs))
 
     def _audit_decision(
         self, span: Any, call_id: str, inputs: list[T.CheckInput], outputs: list[T.CheckOutput]
@@ -218,6 +226,7 @@ class CerbosService:
                 outputs = await self.engine.check_await(
                     inputs, params=params, deadline=deadline, wf=wf, pclass=pclass
                 )
+                self._note_validation(span, outputs)
                 if wf is not None:
                     wf.part(BACK_WAKE)
                 self._audit_decision(span, call_id, inputs, outputs)

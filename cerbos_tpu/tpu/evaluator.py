@@ -32,6 +32,7 @@ from ..engine import types as T
 from ..observability import metrics, start_span
 from ..ruletable.check import EvalContext, build_request_messages, check_input
 from ..ruletable.table import RuleTable
+from ..schema import Tally
 from . import compilestats
 from .condcompile import Refs
 from .lowering import (
@@ -44,6 +45,9 @@ from .lowering import (
 from .packer import PackedBatch, Packer, PT_PRINCIPAL, PT_RESOURCE
 
 _log = logging.getLogger("cerbos_tpu.evaluator")
+
+# cerbos_tpu_assemble_memo_total{result}: what the assembly memo did for a device-served input
+_MEMO_RESULTS = ("hit", "miss", "bypass_validation", "bypass_other")
 
 def _clone_output(template: "T.CheckOutput", inp: "T.CheckInput") -> "T.CheckOutput":
     """Fresh CheckOutput from a memoized assembly (ids swapped). ActionEffect
@@ -1198,12 +1202,12 @@ def _device_dispatch(
 def _device_finalize(h: _DeviceHandle):
     """Block on one in-flight batch and slice its results apart."""
     if h.ready is not None:
-        drainclock.to(drainclock.ASSEMBLE)
+        drainclock.to(drainclock.ASSEMBLE, drainclock.ASSEMBLE_OUTPUTS)
         return h.ready
     K, BA = h.K, h.BA
     drainclock.to(drainclock.FETCH)
     flat = np.asarray(h.out)  # ONE device->host fetch: the wait for the device is here
-    drainclock.to(drainclock.ASSEMBLE)
+    drainclock.to(drainclock.ASSEMBLE, drainclock.ASSEMBLE_OUTPUTS)
     h.fetch_bytes = flat.nbytes
     if h.leased:
         # the output is materialized, so every transfer that read the staging
@@ -1299,6 +1303,16 @@ class TpuEvaluator:
             label="shard",
             buckets=[1, 2, 4, 8, 16, 32, 64, 128],
         ).labels(shard_label)
+        self._m_memo = metrics().counter_vec(
+            "cerbos_tpu_assemble_memo_total",
+            "device-served inputs by what the assembly memo did for them: hit (a remembered output cloned), miss "
+            "(assembled in full and remembered), bypass_validation (the input carries validation errors, so it is "
+            "assembled in full or denied by reject and never remembered), bypass_other (no memo key: a table with "
+            "outputs, or a derived-role condition the host evaluates)",
+            label="result",
+        )
+        for result in _MEMO_RESULTS:
+            self._m_memo.inc(result, 0.0)
         self._jit_cache: dict = {}
         self._preloader = _LayoutPreloader(self)
         self._dr_table_cache: dict = {}
@@ -1489,6 +1503,8 @@ class TpuEvaluator:
             self._batch_dr_bits(batch, sat_arr, col_map, params) if dec_buf is not None else None
         )
 
+        validated = self._validate_batch(batch)
+        memo_hit = memo_miss = bypass_validation = bypass_other = 0
         outputs: list[T.CheckOutput] = []
         for bi, plan in enumerate(batch.plans):
             inp = plan.input
@@ -1510,10 +1526,8 @@ class TpuEvaluator:
             # oracle's pre-loop check (check.go:129-151); a reject means
             # every action denies without evaluating rules
             vr_errors: list = []
-            if self.schema_mgr is not None:
-                vr_errors, reject = self.schema_mgr.validate_check_input(
-                    self.rule_table.get_schema(plan.resource_policy_fqn), inp
-                )
+            if validated is not None:
+                vr_errors, reject = validated[bi]
                 if reject:
                     out = T.CheckOutput(request_id=inp.request_id, resource_id=inp.resource.id)
                     for action in inp.actions:
@@ -1522,9 +1536,12 @@ class TpuEvaluator:
                         )
                     out.validation_errors = vr_errors
                     outputs.append(out)
+                    bypass_validation += 1
                     continue
             key = None
-            if not vr_errors and dec_buf is not None:
+            if vr_errors:
+                bypass_validation += 1
+            elif dec_buf is not None:
                 dr_bits = dr_bits_by_bi.get(bi)
                 if dr_bits is not None:
                     start, end = plan.ba_range
@@ -1533,7 +1550,11 @@ class TpuEvaluator:
                 hit = self._assemble_memo.get(key)
                 if hit is not None:
                     outputs.append(_clone_output(hit, inp))
+                    memo_hit += 1
                     continue
+                memo_miss += 1
+            elif not vr_errors:
+                bypass_other += 1
             out = self._assemble(plan, bi, batch, final, role_results, win_j, sat_arr, col_map, params)
             out.validation_errors = vr_errors
             if key is not None:
@@ -1541,7 +1562,33 @@ class TpuEvaluator:
                     self._assemble_memo.clear()
                 self._assemble_memo[key] = out
             outputs.append(out)
+        # once a batch, not once an input: four increments under the registry's locks
+        for result, n in zip(_MEMO_RESULTS, (memo_hit, memo_miss, bypass_validation, bypass_other)):
+            if n:
+                self._m_memo.inc(result, n)
         return outputs
+
+    def _validate_batch(self, batch: PackedBatch) -> Optional[list]:
+        """``(errors, reject)`` of every device-served input of ``batch`` (None
+        at the others), or None where nothing is validated. On the drain
+        thread this is the ``assemble_schema`` part of ``assemble``: all of a
+        flight's validations in one stretch, so the clock is read twice a
+        flight and not twice an input."""
+        mgr = self.schema_mgr
+        if mgr is None or not mgr.enabled:
+            return None
+        drainclock.part(drainclock.ASSEMBLE_SCHEMA)
+        get_schema = self.rule_table.get_schema
+        tally = Tally()  # booked once a flight: the instruments' locks are not taken 43 times a page
+        validated = [
+            None
+            if plan.oracle or plan.trivial
+            else mgr.validate_check_input(get_schema(plan.resource_policy_fqn), plan.input, route="device", tally=tally)
+            for plan in batch.plans
+        ]
+        mgr.book(tally)
+        drainclock.part(drainclock.ASSEMBLE_OUTPUTS)
+        return validated
 
     def _batch_dr_bits(self, batch: PackedBatch, sat_arr, col_map, params) -> dict[int, bytes]:
         """Per-input derived-role condition bits (part of the assembly memo

@@ -28,7 +28,7 @@ server process:
   profiler capture asked of the served HTTP port (a front end, which holds no
   device) comes back from the device owner and holds TPU operations;
 - after each topology, the same topology booted ONCE MORE on the same cache
-  directory (``run_restart``; not under ``--lanes`` or ``--audit``): the
+  directory (``run_restart``; not under ``--lanes``, ``--audit`` or ``--schema``): the
   second process must load layouts ahead of traffic from the layout manifest
   the first one filed (``xla_preloads_total{outcome="loaded"}`` > 0), build
   fewer layouts inside requests over the same pass than a first boot that
@@ -315,6 +315,7 @@ class Request:
             for i in inputs
         ]
         self.decisions = sum(len(e) for e in self.expected)
+        self.errors = None  # --schema: each result's validation errors by the plain reading (expect_validation)
 
 
 def build_requests(mods: int, seed: int, n_single: int, n_batch: int):
@@ -345,6 +346,50 @@ def build_requests(mods: int, seed: int, n_single: int, n_batch: int):
     return singles, batches
 
 
+def expect_validation(reqs: list[Request], mods: int, level: str) -> dict:
+    """``--schema``: what the plain reading (``benchmarks/tools/schema_check.py``:
+    no validator library, nothing of the program) says each result's
+    ``validationErrors`` must be; under ``reject`` an input with any error
+    reads EFFECT_DENY for every action, the others as without schemas."""
+    from types import SimpleNamespace
+
+    from benchmarks.tools import schema_check
+    from cerbos_tpu.util import bench_corpus
+
+    table = schema_check.Table(bench_corpus.corpus_yaml(mods).split("\n---\n"), bench_corpus.schemas(mods))
+    seen = {"results": 0, "with_errors": 0, "errors": 0}
+    for req in reqs:
+        body = req.body
+        plain = SimpleNamespace(
+            principal=body["principal"], entries=[(e["resource"], e["actions"]) for e in body["resources"]]
+        )
+        req.errors = table.expected(plain)
+        for k, found in enumerate(req.errors):
+            seen["results"] += 1
+            seen["with_errors"] += bool(found)
+            seen["errors"] += len(found)
+            if found and level == "reject":
+                req.expected[k] = dict.fromkeys(req.expected[k], "EFFECT_DENY")
+    return seen
+
+
+def served_problem(req: Request, results: list[dict]) -> str | None:
+    """What is wrong with a reply's results: effects against the oracle's
+    and, under ``--schema``, validation errors against the plain reading."""
+    got = [r.get("actions", {}) for r in results]
+    if got != req.expected:
+        return f"got {got} want {req.expected}"
+    if req.errors is None:
+        return None
+    from benchmarks.tools import schema_check
+
+    served = [
+        [(e.get("source", ""), e.get("path", ""), e.get("message", "")) for e in r.get("validationErrors", [])]
+        for r in results
+    ]
+    return schema_check.diff(req.errors, served)
+
+
 def write_policies(policy_dir: str, mods: int) -> int:
     """One policy per file plus the schemas, as ``benchmarks/lib/server.py`` does."""
     from cerbos_tpu.util import bench_corpus
@@ -365,7 +410,9 @@ def write_policies(policy_dir: str, mods: int) -> int:
 
 
 class ServerProc:
-    def __init__(self, name: str, policy_dir: str, extra_args: list[str], tpu_conf: dict, audit_path: str = ""):
+    def __init__(
+        self, name: str, policy_dir: str, extra_args: list[str], tpu_conf: dict, audit_path: str = "", schema: str = ""
+    ):
         import yaml
 
         self.name = name
@@ -400,6 +447,8 @@ class ServerProc:
                 "enabled": True, "backend": "file", "accessLogsEnabled": True, "decisionLogsEnabled": True,
                 "file": {"path": audit_path, "logRotation": {"maxFileSizeMB": AUDIT_ROTATE_MB, "maxFileCount": 1000}},
             }
+        if schema:
+            cfg["schema"] = {"enforcement": schema}  # upstream's schema block, over the template's own _schemas/
         cfg_path = os.path.join(os.path.dirname(policy_dir), f"{name}.cerbos.yaml")
         with open(cfg_path, "w") as f:
             yaml.safe_dump(cfg, f)
@@ -565,7 +614,7 @@ def _http_caller(srv: ServerProc, timeout: float):
         raw = resp.read()
         if resp.status != 200:
             raise RuntimeError(f"status {resp.status} {raw[:200]!r}")
-        return [r.get("actions", {}) for r in json.loads(raw).get("results", [])]
+        return json.loads(raw).get("results", [])
 
     return call, conn.close
 
@@ -589,7 +638,7 @@ def _grpc_caller(srv: ServerProc, timeout: float):
             req.body, request_pb2.CheckResourcesRequest(), ignore_unknown_fields=True
         )
         resp = stub(msg, timeout=timeout)
-        return [r.get("actions", {}) for r in json_format.MessageToDict(resp).get("results", [])]
+        return json_format.MessageToDict(resp).get("results", [])
 
     return call, channel.close
 
@@ -605,8 +654,7 @@ def send(make_caller, srv: ServerProc, reqs: list[Request], connections: int, ti
         try:
             for req in reqs[w::connections]:
                 try:
-                    got = call(req)
-                    problem = None if got == req.expected else f"got {got} want {req.expected}"
+                    problem = served_problem(req, call(req))
                 except Exception as e:  # noqa: BLE001 - any failed request fails the pass, with its cause
                     problem = f"{type(e).__name__}: {e}"
                 if problem:
@@ -834,10 +882,37 @@ def check_audit(path: str, final: dict[tuple, float]) -> dict:
     return out
 
 
+def check_schema_counters(final: dict[tuple, float], level: str) -> dict:
+    """``--schema``: every reply has already been held to the plain reading
+    (``served_problem``); this reads what the server says of its own
+    validating: the validators it loaded at boot, and that the device route
+    did validate (the batch-shaped requests) beside the CPU walk's routes."""
+    name = "cerbos_tpu_schema_validations_total"
+    out = {
+        "level": level,
+        "validators": {k: int(v) for k, v in by_label(final, "cerbos_tpu_schema_validators", "state").items()},
+        "validations_by_route": {k: int(v) for k, v in by_label(final, name, "route").items()},
+        "validations_by_outcome": {k: int(v) for k, v in by_label(final, name, "outcome").items()},
+        "errors": int(msum(final, "cerbos_tpu_schema_errors_total")),
+        "memo": {k: int(v) for k, v in by_label(final, "cerbos_tpu_assemble_memo_total", "result").items()},
+    }
+    log(f"  schema: {out}")
+    failures = []
+    if out["validators"].get("loaded", 0) < 2 * MODS or out["validators"].get("failed", 0):
+        failures.append(f"schema_validators reads {out['validators']}: the template ships {3 * MODS} schemas and its policies name them all")
+    if out["validations_by_route"].get("device", 0) <= 0:
+        failures.append("no validation was counted on the device route")
+    if out["errors"] <= 0 or out["memo"].get("bypass_validation", 0) <= 0:
+        failures.append("no validation error was counted: the sample is not clean, and the replies said so")
+    if failures:
+        raise SmokeFailure("schema: " + "; ".join(failures))
+    return out
+
+
 def run_topology(name, extra_args, tpu_conf, workers, policy_dir, singles, batches, args) -> dict:
     log(f"== topology {name}: cerbos_tpu.cli server {' '.join(extra_args)} {tpu_conf or ''}")
     audit_path = os.path.join(os.path.dirname(policy_dir), "audit", f"{name}.log") if args.audit else ""
-    srv = ServerProc(name, policy_dir, extra_args, tpu_conf, audit_path)
+    srv = ServerProc(name, policy_dir, extra_args, tpu_conf, audit_path, args.schema)
     try:
         srv.wait_serving(timeout=300)
         status, _ = srv.status()
@@ -935,6 +1010,7 @@ def run_topology(name, extra_args, tpu_conf, workers, policy_dir, singles, batch
             raise SmokeFailure(f"server exit code on SIGTERM: {code} (None = had to be killed)")
         log(f"  server exited 0 on SIGTERM")
         audit = check_audit(audit_path, final) if audit_path else None
+        schema = check_schema_counters(final, args.schema) if args.schema else None
         return {
             "device": {"platform": dev["platform"], "kind": dev["device_kind"], "count": dev["count"]},
             "xla_cache": status["dir"],
@@ -946,6 +1022,7 @@ def run_topology(name, extra_args, tpu_conf, workers, policy_dir, singles, batch
             "burst": burst,
             "capture": capture,
             **({"audit": audit} if audit else {}),
+            **({"schema": schema} if schema else {}),
             # for run_restart: what this boot built inside requests over its first pass
             "first_pass": {
                 "built": int(msum(cold["after"], "cerbos_tpu_jit_cache_misses_total")),
@@ -1120,6 +1197,12 @@ def main() -> int:
         help="builder-run: boot both topologies with the audit log on (file backend, access and decision "
         "logs, rotated) and hold what they leave on disk to their own counters",
     )
+    ap.add_argument(
+        "--schema", choices=("warn", "reject"), default="",
+        help="builder-run: boot both topologies with schema.enforcement at this level over the template's own "
+        "schemas and hold every reply's validationErrors to benchmarks/tools/schema_check.py (under reject an "
+        "input with errors must read EFFECT_DENY for every action)",
+    )
     args = ap.parse_args()
 
     sys.path.insert(0, REPO)
@@ -1146,6 +1229,9 @@ def main() -> int:
             f"{sum(r.decisions for r in batches)} decisions), each sent over HTTP and gRPC; "
             f"oracle effects computed in {time.monotonic() - t_start:.1f} s"
         )
+        if args.schema:
+            seen = expect_validation(singles + batches, MODS, args.schema)
+            log(f"schema.enforcement {args.schema}: the plain reading expects {seen} over the sample")
         if args.lanes:
             topologies = [("single-shards", [], {"mesh": {"shards": "auto"}}, ())]
         else:
@@ -1161,7 +1247,7 @@ def main() -> int:
         results = {}
         for name, extra, tpu_conf, workers in topologies:
             results[name] = run_topology(name, extra, tpu_conf, workers, policy_dir, singles, batches, args)
-            if not args.lanes and not args.audit:
+            if not args.lanes and not args.audit and not args.schema:
                 results[name]["restart"] = run_restart(
                     name, extra, tpu_conf, workers, policy_dir, singles, batches, results[name].pop("first_pass")
                 )

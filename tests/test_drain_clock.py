@@ -41,7 +41,7 @@ STAGE = "cerbos_tpu_batch_stage_seconds"
 WINDOW = "cerbos_tpu_batcher_window_wait_seconds"
 NOVEL = "cerbos_tpu_xla_compile_novel_total"
 NEW_STAGES = ("stack", "dispatch", "oracle", "fetch", "assemble", "post")
-PACK_PARTS, DISPATCH_PARTS = dc.PARTS[dc.PACK], dc.PARTS[dc.DISPATCH]
+PACK_PARTS, DISPATCH_PARTS, ASSEMBLE_PARTS = dc.PARTS[dc.PACK], dc.PARTS[dc.DISPATCH], dc.PARTS[dc.ASSEMBLE]
 TRANSFER = "cerbos_tpu_batch_transfer_bytes"
 ROUNDING = 0.5001e-6  # a flight record's timings are rounded to a microsecond each
 
@@ -68,8 +68,9 @@ class FakeStreamingEvaluator:
     rule_table = None
     schema_mgr = None
 
-    def __init__(self, gate: threading.Event | None = None):
+    def __init__(self, gate: threading.Event | None = None, validates: bool = False):
         self.gate = gate  # the first submit waits for it, holding the drain thread
+        self.validates = validates  # as with schema.enforcement warn: assemble leaves its outputs part for the schema part
 
     def submit(self, inputs, params=None):
         if self.gate is not None:
@@ -92,8 +93,13 @@ class FakeStreamingEvaluator:
     def collect(self, ticket):
         dc.to(dc.FETCH)
         time.sleep(0.001)
-        dc.to(dc.ASSEMBLE)
-        spin(0.0003)
+        dc.to(dc.ASSEMBLE, dc.ASSEMBLE_OUTPUTS)
+        spin(0.0001)
+        if self.validates:
+            dc.part(dc.ASSEMBLE_SCHEMA)
+            spin(0.0002)
+            dc.part(dc.ASSEMBLE_OUTPUTS)
+        spin(0.0002)
         return [T.CheckOutput(request_id="", resource_id=str(k)) for k in range(len(ticket.inputs))]
 
 
@@ -229,6 +235,38 @@ def test_the_parts_tile_pack_and_dispatch_of_the_same_flight_and_are_observed_on
     assert prom.total(d, THREAD, state="pack", clock="wall", shard=str(shard)) == pytest.approx(seconds("pack"))
     assert prom.total(d, THREAD, state="dispatch", clock="wall", shard=str(shard)) == pytest.approx(seconds("dispatch"))
     assert not [k for k in d if k[0] == THREAD and dict(k[1])["state"] not in dc.STATES + (dc.ALL,)]
+
+
+@pytest.mark.parametrize("validates", [False, True], ids=["enforcement-none", "enforcement-warn"])
+def test_assembles_two_parts_tile_it_and_the_schema_part_is_observed_only_by_a_flight_that_entered_it(shard, validates):
+    before = scrape()
+    b = BatchingEvaluator(FakeStreamingEvaluator(validates=validates), max_wait_ms=0.5, shard_id=shard)
+    try:
+        fly(b, 20)
+    finally:
+        b.close()
+    d = prom.delta(before, scrape())
+    records = flights_of(shard)
+    assert len(records) == 20
+
+    def seconds(stage):
+        return prom.total(d, STAGE + "_sum", stage=stage, shard=str(shard))
+
+    def count(stage):
+        return prom.total(d, STAGE + "_count", stage=stage, shard=str(shard))
+
+    for rec in records:
+        t = rec["timings"]
+        assert ("assemble_schema" in t) is validates
+        assert t.get("assemble_schema", 0.0) + t["assemble_outputs"] == pytest.approx(t["assemble"], abs=ROUNDING * 3)
+        assert t["assemble_outputs"] >= 0.0003 and t.get("assemble_schema", 0.0002) >= 0.0002
+    assert count("assemble") == count("assemble_outputs") == 20
+    assert count("assemble_schema") == (20 if validates else 0)
+    # the series is there at 0 from the batcher's start, whether or not a flight ever enters the part
+    assert (STAGE + "_count", (("shard", str(shard)), ("stage", "assemble_schema"))) in scrape()
+    assert seconds("assemble_schema") + seconds("assemble_outputs") == pytest.approx(seconds("assemble"), abs=1e-9)
+    assert prom.total(d, THREAD, state="assemble", clock="wall", shard=str(shard)) == pytest.approx(seconds("assemble"))
+    assert (seconds("assemble_schema") >= 20 * 0.0002) is validates
 
 
 def test_a_part_is_nothing_in_a_state_entered_without_one_and_an_oracle_flight_has_none(shard):
@@ -470,11 +508,12 @@ def host_event_names(planes) -> dict[str, int]:
 def test_a_real_capture_holds_the_programs_regions_and_no_python_frame(capture):
     names = host_event_names(capture["planes"])
     # ``batch.pack`` is still there as the span's region; the STATES pack and dispatch show as their parts
-    for want in ("batch.pack", "batch.stack", "batch.fetch", "batch.assemble", "batch.post",
+    for want in ("batch.pack", "batch.stack", "batch.fetch", "batch.assemble_outputs", "batch.post",
                  "batch.oracle", "batcher.idle", "batcher.window", "batch.submit", "batch.collect", "request.settle"
                  ) + tuple("batch." + p for p in PACK_PARTS + DISPATCH_PARTS):
         assert names.get(want, 0) >= 6, (want, names)
-    assert "batch.dispatch" not in names  # a part's region takes the state's place, it does not nest in it
+    # a part's region takes the state's place, it does not nest in it; nothing is validated here (no schema manager)
+    assert not {"batch.dispatch", "batch.assemble", "batch.assemble_schema"} & set(names)
     assert names["cerbos.clock"] == 2
     frames = [n for n in names if ".py" in n or n.startswith("$")]
     assert not frames, frames
@@ -573,8 +612,8 @@ def test_span_export_builds_nothing_when_debug_logging_is_off():
 
 
 def test_one_flights_worth_of_the_clock_costs_microseconds(shard):
-    """12 ``to()``, 6 ``part()``, 2 ``take_lap`` and 15 histogram observes (8 of
-    them the parts', PR 38): what a flight
+    """12 ``to()``, 6 ``part()``, 2 ``take_lap`` and 16 histogram observes (9 of
+    them the parts', PR 38 and ``assemble_outputs``): what a flight
     pays with no capture open (the CPU clock, read ten times a second, apart). Printed for PERF.md (``pytest -s``); the limit
     is loose, a tenth of the cheapest stage."""
     from cerbos_tpu.engine.batcher import _ShardStageView
@@ -602,10 +641,12 @@ def test_one_flights_worth_of_the_clock_costs_microseconds(shard):
             b.m_window_wait.observe(0.002)
             for stage in ("stack", "dispatch", "oracle") + PACK_PARTS + DISPATCH_PARTS:
                 stages.observe(stage, lap.get(stage, 0.0))
-            for state in walk[8:]:
+            dc.to(dc.FETCH)
+            dc.to(dc.ASSEMBLE, dc.ASSEMBLE_OUTPUTS)
+            for state in walk[10:]:
                 dc.to(state)
             lap = clock.take_lap()
-            for stage in ("fetch", "assemble"):
+            for stage in ("fetch", "assemble", "assemble_outputs"):
                 stages.observe(stage, lap.get(stage, 0.0))
             stages.observe("post", clock.to(dc.OTHER))
 
