@@ -885,12 +885,15 @@ def check_audit(path: str, final: dict[tuple, float]) -> dict:
 def check_schema_counters(final: dict[tuple, float], level: str) -> dict:
     """``--schema``: every reply has already been held to the plain reading
     (``served_problem``); this reads what the server says of its own
-    validating: the validators it loaded at boot, and that the device route
+    validating: the validators it loaded at boot, that every one of them was
+    compiled (cerbos_tpu/schema.py) and made the runs, and that the device route
     did validate (the batch-shaped requests) beside the CPU walk's routes."""
     name = "cerbos_tpu_schema_validations_total"
     out = {
         "level": level,
         "validators": {k: int(v) for k, v in by_label(final, "cerbos_tpu_schema_validators", "state").items()},
+        "validators_compiled": int(msum(final, "cerbos_tpu_schema_validators_compiled")),
+        "runs_by_engine": by_label(final, "cerbos_tpu_schema_validator_runs_total", "engine"),
         "validations_by_route": {k: int(v) for k, v in by_label(final, name, "route").items()},
         "validations_by_outcome": {k: int(v) for k, v in by_label(final, name, "outcome").items()},
         "errors": int(msum(final, "cerbos_tpu_schema_errors_total")),
@@ -900,6 +903,12 @@ def check_schema_counters(final: dict[tuple, float], level: str) -> dict:
     failures = []
     if out["validators"].get("loaded", 0) < 2 * MODS or out["validators"].get("failed", 0):
         failures.append(f"schema_validators reads {out['validators']}: the template ships {3 * MODS} schemas and its policies name them all")
+    # the template's schemas hold type, properties, required and enum alone: each compiles, in every process
+    compiled, loaded = out["validators_compiled"], out["validators"].get("loaded", 0)
+    if compiled < 3 * MODS or compiled != loaded:
+        failures.append(f"{compiled} of the {loaded} loaded validators are compiled; the template's {3 * MODS} schemas all should be")
+    if out["runs_by_engine"].get("generic", 0) or out["runs_by_engine"].get("compiled", 0) <= 0:
+        failures.append(f"validator runs by engine read {out['runs_by_engine']}: python-jsonschema should have made none")
     if out["validations_by_route"].get("device", 0) <= 0:
         failures.append("no validation was counted on the device route")
     if out["errors"] <= 0 or out["memo"].get("bypass_validation", 0) <= 0:

@@ -22,7 +22,6 @@ import threading
 import time
 from datetime import datetime, timedelta, timezone
 
-import jsonschema
 import pytest
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -159,6 +158,10 @@ def test_a_served_reply_carries_the_plain_readings_errors_and_the_levels_effects
     assert moved(before, after, ERRORS) == want["errors"]
     assert moved(before, after, ERRORS, source="principal") == want["errors_principal"]
     assert moved(before, after, "cerbos_tpu_schema_validate_seconds_count") == want["validations"]
+    # ... each run made by a compiled validator (tests/test_schema_compiled.py), python-jsonschema's none
+    assert moved(before, after, "cerbos_tpu_schema_validator_runs_total", engine="compiled") == want["validations"]
+    assert moved(before, after, "cerbos_tpu_schema_validator_runs_total", engine="generic") == 0
+    assert prom.total(after, "cerbos_tpu_schema_validators_compiled") == 3 * MODS
     for r in ("device", "inline", "oracle"):
         ran = sum(moved(before, after, VALIDATIONS, route=r, outcome=o) for o in ("valid", "invalid"))
         assert ran == (want["validations"] if r == route else 0), r
@@ -184,6 +187,7 @@ def test_with_none_nothing_is_validated_counted_or_loaded(servers, route):
     # every series is there from boot, at 0, and stays there
     assert len([k for k in after if k[0] == VALIDATIONS]) == 2 * 4 * 3 and len([k for k in after if k[0] == ERRORS]) == 2
     for name in (VALIDATIONS, ERRORS, "cerbos_tpu_schema_validate_seconds_count", "cerbos_tpu_schema_validators",
+                 "cerbos_tpu_schema_validators_compiled", "cerbos_tpu_schema_validator_runs_total",
                  "cerbos_tpu_schema_cache_resets_total"):
         assert prom.has(after, name) and prom.total(after, name) == 0, name
     assert moved(before, after, MEMO, result="bypass_validation") == 0
@@ -480,7 +484,7 @@ def test_a_validator_built_from_what_the_store_held_before_an_event_is_never_fil
     disk.reload()  # the event lands while the old bytes are being compiled
     go.set()
     t.join(10)
-    assert not t.is_alive() and isinstance(built[0], jsonschema.Draft202012Validator)  # the request in hand is answered
+    assert not t.is_alive() and built[0].engine == schema_mod.ENGINE_COMPILED  # the request in hand is answered
     assert "cerbos:///principal_0.json" not in mgr._cache  # ... and the next one builds from the store again
 
 
