@@ -24,7 +24,10 @@ cache in :mod:`jitcache` and answers, per process:
   (``cerbos_tpu_xla_preloads_total{outcome}``,
   ``cerbos_tpu_xla_preload_seconds``, flight event ``xla_preload_done``).
   Its loads are compiles like any other to the three families above, and
-  are kept from the storm detector: a deliberate walk is not churn.
+  are kept from the storm detector: a deliberate walk is not churn;
+- the table's layout class, the ``(K, J, D)`` every batch is packed at
+  (``packer.LayoutClass``): ``cerbos_tpu_xla_layout_class{dim}`` and how
+  often traffic raised it, ``cerbos_tpu_xla_layout_class_grows_total{dim}``.
 
 Everything is process-global (like the metrics registry it feeds) so the
 serving batcher, a direct ``check()`` and bench all account into one place.
@@ -58,7 +61,8 @@ STORM_WINDOW_S = 120.0
 PRELOAD_OUTCOMES = ("loaded", "fresh", "held", "failed")
 
 # the dimensions of a jit key, in the order a compile is blamed on them
-NOVEL_DIMS = ("shape", "depth", "variant", "columns")
+NOVEL_DIMS = ("shape", "class", "variant", "columns")
+CLASS_DIMS = ("K", "J", "D")
 NOVEL_COMBINATION = "combination"  # every component seen before, never together
 
 
@@ -72,7 +76,7 @@ def key_components(trace_key: Any) -> Optional[dict]:
         return None
     return {
         "shape": trace_key[:2],
-        "depth": trace_key[2:5],
+        "class": trace_key[2:5],
         "variant": trace_key[5],
         "columns": trace_key[6] if len(trace_key) == 7 else None,
     }
@@ -89,10 +93,14 @@ def _digest(component: Any) -> Optional[str]:
 
 class NoveltyClassifier:
     """Which dimension of the jit key made a compile necessary: the first, in
-    the order of ``NOVEL_DIMS``, whose value no earlier compile had."""
+    the order of ``NOVEL_DIMS``, whose value no earlier compile had. A growth
+    of the layout class is named as what it is: a program (shape and variant)
+    that was built before at ANOTHER class is blamed on ``class``, whether or
+    not a new shape brought that class's value first."""
 
     def __init__(self):
         self._seen: dict[str, set] = {d: set() for d in NOVEL_DIMS}
+        self._classes: dict[tuple, set] = {}  # (shape, variant) -> the classes it was built at
 
     def observe(self, components: dict) -> str:
         novel = NOVEL_COMBINATION
@@ -101,6 +109,10 @@ class NoveltyClassifier:
             if value is not None and value not in self._seen[dim]:
                 self._seen[dim].add(value)
                 novel = dim
+        built_at = self._classes.setdefault((components["shape"], components["variant"]), set())
+        if built_at and components["class"] not in built_at:
+            novel = "class"
+        built_at.add(components["class"])
         return novel
 
 
@@ -170,7 +182,8 @@ class CompileStats:
         self.m_novel = reg.counter_vec(
             "cerbos_tpu_xla_compile_novel_total",
             "XLA compilations by the first dimension of the jit key that was new: shape (B_pad, BA_pad), "
-            "depth (K, J, D), variant, columns (the column layout), or combination (all seen, never together)",
+            "class (K, J, D: a shape and variant built before at another layout class, so the class grew), "
+            "variant, columns (the column layout), or combination (all seen, never together)",
             label="dim",
         )
         self.m_compile_seconds = reg.histogram(
@@ -223,9 +236,26 @@ class CompileStats:
             "Wall time the preloader spent on each manifest entry it walked (trace, load or compile, first call)",
             buckets=_COMPILE_BUCKETS,
         )
+        self.m_class = reg.gauge_vec(
+            "cerbos_tpu_xla_layout_class",
+            "The serving table's layout class by dimension: the K (role slots), J (candidates a slot) and D (scope "
+            "depth) every batch is packed and dispatched at, the running maximum of what its request shapes needed "
+            "(0 before the first device-route pack)",
+            label="dim",
+        )
+        self.m_class_grows = reg.counter_vec(
+            "cerbos_tpu_xla_layout_class_grows_total",
+            "Times a request shape raised a dimension of the layout class since boot: every shape bucket met after "
+            "it is built anew at the grown class, and the layouts of the smaller class are dead",
+            label="dim",
+        )
+        for dim in CLASS_DIMS:
+            self.m_class.set(dim, 0.0)
+            self.m_class_grows.inc(dim, 0.0)
         self.detector = RecompileStormDetector(
             threshold=storm_threshold, window_s=storm_window_s, clock=clock
         )
+        self._layout_class: Optional[tuple] = None
         self._novelty = NoveltyClassifier()
         self._lock = threading.Lock()
         self._layouts: set[Any] = set()
@@ -263,12 +293,15 @@ class CompileStats:
         with self._lock:
             if parts is not None:
                 novel = self._novelty.observe(parts)
-                (b_pad, ba_pad), (k, j, d) = parts["shape"], parts["depth"]
+                (b_pad, ba_pad), (k, j, d) = parts["shape"], parts["class"]
                 key_fields = {
                     "B_pad": b_pad, "BA_pad": ba_pad, "K": k, "J": j, "D": d,
                     "variant": _digest(parts["variant"]), "columns": _digest(parts["columns"]),
                     "novel": novel,
                 }
+                if self._layout_class is not None:
+                    # the table's class NOW: where it differs from K, J, D the batch was packed before a growth
+                    key_fields["layout_class"] = list(self._layout_class)
             self._compiles += 1
             self._compile_seconds += seconds
             if source == "persistent":
@@ -319,6 +352,15 @@ class CompileStats:
         self.m_misses.inc()
         with self._lock:
             self._misses += 1
+
+    def record_layout_class(self, kjd: tuple, grown: tuple = ()) -> None:
+        """The table's layout class is ``kjd``: restored from the manifest,
+        or raised by a request shape in the dimensions ``grown``."""
+        self._layout_class = tuple(kjd)
+        for dim, extent in zip(CLASS_DIMS, kjd):
+            self.m_class.set(dim, float(extent))
+        for dim in grown:
+            self.m_class_grows.inc(dim)
 
     def record_variant_fallback(self) -> None:
         self.m_variant_fallbacks.inc()

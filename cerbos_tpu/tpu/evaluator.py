@@ -42,7 +42,7 @@ from .lowering import (
     SP_OVERRIDE,
     lower_table,
 )
-from .packer import PackedBatch, Packer, PT_PRINCIPAL, PT_RESOURCE
+from .packer import LayoutClass, PackedBatch, Packer, PT_PRINCIPAL, PT_RESOURCE
 
 _log = logging.getLogger("cerbos_tpu.evaluator")
 
@@ -994,8 +994,18 @@ class _LayoutPreloader:
     second finds the key held and drops its copy. ``stop()`` (the
     evaluator's ``invalidate()``, so every cutover) ends the walk: the
     generation is checked under the lock that publishes, so a function built
-    for one table is never published once another is in place. The walk is
-    not started again for the new table.
+    for one table is never published once another is in place. It also arms
+    the preloader again: the new table's first device flight starts a walk
+    of the entries filed under ITS identity, once the old walk has let go of
+    the entry it had in hand.
+
+    The manifest also holds the table's layout class (``packer.LayoutClass``),
+    and :meth:`restore` hands it to the packer ahead of the table's first
+    device-route pack: a file read, no load and no compile. Not where the
+    evaluator is built: that may be a pool's parent, before the fork and
+    before any process has opened the device whose kind the manifest's scope
+    names. The walk skips an entry of another class than the table's own: no
+    flight will ask for it.
     """
 
     def __init__(self, evaluator: "TpuEvaluator"):
@@ -1007,7 +1017,7 @@ class _LayoutPreloader:
 
     def flight(self) -> None:
         """Called by every flight that has to build a layout, once its key is
-        in the jit cache; the first call starts the walk."""
+        in the jit cache; a table's first call starts the walk."""
         if self._started:
             return
         self._started = True
@@ -1015,7 +1025,9 @@ class _LayoutPreloader:
 
         if layoutmanifest.path() is None:
             return
-        self.thread = threading.Thread(target=self._walk, args=(self._gen,), name="xla-preload", daemon=True)
+        self.thread = threading.Thread(
+            target=self._walk, args=(self._gen, self.thread), name="xla-preload", daemon=True
+        )
         # a daemon thread that the interpreter's exit finds inside XLA is torn
         # down with it and aborts the process: an exit waits for the entry in hand
         atexit.register(self.close)
@@ -1024,6 +1036,26 @@ class _LayoutPreloader:
     def stop(self) -> None:
         with self._lock:
             self._gen += 1
+            self._started = False
+
+    def restore(self) -> None:
+        """Ahead of a table's first device-route pack: its packer starts at
+        the class the manifest holds for it. Once a table's life."""
+        cls = self._ev.packer.layout_class
+        if not cls.restored:
+            cls.restore(self._filed_class)
+
+    def _filed_class(self) -> Optional[tuple]:
+        from . import layoutmanifest
+
+        if layoutmanifest.path() is None:
+            return None
+        try:
+            scope = self._scope()
+            return layoutmanifest.layout_class(scope) if scope is not None else None
+        except Exception:  # noqa: BLE001  (the manifest is never worth a request)
+            _log.debug("layout manifest: class not restored", exc_info=True)
+            return None
 
     def close(self) -> None:
         """End the walk and wait for the entry it has in hand."""
@@ -1056,9 +1088,11 @@ class _LayoutPreloader:
         except Exception:  # noqa: BLE001  (the manifest is never worth a request)
             _log.debug("layout manifest: entry not recorded", exc_info=True)
 
-    def _walk(self, gen: int) -> None:
+    def _walk(self, gen: int, before: Optional[threading.Thread]) -> None:
         from . import layoutmanifest
 
+        if before is not None:
+            before.join()  # the walk of the table before this one, stopped, may have an entry in hand
         stats = compilestats.stats()
         counts = dict.fromkeys(compilestats.PRELOAD_OUTCOMES, 0)
         t0 = time.perf_counter()
@@ -1074,14 +1108,18 @@ class _LayoutPreloader:
                     stats.record_preload(outcome, time.perf_counter() - e0)
         except Exception:  # noqa: BLE001  (a walk that fails is a process without a manifest)
             _log.warning("layout preload: walk abandoned", exc_info=True)
-        atexit.unregister(self.close)
+        if self.thread is threading.current_thread():  # not under a later table's walk, which waits for this one
+            atexit.unregister(self.close)
         stats.record_preload_done(counts, time.perf_counter() - t0, stopped=self._gen != gen)
 
     def _load(self, entry: dict, gen: int) -> Optional[str]:
-        """One entry: its outcome, or None when the walk was stopped under it."""
+        """One entry: its outcome, or None when the walk was stopped under it
+        or the entry is of another class than the table's."""
         ev = self._ev
         try:
             key, cut, zeros = _entry_parts(entry)
+            if key[2:5] != ev.packer.layout_class.kjd:
+                return None  # filed before this table's class grew: no flight asks for it again
             if key in ev._jit_cache:
                 return "held"
             B_pad, BA_pad, _K, _J, D, variant, _sig = key
@@ -1265,13 +1303,19 @@ class TpuEvaluator:
         device=None,
         shard_id: Optional[int] = None,
         _lowered: Optional[LoweredTable] = None,
+        _layout_class: Optional[LayoutClass] = None,
     ):
         self.rule_table = rule_table
         self.schema_mgr = schema_mgr
         # lowering is the expensive part of construction; shard clones pass
         # the shared LoweredTable in so a pool of N evaluators lowers ONCE
         self.lowered = _lowered if _lowered is not None else lower_table(rule_table, globals_)
-        self.packer = Packer(self.lowered, max_roles=max_roles, max_candidates=max_candidates, max_depth=max_depth)
+        # one layout class a table, as one lowered table: a lane is handed its owner's and never restarts it
+        self._owns_class = _layout_class is None
+        self.packer = Packer(
+            self.lowered, max_roles=max_roles, max_candidates=max_candidates, max_depth=max_depth,
+            layout_class=_layout_class,
+        )
         self.use_jax = use_jax
         self.min_device_batch = min_device_batch
         self.mesh = mesh
@@ -1332,6 +1376,9 @@ class TpuEvaluator:
         ``refresh()`` re-lowers and then calls this; shard clones sharing the
         lowered table call only this after the owner re-lowered."""
         self.packer.invalidate()
+        if self._owns_class:
+            # after the packer's stores are empty: the table now in place starts from its own class
+            self.packer.layout_class.restart()
         self._preloader.stop()  # before the clear: nothing built for the old table is published after it
         self._jit_cache.clear()
         self._dr_table_cache.clear()
@@ -1374,6 +1421,7 @@ class TpuEvaluator:
             device=device,
             shard_id=shard_id,
             _lowered=self.lowered,
+            _layout_class=self.packer.layout_class,
         )
         return clone
 
@@ -1431,6 +1479,7 @@ class TpuEvaluator:
             return t
         chunks = self._chunk_inputs(inputs)
         t.parts = []
+        self._preloader.restore()
         with start_span("batch.pack", inputs=len(inputs), chunks=len(chunks)), self._device_scope():
             for ch in chunks:
                 drainclock.to(drainclock.PACK, drainclock.PACK_PLAN)

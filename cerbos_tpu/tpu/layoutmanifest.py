@@ -7,7 +7,13 @@ the program, and the program's shape is known only when traffic presents
 it. This file remembers the shapes. Every jitted function the dispatch site
 builds (``evaluator._device_dispatch``) is filed here with what is needed to
 build it again without a batch, so the next process serving the same table
-loads them ahead of traffic (``evaluator._LayoutPreloader``).
+loads them ahead of traffic (``evaluator._LayoutPreloader``). Beside a
+table's layouts it keeps the table's layout CLASS, the ``(K, J, D)`` its
+batches are packed at (``packer.LayoutClass``): the next process starts
+there, so it packs no batch at a smaller class and every layout it needs is
+one the manifest holds. A table's entries are all OF its class: the entry
+that raises the class takes the smaller class's entries out of the file (no
+process of this table builds them again).
 
 Where it lives: ``<jitcache.directory()>/layouts/manifest.json``. A
 subdirectory, so ``jitcache.entry_count()`` (files of the cache directory
@@ -38,9 +44,10 @@ from . import jitcache
 
 _log = logging.getLogger("cerbos_tpu.layoutmanifest")
 
-# 2: an entry is the jit key's parts alone; the device program takes ONE staging buffer whose cut follows
-# from them (1 listed the eight arguments of the program it described, which no process builds any more)
-FORMAT = 2
+# 3: a table is {"class": [K, J, D], "entries": {...}}, every entry of that class (in 2 a table was its entries,
+# of whatever (K, J, D) each batch had of its own; 2 was the first whose entry is the jit key's parts alone). A file
+# of an older format is read as empty and overwritten by the first layout a flight builds
+FORMAT = 3
 MAX_ENTRIES = 512
 _SUBDIR = "layouts"
 _FILE = "manifest.json"
@@ -95,45 +102,79 @@ def _read(p: pathlib.Path) -> dict:
         not isinstance(tables, dict)
         or raw.get("format") != FORMAT
         or not isinstance(raw.get("seq"), int)
-        or not all(
-            isinstance(t, dict) and all(isinstance(e, dict) and isinstance(e.get("met"), int) for e in t.values())
-            for t in tables.values()
-        )
+        or not all(_well_formed(t) for t in tables.values())
     ):
         _warn_once("layout manifest %s is not of format %d, read as empty", p, FORMAT)
         return _empty()
     return raw
 
 
+def _is_class(kjd) -> bool:
+    return isinstance(kjd, list) and len(kjd) == 3 and all(isinstance(x, int) and x > 0 for x in kjd)
+
+
+def _well_formed(table) -> bool:
+    return (
+        isinstance(table, dict)
+        and _is_class(table.get("class"))
+        and isinstance(table.get("entries"), dict)
+        and all(
+            isinstance(e, dict) and isinstance(e.get("met"), int) and e.get("depth") == table["class"]
+            for e in table["entries"].values()
+        )
+    )
+
+
 def _rank(entry: dict) -> tuple:
     return (-entry["met"], entry.get("seq", 0))
 
 
-def entries(scope_key: str) -> list[dict]:
-    """The layouts met under ``scope_key``, most-met first, and among equals
-    in the order traffic first presented them."""
+def _table(scope_key: str) -> Optional[dict]:
     p = path()
-    if p is None:
-        return []
-    return sorted(_read(p)["tables"].get(scope_key, {}).values(), key=_rank)
+    return _read(p)["tables"].get(scope_key) if p is not None else None
+
+
+def entries(scope_key: str) -> list[dict]:
+    """The layouts met under ``scope_key``, all of its class, most-met first,
+    and among equals in the order traffic first presented them."""
+    table = _table(scope_key)
+    return sorted(table["entries"].values(), key=_rank) if table else []
+
+
+def layout_class(scope_key: str) -> Optional[tuple[int, int, int]]:
+    """The class filed under ``scope_key``, or None: one read of the file, no
+    load and no compile."""
+    table = _table(scope_key)
+    return tuple(table["class"]) if table else None
 
 
 def record(scope_key: str, entry: dict) -> None:
     """One process met ``entry`` under ``scope_key``: file it, or count it
-    once more. Never raises: a manifest that cannot be written is a process
-    that records nothing."""
+    once more. An entry larger than the table's class in any extent raises
+    the class to it and takes the entries of the smaller class out; one
+    smaller than the class (a batch packed before a growth and built after
+    it) is not filed. Never raises: a manifest that cannot be written is a
+    process that records nothing."""
     p = path()
     if p is None:
         return
     try:
         doc = _read(p)
         doc["seq"] += 1
-        table = doc["tables"].setdefault(scope_key, {})
-        eid = entry_id(entry)
-        if eid in table:
-            table[eid]["met"] += 1
-        else:
-            table[eid] = dict(entry, met=1, seq=doc["seq"])
+        depth = [int(x) for x in entry["depth"]]
+        table = doc["tables"].setdefault(scope_key, {"class": depth, "entries": {}})
+        kjd = [max(a, b) for a, b in zip(table["class"], depth)]
+        grew = kjd != table["class"]
+        if grew:
+            table["class"], table["entries"] = kjd, {}
+        if depth == kjd:
+            eid = entry_id(entry)
+            if eid in table["entries"]:
+                table["entries"][eid]["met"] += 1
+            else:
+                table["entries"][eid] = dict(entry, met=1, seq=doc["seq"])
+        elif not grew:
+            return
         _bound(doc)
         p.parent.mkdir(parents=True, exist_ok=True)
         fd, tmp = tempfile.mkstemp(dir=p.parent, prefix=_FILE, suffix=".tmp")
@@ -150,15 +191,15 @@ def record(scope_key: str, entry: dict) -> None:
 
 
 def _bound(doc: dict) -> None:
-    over = sum(len(t) for t in doc["tables"].values()) - MAX_ENTRIES
+    over = sum(len(t["entries"]) for t in doc["tables"].values()) - MAX_ENTRIES
     if over <= 0:
         return
     ranked = sorted(
-        (e["met"], e.get("seq", 0), sk, eid) for sk, t in doc["tables"].items() for eid, e in t.items()
+        (e["met"], e.get("seq", 0), sk, eid) for sk, t in doc["tables"].items() for eid, e in t["entries"].items()
     )
     for _, _, sk, eid in ranked[:over]:
-        del doc["tables"][sk][eid]
-    doc["tables"] = {sk: t for sk, t in doc["tables"].items() if t}
+        del doc["tables"][sk]["entries"][eid]
+    doc["tables"] = {sk: t for sk, t in doc["tables"].items() if t["entries"]}
 
 
 def size() -> dict:

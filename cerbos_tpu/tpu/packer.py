@@ -12,6 +12,7 @@ correctness property.
 
 from __future__ import annotations
 
+import threading
 from dataclasses import dataclass
 from typing import Any, Optional
 
@@ -29,6 +30,7 @@ from .columns import (
     TAG_OTHER,
     encode_value,
 )
+from . import compilestats
 from .condcompile import TAG_ERR, evaluate_pred_host
 from .lowering import (
     EFFECT_DENY_CODE,
@@ -95,9 +97,67 @@ class PackedBatch:
     scope_sp: np.ndarray
     # host-side candidate entries for attribution/output reconstruction
     cand_entries: list[list[list[Optional[CandEntry]]]]  # [BA][K][J]
+    # the table's layout class when the batch was packed (LayoutClass), not the batch's own extents
     K: int
     J: int
     D: int
+
+
+class LayoutClass:
+    """The ``(K, J, D)`` every batch of one table is packed and dispatched at.
+
+    Three extents (role slots, candidates a slot, scope depth), each a power
+    of two and at most its cap: the running maximum of what the table's
+    request shapes have needed, never a batch's own maxima, so one shape
+    bucket is one device program whatever the page holds. ``cover`` raises
+    an extent and nothing lowers one within a table's life; ``restart``
+    begins the next table's. Lane evaluators of one table share one object
+    (``TpuEvaluator.shard_clone``), as they share the lowered table. The
+    class starts at ``(1, 1, 1)`` or at what the layout manifest holds for
+    the table (``restore``, once a life, ahead of the first device-route
+    pack: see ``evaluator._LayoutPreloader.restore``)."""
+
+    __slots__ = ("caps", "kjd", "restored", "_lock")
+
+    def __init__(self, max_roles: int, max_candidates: int, max_depth: int):
+        self.caps = (max_roles, max_candidates, max_depth)
+        self.kjd = (1, 1, 1)
+        self.restored = False
+        self._lock = threading.Lock()
+
+    def _raised(self, need) -> tuple[int, int, int]:
+        return tuple(max(have, min(_pow2(n), cap)) for have, n, cap in zip(self.kjd, need, self.caps))
+
+    def cover(self, K: int, J: int, D: int) -> tuple[int, int, int]:
+        """The class, raised first where a request shape needs more."""
+        kjd = self.kjd
+        if K <= kjd[0] and J <= kjd[1] and D <= kjd[2]:
+            return kjd
+        with self._lock:
+            old, self.kjd = self.kjd, self._raised((K, J, D))
+            grown = [dim for dim, a, b in zip(compilestats.CLASS_DIMS, old, self.kjd) if b > a]
+        compilestats.stats().record_layout_class(self.kjd, grown)
+        return self.kjd
+
+    def restore(self, filed) -> None:
+        """Once a table's life: start at ``filed()``'s class (None: stay).
+        Under the lock, so a sibling lane's first pack waits for the read
+        instead of packing below the filed class. Not a growth."""
+        with self._lock:
+            if self.restored:
+                return
+            self.restored = True
+            kjd = filed()
+            if kjd is not None:
+                self.kjd = self._raised(kjd)
+        compilestats.stats().record_layout_class(self.kjd)
+
+    def restart(self) -> None:
+        """Another table is in place: its class is its own, from ``(1, 1, 1)``
+        or from what the manifest holds for ITS identity (``restore``)."""
+        with self._lock:
+            self.kjd = (1, 1, 1)
+            self.restored = False
 
 
 
@@ -140,11 +200,20 @@ def _memo_put(memo: dict, key, val):
 
 
 class Packer:
-    def __init__(self, lowered: LoweredTable, max_roles: int = 8, max_candidates: int = 32, max_depth: int = 8):
+    def __init__(
+        self,
+        lowered: LoweredTable,
+        max_roles: int = 8,
+        max_candidates: int = 32,
+        max_depth: int = 8,
+        layout_class: Optional[LayoutClass] = None,
+    ):
         self.lt = lowered
+        # the caps; the extents a batch is built at are the layout class's
         self.K = max_roles
         self.J = max_candidates
         self.D = max_depth
+        self.layout_class = layout_class if layout_class is not None else LayoutClass(max_roles, max_candidates, max_depth)
         self._cand_cache: dict[tuple, Optional[list[list[CandEntry]]]] = {}
         self._pred_cache: dict[tuple, tuple[bool, bool]] = {}
         self._scope_cache: dict[tuple, tuple] = {}
@@ -165,7 +234,7 @@ class Packer:
         # Python work. Same scheme for scope-permission rows.
         self._block_uid: dict[int, int] = {}
         self._block_store: list[tuple] = []
-        self._block_stacked: dict[tuple[int, int], tuple[int, list[np.ndarray]]] = {}
+        self._block_stacked: Optional[tuple[int, int, int, list[np.ndarray]]] = None  # (K, J, n, arrays)
         self._sp_uid: dict[bytes, int] = {}
         self._sp_store: list[np.ndarray] = []
         self._sp_stacked: Optional[tuple[int, np.ndarray]] = None
@@ -191,7 +260,7 @@ class Packer:
         self._pred_scratch.clear()
         self._block_uid.clear()
         self._block_store.clear()
-        self._block_stacked.clear()
+        self._block_stacked = None
         self._sp_uid.clear()
         self._sp_store.clear()
         self._sp_stacked = None
@@ -395,12 +464,12 @@ class Packer:
 
         drainclock.part(drainclock.PACK_GATHER)
         BA = ba_count
-        # the depth axis buckets to the batch's real max scope-chain length
-        # (pow2 so jit traces are reused), not the configured cap — shallow
-        # fleets halve the lattice's per-depth loop
-        D = min(_pow2(chain_max), self.D)
-        K = min(_pow2(K_max), self.K)
-        J = min(_pow2(J_max), self.J)
+        # the table's class, not this batch's own maxima: what a page holds
+        # (a second role, a second candidate, a scoped resource) does not
+        # choose among device programs. Slots past a shape's own extents are
+        # what they are when one batch mixes shapes: invalid candidates,
+        # conditions and depths -1, scope rows 0
+        K, J, D = self.layout_class.cover(K_max, J_max, chain_max)
         if BA:
             ba_input = np.repeat(
                 np.arange(len(plans), dtype=np.int32),
@@ -456,7 +525,7 @@ class Packer:
         self._cell_cache.clear()
         self._block_uid.clear()
         self._block_store.clear()
-        self._block_stacked.clear()
+        self._block_stacked = None
         self._sp_uid.clear()
         self._sp_store.clear()
         self._sp_stacked = None
@@ -481,18 +550,18 @@ class Packer:
         return uid
 
     def _stacked_blocks(self, K: int, J: int) -> list[np.ndarray]:
-        """[n_blocks, K, J] stacks of every registered block, padded.
-
-        Grows INCREMENTALLY per (K, J) bucket: new registrations append into
-        capacity-doubled arrays (amortized O(new blocks), not O(all blocks)
-        per batch). Buckets are few (pow2 K/J), but evict wholesale past a
-        small cap so stale buckets don't pin old full-size stacks."""
+        """[n_blocks, K, J] stacks of every registered block, padded to the
+        layout class: ONE stack, grown incrementally (new registrations
+        append into capacity-doubled arrays: amortized O(new blocks), not
+        O(all blocks) per batch) and restacked when the class grows."""
         n = len(self._block_store)
-        hit = self._block_stacked.get((K, J))
-        if hit is not None and hit[0] == n:
-            return [a[:n] for a in hit[1]]
-        if hit is not None and hit[1][0].shape[0] >= n:
-            start, arrays = hit[0], hit[1]
+        hit = self._block_stacked
+        if hit is not None and hit[:2] != (K, J):
+            hit = None  # the class grew: restack
+        if hit is not None and hit[2] == n:
+            return [a[:n] for a in hit[3]]
+        if hit is not None and hit[3][0].shape[0] >= n:
+            start, arrays = hit[2], hit[3]
         else:
             cap = max(16, 1 << (n - 1).bit_length()) if n else 16
             arrays = [
@@ -503,25 +572,22 @@ class Packer:
                 np.full((cap, K, J), -1, dtype=np.int8),
                 np.zeros((cap, K, J), dtype=bool),
             ]
+            start = 0
             if hit is not None:
-                old_n = hit[0]
-                for a, old in zip(arrays, hit[1]):
-                    a[:old_n] = old[:old_n]
-                start = old_n
-            else:
-                start = 0
+                start = hit[2]
+                for a, old in zip(arrays, hit[3]):
+                    a[:start] = old[:start]
         for i in range(start, n):
             blk = self._block_store[i]
             kk, jj = blk[0].shape
-            # blocks larger than this batch's (K, J) bucket can never be
-            # gathered by it (the bucket covers the batch max), so truncating
-            # them in this stack is safe
+            # a block this batch gathers fits (its shape raised the class
+            # before the gather). One larger than the class is a lane's, of
+            # the table before a ``restart``, until its own ``invalidate``:
+            # never gathered at this class, so cutting it here is safe
             kk, jj = min(kk, K), min(jj, J)
             for a, src in zip(arrays, blk[:6]):
                 a[i, :kk, :jj] = src[:kk, :jj]
-        if len(self._block_stacked) > 8 and (K, J) not in self._block_stacked:
-            self._block_stacked.clear()
-        self._block_stacked[(K, J)] = (n, arrays)
+        self._block_stacked = (K, J, n, arrays)
         return [a[:n] for a in arrays]
 
     def _stacked_sp(self) -> np.ndarray:

@@ -358,12 +358,12 @@ def test_novelty_classifier_on_a_hand_written_sequence():
     keys = [
         ((32, 64, 2, 4, 1, V1, S1), "shape"),        # everything is new: blamed on the first
         ((32, 128, 2, 4, 1, V1, S1), "shape"),       # BA_pad alone
-        ((32, 64, 2, 8, 1, V1, S1), "depth"),        # J alone
+        ((32, 64, 2, 8, 1, V1, S1), "class"),        # J alone
         ((32, 64, 2, 4, 1, V2, S1), "variant"),
         ((32, 64, 2, 4, 1, V1, S2), "columns"),
         ((32, 128, 2, 8, 1, V2, S2), "combination"),  # every component seen, never together
-        ((64, 128, 2, 8, 2, V2, S2), "shape"),       # shape before depth
-        ((32, 64, 2, 8, 2, V1, S1), "combination"),  # that (K, J, D) came with the key before
+        ((64, 128, 2, 8, 2, V2, S2), "shape"),       # shape before class
+        ((32, 64, 2, 8, 2, V1, S1), "class"),        # that (K, J, D) came with a new shape, but THIS shape was built at another: a growth
         ((16, 16, 1, 1, 1, V1), "shape"),            # the mesh path's key has no column layout
     ]
     for key, want in keys:

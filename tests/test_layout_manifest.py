@@ -86,6 +86,16 @@ def layout_manifest(monkeypatch, tmp_path):
     return tmp_path / "layouts" / "manifest.json"
 
 
+def the_table(p) -> dict:
+    """The one table of the manifest at ``p``: its class and its entries."""
+    (t,) = json.loads(p.read_text())["tables"].values()
+    return t
+
+
+def filed(p) -> dict:
+    return the_table(p)["entries"]
+
+
 def first_process(flag: str = "public") -> tuple[TpuEvaluator, dict]:
     """An evaluator that meets three layouts inside its own flights and files them."""
     ev = TpuEvaluator(table(flag), use_jax=True)
@@ -103,6 +113,7 @@ def test_a_second_process_holds_every_layout_after_one_flight_and_answers_the_sa
     first, answers = first_process()
     assert len(keys(first)) == len(SIZES)
     assert len(layoutmanifest.entries(next(iter(json.loads(layout_manifest.read_text())["tables"])))) == len(SIZES)
+    assert len(filed(layout_manifest)) == len(SIZES)
     before, snap0 = preloads(), compilestats.stats().snapshot()
     second = TpuEvaluator(table(), use_jax=True)
     assert second.check(inputs(SIZES[0]), EvalParams()) == answers[SIZES[0]]
@@ -148,20 +159,26 @@ def test_no_flight_no_thread_and_no_manifest_read(layout_manifest, monkeypatch):
     reads = []
     real = layoutmanifest._read
     monkeypatch.setattr(layoutmanifest, "_read", lambda p: reads.append(p) or real(p))
+    compiles0 = compilestats.stats().snapshot()["compiles"]
     ev = TpuEvaluator(table(), use_jax=True)
-    # under min_device_batch the oracle answers, and a batch no policy covers never reaches the device
+    # under min_device_batch the oracle answers: nothing is packed, nothing read
     ev.check(inputs(3), EvalParams())
+    assert jitcache.status()["manifest"]["bytes"] > 0  # the boot line's status is a stat, not a read
+    assert reads == []
+    numpy_ev = TpuEvaluator(table(), use_jax=False)
+    numpy_ev.check(inputs(SIZES[0]), EvalParams())
+    assert numpy_ev._preloader.thread is None and reads == []
+    # a batch no policy covers is packed for the device and never reaches it: the table's class is read
+    # ahead of that first pack, once (a file read: no thread, no load, no compile), and not again
     strangers = [
         CheckInput(principal=Principal(id="u", roles=["user"]), resource=Resource(kind="nothing", id=str(i)), actions=["view"])
         for i in range(20)
     ]
     ev.check(strangers, EvalParams())
-    assert jitcache.status()["manifest"]["bytes"] > 0  # the boot line's status is a stat, not a read
-    assert ev._preloader.thread is None and reads == []
+    ev.check(strangers, EvalParams())
+    assert ev._preloader.thread is None and len(reads) == 1
     assert not [t for t in threading.enumerate() if t.name == "xla-preload"]
-    numpy_ev = TpuEvaluator(table(), use_jax=False)
-    numpy_ev.check(inputs(SIZES[0]), EvalParams())
-    assert numpy_ev._preloader.thread is None and reads == []
+    assert compilestats.stats().snapshot()["compiles"] == compiles0
 
 
 def test_without_a_cache_directory_nothing_is_recorded_or_loaded(layout_manifest, monkeypatch):
@@ -208,14 +225,35 @@ def _the_format_before_one_buffer(p, first):
     the eight arguments of the program it described."""
     doc = json.loads(p.read_text())
     doc["format"] = 1
+    doc["tables"] = {k: t["entries"] for k, t in doc["tables"].items()}
     for t in doc["tables"].values():
         for e in t.values():
             e["args"] = {name: [[2, 16], "<i4"] for name in OLD_ARGS}
     p.write_text(json.dumps(doc))
 
 
+def _the_format_before_the_class(p, first):
+    """The file as PR 40 to PR 45 wrote it: format 2, a table is its entries."""
+    doc = json.loads(p.read_text())
+    doc["format"] = 2
+    doc["tables"] = {k: t["entries"] for k, t in doc["tables"].items()}
+    p.write_text(json.dumps(doc))
+
+
+def _an_entry_of_another_class(p, first):
+    """Format 3, ill-formed: every entry of a table is of the table's class."""
+    doc = json.loads(p.read_text())
+    for t in doc["tables"].values():
+        next(iter(t["entries"].values()))["depth"] = [8, 8, 8]
+    p.write_text(json.dumps(doc))
+
+
 @pytest.mark.parametrize(
-    "spoil", [_corrupt, _another_table, _another_jax, _another_format, _a_list, _the_format_before_one_buffer]
+    "spoil",
+    [
+        _corrupt, _another_table, _another_jax, _another_format, _a_list, _the_format_before_one_buffer,
+        _the_format_before_the_class, _an_entry_of_another_class,
+    ],
 )
 def test_a_manifest_that_is_not_this_process_s_loads_nothing_and_fails_nothing(layout_manifest, spoil):
     first, answers = first_process()
@@ -232,28 +270,33 @@ def test_a_manifest_that_is_not_this_process_s_loads_nothing_and_fails_nothing(l
     assert keys(second) == keys(first)
 
 
-def test_a_manifest_of_the_old_format_is_ignored_not_walked_and_the_next_record_starts_anew(layout_manifest, caplog):
+@pytest.mark.parametrize("older", [_the_format_before_one_buffer, _the_format_before_the_class])
+def test_a_manifest_of_the_old_format_is_ignored_not_walked_and_the_next_record_starts_anew(
+    layout_manifest, caplog, older
+):
     first_process()
-    _the_format_before_one_buffer(layout_manifest, None)
+    older(layout_manifest, None)
     (scope,) = json.loads(layout_manifest.read_text())["tables"]
     before = preloads()
     with caplog.at_level("WARNING", logger="cerbos_tpu.layoutmanifest"):
-        assert layoutmanifest.entries(scope) == []
-    assert any("not of format 2" in r.getMessage() for r in caplog.records)
+        assert layoutmanifest.entries(scope) == [] and layoutmanifest.layout_class(scope) is None
+    assert any("not of format 3" in r.getMessage() for r in caplog.records)
     second = TpuEvaluator(table(), use_jax=True)
     second.check(inputs(SIZES[1]), EvalParams())
     walked(second)
     assert grown(before) == dict.fromkeys(compilestats.PRELOAD_OUTCOMES, 0)  # nothing walked: no ``failed`` either
     doc = json.loads(layout_manifest.read_text())
-    assert doc["format"] == layoutmanifest.FORMAT == 2
-    (entry,) = doc["tables"][scope].values()
+    assert doc["format"] == layoutmanifest.FORMAT == 3
+    assert doc["tables"][scope]["class"] == [1, 1, 1]
+    (entry,) = doc["tables"][scope]["entries"].values()
     assert entry["shape"][0] == 64 and entry["met"] == 1 and "args" not in entry
 
 
 def test_an_entry_that_cannot_be_built_is_counted_and_skipped(layout_manifest):
     first, answers = first_process()
     doc = json.loads(layout_manifest.read_text())
-    (entries,) = doc["tables"].values()
+    (t,) = doc["tables"].values()
+    entries = t["entries"]
     ids = sorted(entries, key=lambda i: entries[i]["seq"])
     entries[ids[1]]["variant"] = [[99, None]]  # a group this table does not have
     del entries[ids[2]]["layout"]["paths"]
@@ -284,23 +327,53 @@ class _Gate:
 
 
 def test_invalidate_mid_walk_publishes_nothing_for_the_old_table(layout_manifest, monkeypatch):
-    first_process()
+    first, _ = first_process()
     gate = _Gate(monkeypatch)
     before = preloads()
     second = TpuEvaluator(table(), use_jax=True)
     second.check(inputs(SIZES[0]), EvalParams())
     assert gate.inside.wait(60)  # the walk holds an entry it has not built yet
+    old_walk = second._preloader.thread
     second.invalidate()
     assert keys(second) == set()
     gate.go.set()
-    walked(second)
+    old_walk.join(60)
     assert keys(second) == set()
     assert grown(before)["loaded"] + grown(before)["fresh"] == 0
     done = [e for e in recorder().dump()["events"] if e["kind"] == "xla_preload_done"][-1]
     assert done["stopped"] is True and done["loaded"] + done["fresh"] == 0
-    # the table that is in place now is served, and its layouts are its own flights'
+    # the table that is in place now is served, and ITS first device flight starts ITS walk (here the same
+    # identity: ``invalidate()`` alone changes no table), which brings in what its flight did not build
     assert len(second.check(inputs(SIZES[1]), EvalParams())) == SIZES[1]
-    assert len(keys(second)) == 1
+    assert second._preloader.thread is not old_walk
+    walked(second)
+    assert keys(second) == keys(first)
+    done = [e for e in recorder().dump()["events"] if e["kind"] == "xla_preload_done"][-1]
+    assert done["stopped"] is False and done["loaded"] + done["fresh"] == len(SIZES) - 1 and done["held"] == 1
+
+
+def test_a_walk_started_under_a_stopped_one_waits_for_it(layout_manifest, monkeypatch):
+    """Two tables in a row, the second in place while the first's walk still
+    has an entry in hand: one ``xla-preload`` thread inside XLA at a time, and
+    ``close()`` (the interpreter's exit) waits for both."""
+    first_process()
+    gate = _Gate(monkeypatch)
+    second = TpuEvaluator(table(), use_jax=True)
+    second.check(inputs(SIZES[0]), EvalParams())
+    assert gate.inside.wait(60)
+    old_walk = second._preloader.thread
+    second.invalidate()
+    second.check(inputs(SIZES[1]), EvalParams())
+    new_walk = second._preloader.thread
+    assert new_walk is not old_walk and old_walk.is_alive() and new_walk.is_alive()
+    before = preloads()
+    closer = threading.Thread(target=second._preloader.close)
+    closer.start()
+    closer.join(0.5)
+    assert closer.is_alive() and grown(before) == dict.fromkeys(compilestats.PRELOAD_OUTCOMES, 0)
+    gate.go.set()
+    closer.join(60)
+    assert not closer.is_alive() and not old_walk.is_alive() and not new_walk.is_alive()
 
 
 def test_a_flight_and_the_walk_racing_on_one_key_leave_one_function(layout_manifest, monkeypatch):
@@ -322,9 +395,7 @@ def test_a_flight_and_the_walk_racing_on_one_key_leave_one_function(layout_manif
     for n in SIZES:
         assert second.check(inputs(n), EvalParams()) == answers[n]
     # the flight met the layout before the walk did: it is counted once more
-    doc = json.loads(layout_manifest.read_text())
-    (entries,) = doc["tables"].values()
-    assert sorted(e["met"] for e in entries.values()) == [1, 2, 2]
+    assert sorted(e["met"] for e in filed(layout_manifest).values()) == [1, 2, 2]
 
 
 def test_the_walk_never_feeds_the_storm_detector(layout_manifest, monkeypatch):
@@ -371,7 +442,7 @@ def test_the_manifest_is_bounded_and_the_least_met_go_first(layout_manifest, mon
     layoutmanifest.record("old", _entry(2))  # met twice
     for n in (4, 5, 6):
         layoutmanifest.record("new", _entry(n))
-    assert sum(len(t) for t in json.loads(layout_manifest.read_text())["tables"].values()) == 4
+    assert sum(len(t["entries"]) for t in json.loads(layout_manifest.read_text())["tables"].values()) == 4
     # of those met once the oldest went: 1 and 3; the one met twice stays, and leads its table
     assert [e["shape"][0] for e in layoutmanifest.entries("old")] == [2]
     assert [e["shape"][0] for e in layoutmanifest.entries("new")] == [4, 5, 6]
@@ -413,10 +484,8 @@ def test_a_directory_that_cannot_be_written_records_nothing_and_raises_nothing(l
 
 def test_an_entry_round_trips_to_the_key_a_flight_computes(layout_manifest):
     first, _ = first_process()
-    doc = json.loads(layout_manifest.read_text())
-    (entries,) = doc["tables"].values()
     rebuilt = set()
-    for entry in entries.values():
+    for entry in filed(layout_manifest).values():
         key, cut, zeros = evmod._entry_parts(entry)
         rebuilt.add(key)
         assert key[6] == cut.sig

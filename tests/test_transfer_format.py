@@ -251,6 +251,8 @@ def flight_keys(packer, flights):
         stacked, cut, leased = evmod._pad_stack(*pad_args(batch))
         evmod._buffer_pool.release(leased)
         variant = tuple((gi, None) for gi in range(len(packer.lt.compiler.groups)))
+        # since PR 46 the key's (K, J, D) is the table's layout class, whatever this flight holds of its own
+        assert (batch.K, batch.J, batch.D) == packer.layout_class.kjd == (cut.K, cut.J, cut.lay.D)
         yield (cut.B_pad, cut.BA_pad, batch.K, batch.J, batch.D, variant, cut.sig), cut
 
 
