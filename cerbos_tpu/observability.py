@@ -15,6 +15,7 @@ import contextlib
 import json
 import logging
 import os
+import random
 import re
 import signal
 import sys
@@ -69,14 +70,38 @@ def init_logging(level: str = "info", fmt: str = "json") -> None:
 # spans
 
 
+# One generator a process hands out every identifier a request draws (its call
+# id, its trace id, the ids of its spans and of its flight's): seeded from the
+# system when this module is imported and again in every forked child, because
+# a pool's front ends fork after load and would otherwise hand out the same
+# ids in the same order. ``getrandbits`` is one C call under the interpreter
+# lock, so threads share the generator with no lock of its own, and no request
+# leaves the lock for an identifier (``os.urandom`` drops it around the system
+# call, and a request thread that drops it waits out whoever took it). Random,
+# not unpredictable, which is what W3C trace-context asks for and what
+# OpenTelemetry's own ``RandomIdGenerator`` gives: the ids are not secrets.
+_ids = random.Random()  # no argument: seeded from the system's entropy
+os.register_at_fork(after_in_child=_ids.seed)
+
+
 def new_trace_id() -> str:
     """A proper W3C trace id: 32 lowercase hex chars, never all-zero."""
-    return os.urandom(16).hex()
+    while True:
+        n = _ids.getrandbits(128)
+        if n:
+            return f"{n:032x}"
 
 
 def new_span_id() -> str:
     """A proper W3C span id: 16 lowercase hex chars, never all-zero."""
-    return os.urandom(8).hex()
+    while True:
+        n = _ids.getrandbits(64)
+        if n:
+            return f"{n:016x}"
+
+
+# a call id is drawn as a trace id is: 32 lowercase hex digits
+new_call_id = new_trace_id
 
 
 @dataclass(frozen=True)

@@ -120,10 +120,13 @@ class _StampingPool(futures.ThreadPoolExecutor):
     """The sync gRPC server's thread pool, with one clock on it: the wait
     between gRPC's core handing a call to the pool (``submit``) and a worker
     thread starting it: a thread's wake-up and its wait for the interpreter
-    lock. It lies BEFORE the handler's extent (which starts in the stamping
-    deserializer, on the worker thread) and is not added into it; every call
-    gRPC submits is observed, health checks included. Two ``perf_counter``
-    reads and one closure a call."""
+    lock. It lies BEFORE the handler's extent and is not added into it; every
+    call gRPC submits is observed, health checks included. (That extent starts
+    in the stamping deserializer, which the sync server runs on its SERVING
+    thread, in the ``receive_message`` callback: the worker started here then
+    waits on the call's condition for the decoded message, a second wake-up,
+    inside the extent's ``validate`` part.) Two ``perf_counter`` reads and one
+    closure a call."""
 
     def __init__(self, max_workers: int):
         super().__init__(max_workers=max_workers)

@@ -8,7 +8,6 @@ from __future__ import annotations
 
 import contextlib
 import time
-import uuid
 from dataclasses import dataclass, field
 from typing import Any, Optional
 
@@ -16,7 +15,7 @@ from .. import __version__
 from ..engine import types as T
 from ..engine.budget import BACK_AUDIT, BACK_WAKE, FRONT_SPAN
 from ..engine.engine import Engine
-from ..observability import SpanContext, start_span
+from ..observability import SpanContext, new_call_id, start_span
 
 
 class RequestLimitExceeded(ValueError):
@@ -102,7 +101,7 @@ class CerbosService:
         pclass: Optional[str] = None,
         access: Optional[tuple[str, str]] = None,
     ) -> tuple[list[T.CheckOutput], str]:
-        call_id = uuid.uuid4().hex
+        call_id = new_call_id()
         with self._access_logged(call_id, access):
             self._validate_check(inputs)
             t0 = time.perf_counter()
@@ -209,7 +208,7 @@ class CerbosService:
         """``check_resources`` for evaluators that settle on the event loop
         (front-end mode): the handler coroutine awaits the batcher ticket
         directly — no thread-pool hop per request."""
-        call_id = uuid.uuid4().hex
+        call_id = new_call_id()
         with self._access_logged(call_id, access):
             self._validate_check(inputs)
             t0 = time.perf_counter()
@@ -238,7 +237,7 @@ class CerbosService:
     def plan_resources(
         self, input: Any, params: Optional[T.EvalParams] = None, access: Optional[tuple[str, str]] = None
     ) -> tuple[Any, str]:
-        call_id = uuid.uuid4().hex
+        call_id = new_call_id()
         with self._access_logged(call_id, access):
             return self._plan(call_id, input, params), call_id
 

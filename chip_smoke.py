@@ -466,6 +466,7 @@ class ServerProc:
         self.http_port = self.grpc_port = 0
         self.native = None
         self.last_scrape = ""
+        self.call_ids: list[str] = []  # every reply's cerbosCallId, for check_call_ids
         # the server's stdout is read by its own thread for as long as the
         # pipe is open: every line is echoed, the serving line is kept
         self._serving_line = ""
@@ -614,7 +615,9 @@ def _http_caller(srv: ServerProc, timeout: float):
         raw = resp.read()
         if resp.status != 200:
             raise RuntimeError(f"status {resp.status} {raw[:200]!r}")
-        return json.loads(raw).get("results", [])
+        reply = json.loads(raw)
+        srv.call_ids.append(reply.get("cerbosCallId", ""))
+        return reply.get("results", [])
 
     return call, conn.close
 
@@ -638,6 +641,7 @@ def _grpc_caller(srv: ServerProc, timeout: float):
             req.body, request_pb2.CheckResourcesRequest(), ignore_unknown_fields=True
         )
         resp = stub(msg, timeout=timeout)
+        srv.call_ids.append(resp.cerbos_call_id)
         return json_format.MessageToDict(resp).get("results", [])
 
     return call, channel.close
@@ -825,6 +829,20 @@ def run_pass(srv, singles, batches, workers, timeout: float, label: str) -> dict
 AUDIT_ROTATE_MB = 4
 
 
+def check_call_ids(call_ids: list[str]) -> list[str]:
+    """Every reply of a topology names its call: 32 hex digits, and no two the
+    same, whichever process answered (a pool's front ends fork after load, and
+    each draws its ids from a generator of its own seed: observability.py)."""
+    malformed = sum(1 for c in call_ids if not re.fullmatch(r"[0-9a-f]{32}", c))
+    repeated = len(call_ids) - len(set(call_ids))
+    failures = []
+    if malformed:
+        failures.append(f"{malformed} of {len(call_ids)} replies carry no call id of 32 hex digits")
+    if repeated:
+        failures.append(f"{repeated} of {len(call_ids)} replies repeat another reply's call id")
+    return failures
+
+
 def check_audit(path: str, final: dict[tuple, float]) -> dict:
     """``--audit``: what the server left in its audit files once it has exited,
     against its own counters at the last scrape (no check is sent after it):
@@ -1005,6 +1023,7 @@ def run_topology(name, extra_args, tpu_conf, workers, policy_dir, singles, batch
             json.dump({"jitcache": status, "flight": flight}, f)
         if args.lanes:
             failures += check_lanes(status, flight, args.lanes)
+        failures += check_call_ids(srv.call_ids)
         if failures:
             raise SmokeFailure("; ".join(failures))
         log(
@@ -1028,6 +1047,7 @@ def run_topology(name, extra_args, tpu_conf, workers, policy_dir, singles, batch
             "compile_seconds": round(msum(checked["after"], "cerbos_tpu_xla_compile_seconds_sum"), 3),
             "device_share_batch": round(share, 4),
             "split": checked["split"],
+            "call_ids_distinct": len(set(srv.call_ids)),
             "burst": burst,
             "capture": capture,
             **({"audit": audit} if audit else {}),

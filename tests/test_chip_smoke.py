@@ -6,7 +6,7 @@ a subprocess), and its metric parser and each assertion hold or fail as they
 should on recorded ``/_cerbos/metrics`` text — one passing pair of scrapes,
 and one mutation per failure (no device decisions, a fallback reason, a
 breaker trip, a compile in the checked pass, a parity divergence, no parity
-check, no compile at all, no device memory).
+check, no compile at all, no device memory, a call id twice or malformed).
 """
 
 import json
@@ -196,6 +196,23 @@ class TestTotals:
         # what a CPU backend's scrape looks like: the gauge exists and is 0
         failures = self.mutated(cerbos_tpu_device_memory_bytes_in_use=0.0)
         assert failures == ["device_memory_bytes_in_use is 0: the backend holds no device memory"]
+
+
+class TestCallIds:
+    """Every reply of a topology names its call, and no two the same: a pool's
+    front ends fork after load, and each has to draw from a seed of its own."""
+
+    IDS = [f"{n:032x}" for n in (1, 2, 0xABCDEF)]
+
+    def test_distinct_ids_hold(self):
+        assert chip_smoke.check_call_ids(self.IDS) == []
+
+    def test_two_replies_under_one_call_id_fail(self):
+        assert chip_smoke.check_call_ids(self.IDS + self.IDS[:1]) == ["1 of 4 replies repeat another reply's call id"]
+
+    @pytest.mark.parametrize("bad", ["", "ABCDEF" + "0" * 26, "0" * 31])
+    def test_a_reply_without_a_call_id_of_32_hex_digits_fails(self, bad):
+        assert chip_smoke.check_call_ids(self.IDS + [bad]) == ["1 of 4 replies carry no call id of 32 hex digits"]
 
 
 class TestPlatform:

@@ -4,9 +4,11 @@ thread starts the call: once a call, before the handler's extent and not in
 it; the aio server has no pool and observes nothing. Real listeners (the
 test_request_parts harness)."""
 
+import threading
 import time
 
 from cerbos_tpu import observability as obs
+from cerbos_tpu.server import server as server_mod
 from cerbos_tpu.server.server import _StampingPool
 
 from test_request_parts import send_grpc, serve, tracker  # noqa: F401  (the fixture: the waterfall on, so the handler is observed)
@@ -61,3 +63,36 @@ def test_the_wait_is_from_submit_to_the_workers_start_and_the_call_keeps_its_res
         assert 0.04 <= hist(WAIT).sum - before_s < 5
     finally:
         pool.shutdown()
+
+
+def test_the_sync_server_decodes_a_request_on_its_serving_thread_and_handles_it_on_a_worker(tmp_path, tracker, monkeypatch):
+    """Under the sync server gRPC runs the request deserializer in its
+    ``receive_message`` callback, on the one thread that serves the completion
+    queue; the pool's worker, already started (``pool_wait``), waits on the
+    call's condition for the decoded message: so the handler's extent starts
+    on one thread and goes on on another, and its ``front_validate`` part holds
+    a second thread's wake-up."""
+    threads: dict[str, list[threading.Thread]] = {"decode": [], "handle": []}
+
+    class Stamps(server_mod._IngressStamps):
+        def put(self, key, t_raw, t_decoded):  # the stamping deserializer's
+            threads["decode"].append(threading.current_thread())
+            super().put(key, t_raw, t_decoded)
+
+        def pop(self, key):  # the handler's first statement
+            threads["handle"].append(threading.current_thread())
+            return super().pop(key)
+
+    monkeypatch.setattr(server_mod, "_GRPC_STAMPS", Stamps())
+    srv, closers = serve(tmp_path, "standalone")
+    try:
+        for _ in range(5):
+            send_grpc(srv)
+    finally:
+        for close in closers:
+            close()
+    assert len(threads["decode"]) == len(threads["handle"]) == 5
+    assert len({t.ident for t in threads["decode"]}) == 1  # one serving thread
+    assert not {t.ident for t in threads["decode"]} & {t.ident for t in threads["handle"]}
+    assert all(t.name.startswith("ThreadPoolExecutor") for t in threads["handle"])  # the pool's workers
+    assert not threads["decode"][0].name.startswith("ThreadPoolExecutor")
