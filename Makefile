@@ -11,8 +11,9 @@ OVERLOAD_TESTS := tests/test_overload.py
 PLAN_TESTS := tests/test_plan_batch.py
 ROLLOUT_TESTS := tests/test_rollout.py
 PROVENANCE_TESTS := tests/test_provenance.py
-# the native-touching suites: codec round-trips, frame rings, truncation fuzz
-ASAN_TESTS := tests/test_native.py tests/test_shm_transport.py
+# the native-touching suites: codec round-trips, frame rings, truncation fuzz,
+# the gRPC listener's wire codec against protobuf (every corruption of a request)
+ASAN_TESTS := tests/test_native.py tests/test_shm_transport.py tests/test_wire_codec.py
 
 .PHONY: all native native-asan clean test test-transport test-overload \
 	test-plan test-rollout test-provenance test-native-asan lint

@@ -102,9 +102,9 @@ STAGES = (
 
 # parts of the front (tile ``admission``) and of the back (``wake`` + ``audit``
 # + ``encode`` tile ``reply_encode`` for gRPC; for HTTP ``serialize`` lies inside it too)
-FRONT_VALIDATE = "validate"  # wire validation of the decoded request
+FRONT_VALIDATE = "validate"  # wire validation of the decoded request (gRPC: the worker's wake-up; the native reader validated)
 FRONT_AUXDATA = "auxdata"    # the token's extraction and verification (a field test where there is none)
-FRONT_CONVERT = "convert"    # message -> CheckInputs
+FRONT_CONVERT = "convert"    # message -> CheckInputs (gRPC: the token's AuxData attached to what the native reader built)
 FRONT_ADMIT = "admit"        # admission class and try_admit
 FRONT_SPAN = "span"          # deadline, traceparent, request limits, call id, the request span's set-up
 FRONT_ENQUEUE = "enqueue"    # engine.check entry -> the mark that ends admission (lane choice, the queue's lock)
@@ -113,8 +113,8 @@ FRONT_PARTS = (
 )
 BACK_WAKE = "wake"            # the last mark of another thread (settle) -> the handler's thread running again
 BACK_AUDIT = "audit"          # the decision entry built and queued for the audit writer (0 with audit off)
-BACK_ENCODE = "encode"        # span end, the access entry queued, outputs -> response message
-BACK_SERIALIZE = "serialize"  # response message -> bytes
+BACK_ENCODE = "encode"        # span end, the access entry queued, outputs -> response message (gRPC: -> its bytes, native)
+BACK_SERIALIZE = "serialize"  # response message -> bytes (gRPC: a pass-through where the native writer made them)
 BACK_PARTS = (BACK_WAKE, BACK_AUDIT, BACK_ENCODE, BACK_SERIALIZE)
 
 OUTCOME_MET = "deadline_met"

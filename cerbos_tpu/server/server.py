@@ -42,7 +42,7 @@ from ..engine.budget import (
     STAGE_REPLY_ENCODE,
 )
 from ..engine.budget import tracker as budget_tracker
-from .. import fastjson
+from .. import fastjson, native
 from ..engine.flight import recorder as flight_recorder
 from ..engine.pressure import monitor as pressure_monitor
 from ..engine.readiness import state as readiness_state
@@ -87,7 +87,7 @@ _GRPC_REPLY_STAMPS = _IngressStamps()
 
 
 def _stamping_deserializer(deserialize):
-    """Wrap a protobuf ``FromString`` so decode start/end are captured at
+    """Wrap a request deserializer so decode start/end are captured at
     the raw-bytes boundary (works under both the sync and aio servers —
     each runs the deserializer before dispatching to the handler)."""
 
@@ -104,16 +104,71 @@ def _stamping_serializer(serialize):
     """Wrap a protobuf ``SerializeToString`` so the handler's extent ends
     where the response BYTES exist: observes ``serialize`` (end of
     ``reply_encode`` to here) and the handler histogram, once each, for the
-    responses the handler stamped (none with the waterfall off)."""
+    responses the handler stamped (none with the waterfall off). A reply the
+    native codec wrote is bytes already and passes through."""
 
     def wrapped(msg) -> bytes:
         stamp = _GRPC_REPLY_STAMPS.pop(id(msg))
-        data = serialize(msg)
+        data = msg if type(msg) is bytes else serialize(msg)
         if stamp is not None:
             budget_tracker().observe_reply(stamp[0], stamp[1], time.monotonic())
         return data
 
     return wrapped
+
+
+class _WireCodec:
+    """What stands between a CheckResources request's wire bytes and the
+    engine's types, in both directions: the native module's reader and writer
+    (``check_request_decode``, ``check_reply_encode``) where it is loaded and
+    takes the bytes or the outputs at hand, else protobuf's own parse with
+    ``convert`` and ``wire_validate``: the definition, which answers whatever
+    the native codec declines (it returns None: a singular message field met
+    twice, a group, deep nesting, a pattern's non-ASCII subject, an output
+    value ``py_to_value`` would stringify) and raises what is to be raised
+    (malformed bytes: ``DecodeError``). Which path took a request or a reply
+    is decided by what it holds, never by a setting, and counted."""
+
+    def __init__(self):
+        from ..api.cerbos.request.v1 import request_pb2
+
+        self._from_string = request_pb2.CheckResourcesRequest.FromString
+        mod = native.get()
+        self._decode = getattr(mod, "check_request_decode", None)
+        self._encode = getattr(mod, "check_reply_encode", None)
+        self._count = metrics().counter_vec(
+            "cerbos_tpu_wire_codec_total",
+            "gRPC CheckResources requests read (dir=request) and replies written (dir=reply), by what did it: "
+            "native (cerbos_native, no protobuf message built) or python (protobuf + server/convert.py: the native "
+            "module is not loaded, or it declined what the request or the reply holds)",
+            label=("dir", "path"),
+        ).inc
+
+    def decode(self, data: bytes):
+        """The request a handler takes: the native reader's tuple ``(inputs,
+        request_id, include_meta, token, key_set_id, violation, data)``, or
+        the message."""
+        if self._decode is not None:
+            req = self._decode(data, T.Principal, T.Resource, T.CheckInput)
+            if req is not None:
+                self._count(("request", "native"))
+                return req
+        req = self._from_string(data)
+        self._count(("request", "python"))
+        return req
+
+    def encode(self, req, request_id: str, call_id: str, inputs, outputs, include_meta: bool):
+        """The reply a handler returns: its bytes, or the message. ``req`` is
+        what ``decode`` gave, for the message path to read."""
+        if self._encode is not None:
+            data = self._encode(request_id, call_id, inputs, outputs, include_meta)
+            if data is not None:
+                self._count(("reply", "native"))
+                return data
+        if type(req) is tuple:
+            req = self._from_string(req[6])
+        self._count(("reply", "python"))
+        return convert.outputs_to_check_resources_response(req, outputs, call_id)
 
 
 class _StampingPool(futures.ThreadPoolExecutor):
@@ -349,10 +404,16 @@ def _grpc_rpcs(svc: CerbosService):
     from ..api.cerbos.request.v1 import request_pb2
     from ..api.cerbos.response.v1 import response_pb2
 
-    def check_resources(req: request_pb2.CheckResourcesRequest, ctx: grpc.ServicerContext):
+    codec = _WireCodec()
+
+    def check_resources(req, ctx: grpc.ServicerContext):
+        """``req`` is what the codec's reader gave the deserializer: the native
+        one's tuple (inputs built and the wire rules applied, on the bytes), or
+        a ``CheckResourcesRequest`` (the Python path; tests and shims hand the
+        handler one too)."""
         # raw-bytes ingress stamp recorded by the wrapped deserializer: the
-        # waterfall starts when the request BYTES arrived, so protobuf
-        # decode cost is a visible stage instead of unattributed time
+        # waterfall starts when the request BYTES arrived, so the decode's
+        # cost is a visible stage instead of unattributed time
         stamp = _GRPC_STAMPS.pop(id(req))
         t_raw = stamp[0] if stamp is not None else time.monotonic()
         # the record exists from here so the seams of the front half can be
@@ -360,7 +421,14 @@ def _grpc_rpcs(svc: CerbosService):
         wf = budget_tracker().start(t0=t_raw)
         if wf is not None and stamp is not None:
             wf.mark(STAGE_INGRESS_PARSE, now=stamp[1])
-        verr = wire_validate.check_resources_proto(req)
+        if type(req) is tuple:
+            inputs, request_id, include_meta, token, key_set_id, verr, _ = req
+        else:
+            inputs = None
+            request_id, include_meta = req.request_id, req.include_meta
+            jwt = req.aux_data.jwt if req.HasField("aux_data") else None
+            token, key_set_id = (jwt.token, jwt.key_set_id) if jwt is not None else ("", "")
+            verr = wire_validate.check_resources_proto(req)
         if verr:
             budget_tracker().count(OUTCOME_REFUSED)
             ctx.abort(grpc.StatusCode.INVALID_ARGUMENT, verr)
@@ -369,12 +437,14 @@ def _grpc_rpcs(svc: CerbosService):
         ticket = None
         pclass = None
         try:
-            aux = None
-            if req.HasField("aux_data") and req.aux_data.jwt.token:
-                aux = svc._extract_aux_data(req.aux_data.jwt.token, req.aux_data.jwt.key_set_id)
+            aux = svc._extract_aux_data(token, key_set_id) if token else None
             if wf is not None:
                 wf.part(FRONT_AUXDATA)
-            inputs = convert.check_resources_request_to_inputs(req, aux)
+            if inputs is None:
+                inputs = convert.check_resources_request_to_inputs(req, aux)
+            elif aux is not None:
+                for i in inputs:
+                    i.aux_data = aux
             if wf is not None:
                 wf.part(FRONT_CONVERT)
             # front-door admission (see the HTTP handler): refuse with
@@ -414,7 +484,7 @@ def _grpc_rpcs(svc: CerbosService):
             if trace_ctx is not None:
                 with contextlib.suppress(Exception):  # shim contexts may lack it
                     ctx.set_trailing_metadata((("traceparent", trace_ctx.to_traceparent()),))
-            resp = convert.outputs_to_check_resources_response(req, outputs, call_id)
+            resp = codec.encode(req, request_id, call_id, inputs, outputs, include_meta)
             outcome = OUTCOME_ORACLE if wf is not None and wf.served_by == "oracle" else OUTCOME_MET
             t_encoded = budget_tracker().finish(
                 wf, outcome, final_stage=STAGE_REPLY_ENCODE, final_part=BACK_ENCODE
@@ -598,9 +668,7 @@ def _grpc_rpcs(svc: CerbosService):
             check_resources,
             # stamped at the raw-bytes boundary: decode cost is waterfall
             # stage one, not invisible pre-handler time
-            request_deserializer=_stamping_deserializer(
-                request_pb2.CheckResourcesRequest.FromString
-            ),
+            request_deserializer=_stamping_deserializer(codec.decode),
             # and the handler's extent ends where the response bytes exist
             response_serializer=_stamping_serializer(
                 response_pb2.CheckResourcesResponse.SerializeToString

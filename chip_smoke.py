@@ -22,7 +22,10 @@ server process:
   ``decision_source_total{source="device"}`` covers >= 0.9 of the batch-shaped
   decisions, no oracle fallback for any reason, no breaker trip, no XLA
   compile; overall: >= 1 compile, >= 1 parity check and 0 divergences, device
-  memory in use > 0, the native module loaded, exit 0 on SIGTERM;
+  memory in use > 0, the native module loaded, exit 0 on SIGTERM; every gRPC
+  request read and every reply written by the listener's native codec
+  (``wire_codec_total`` shows no ``path="python"`` in either direction, in
+  whichever process listened);
 - under ``--frontends 2``, that the pool is one server to its operator: every
   scrape is ONE request and must hold ``fe1``, ``fe2`` and ``batcher``, and one
   profiler capture asked of the served HTTP port (a front end, which holds no
@@ -843,6 +846,19 @@ def check_call_ids(call_ids: list[str]) -> list[str]:
     return failures
 
 
+def check_codec(final: dict[tuple, float]) -> list[str]:
+    """The gRPC listener's codec on the classic traffic: every request read
+    and every reply written natively, in whichever process listened (the
+    requests come from protobuf's own encoder and the classic template emits
+    no rule outputs, so one ``python`` is a codec that lost its way)."""
+    failures = []
+    for direction in ("request", "reply"):
+        took = {p: int(msum(final, "cerbos_tpu_wire_codec_total", dir=direction, path=p)) for p in ("native", "python")}
+        if took["python"] or not took["native"]:
+            failures.append(f"wire codec, {direction}: {took} (want every gRPC {direction} native)")
+    return failures
+
+
 def check_audit(path: str, final: dict[tuple, float]) -> dict:
     """``--audit``: what the server left in its audit files once it has exited,
     against its own counters at the last scrape (no check is sent after it):
@@ -1024,6 +1040,7 @@ def run_topology(name, extra_args, tpu_conf, workers, policy_dir, singles, batch
         if args.lanes:
             failures += check_lanes(status, flight, args.lanes)
         failures += check_call_ids(srv.call_ids)
+        failures += check_codec(final)
         if failures:
             raise SmokeFailure("; ".join(failures))
         log(
@@ -1048,6 +1065,7 @@ def run_topology(name, extra_args, tpu_conf, workers, policy_dir, singles, batch
             "device_share_batch": round(share, 4),
             "split": checked["split"],
             "call_ids_distinct": len(set(srv.call_ids)),
+            "wire_codec": by_label(final, "cerbos_tpu_wire_codec_total", "path"),
             "burst": burst,
             "capture": capture,
             **({"audit": audit} if audit else {}),

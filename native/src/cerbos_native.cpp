@@ -2351,6 +2351,815 @@ PyObject* PyReplyUnpack(PyObject*, PyObject* args) {
   return out;
 }
 
+// -- the gRPC listener's codec -----------------------------------------------
+//
+// check_request_decode / check_reply_encode (server/server.py): a
+// cerbos.request.v1.CheckResourcesRequest read from its wire bytes straight
+// into validated CheckInputs, and a cerbos.response.v1.CheckResourcesResponse
+// written straight from the CheckOutputs, so a request builds no protobuf
+// message and walks none in Python. protobuf's own parse with
+// server/convert.py and server/wire_validate.py stays the DEFINITION: both
+// functions DECLINE (return None, raise nothing) whatever they are not sure
+// to read or write exactly as those do, and the caller then takes that route,
+// which also raises what is to be raised (malformed bytes: FromString's
+// DecodeError). tests/test_wire_codec.py holds the two to each other.
+
+namespace wire {
+
+// Value-in-Value nesting the decoder follows and the encoder writes. A level
+// is three messages to protobuf (Value, Struct or ListValue, the entry),
+// whose own limit is 100: what is declined here for depth it still decides.
+constexpr int kMaxValueDepth = 16;
+
+struct Ref {
+  PyObject* o;
+  explicit Ref(PyObject* p = nullptr) : o(p) {}
+  Ref(Ref&& other) noexcept : o(other.o) { other.o = nullptr; }
+  Ref(const Ref&) = delete;
+  Ref& operator=(const Ref&) = delete;
+  ~Ref() { Py_XDECREF(o); }
+  void reset(PyObject* p) {
+    Py_XDECREF(o);
+    o = p;
+  }
+  PyObject* release() {
+    PyObject* p = o;
+    o = nullptr;
+    return p;
+  }
+  explicit operator bool() const { return o != nullptr; }
+};
+
+struct Cur {
+  const uint8_t* p;
+  const uint8_t* end;
+  bool done() const { return p >= end; }
+  size_t left() const { return static_cast<size_t>(end - p); }
+};
+
+// the bytes of a string field, kept beside its str for the validation rules
+struct Slice {
+  const char* p = nullptr;
+  size_t n = 0;
+  bool operator==(const Slice& other) const {
+    return n == other.n && (n == 0 || memcmp(p, other.p, n) == 0);
+  }
+};
+
+enum : uint32_t { kVarint = 0, kFixed64 = 1, kLen = 2, kFixed32 = 5 };
+
+// A varint of at most ten bytes in its shortest spelling (a padded one reads
+// the same everywhere, but no encoder writes it: declined, not studied).
+inline bool ReadVarint(Cur& c, uint64_t* out) {
+  uint64_t v = 0;
+  for (int i = 0; i < 10; i++) {
+    if (c.done()) return false;
+    const uint8_t b = *c.p++;
+    if (i == 9 && b > 1) return false;
+    v |= static_cast<uint64_t>(b & 0x7f) << (7 * i);
+    if (!(b & 0x80)) {
+      if (i > 0 && b == 0) return false;
+      *out = v;
+      return true;
+    }
+  }
+  return false;
+}
+
+inline bool ReadTag(Cur& c, uint32_t* field, uint32_t* wt) {
+  uint64_t v;
+  if (!ReadVarint(c, &v) || v > UINT32_MAX || (v >> 3) == 0) return false;
+  *field = static_cast<uint32_t>(v >> 3);
+  *wt = static_cast<uint32_t>(v & 7);
+  return true;
+}
+
+inline bool ReadLen(Cur& c, Cur* sub) {
+  uint64_t n;
+  if (!ReadVarint(c, &n) || n >= INT32_MAX || n > c.left()) return false;
+  sub->p = c.p;
+  sub->end = c.p + n;
+  c.p += n;
+  return true;
+}
+
+// An unknown field is skipped as protobuf skips it; a group (wire types 3
+// and 4) and the two wire types that do not exist are declined.
+inline bool Skip(Cur& c, uint32_t wt) {
+  uint64_t v;
+  Cur sub;
+  switch (wt) {
+    case kVarint:
+      return ReadVarint(c, &v);
+    case kFixed64:
+      if (c.left() < 8) return false;
+      c.p += 8;
+      return true;
+    case kLen:
+      return ReadLen(c, &sub);
+    case kFixed32:
+      if (c.left() < 4) return false;
+      c.p += 4;
+      return true;
+    default:
+      return false;
+  }
+}
+
+// A proto3 string: strict UTF-8, as FromString refuses anything else.
+inline PyObject* ReadStr(Cur& c, Slice* slice = nullptr) {
+  Cur s;
+  if (!ReadLen(c, &s)) return nullptr;
+  const char* q = reinterpret_cast<const char*>(s.p);
+  if (slice) {
+    slice->p = q;
+    slice->n = s.left();
+  }
+  return PyUnicode_DecodeUTF8(q, static_cast<Py_ssize_t>(s.left()), nullptr);
+}
+
+// A singular string field: the last one met stands, as in protobuf.
+inline bool ReadStrInto(Cur& c, Ref* slot, Slice* slice = nullptr) {
+  PyObject* s = ReadStr(c, slice);
+  if (!s) return false;
+  slot->reset(s);
+  return true;
+}
+
+inline bool AppendStr(Cur& c, PyObject* lst, std::vector<Slice>* slices) {
+  Slice slice;
+  Ref s(ReadStr(c, &slice));
+  if (!s || PyList_Append(lst, s.o) < 0) return false;
+  slices->push_back(slice);
+  return true;
+}
+
+PyObject* DecodeValueMsg(Cur c, int depth);
+
+// One entry of map<string, google.protobuf.Value> (Principal.attr,
+// Resource.attr, Struct.fields): key 1, value 2, in either order; an entry
+// of a key the map has replaces it. An entry that holds anything else is
+// declined: upb keeps such an entry out of the map, among the unknown fields.
+bool DecodeAttrEntry(Cur c, PyObject* dict, int depth) {
+  Ref key, val;
+  while (!c.done()) {
+    uint32_t f, wt;
+    if (!ReadTag(c, &f, &wt) || wt != kLen) return false;
+    if (f == 1) {
+      if (!ReadStrInto(c, &key)) return false;
+    } else if (f == 2) {
+      Cur sub;
+      if (val || !ReadLen(c, &sub)) return false;  // a second value would MERGE
+      val.reset(DecodeValueMsg(sub, depth));
+      if (!val) return false;
+    } else {
+      return false;
+    }
+  }
+  if (!key) {
+    key.reset(PyUnicode_New(0, 0));
+    if (!key) return false;
+  }
+  return PyDict_SetItem(dict, key.o, val ? val.o : Py_None) == 0;
+}
+
+// Struct (fields 1, the map) when `entries`, else ListValue (values 1).
+PyObject* DecodeContainer(Cur c, bool entries, int depth) {
+  if (depth > kMaxValueDepth) return nullptr;
+  Ref out(entries ? PyDict_New() : PyList_New(0));
+  if (!out) return nullptr;
+  while (!c.done()) {
+    uint32_t f, wt;
+    if (!ReadTag(c, &f, &wt)) return nullptr;
+    if (f != 1) {
+      if (!Skip(c, wt)) return nullptr;
+      continue;
+    }
+    Cur sub;
+    if (wt != kLen || !ReadLen(c, &sub)) return nullptr;
+    if (entries) {
+      if (!DecodeAttrEntry(sub, out.o, depth)) return nullptr;
+    } else {
+      Ref v(DecodeValueMsg(sub, depth));
+      if (!v || PyList_Append(out.o, v.o) < 0) return nullptr;
+    }
+  }
+  return out.release();
+}
+
+// google.protobuf.Value -> what convert.value_to_py gives: no field of the
+// oneof is None, a number a float. A second field of the oneof (protobuf: the
+// last one stands, two of one message merge) is declined.
+PyObject* DecodeValueMsg(Cur c, int depth) {
+  Ref out;
+  while (!c.done()) {
+    uint32_t f, wt;
+    if (!ReadTag(c, &f, &wt)) return nullptr;
+    if (f > 6) {
+      if (!Skip(c, wt)) return nullptr;
+      continue;
+    }
+    if (out) return nullptr;
+    uint64_t v;
+    Cur sub;
+    switch (f) {
+      case 1:  // null_value
+        if (wt != kVarint || !ReadVarint(c, &v)) return nullptr;
+        out.reset(Py_NewRef(Py_None));
+        break;
+      case 2: {  // number_value
+        if (wt != kFixed64 || c.left() < 8) return nullptr;
+        double d;
+        memcpy(&d, c.p, 8);
+        c.p += 8;
+        out.reset(PyFloat_FromDouble(d));
+        break;
+      }
+      case 3:  // string_value
+        if (wt != kLen) return nullptr;
+        out.reset(ReadStr(c));
+        break;
+      case 4:  // bool_value
+        if (wt != kVarint || !ReadVarint(c, &v)) return nullptr;
+        out.reset(PyBool_FromLong(v != 0));
+        break;
+      default:  // 5 struct_value, 6 list_value
+        if (wt != kLen || !ReadLen(c, &sub)) return nullptr;
+        out.reset(DecodeContainer(sub, f == 5, depth + 1));
+    }
+    if (!out) return nullptr;
+  }
+  if (!out) Py_RETURN_NONE;
+  return out.release();
+}
+
+// engine.v1.Principal (id 1, policy_version 2, roles 3, attr 4, scope 5) or
+// engine.v1.Resource (kind 1, policy_version 2, id 3, attr 4, scope 5) as it
+// was read: the strs the instance gets, and their bytes for the rules.
+struct Entity {
+  Ref first, version, third, scope, attr;
+  Slice s_first, s_version, s_third, s_scope;
+  std::vector<Slice> roles;
+};
+
+bool ParseEntity(Cur c, bool principal, Entity* e) {
+  e->attr.reset(PyDict_New());
+  if (!e->attr) return false;
+  if (principal) {
+    e->third.reset(PyList_New(0));
+    if (!e->third) return false;
+  }
+  while (!c.done()) {
+    uint32_t f, wt;
+    if (!ReadTag(c, &f, &wt)) return false;
+    if (f > 5) {
+      if (!Skip(c, wt)) return false;
+      continue;
+    }
+    if (wt != kLen) return false;
+    bool ok;
+    switch (f) {
+      case 1:
+        ok = ReadStrInto(c, &e->first, &e->s_first);
+        break;
+      case 2:
+        ok = ReadStrInto(c, &e->version, &e->s_version);
+        break;
+      case 3:
+        ok = principal ? AppendStr(c, e->third.o, &e->roles)
+                       : ReadStrInto(c, &e->third, &e->s_third);
+        break;
+      case 4: {
+        Cur sub;
+        ok = ReadLen(c, &sub) && DecodeAttrEntry(sub, e->attr.o, 0);
+        break;
+      }
+      default:
+        ok = ReadStrInto(c, &e->scope, &e->s_scope);
+    }
+    if (!ok) return false;
+  }
+  return true;
+}
+
+// Built as ticket_unpack builds them: no __init__, and no normalize_attr,
+// which has nothing to do on what a Value holds.
+PyObject* BuildEntity(const Entity& e, PyObject* cls, bool principal, PyObject* empty) {
+  Ref obj(NewInstance(cls));
+  if (!obj) return nullptr;
+  auto set = [&](PyObject* name, const Ref& v) {
+    return PyObject_SetAttr(obj.o, name, v ? v.o : empty) == 0;
+  };
+  if (!set(principal ? I.id : I.kind, e.first) || !set(principal ? I.roles : I.id, e.third) ||
+      !set(I.attr, e.attr) || !set(I.policy_version, e.version) || !set(I.scope, e.scope))
+    return nullptr;
+  return obj.release();
+}
+
+// CheckResourcesRequest.ResourceEntry: actions 1, resource 2
+struct Entry {
+  Ref actions;
+  std::vector<Slice> action_slices;
+  bool has_resource = false;
+  Entity resource;
+};
+
+bool ParseEntry(Cur c, Entry* e) {
+  e->actions.reset(PyList_New(0));
+  if (!e->actions) return false;
+  while (!c.done()) {
+    uint32_t f, wt;
+    if (!ReadTag(c, &f, &wt)) return false;
+    if (f > 2) {
+      if (!Skip(c, wt)) return false;
+      continue;
+    }
+    if (wt != kLen) return false;
+    if (f == 1) {
+      if (!AppendStr(c, e->actions.o, &e->action_slices)) return false;
+    } else {
+      Cur sub;
+      if (e->has_resource || !ReadLen(c, &sub) || !ParseEntity(sub, false, &e->resource))
+        return false;
+      e->has_resource = true;
+    }
+  }
+  return true;
+}
+
+// AuxData (jwt 1) -> JWT (token 1, key_set_id 2)
+bool ParseAuxData(Cur c, Ref* token, Ref* key_set) {
+  bool seen = false;
+  while (!c.done()) {
+    uint32_t f, wt;
+    if (!ReadTag(c, &f, &wt)) return false;
+    if (f != 1) {
+      if (!Skip(c, wt)) return false;
+      continue;
+    }
+    Cur jwt;
+    if (wt != kLen || seen || !ReadLen(c, &jwt)) return false;
+    seen = true;
+    while (!jwt.done()) {
+      if (!ReadTag(jwt, &f, &wt)) return false;
+      if (f > 2) {
+        if (!Skip(jwt, wt)) return false;
+        continue;
+      }
+      if (wt != kLen || !ReadStrInto(jwt, f == 1 ? token : key_set)) return false;
+    }
+  }
+  return true;
+}
+
+// -- the protovalidate rules, as server/wire_validate.py words them ----------
+//
+// The two patterns are Python's, matched on a str: \w is every Unicode word
+// character, so a value with a byte over 0x7f is the regular expression's to
+// judge (declined); and `$` also matches before ONE newline that ends the
+// string, so such a newline is taken off before the look.
+
+inline bool IsWord(char ch) {
+  return (ch >= '0' && ch <= '9') || (ch >= 'a' && ch <= 'z') || (ch >= 'A' && ch <= 'Z') ||
+         ch == '_';
+}
+
+inline bool Ascii(Slice s) {
+  for (size_t i = 0; i < s.n; i++)
+    if (static_cast<unsigned char>(s.p[i]) & 0x80) return false;
+  return true;
+}
+
+inline Slice BeforeLastNewline(Slice s) {
+  if (s.n && s.p[s.n - 1] == '\n') s.n--;
+  return s;
+}
+
+// ^[\w]*$
+bool VersionOk(Slice s) {
+  s = BeforeLastNewline(s);
+  for (size_t i = 0; i < s.n; i++)
+    if (!IsWord(s.p[i])) return false;
+  return true;
+}
+
+// ^(^$|\.|[0-9a-zA-Z][\w\-]*(\.\w[\w\-]*)*)$
+bool ScopeOk(Slice s) {
+  s = BeforeLastNewline(s);
+  if (s.n == 0 || (s.n == 1 && s.p[0] == '.')) return true;
+  if (!IsWord(s.p[0]) || s.p[0] == '_') return false;
+  for (size_t i = 1; i < s.n; i++) {
+    const char ch = s.p[i];
+    if (ch == '.') {
+      if (i + 1 >= s.n || !IsWord(s.p[i + 1])) return false;
+    } else if (!IsWord(ch) && ch != '-') {
+      return false;
+    }
+  }
+  return true;
+}
+
+enum class Rule { kHolds, kViolated, kDeclined };
+
+// CheckItems looks for a duplicate pair by pair: a list longer than any the
+// service admits (50 actions a resource) is the Python path's, with its set
+constexpr size_t kMaxItems = 64;
+
+// wire_validate._check_actions over a repeated string that is there
+Rule CheckItems(const std::vector<Slice>& items, const std::string& field, std::string* msg) {
+  if (items.empty()) {
+    *msg = field + ": value is required and must contain at least one item";
+    return Rule::kViolated;
+  }
+  for (size_t i = 0; i < items.size(); i++) {
+    if (items[i].n == 0) {
+      *msg = field + ": items must be non-empty strings";
+      return Rule::kViolated;
+    }
+    for (size_t j = 0; j < i; j++) {
+      if (items[j] == items[i]) {
+        *msg = field + ": items must be unique";
+        return Rule::kViolated;
+      }
+    }
+  }
+  return Rule::kHolds;
+}
+
+// the policy version and the scope of a principal or a resource
+Rule CheckVersionScope(const Entity& e, const std::string& field, std::string* msg) {
+  if (!Ascii(e.s_version)) return Rule::kDeclined;
+  if (!VersionOk(e.s_version)) {
+    *msg = field + ".policyVersion: must match ^[\\w]*$";
+    return Rule::kViolated;
+  }
+  if (!Ascii(e.s_scope)) return Rule::kDeclined;
+  if (!ScopeOk(e.s_scope)) {
+    *msg = field + ".scope: invalid scope";
+    return Rule::kViolated;
+  }
+  return Rule::kHolds;
+}
+
+// wire_validate.check_resources_proto: the same rules in the same order,
+// the first violation in the same words.
+Rule Validate(bool has_principal, const Entity& principal, const std::vector<Entry>& entries,
+              std::string* msg) {
+  if (!has_principal) {
+    *msg = "principal: value is required";
+    return Rule::kViolated;
+  }
+  if (principal.s_first.n == 0) {
+    *msg = "principal.id: value length must be at least 1";
+    return Rule::kViolated;
+  }
+  Rule r = CheckItems(principal.roles, "principal.roles", msg);
+  if (r != Rule::kHolds) return r;
+  r = CheckVersionScope(principal, "principal", msg);
+  if (r != Rule::kHolds) return r;
+  if (entries.empty()) {
+    *msg = "resources: value is required and must contain at least one item";
+    return Rule::kViolated;
+  }
+  for (size_t i = 0; i < entries.size(); i++) {
+    const Entry& e = entries[i];
+    const std::string at = "resources[" + std::to_string(i) + "]";
+    r = CheckItems(e.action_slices, at + ".actions", msg);
+    if (r != Rule::kHolds) return r;
+    if (!e.has_resource) {
+      *msg = at + ".resource: value is required";
+      return Rule::kViolated;
+    }
+    if (e.resource.s_first.n == 0) {
+      *msg = at + ".resource.kind: value length must be at least 1";
+      return Rule::kViolated;
+    }
+    if (e.resource.s_third.n == 0) {
+      *msg = at + ".resource.id: value length must be at least 1";
+      return Rule::kViolated;
+    }
+    r = CheckVersionScope(e.resource, at + ".resource", msg);
+    if (r != Rule::kHolds) return r;
+  }
+  return Rule::kHolds;
+}
+
+// -> (inputs, request_id, include_meta, token, key_set_id, violation, data),
+// or nullptr: declined where no exception is set.
+PyObject* DecodeRequest(PyObject* data, PyObject* cls_p, PyObject* cls_r, PyObject* cls_inp) {
+  const uint8_t* base = reinterpret_cast<const uint8_t*>(PyBytes_AS_STRING(data));
+  Cur c{base, base + PyBytes_GET_SIZE(data)};
+  Ref empty(PyUnicode_New(0, 0));
+  Ref request_id, token, key_set;
+  if (!empty) return nullptr;
+  bool include_meta = false, has_principal = false, aux_seen = false;
+  Entity principal;
+  std::vector<Entry> entries;
+  while (!c.done()) {
+    uint32_t f, wt;
+    if (!ReadTag(c, &f, &wt)) return nullptr;
+    if (f > 5) {
+      if (!Skip(c, wt)) return nullptr;
+      continue;
+    }
+    if (f == 2) {
+      uint64_t v;
+      if (wt != kVarint || !ReadVarint(c, &v)) return nullptr;
+      include_meta = v != 0;
+      continue;
+    }
+    if (wt != kLen) return nullptr;
+    if (f == 1) {
+      if (!ReadStrInto(c, &request_id)) return nullptr;
+      continue;
+    }
+    Cur sub;
+    if (!ReadLen(c, &sub)) return nullptr;
+    if (f == 3) {
+      if (has_principal || !ParseEntity(sub, true, &principal)) return nullptr;
+      has_principal = true;
+    } else if (f == 4) {
+      entries.emplace_back();
+      Entry& e = entries.back();
+      if (!ParseEntry(sub, &e) || e.action_slices.size() > kMaxItems) return nullptr;
+    } else {
+      if (aux_seen || !ParseAuxData(sub, &token, &key_set)) return nullptr;
+      aux_seen = true;
+    }
+  }
+  if (principal.roles.size() > kMaxItems) return nullptr;
+
+  std::string msg;
+  const Rule rule = Validate(has_principal, principal, entries, &msg);
+  if (rule == Rule::kDeclined) return nullptr;
+  Ref violation;
+  Ref inputs(PyList_New(0));
+  if (!inputs) return nullptr;
+  PyObject* rid = request_id ? request_id.o : empty.o;
+  if (rule == Rule::kViolated) {
+    violation.reset(PyUnicode_FromStringAndSize(msg.data(), static_cast<Py_ssize_t>(msg.size())));
+    if (!violation) return nullptr;
+  } else {
+    Ref p(BuildEntity(principal, cls_p, true, empty.o));
+    if (!p) return nullptr;
+    for (const Entry& e : entries) {
+      Ref r(BuildEntity(e.resource, cls_r, false, empty.o));
+      Ref inp(r ? NewInstance(cls_inp) : nullptr);
+      if (!inp || PyObject_SetAttr(inp.o, I.request_id, rid) < 0 ||
+          PyObject_SetAttr(inp.o, I.principal, p.o) < 0 ||
+          PyObject_SetAttr(inp.o, I.resource, r.o) < 0 ||
+          PyObject_SetAttr(inp.o, I.actions, e.actions.o) < 0 ||
+          PyObject_SetAttr(inp.o, I.aux_data, Py_None) < 0 ||
+          PyList_Append(inputs.o, inp.o) < 0)
+        return nullptr;
+    }
+  }
+  return PyTuple_Pack(7, inputs.o, rid, include_meta ? Py_True : Py_False, token ? token.o : empty.o,
+                      key_set ? key_set.o : empty.o, violation ? violation.o : Py_None, data);
+}
+
+// -- the reply's writer --------------------------------------------------------
+
+struct Out {
+  std::string s;
+  void varint(uint64_t v) {
+    while (v >= 0x80) {
+      s.push_back(static_cast<char>(v | 0x80));
+      v >>= 7;
+    }
+    s.push_back(static_cast<char>(v));
+  }
+  void tag(uint32_t field, uint32_t wt) { varint(field << 3 | wt); }
+  // a length-delimited field whose length is known when its content is
+  // written: one byte is kept for it, and the few that are longer than 127
+  // bytes move their content up by the rest
+  size_t begin(uint32_t field) {
+    tag(field, kLen);
+    s.push_back(0);
+    return s.size();
+  }
+  void end(size_t start) {
+    size_t n = s.size() - start;
+    if (n < 0x80) {
+      s[start - 1] = static_cast<char>(n);
+      return;
+    }
+    char more[10];
+    size_t k = 0;
+    s[start - 1] = static_cast<char>(n | 0x80);
+    for (n >>= 7; n >= 0x80; n >>= 7) more[k++] = static_cast<char>(n | 0x80);
+    more[k++] = static_cast<char>(n);
+    s.insert(start, more, k);
+  }
+  // a string field; proto3 leaves an empty singular one out (`always`: an
+  // item of a repeated field, a map's key, a member of a oneof)
+  bool str(uint32_t field, PyObject* u, bool always = false) {
+    if (!u || !PyUnicode_Check(u)) return false;
+    Py_ssize_t n;
+    const char* q = PyUnicode_AsUTF8AndSize(u, &n);
+    if (!q) return false;
+    if (n == 0 && !always) return true;
+    tag(field, kLen);
+    varint(static_cast<uint64_t>(n));
+    s.append(q, static_cast<size_t>(n));
+    return true;
+  }
+  // the attribute `name` of `obj` as a string field
+  bool attr(uint32_t field, PyObject* obj, PyObject* name) {
+    Ref v(PyObject_GetAttr(obj, name));
+    return str(field, v.o);
+  }
+};
+
+inline bool IsListOrTuple(PyObject* v) { return PyList_Check(v) || PyTuple_Check(v); }
+
+// convert.py_to_value: the content of a google.protobuf.Value. What that
+// function would stringify, and a key that is no str, is declined.
+bool EncodeValueMsg(Out& o, PyObject* v, int depth) {
+  if (v == Py_None) {
+    o.tag(1, kVarint);
+    o.varint(0);
+    return true;
+  }
+  if (PyBool_Check(v)) {
+    o.tag(4, kVarint);
+    o.varint(v == Py_True);
+    return true;
+  }
+  if (PyLong_Check(v) || PyFloat_Check(v)) {
+    const double d = PyFloat_Check(v) ? PyFloat_AS_DOUBLE(v) : PyLong_AsDouble(v);
+    if (d == -1.0 && PyErr_Occurred()) return false;  // an int no double holds
+    o.tag(2, kFixed64);
+    o.s.append(reinterpret_cast<const char*>(&d), 8);
+    return true;
+  }
+  if (PyUnicode_Check(v)) return o.str(3, v, true);
+  if (depth >= kMaxValueDepth) return false;
+  if (IsListOrTuple(v)) {
+    const size_t lst = o.begin(6);
+    const Py_ssize_t n = PySequence_Fast_GET_SIZE(v);
+    for (Py_ssize_t i = 0; i < n; i++) {
+      const size_t item = o.begin(1);
+      if (!EncodeValueMsg(o, PySequence_Fast_GET_ITEM(v, i), depth + 1)) return false;
+      o.end(item);
+    }
+    o.end(lst);
+    return true;
+  }
+  if (PyDict_Check(v)) {
+    if (PyDict_GET_SIZE(v) == 0) return true;  // py_to_value sets no member of the oneof
+    const size_t st = o.begin(5);
+    PyObject *key, *value;
+    Py_ssize_t pos = 0;
+    while (PyDict_Next(v, &pos, &key, &value)) {
+      const size_t entry = o.begin(1);
+      if (!o.str(1, key, true)) return false;
+      const size_t val = o.begin(2);
+      if (!EncodeValueMsg(o, value, depth + 1)) return false;
+      o.end(val);
+      o.end(entry);
+    }
+    o.end(st);
+    return true;
+  }
+  return false;
+}
+
+inline uint32_t EffectEnum(PyObject* effect) {
+  if (PyUnicode_CompareWithASCIIString(effect, "EFFECT_ALLOW") == 0) return 1;
+  if (PyUnicode_CompareWithASCIIString(effect, "EFFECT_NO_MATCH") == 0) return 3;
+  return 2;  // EFFECT_DENY, and whatever is no effect: _EFFECT_TO_ENUM.get's default
+}
+
+// One ResultEntry: resource 1, actions 2, validation_errors 3, meta 4, outputs 5
+bool EncodeResult(Out& o, PyObject* inp, PyObject* out, bool include_meta) {
+  Ref res(PyObject_GetAttr(inp, I.resource));
+  if (!res) return false;
+  size_t at = o.begin(1);
+  if (!o.attr(1, res.o, I.id) || !o.attr(2, res.o, I.kind) ||
+      !o.attr(3, res.o, I.policy_version) || !o.attr(4, res.o, I.scope))
+    return false;
+  o.end(at);
+
+  Ref acts(PyObject_GetAttr(out, I.actions));
+  if (!acts || !PyDict_Check(acts.o)) return false;
+  PyObject *action, *ae;
+  Py_ssize_t pos = 0;
+  while (PyDict_Next(acts.o, &pos, &action, &ae)) {
+    Ref effect(PyObject_GetAttr(ae, I.effect));
+    if (!effect || !PyUnicode_Check(effect.o)) return false;
+    at = o.begin(2);
+    if (!o.str(1, action, true)) return false;
+    o.tag(2, kVarint);
+    o.varint(EffectEnum(effect.o));
+    o.end(at);
+  }
+
+  Ref verrs(PyObject_GetAttr(out, I.validation_errors));
+  if (!verrs || !IsListOrTuple(verrs.o)) return false;
+  for (Py_ssize_t i = 0; i < PySequence_Fast_GET_SIZE(verrs.o); i++) {
+    PyObject* ve = PySequence_Fast_GET_ITEM(verrs.o, i);
+    Ref source(PyObject_GetAttr(ve, I.source));
+    if (!source || !PyUnicode_Check(source.o)) return false;
+    at = o.begin(3);
+    if (!o.attr(1, ve, I.path) || !o.attr(2, ve, I.message)) return false;
+    const uint32_t src = PyUnicode_CompareWithASCIIString(source.o, "SOURCE_PRINCIPAL") == 0   ? 1
+                         : PyUnicode_CompareWithASCIIString(source.o, "SOURCE_RESOURCE") == 0 ? 2
+                                                                                              : 0;
+    if (src) {
+      o.tag(3, kVarint);
+      o.varint(src);
+    }
+    o.end(at);
+  }
+
+  if (include_meta) {
+    // Meta: actions 1 (-> EffectMeta: matched_policy 1, matched_scope 2),
+    // effective_derived_roles 2; there even when it holds nothing
+    at = o.begin(4);
+    pos = 0;
+    while (PyDict_Next(acts.o, &pos, &action, &ae)) {
+      const size_t entry = o.begin(1);
+      if (!o.str(1, action, true)) return false;
+      const size_t em = o.begin(2);
+      if (!o.attr(1, ae, I.policy) || !o.attr(2, ae, I.scope)) return false;
+      o.end(em);
+      o.end(entry);
+    }
+    Ref roles(PyObject_GetAttr(out, I.effective_derived_roles));
+    if (!roles || !IsListOrTuple(roles.o)) return false;
+    for (Py_ssize_t i = 0; i < PySequence_Fast_GET_SIZE(roles.o); i++)
+      if (!o.str(2, PySequence_Fast_GET_ITEM(roles.o, i), true)) return false;
+    o.end(at);
+  }
+
+  Ref outs(PyObject_GetAttr(out, I.outputs));
+  if (!outs || !IsListOrTuple(outs.o)) return false;
+  for (Py_ssize_t i = 0; i < PySequence_Fast_GET_SIZE(outs.o); i++) {
+    // engine.v1.OutputEntry: src 1, val 2 (where there is no error), action 3, error 4
+    PyObject* oe = PySequence_Fast_GET_ITEM(outs.o, i);
+    Ref error(PyObject_GetAttr(oe, I.error));
+    if (!error || !PyUnicode_Check(error.o)) return false;
+    at = o.begin(5);
+    if (!o.attr(1, oe, I.src)) return false;
+    if (PyUnicode_GET_LENGTH(error.o) == 0) {
+      Ref val(PyObject_GetAttr(oe, I.val));
+      const size_t v = o.begin(2);
+      if (!val || !EncodeValueMsg(o, val.o, 0)) return false;
+      o.end(v);
+    }
+    if (!o.attr(3, oe, I.action) || !o.str(4, error.o)) return false;
+    o.end(at);
+  }
+  return true;
+}
+
+}  // namespace wire
+
+// check_request_decode(data, Principal, Resource, CheckInput)
+//   -> (inputs, request_id, include_meta, token, key_set_id, violation, data)
+//      | None (declined: FromString + convert + wire_validate answer)
+PyObject* PyCheckRequestDecode(PyObject*, PyObject* args) {
+  PyObject *data, *cls_p, *cls_r, *cls_inp;
+  if (!PyArg_ParseTuple(args, "SOOO", &data, &cls_p, &cls_r, &cls_inp)) return nullptr;
+  PyObject* out = wire::DecodeRequest(data, cls_p, cls_r, cls_inp);
+  if (out) return out;
+  // bytes that are no UTF-8, a class that is none: whatever went wrong, the
+  // Python path reads the same bytes and raises what is to be raised
+  PyErr_Clear();
+  Py_RETURN_NONE;
+}
+
+// check_reply_encode(request_id, call_id, inputs, outputs, include_meta)
+//   -> bytes | None (declined: convert.outputs_to_check_resources_response answers)
+// CheckResourcesResponse: request_id 1, results 2, cerbos_call_id 3
+PyObject* PyCheckReplyEncode(PyObject*, PyObject* args) {
+  PyObject *request_id, *call_id, *inputs, *outputs;
+  int include_meta;
+  if (!PyArg_ParseTuple(args, "OOOOp", &request_id, &call_id, &inputs, &outputs, &include_meta))
+    return nullptr;
+  bool ok = wire::IsListOrTuple(inputs) && wire::IsListOrTuple(outputs);
+  wire::Out o;
+  o.s.reserve(4096);
+  ok = ok && o.str(1, request_id);
+  if (ok) {
+    Py_ssize_t n = PySequence_Fast_GET_SIZE(inputs);
+    if (PySequence_Fast_GET_SIZE(outputs) < n) n = PySequence_Fast_GET_SIZE(outputs);
+    for (Py_ssize_t i = 0; ok && i < n; i++) {
+      const size_t at = o.begin(2);
+      ok = wire::EncodeResult(o, PySequence_Fast_GET_ITEM(inputs, i),
+                              PySequence_Fast_GET_ITEM(outputs, i), include_meta != 0);
+      o.end(at);
+    }
+  }
+  ok = ok && o.str(3, call_id);
+  if (!ok) {
+    PyErr_Clear();
+    Py_RETURN_NONE;
+  }
+  return PyBytes_FromStringAndSize(o.s.data(), static_cast<Py_ssize_t>(o.s.size()));
+}
+
 // -- shared-memory byte ring -------------------------------------------------
 //
 // One ring per direction per front end, over a file-backed shared mmap. The
@@ -3150,6 +3959,13 @@ PyMethodDef kMethods[] = {
     {"reply_unpack", PyReplyUnpack, METH_VARARGS,
      "reply_unpack(data, CheckOutput, ActionEffect, ValidationError, "
      "OutputEntry) -> (outputs, spec)"},
+    {"check_request_decode", PyCheckRequestDecode, METH_VARARGS,
+     "check_request_decode(data, Principal, Resource, CheckInput) -> (inputs, "
+     "request_id, include_meta, token, key_set_id, violation, data) | None — a "
+     "CheckResourcesRequest's wire bytes into validated CheckInputs"},
+    {"check_reply_encode", PyCheckReplyEncode, METH_VARARGS,
+     "check_reply_encode(request_id, call_id, inputs, outputs, include_meta) "
+     "-> bytes | None — a CheckResourcesResponse's wire bytes from CheckOutputs"},
     {"ring_init", PyRingInit, METH_VARARGS,
      "ring_init(buf) — zero the header and stamp magic/capacity"},
     {"ring_push", PyRingPush, METH_VARARGS,

@@ -80,6 +80,8 @@ def test_traced_pages_run_reads_all_eighteen(tmp_path, monkeypatch):
     assert 0 < got["pool_wait_mean_ms.pages"] < 250
     boot = [got[n] for n in BOOT[:-1]]
     assert all(s > 0 for s in boot) and sum(boot) <= got["boot_ready_s"] < setup_s(tmp_path / "out")
+    # PR 49: every page read and every reply written by the listener's native codec
+    assert value(m, "wire_native_share.pages") == 100.0 and "wire_native_share.sidecar" not in m
 
 
 def test_traced_sidecar_run_reads_the_boot_phases_and_the_pool_wait(tmp_path, monkeypatch):
@@ -91,4 +93,5 @@ def test_traced_sidecar_run_reads_the_boot_phases_and_the_pool_wait(tmp_path, mo
     boot = [value(m, n) for n in BOOT]
     assert sum(boot[:-1]) <= boot[-1] < setup_s(tmp_path / "out")
     assert 0 < value(m, "pool_wait_mean_ms.sidecar") < 250
+    assert value(m, "wire_native_share.sidecar") == 100.0 and "wire_native_share.pages" not in m
     assert not set(m) & set(PACK + DISPATCH + BYTES + ["pool_wait_mean_ms.pages", PUTS])

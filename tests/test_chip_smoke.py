@@ -215,6 +215,33 @@ class TestCallIds:
         assert chip_smoke.check_call_ids(self.IDS + [bad]) == ["1 of 4 replies carry no call id of 32 hex digits"]
 
 
+class TestWireCodec:
+    """On the classic traffic every gRPC request is read and every reply
+    written by the native codec, in whichever process listened."""
+
+    CODEC = "cerbos_tpu_wire_codec_total"
+
+    def scrape(self, **python):
+        m = {}
+        for worker in ("fe1", "fe2"):
+            for direction in ("request", "reply"):
+                bump(self.CODEC, 40, dir=direction, path="native", worker=worker)(m)
+        for direction, n in python.items():
+            bump(self.CODEC, n, dir=direction, path="python", worker="fe2")(m)
+        return m
+
+    def test_all_native_in_both_directions_holds(self):
+        assert chip_smoke.check_codec(self.scrape()) == []
+
+    @pytest.mark.parametrize("direction", ["request", "reply"])
+    def test_one_on_the_python_path_in_one_front_end_fails(self, direction):
+        (failure,) = chip_smoke.check_codec(self.scrape(**{direction: 1}))
+        assert failure.startswith(f"wire codec, {direction}: {{'native': 80, 'python': 1}}")
+
+    def test_a_scrape_without_the_counter_fails_in_both_directions(self):
+        assert len(chip_smoke.check_codec({})) == 2
+
+
 class TestPlatform:
     def test_cpu_platform_is_refused(self):
         status = {"device": {"platform": "cpu", "device_kind": "cpu", "count": 1, "pid": 1}}
