@@ -329,7 +329,7 @@ class AuditLog:
 
     def write_access(self, call_id: str, method: str, peer: str = "", error: str = "") -> Optional[str]:
         """One entry per call that reached the service (server/service.py:
-        ``_access_logged``), under the call id of its decision entry;
+        ``_access_entry``), under the call id of its decision entry;
         ``error`` names what a call that was not answered raised."""
         if not self.access_logs_enabled or self.backend is None:
             return None

@@ -26,28 +26,14 @@ from ..engine import brownout as brownout_ctl
 from ..engine import types as T
 from ..engine.admission import OverloadRefused, retry_after_header
 from ..engine.admission import controller as admission_controller
-from ..engine.batcher import DeadlineExceeded
-from ..engine.budget import (
-    BACK_ENCODE,
-    BACK_SERIALIZE,
-    FRONT_ADMIT,
-    FRONT_AUXDATA,
-    FRONT_CONVERT,
-    FRONT_VALIDATE,
-    OUTCOME_EXPIRED,
-    OUTCOME_MET,
-    OUTCOME_ORACLE,
-    OUTCOME_REFUSED,
-    STAGE_INGRESS_PARSE,
-    STAGE_REPLY_ENCODE,
-)
+from ..engine.budget import BACK_ENCODE, BACK_SERIALIZE, OUTCOME_MET, OUTCOME_REFUSED
 from ..engine.budget import tracker as budget_tracker
 from .. import fastjson, native
 from ..engine.flight import recorder as flight_recorder
 from ..engine.pressure import monitor as pressure_monitor
 from ..engine.readiness import state as readiness_state
 from ..observability import metrics, parse_traceparent
-from . import convert, wire_validate
+from . import checkcall, convert, wire_validate
 from .service import CerbosService, RequestLimitExceeded
 
 
@@ -156,6 +142,18 @@ class _WireCodec:
         req = self._from_string(data)
         self._count(("request", "python"))
         return req
+
+    def fields(self, req):
+        """What a handler reads of a request, whichever ``decode`` gave (or a
+        test or a shim hands it: a message): ``(inputs | None, request_id,
+        include_meta, token, key_set_id, violation)``. The native reader built
+        the inputs and applied the wire rules on the bytes; a message's inputs
+        are still to be made (``convert.check_resources_request_to_inputs``)."""
+        if type(req) is tuple:
+            return req[:6]
+        jwt = req.aux_data.jwt if req.HasField("aux_data") else None
+        token, key_set_id = (jwt.token, jwt.key_set_id) if jwt is not None else ("", "")
+        return None, req.request_id, req.include_meta, token, key_set_id, wire_validate.check_resources_proto(req)
 
     def encode(self, req, request_id: str, call_id: str, inputs, outputs, include_meta: bool):
         """The reply a handler returns: its bytes, or the message. ``req`` is
@@ -395,7 +393,7 @@ def aio_generic_handler(service_name: str, rpcs: dict, inline: bool = True):
 
 
 # the method an access entry names (audit.accessLogsEnabled; the service
-# queues the entry, service.py: _access_logged): upstream's HTTP gateway logs
+# queues the entry, service.py: _access_entry): upstream's HTTP gateway logs
 # the gRPC method it forwards to, so both listeners name the same
 _SVC = "/cerbos.svc.v1.CerbosService/"
 
@@ -407,106 +405,44 @@ def _grpc_rpcs(svc: CerbosService):
     codec = _WireCodec()
 
     def check_resources(req, ctx: grpc.ServicerContext):
-        """``req`` is what the codec's reader gave the deserializer: the native
-        one's tuple (inputs built and the wire rules applied, on the bytes), or
-        a ``CheckResourcesRequest`` (the Python path; tests and shims hand the
+        """gRPC's adapter over ``checkcall``. ``req`` is what the codec's reader
+        gave the deserializer: the native one's tuple, or a
+        ``CheckResourcesRequest`` (the Python path; tests and shims hand the
         handler one too)."""
-        # raw-bytes ingress stamp recorded by the wrapped deserializer: the
-        # waterfall starts when the request BYTES arrived, so the decode's
-        # cost is a visible stage instead of unattributed time
+        # the wrapped deserializer's stamp: when the request BYTES arrived and
+        # when they were decoded
         stamp = _GRPC_STAMPS.pop(id(req))
-        t_raw = stamp[0] if stamp is not None else time.monotonic()
-        # the record exists from here so the seams of the front half can be
-        # stamped into it; the deadline joins it below
-        wf = budget_tracker().start(t0=t_raw)
-        if wf is not None and stamp is not None:
-            wf.mark(STAGE_INGRESS_PARSE, now=stamp[1])
-        if type(req) is tuple:
-            inputs, request_id, include_meta, token, key_set_id, verr, _ = req
-        else:
-            inputs = None
-            request_id, include_meta = req.request_id, req.include_meta
-            jwt = req.aux_data.jwt if req.HasField("aux_data") else None
-            token, key_set_id = (jwt.token, jwt.key_set_id) if jwt is not None else ("", "")
-            verr = wire_validate.check_resources_proto(req)
-        if verr:
-            budget_tracker().count(OUTCOME_REFUSED)
-            ctx.abort(grpc.StatusCode.INVALID_ARGUMENT, verr)
-        if wf is not None:
-            wf.part(FRONT_VALIDATE)
-        ticket = None
-        pclass = None
+        call = checkcall.CheckCall(*stamp) if stamp is not None else checkcall.CheckCall(time.monotonic())
+        inputs, call.request_id, call.include_meta, token, key_set_id, violation = codec.fields(req)
         try:
-            aux = svc._extract_aux_data(token, key_set_id) if token else None
-            if wf is not None:
-                wf.part(FRONT_AUXDATA)
-            if inputs is None:
-                inputs = convert.check_resources_request_to_inputs(req, aux)
-            elif aux is not None:
-                for i in inputs:
-                    i.aux_data = aux
-            if wf is not None:
-                wf.part(FRONT_CONVERT)
-            # front-door admission (see the HTTP handler): refuse with
-            # RESOURCE_EXHAUSTED before the batcher sees the request
-            adm = admission_controller()
-            if adm.enabled:
-                first = inputs[0] if inputs else None
-                cls = adm.classify(
-                    first.principal.id if first is not None else "",
-                    first.principal.roles if first is not None else (),
-                    [i.resource.kind for i in inputs],
-                    api="check",
-                )
-                pclass = cls.name
-                ticket = adm.try_admit(cls)
-            if wf is not None:
-                wf.part(FRONT_ADMIT)
-            # propagate the client's gRPC deadline down the device path so
-            # already-expired requests are dropped instead of evaluated
-            deadline = None
+            checkcall.front(
+                svc, call, violation, token, key_set_id, inputs, convert.check_resources_request_to_inputs, req
+            )
             remaining = ctx.time_remaining()
             if remaining is not None:
-                deadline = time.monotonic() + remaining
-            if wf is not None:
-                wf.deadline = deadline
+                call.due(time.monotonic() + remaining)
             # W3C trace-context rides gRPC metadata; the parsed context
             # parents the request span so the device batch joins the
             # caller's trace (shim contexts may lack the metadata accessor)
             meta_fn = getattr(ctx, "invocation_metadata", None)
-            trace_ctx = parse_traceparent(
+            call.trace_ctx = parse_traceparent(
                 dict(meta_fn() or ()).get("traceparent") if meta_fn is not None else None
             )
-            outputs, call_id = svc.check_resources(
-                inputs, deadline=deadline, trace_ctx=trace_ctx, wf=wf, pclass=pclass,
-                access=svc.access_of(_SVC + "CheckResources", lambda: ctx.peer()),
-            )
-            if trace_ctx is not None:
+            call.access = svc.access_of(_SVC + "CheckResources", lambda: ctx.peer())
+            outputs, call_id = checkcall.enter(svc.check_resources, call)
+            if call.trace_ctx is not None:
                 with contextlib.suppress(Exception):  # shim contexts may lack it
-                    ctx.set_trailing_metadata((("traceparent", trace_ctx.to_traceparent()),))
-            resp = codec.encode(req, request_id, call_id, inputs, outputs, include_meta)
-            outcome = OUTCOME_ORACLE if wf is not None and wf.served_by == "oracle" else OUTCOME_MET
-            t_encoded = budget_tracker().finish(
-                wf, outcome, final_stage=STAGE_REPLY_ENCODE, final_part=BACK_ENCODE
-            )
+                    ctx.set_trailing_metadata((("traceparent", call.trace_ctx.to_traceparent()),))
+            resp = codec.encode(req, call.request_id, call_id, call.inputs, outputs, call.include_meta)
+            t_encoded = checkcall.back(call, BACK_ENCODE)
             if t_encoded is not None:
-                _GRPC_REPLY_STAMPS.put(id(resp), t_raw, t_encoded)
+                _GRPC_REPLY_STAMPS.put(id(resp), call.t_raw, t_encoded)
             return resp
-        except OverloadRefused as e:
-            admission_controller().observe_refusal(time.monotonic() - t_raw)
-            budget_tracker().finish(wf, OUTCOME_REFUSED)
-            ctx.abort(grpc.StatusCode.RESOURCE_EXHAUSTED, str(e))
-        except RequestLimitExceeded as e:
-            budget_tracker().finish(wf, OUTCOME_REFUSED)
-            ctx.abort(grpc.StatusCode.INVALID_ARGUMENT, str(e))
-        except DeadlineExceeded as e:
-            budget_tracker().finish(wf, OUTCOME_EXPIRED)
-            ctx.abort(grpc.StatusCode.DEADLINE_EXCEEDED, str(e))
-        except Exception as e:  # noqa: BLE001
-            ctx.abort(grpc.StatusCode.INTERNAL, f"check failed: {e}")
+        except Exception as e:  # noqa: BLE001  (every row of checkcall.REFUSALS, the last one anything else)
+            row, message, _ = checkcall.refuse(call, e)
         finally:
-            if ticket is not None:
-                ticket.release()
+            call.release()
+        ctx.abort(row.grpc, message)
 
     def plan_resources(req: request_pb2.PlanResourcesRequest, ctx: grpc.ServicerContext):
         if brownout_ctl.controller().active("shed_plan"):
@@ -720,6 +656,11 @@ def _health_handler():
     return grpc.method_handlers_generic_handler("grpc.health.v1.Health", _health_rpcs())
 
 
+def _json_inputs(body: dict, aux: Optional[T.AuxData]) -> list[T.CheckInput]:
+    """JSON's maker of a request's inputs, for ``checkcall.front``."""
+    return convert.json_to_check_inputs(body, aux)[0]
+
+
 def _plan_from_json(
     svc: CerbosService, body: dict, aux: Optional[T.AuxData], access: Optional[tuple[str, str]] = None
 ) -> tuple[dict, str]:
@@ -820,7 +761,9 @@ class Server:
         (they are short and synchronous), so a call costs no thread hop —
         the sync server's dominant per-call overhead on small hosts."""
         server = grpc.aio.server(options=self._grpc_options())
-        inline = self.config.direct_dispatch
+        # its handlers are synchronous: they cannot await the evaluator
+        way = checkcall.way_in(self.svc.engine, self.config.direct_dispatch, can_await=False)
+        inline = way is checkcall.INLINE
         handlers = [
             aio_generic_handler("cerbos.svc.v1.CerbosService", _grpc_rpcs(self.svc), inline),
             # health checks are tiny and non-blocking: always inline
@@ -1381,122 +1324,57 @@ class Server:
         return web.Response(text=body, content_type="text/plain")
 
     async def _h_check_resources(self, request: web.Request) -> web.Response:
-        # ingress stamp BEFORE the body is read/parsed: the waterfall starts
-        # at the raw-bytes boundary, so JSON decode cost is stage one
-        t_raw = time.monotonic()
+        """HTTP's adapter over ``checkcall``."""
+        # ingress stamp BEFORE the body is read: the waterfall starts at the
+        # raw-bytes boundary, so the JSON decode's cost is stage one
+        call = checkcall.CheckCall(time.monotonic())
         try:
             # parse from raw bytes via the native JSON kernel when built
-            # (fastjson falls back to stdlib) — skips aiohttp's str decode
+            # (fastjson falls back to stdlib): skips aiohttp's str decode
             body = fastjson.loads(await request.read())
         except json.JSONDecodeError:
             return web.json_response({"code": 3, "message": "invalid JSON payload"}, status=400)
         if not isinstance(body, dict):
             return web.json_response({"code": 3, "message": "invalid JSON payload"}, status=400)
-        # the parse stage ends where the body is decoded, as in the gRPC
-        # handler: wire validation is the first part of admission
-        wf = budget_tracker().start(t0=t_raw)
-        if wf is not None:
-            wf.mark(STAGE_INGRESS_PARSE)
-        verr = wire_validate.check_resources_body(body)
-        if verr:
-            budget_tracker().count(OUTCOME_REFUSED)
-            return web.json_response({"code": 3, "message": verr}, status=400)
-        if wf is not None:
-            wf.part(FRONT_VALIDATE)
-        ticket = None
-        pclass = None
+        # the parse stage ends where the body is decoded, as for gRPC: wire
+        # validation is the first part of admission
+        call.t_parsed = time.monotonic()
+        violation = wire_validate.check_resources_body(body)
         try:
-            aux = None
-            aux_j = (body.get("auxData") or {}).get("jwt") or {}
-            if aux_j.get("token"):
-                aux = self.svc._extract_aux_data(aux_j["token"], aux_j.get("keySetId", ""))
-            if wf is not None:
-                wf.part(FRONT_AUXDATA)
-            inputs, request_id, include_meta = convert.json_to_check_inputs(body, aux)
-            if wf is not None:
-                wf.part(FRONT_CONVERT)
-            # front-door admission: classify and gate BEFORE any dispatch —
-            # a refusal costs parse + one bucket update and never reaches
-            # the batcher, the ticket ring, or a device batch
-            adm = admission_controller()
-            if adm.enabled:
-                first = inputs[0] if inputs else None
-                cls = adm.classify(
-                    first.principal.id if first is not None else "",
-                    first.principal.roles if first is not None else (),
-                    [i.resource.kind for i in inputs],
-                    api="check",
-                )
-                pclass = cls.name
-                ticket = adm.try_admit(cls)
-            if wf is not None:
-                wf.part(FRONT_ADMIT)
-            trace_ctx = parse_traceparent(request.headers.get("traceparent"))
-            access = self.svc.access_of(_SVC + "CheckResources", lambda: request.remote)
-            if getattr(self.svc.engine, "supports_async", False):
-                # front-end mode: the evaluator settles on this event loop
-                # (RemoteBatcherClient futures) — awaiting directly skips the
-                # per-request thread-pool hop entirely
-                outputs, call_id = await self.svc.check_resources_async(
-                    inputs, trace_ctx=trace_ctx, wf=wf, pclass=pclass, access=access
-                )
-            elif self.config.direct_dispatch:
-                outputs, call_id = self.svc.check_resources(
-                    inputs, trace_ctx=trace_ctx, wf=wf, pclass=pclass, access=access
-                )
-            else:
-                loop = asyncio.get_running_loop()
-                outputs, call_id = await loop.run_in_executor(
-                    None,
-                    lambda: self.svc.check_resources(
-                        inputs, trace_ctx=trace_ctx, wf=wf, pclass=pclass, access=access
-                    ),
-                )
+            jwt = (body.get("auxData") or {}).get("jwt") or {}
+            call.request_id, call.include_meta = body.get("requestId", ""), bool(body.get("includeMeta", False))
+            checkcall.front(
+                self.svc, call, violation, jwt.get("token"), jwt.get("keySetId", ""), None, _json_inputs, body
+            )
+            call.trace_ctx = parse_traceparent(request.headers.get("traceparent"))
+            call.access = self.svc.access_of(_SVC + "CheckResources", lambda: request.remote)
+            way = checkcall.way_in(self.svc.engine, self.config.direct_dispatch, can_await=True)
+            outputs, call_id = await checkcall.enter_from_loop(self.svc, call, way)
             payload = convert.outputs_to_json(
                 body,
                 outputs,
-                request_id,
-                include_meta,
+                call.request_id,
+                call.include_meta,
                 call_id,
                 provenance="X-Cerbos-TPU-Provenance" in request.headers,
             )
-            if wf is not None:
-                wf.part(BACK_ENCODE)
+            if call.wf is not None:
+                call.wf.part(BACK_ENCODE)
             resp = web.Response(body=fastjson.dumps(payload), content_type="application/json")
-            if trace_ctx is not None:
+            if call.trace_ctx is not None:
                 # echo the trace the work joined so callers can correlate
-                resp.headers["traceparent"] = trace_ctx.to_traceparent()
-            outcome = OUTCOME_ORACLE if wf is not None and wf.served_by == "oracle" else OUTCOME_MET
-            # the JSON dump is inside reply_encode here (for gRPC the bytes
-            # are made after it, by the wrapped serializer): the handler's
-            # extent ends at the same instant
-            t_done = budget_tracker().finish(
-                wf, outcome, final_stage=STAGE_REPLY_ENCODE, final_part=BACK_SERIALIZE
-            )
+                resp.headers["traceparent"] = call.trace_ctx.to_traceparent()
+            # the JSON dump is inside reply_encode here: the handler's extent
+            # ends at the same instant
+            t_done = checkcall.back(call, BACK_SERIALIZE)
             if t_done is not None:
-                budget_tracker().m_handler.observe(t_done - t_raw)
+                budget_tracker().m_handler.observe(t_done - call.t_raw)
             return resp
-        except OverloadRefused as e:
-            # 429 + Retry-After, counted as a refused decision in THIS
-            # worker; refusal latency is the ingress-to-refusal wall time
-            admission_controller().observe_refusal(time.monotonic() - t_raw)
-            budget_tracker().finish(wf, OUTCOME_REFUSED)
-            return web.json_response(
-                {"code": 8, "message": str(e)},
-                status=429,
-                headers={"Retry-After": retry_after_header(e)},
-            )
-        except RequestLimitExceeded as e:
-            budget_tracker().finish(wf, OUTCOME_REFUSED)
-            return web.json_response({"code": 3, "message": str(e)}, status=400)
-        except DeadlineExceeded as e:
-            budget_tracker().finish(wf, OUTCOME_EXPIRED)
-            return web.json_response({"code": 4, "message": str(e)}, status=504)
-        except Exception as e:  # noqa: BLE001
-            return web.json_response({"code": 13, "message": f"check failed: {e}"}, status=500)
+        except Exception as e:  # noqa: BLE001  (every row of checkcall.REFUSALS, the last one anything else)
+            row, message, headers = checkcall.refuse(call, e)
         finally:
-            if ticket is not None:
-                ticket.release()
+            call.release()
+        return web.json_response({"code": row.code, "message": message}, status=row.http, headers=headers)
 
     async def _h_check_resource_set(self, request: web.Request) -> web.Response:
         """Deprecated CheckResourceSet: one resource kind, instance map."""

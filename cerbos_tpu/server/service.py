@@ -63,6 +63,17 @@ class ServiceMetrics:
         }
 
 
+class _Checked:
+    """What ``CerbosService._checking`` hands its block: the call's id, and
+    the place for the engine's answer."""
+
+    __slots__ = ("call_id", "outputs")
+
+    def __init__(self, call_id: str):
+        self.call_id = call_id
+        self.outputs: list[T.CheckOutput] = []
+
+
 class CerbosService:
     def __init__(
         self,
@@ -101,16 +112,48 @@ class CerbosService:
         pclass: Optional[str] = None,
         access: Optional[tuple[str, str]] = None,
     ) -> tuple[list[T.CheckOutput], str]:
-        call_id = new_call_id()
-        with self._access_logged(call_id, access):
+        with self._checking(inputs, trace_ctx, wf, access) as call:
+            call.outputs = self.engine.check(inputs, params=params, deadline=deadline, wf=wf, pclass=pclass)
+        return call.outputs, call.call_id
+
+    async def check_resources_async(
+        self,
+        inputs: list[T.CheckInput],
+        params: Optional[T.EvalParams] = None,
+        deadline: Optional[float] = None,
+        trace_ctx: Optional[SpanContext] = None,
+        wf: Optional[Any] = None,
+        pclass: Optional[str] = None,
+        access: Optional[tuple[str, str]] = None,
+    ) -> tuple[list[T.CheckOutput], str]:
+        """``check_resources`` for evaluators that settle on the event loop
+        (front-end mode): the handler coroutine awaits the batcher ticket
+        directly, with no thread-pool hop per request."""
+        with self._checking(inputs, trace_ctx, wf, access) as call:
+            call.outputs = await self.engine.check_await(inputs, params=params, deadline=deadline, wf=wf, pclass=pclass)
+        return call.outputs, call.call_id
+
+    @contextlib.contextmanager
+    def _checking(
+        self,
+        inputs: list[T.CheckInput],
+        trace_ctx: Optional[SpanContext],
+        wf: Optional[Any],
+        access: Optional[tuple[str, str]],
+    ):
+        """Everything of a CheckResources call but the engine's answer, which
+        the block puts into the ``_Checked`` it is given (``outputs``): before
+        it the call id, the request limits, the span and the ``span`` part;
+        after it the validation count, ``wake``, the audit hand-off, ``audit``
+        and the service's own latency; around it the access entry."""
+        call = _Checked(new_call_id())
+        try:
             self._validate_check(inputs)
             t0 = time.perf_counter()
             # trace_ctx is the caller's W3C traceparent (gRPC metadata / HTTP
             # header); with parent=None this still roots a fresh local trace
-            with start_span(
-                "request.CheckResources", parent=trace_ctx, resources=len(inputs)
-            ) as span:
-                span.set_attribute("call_id", call_id)
+            with start_span("request.CheckResources", parent=trace_ctx, resources=len(inputs)) as span:
+                span.set_attribute("call_id", call.call_id)
                 # clear any shard/epoch affinity left by a previous request on
                 # this thread; the evaluator that resolves this request
                 # re-stamps both
@@ -120,17 +163,18 @@ class CerbosService:
                     if not wf.trace_id:
                         wf.trace_id = span.context.trace_id
                     wf.part(FRONT_SPAN)
-                outputs = self.engine.check(
-                    inputs, params=params, deadline=deadline, wf=wf, pclass=pclass
-                )
-                self._note_validation(span, outputs)
+                yield call
+                self._note_validation(span, call.outputs)
                 if wf is not None:
                     wf.part(BACK_WAKE)
-                self._audit_decision(span, call_id, inputs, outputs)
+                self._audit_decision(span, call.call_id, inputs, call.outputs)
                 if wf is not None:
                     wf.part(BACK_AUDIT)
             self.metrics.record_check((time.perf_counter() - t0) * 1000, len(inputs))
-        return outputs, call_id
+        except BaseException as e:
+            self._access_entry(call.call_id, access, error=type(e).__name__)
+            raise
+        self._access_entry(call.call_id, access)
 
     def _note_validation(self, span: Any, outputs: list[T.CheckOutput]) -> None:
         """The request's count of schema validation errors, on its span; not
@@ -167,20 +211,14 @@ class CerbosService:
             return None
         return method, peer() or ""
 
-    @contextlib.contextmanager
-    def _access_logged(self, call_id: str, access: Optional[tuple[str, str]]):
-        """One access entry for the call in the block, under the call id of
-        its decision entry, whether it is answered or raises (the entry then
-        names the error). A call refused before it reaches the service (wire
-        validation, admission, the ``shed_plan`` rung) writes none."""
-        try:
-            yield
-        except BaseException as e:
-            if access is not None:
-                self.audit_log.write_access(call_id, *access, error=type(e).__name__)
-            raise
+    def _access_entry(self, call_id: str, access: Optional[tuple[str, str]], error: str = "") -> None:
+        """One access entry for a call that reached the service, under the
+        call id of its decision entry, whether it was answered or raised (the
+        entry then names the error). A call refused before it reaches the
+        service (wire validation, admission, the ``shed_plan`` rung) writes
+        none."""
         if access is not None:
-            self.audit_log.write_access(call_id, *access)
+            self.audit_log.write_access(call_id, *access, error=error)
 
     def _validate_check(self, inputs: list[T.CheckInput]) -> None:
         if len(inputs) > self.limits.max_resources_per_request:
@@ -195,51 +233,17 @@ class CerbosService:
             if not i.actions:
                 raise RequestLimitExceeded("at least one action must be specified")
 
-    async def check_resources_async(
-        self,
-        inputs: list[T.CheckInput],
-        params: Optional[T.EvalParams] = None,
-        deadline: Optional[float] = None,
-        trace_ctx: Optional[SpanContext] = None,
-        wf: Optional[Any] = None,
-        pclass: Optional[str] = None,
-        access: Optional[tuple[str, str]] = None,
-    ) -> tuple[list[T.CheckOutput], str]:
-        """``check_resources`` for evaluators that settle on the event loop
-        (front-end mode): the handler coroutine awaits the batcher ticket
-        directly — no thread-pool hop per request."""
-        call_id = new_call_id()
-        with self._access_logged(call_id, access):
-            self._validate_check(inputs)
-            t0 = time.perf_counter()
-            with start_span(
-                "request.CheckResources", parent=trace_ctx, resources=len(inputs)
-            ) as span:
-                span.set_attribute("call_id", call_id)
-                T.set_current_shard(None)
-                T.set_current_epoch(None)
-                if wf is not None:
-                    if not wf.trace_id:
-                        wf.trace_id = span.context.trace_id
-                    wf.part(FRONT_SPAN)
-                outputs = await self.engine.check_await(
-                    inputs, params=params, deadline=deadline, wf=wf, pclass=pclass
-                )
-                self._note_validation(span, outputs)
-                if wf is not None:
-                    wf.part(BACK_WAKE)
-                self._audit_decision(span, call_id, inputs, outputs)
-                if wf is not None:
-                    wf.part(BACK_AUDIT)
-            self.metrics.record_check((time.perf_counter() - t0) * 1000, len(inputs))
-        return outputs, call_id
-
     def plan_resources(
         self, input: Any, params: Optional[T.EvalParams] = None, access: Optional[tuple[str, str]] = None
     ) -> tuple[Any, str]:
         call_id = new_call_id()
-        with self._access_logged(call_id, access):
-            return self._plan(call_id, input, params), call_id
+        try:
+            output = self._plan(call_id, input, params)
+        except BaseException as e:
+            self._access_entry(call_id, access, error=type(e).__name__)
+            raise
+        self._access_entry(call_id, access)
+        return output, call_id
 
     def _plan(self, call_id: str, input: Any, params: Optional[T.EvalParams]) -> Any:
         if self.planner is None and self.plan_batcher is None:

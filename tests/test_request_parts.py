@@ -67,19 +67,22 @@ def finished(tracker, monkeypatch):
     return seen
 
 
-def serve(tmp_path, topology, grpc_async=False):
-    """A started Server in the given topology, and what to close after."""
+def serve(tmp_path, topology, grpc_async=False, evaluator=None, limits=None):
+    """A started Server in the given topology, and what to close after.
+    ``evaluator``: the engine's, in place of the batcher over the CPU oracle;
+    ``limits``: the service's request limits."""
     rt = table()
-    batcher = BatchingEvaluator(OracleEvaluator(rt), max_wait_ms=1.0)
-    closers = [batcher.close]
-    evaluator = batcher
+    closers = []
+    if evaluator is None:
+        evaluator = batcher = BatchingEvaluator(OracleEvaluator(rt), max_wait_ms=1.0)
+        closers = [batcher.close]
     if topology == "frontend":
         ipc = BatcherIpcServer(str(tmp_path / "b.sock"), batcher)
         ipc.start()
         evaluator = RemoteBatcherClient(ipc.socket_path, rt, worker_label="fe1", status_poll_s=0.05)
         assert wait_for(evaluator._connected.is_set)
         closers = [evaluator.close, ipc.close, batcher.close]
-    svc = CerbosService(Engine(rt, tpu_evaluator=evaluator, tpu_batch_threshold=1))
+    svc = CerbosService(Engine(rt, tpu_evaluator=evaluator, tpu_batch_threshold=1), limits=limits)
     srv = Server(
         svc,
         ServerConfig(http_listen_addr="127.0.0.1:0", grpc_listen_addr="127.0.0.1:0", grpc_async=grpc_async),
@@ -88,21 +91,27 @@ def serve(tmp_path, topology, grpc_async=False):
     return srv, [srv.stop] + closers
 
 
-def send_grpc(srv):
+def grpc_request(body):
     from cerbos_tpu.api.cerbos.request.v1 import request_pb2
-    from cerbos_tpu.api.cerbos.response.v1 import response_pb2
     from cerbos_tpu.server.convert import py_to_value
 
-    req = request_pb2.CheckResourcesRequest(request_id=BODY["requestId"])
-    req.principal.id = "u1"
-    req.principal.roles.append("user")
-    for r in BODY["resources"]:
+    req = request_pb2.CheckResourcesRequest(request_id=body["requestId"])
+    req.principal.id = body["principal"]["id"]
+    req.principal.roles.extend(body["principal"]["roles"])
+    for r in body["resources"]:
         entry = req.resources.add()
-        entry.actions.append("view")
-        entry.resource.kind = "album"
+        entry.actions.extend(r["actions"])
+        entry.resource.kind = r["resource"]["kind"]
         entry.resource.id = r["resource"]["id"]
         for k, v in r["resource"]["attr"].items():
             entry.resource.attr[k].CopyFrom(py_to_value(v))
+    return req
+
+
+def send_grpc(srv):
+    from cerbos_tpu.api.cerbos.response.v1 import response_pb2
+
+    req = grpc_request(BODY)
     with grpc.insecure_channel(f"127.0.0.1:{srv.grpc_port}") as ch:
         stub = ch.unary_unary(
             "/cerbos.svc.v1.CerbosService/CheckResources",
@@ -113,14 +122,17 @@ def send_grpc(srv):
     assert len(resp.results) == 3
 
 
-def send_http(srv):
-    req = urllib.request.Request(
+def http_request(srv, body):
+    return urllib.request.Request(
         f"http://127.0.0.1:{srv.http_port}/api/check/resources",
-        data=json.dumps(BODY).encode(),
+        data=json.dumps(body).encode(),
         headers={"Content-Type": "application/json"},
         method="POST",
     )
-    with urllib.request.urlopen(req, timeout=10) as resp:
+
+
+def send_http(srv):
+    with urllib.request.urlopen(http_request(srv, BODY), timeout=10) as resp:
         assert len(json.loads(resp.read())["results"]) == 3
 
 
@@ -137,6 +149,9 @@ CASES = [
     pytest.param("grpc", "single", True, STAGE_ADMISSION, id="grpc-aio-sync"),
     pytest.param("grpc", "frontend", False, STAGE_IPC_ENCODE, id="grpc-frontend-sync"),
     pytest.param("http", "frontend", False, STAGE_IPC_ENCODE, id="http-frontend-async"),
+    # the aio listener in a front end: its handlers are synchronous and the ticket client blocks
+    # (direct_dispatch is false there), so the call hops to the executor and takes check_resources
+    pytest.param("grpc", "frontend", True, STAGE_IPC_ENCODE, id="grpc-aio-frontend"),
 ]
 
 
@@ -176,6 +191,114 @@ def test_parts_tile_admission_and_reply_encode(tmp_path, tracker, finished, surf
     # the handler's extent is the waterfall and, for gRPC, the serialization after it
     extent = wf.attributed() + (grew["serialize"][1] if surface == "grpc" else 0.0)
     assert grew["handler"][1] == pytest.approx(extent, abs=5e-6)
+
+
+class Raising:
+    """An engine's evaluator that answers every batch by raising."""
+
+    def __init__(self, exc):
+        self.exc = exc
+
+    def check(self, inputs, params=None, **kwargs):
+        raise self.exc
+
+
+def refused_grpc(srv, body):
+    """What a gRPC client sees of a refusal: its status and its words."""
+    from cerbos_tpu.api.cerbos.response.v1 import response_pb2
+
+    with grpc.insecure_channel(f"127.0.0.1:{srv.grpc_port}") as ch:
+        stub = ch.unary_unary(
+            "/cerbos.svc.v1.CerbosService/CheckResources",
+            request_serializer=lambda m: m.SerializeToString(),
+            response_deserializer=response_pb2.CheckResourcesResponse.FromString,
+        )
+        with pytest.raises(grpc.RpcError) as err:
+            stub(grpc_request(body), timeout=10)
+    return err.value.code(), err.value.details()
+
+
+def refused_http(srv, body):
+    """What an HTTP client sees of one: status, the JSON error body, Retry-After."""
+    import urllib.error
+
+    with pytest.raises(urllib.error.HTTPError) as err:
+        urllib.request.urlopen(http_request(srv, body), timeout=10)
+    return err.value.code, json.loads(err.value.read()), err.value.headers.get("Retry-After")
+
+
+TWICE = {**BODY, "resources": [{**BODY["resources"][0], "actions": ["view", "view"]}]}
+
+# the row's exception; where it is raised; then what each wire and the books must show: gRPC status, HTTP status,
+# the JSON body's code, Retry-After, the message, the decision counted, refusal latency observed, a ticket taken
+REFUSED = [
+    pytest.param("WireViolation", "wire", "INVALID_ARGUMENT", 400, 3, None,
+                 "resources[0].actions: items must be unique", "refused", False, False, id="wire-violation"),
+    pytest.param("OverloadRefused", "admission", "RESOURCE_EXHAUSTED", 429, 8, "1",
+                 "overloaded: concurrency (class 'default')", "refused", True, False, id="overload-at-admission"),
+    pytest.param("OverloadRefused", "evaluator", "RESOURCE_EXHAUSTED", 429, 8, "3",
+                 "overloaded: queue budget (class 'default')", "refused", True, True, id="overload-below"),
+    pytest.param("RequestLimitExceeded", "service", "INVALID_ARGUMENT", 400, 3, None,
+                 "number of resources exceeds the limit of 2", "refused", False, True, id="request-limit"),
+    pytest.param("DeadlineExceeded", "evaluator", "DEADLINE_EXCEEDED", 504, 4, None,
+                 "too late", "expired", False, True, id="deadline"),
+    pytest.param("Exception", "evaluator", "INTERNAL", 500, 13, None,
+                 "check failed: boom", None, False, True, id="anything-else"),
+]
+
+
+@pytest.mark.parametrize("surface", ["grpc", "http"])
+@pytest.mark.parametrize("exc,where,status,http,code,retry_after,message,outcome,timed,ticketed", REFUSED)
+def test_a_refusal_is_one_row_on_both_wires(
+    tmp_path, tracker, surface, exc, where, status, http, code, retry_after, message, outcome, timed, ticketed
+):
+    """Every row of ``checkcall.REFUSALS`` through the real listeners: what the
+    client sees on each wire, what is booked, and that the admission ticket
+    taken for the call is given back."""
+    from cerbos_tpu.engine import admission
+    from cerbos_tpu.engine.admission import OverloadRefused
+    from cerbos_tpu.engine.batcher import DeadlineExceeded
+    from cerbos_tpu.engine.budget import OUTCOMES
+    from cerbos_tpu.server import checkcall
+    from cerbos_tpu.server.service import ServiceLimits
+
+    (row,) = [r for r in checkcall.REFUSALS if r.exc.__name__ == exc]
+    assert (row.grpc.name, row.http, row.code, row.retry_after, row.outcome, row.timed) == (
+        status, http, code, retry_after is not None, outcome, timed,
+    )
+    raised = {
+        "OverloadRefused": OverloadRefused("default", "queue budget", retry_after=2.5),
+        "DeadlineExceeded": DeadlineExceeded("too late"),
+        "Exception": RuntimeError("boom"),
+    }
+    adm = admission.controller()
+    # a default class with a cap makes admission live: every call that reaches it takes a ticket
+    adm.configure({"enabled": True, "default": {"maxConcurrent": 1}})
+    held = adm.try_admit(adm.default) if where == "admission" else None  # the class's one place
+    srv, closers = serve(
+        tmp_path, "single",
+        evaluator=Raising(raised[exc]) if where == "evaluator" else None,
+        limits=ServiceLimits(max_resources_per_request=2) if where == "service" else None,
+    )
+    try:
+        decided = {o: tracker.m_decisions.get(("check", o)) for o in OUTCOMES}
+        admitted = adm.m_total.get(("default", "admitted"))
+        latencies = adm.m_refusal_seconds.count
+        body = TWICE if where == "wire" else BODY
+        if surface == "grpc":
+            assert refused_grpc(srv, body) == (getattr(grpc.StatusCode, status), message)
+        else:
+            assert refused_http(srv, body) == (http, {"code": code, "message": message}, retry_after)
+        grew = {o: tracker.m_decisions.get(("check", o)) - n for o, n in decided.items()}
+        assert grew == {o: (1 if o == outcome else 0) for o in OUTCOMES}
+        assert adm.m_refusal_seconds.count - latencies == (1 if timed else 0)
+        assert adm.m_total.get(("default", "admitted")) - admitted == (1 if ticketed else 0)
+        if held is not None:
+            held.release()
+        assert [c["inflight"] for c in adm.snapshot()["classes"]] == [0]  # and given back
+    finally:
+        for close in [*closers, lambda: adm.configure(None)]:
+            close()
 
 
 def test_parts_are_off_with_the_waterfall(tmp_path, tracker):
